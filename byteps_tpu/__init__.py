@@ -22,12 +22,8 @@ from __future__ import annotations
 
 from typing import Optional
 
-from .utils import jax_compat as _jax_compat
-
-_jax_compat.ensure()
-
-from .config import Config  # noqa: E402
-from .core.state import get_state  # noqa: E402
+from .config import Config
+from .core.state import get_state
 from .core.types import DataType, QueueType, Status
 from .ops.push_pull import push_pull, broadcast
 

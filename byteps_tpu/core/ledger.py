@@ -7,9 +7,9 @@ efficient a step is*. Three coupled pieces (docs/observability.md
 
 - **Cost-model attribution** — at train-step (re)build time the JAX
   train layer extracts per-compiled-unit FLOPs and bytes-accessed
-  estimates from XLA cost analysis (``lowered.cost_analysis()``,
-  version-tolerant: dict vs list shapes, missing keys, raising
-  backends all degrade to None instead of breaking the step) and
+  estimates from XLA cost analysis (``lowered.cost_analysis()``;
+  missing keys, NaN placeholders and raising backends all degrade to
+  None instead of breaking the step) and
   registers them here together with the plan's ideal exchange bytes
   (each gradient leaf crosses the wire once each way). ``StepProfiler``
   then prices every finished step: ``achieved_flops``, ``mfu`` against
@@ -58,10 +58,8 @@ __all__ = [
 # bf16 peak FLOP/s and HBM GB/s per device kind, matched as lowercase
 # substrings of ``device.device_kind`` LONGEST FIRST (so "v5 lite" wins
 # over "v5"). Sources: published TPU specs (docs/performance.md "Chip
-# peak table"). The CPU row is a NOMINAL anchor — absolute CPU MFU is
-# meaningless, but a stable denominator makes the per-step series
-# regression-trackable on loopback CI hosts; override with
-# BYTEPS_PEAK_FLOPS when an absolute number matters.
+# peak table"). A device that is in no row is an ERROR, not a default:
+# a utilization against an assumed peak is not a measurement.
 PEAK_TABLE: Tuple[Tuple[str, float, float], ...] = (
     ("v6 lite", 918e12, 1640.0),
     ("v6e", 918e12, 1640.0),
@@ -72,27 +70,30 @@ PEAK_TABLE: Tuple[Tuple[str, float, float], ...] = (
     ("v3", 123e12, 900.0),
     ("v2", 45e12, 700.0),
 )
-# nominal per-core CPU fp32 peak (≈3 GHz × 2×8-lane FMA) and a flat
-# host memory bandwidth — the loopback-CI denominator (see PEAK_TABLE)
+# The CPU row is a NOMINAL anchor (≈3 GHz × 2×8-lane FMA per core, a
+# flat host memory bandwidth) kept for the CPU-mesh tests, whose
+# loopback steps need a stable denominator to exercise the pricing
+# path. Source ``cpu-nominal``: a ratio against it tracks regressions
+# on one host and is NOT a model-FLOP/s utilization — nothing that
+# reports a device metric (bench.py's device phases, chip_smoke.py)
+# accepts this source.
 _CPU_FLOPS_PER_CORE = 5e10
 _CPU_BW_GBPS = 20.0
-# last-resort default when even the platform is unknown
-_DEFAULT_PEAK = (1e12, 100.0)
 
 
-def detect_peak(device_kind: str = "",
+def detect_peak(device_kind: str,
                 env=os.environ) -> Tuple[float, float, str]:
     """``(peak_flops, peak_bw_gbps, source)`` for a device kind.
 
-    ``BYTEPS_PEAK_FLOPS`` / ``BYTEPS_PEAK_BW_GBPS`` (> 0) override the
-    table per component (source ``env``); otherwise the longest
-    matching PEAK_TABLE row wins (source ``table``), then the CPU
-    nominal (source ``cpu-nominal``), then a documented default
-    (source ``default``).
+    The longest matching PEAK_TABLE row wins (source ``table``), then
+    the CPU nominal (source ``cpu-nominal``). ``BYTEPS_PEAK_FLOPS`` /
+    ``BYTEPS_PEAK_BW_GBPS`` (> 0) override per component (source
+    ``env``). A kind that matches nothing, with no override for BOTH
+    components, raises ``ValueError``: an unknown device has no peak.
     """
     kind = (device_kind or "").lower()
     flops = bw = None
-    source = "default"
+    source = None
     for pat, f, b in sorted(PEAK_TABLE, key=lambda r: -len(r[0])):
         if pat in kind:
             flops, bw, source = f, b, "table"
@@ -100,8 +101,6 @@ def detect_peak(device_kind: str = "",
     if flops is None and "cpu" in kind:
         flops = (os.cpu_count() or 1) * _CPU_FLOPS_PER_CORE
         bw, source = _CPU_BW_GBPS, "cpu-nominal"
-    if flops is None:
-        flops, bw = _DEFAULT_PEAK
     try:
         ov = float(env.get("BYTEPS_PEAK_FLOPS", "0") or "0")
     except ValueError:
@@ -114,21 +113,22 @@ def detect_peak(device_kind: str = "",
         ovb = 0.0
     if ovb > 0:
         bw = ovb
+    if flops is None or bw is None:
+        raise ValueError(
+            f"no peak known for device kind {device_kind!r}: add a "
+            f"PEAK_TABLE row (core/ledger.py) with its published source, "
+            f"or set BYTEPS_PEAK_FLOPS and BYTEPS_PEAK_BW_GBPS")
     return float(flops), float(bw), source
 
 
 def extract_cost(lowered) -> Optional[dict]:
-    """Version-tolerant XLA cost-analysis extraction: ``{"flops":…,
-    "bytes_accessed":…}`` (either key may be absent) or None when the
-    backend returns nothing usable. Handles the dict shape (jax ≥0.4.x
-    single-device), the legacy list-of-dicts shape, raising backends
-    and NaN placeholders — callers never branch on the jax version."""
+    """XLA cost-analysis extraction: ``{"flops":…, "bytes_accessed":…}``
+    (either key may be absent) or None when the backend returns nothing
+    usable — a raising backend, a non-dict result or NaN placeholders."""
     try:
         ca = lowered.cost_analysis()
     except Exception:  # noqa: BLE001 - no cost model on this backend
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     out = {}
@@ -361,13 +361,8 @@ class EfficiencyLedger:
         with self._mu:
             if self._peak is not None:
                 return self._peak
-        kind = ""
-        try:
-            import jax
-            dev = jax.devices()[0]
-            kind = getattr(dev, "device_kind", "") or dev.platform
-        except Exception:  # noqa: BLE001 - no backend: defaults apply
-            kind = ""
+        import jax
+        kind = jax.devices()[0].device_kind
         flops, bw, source = detect_peak(kind)
         if self._cfg_peak > 0:
             flops, source = self._cfg_peak, "config"
@@ -375,7 +370,7 @@ class EfficiencyLedger:
             bw = self._cfg_bw
         with self._mu:
             peak = self._peak = (flops, bw, source)
-            self._device_kind = kind or None
+            self._device_kind = kind
         return peak
 
     def peak_flops(self) -> float:

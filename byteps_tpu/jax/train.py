@@ -166,7 +166,6 @@ def make_zero_train_step(
 _COMP_POOL = None
 _EXPORT_POOL = None
 _rowsparse_warned: set = set()  # names warned about dense fallback
-_stream_build_warned: list = []  # once-only streamed-export build warning
 _chaos_nan_fired: set = set()   # BYTEPS_CHAOS_NAN_LEAF specs consumed
 
 
@@ -255,18 +254,6 @@ def _release_pool():
     return _RELEASE_POOL
 
 
-def _disable_stream(stream_state: dict, msg: str, *args) -> None:
-    """Latch the streamed-export fallback for this step closure and warn
-    once per process — shared by the build-failure, dispatch-failure and
-    taps-never-fired paths so the latch semantics cannot drift."""
-    stream_state["disabled"] = True
-    stream_state["fn"] = None
-    if not _stream_build_warned:
-        from ..utils.logging import log
-        _stream_build_warned.append(True)
-        log.warning(msg, *args)
-
-
 class _StreamRound:
     """One PS train step's streamed-export state (BYTEPS_STREAM_EXPORT).
 
@@ -288,11 +275,11 @@ class _StreamRound:
       "last layer first" is measured, not assumed;
     - publishes the waiter for the step's completion-ordered drain.
 
-    The main thread ``claim``s each eligible leaf: normally that just
-    collects the ingest's waiter; if it hasn't fired within the
-    timeout (callbacks broken at runtime), the leaf is claimed for the
-    post-jit fallback loop and a late ingest is ignored — double
-    submit is impossible by construction.
+    The main thread ``claim``s each eligible leaf, which collects the
+    ingest's waiter. A tap that has not fired long after its gradient
+    was ready means the callback path is dead: that is an error, never
+    a quiet switch to the post-jit export (the streamed export is the
+    COMPUTE/PUSH overlap this step exists for).
     """
 
     def __init__(self, tag: int, names, submit_streamed, mark_first_push,
@@ -312,11 +299,9 @@ class _StreamRound:
         self._shard_left: Dict[int, int] = {}
         self._shard_started: set = set()
         self._errors: Dict[int, BaseException] = {}
-        self._claimed: set = set()
         self._done: set = set()   # whole leaves done + (i, dev) shard fires
         self.streamed = 0
         self.shard_leaves = 0  # leaves exported as per-device shards
-        self.broken = False  # a final claim timed out: callbacks dead
         self.dead = False    # cancelled: late ingests must no-op
 
     def expect(self, i: int) -> None:
@@ -340,14 +325,14 @@ class _StreamRound:
             return
         if i in self._shard_plan:
             with self._mu:
-                if (i, dev) in self._done or i in self._claimed:
+                if (i, dev) in self._done:
                     return
                 self._done.add((i, dev))
                 self._shard_started.add(i)
             _shard_export_pool(dev).submit(self._ingest_shard, i, dev, arr)
             return
         with self._mu:
-            if i in self._done or i in self._claimed:
+            if i in self._done:
                 return
             self._done.add(i)
         try:
@@ -405,9 +390,7 @@ class _StreamRound:
         flight, which may be checking out an arena lease and allocating
         a handle, finishes BEFORE the caller's abandon/discard cleanup
         runs. Without this, a late submit after cleanup leaks a
-        permanently-busy slot and a gradient-sized handle entry (and,
-        on the dispatch-fallback path, hands a stale-pull-targeted
-        lease to the live round)."""
+        permanently-busy slot and a gradient-sized handle entry."""
         self.dead = True
         pools = [_export_pool()]
         pools.extend(_shard_export_pool(d) for d in sorted(_SHARD_POOLS))
@@ -423,19 +406,15 @@ class _StreamRound:
     def claim(self, i: int, timeout: float, final: bool):
         """Collect leaf ``i``'s waiter — a ``(finish, notifier)`` tuple
         for whole leaves, ``("shards", [(dev, waiter), ...])`` for
-        shard-planned leaves — or None when the ingest hasn't fired
-        within ``timeout``. ``final=False`` just peeks (the loop then
-        blocks on the leaf itself, surfacing a compute error promptly
-        instead of stalling here); ``final=True`` claims the leaf for
-        the synchronous fallback on timeout — a late ingest is then
-        ignored — and latches ``broken`` so the round's remaining
-        leaves skip straight to the fallback. A shard leaf whose round
-        PARTIALLY started is never claimed for fallback: some of its
-        shard keys are already on the wire, and a whole-leaf resubmit
-        would desynchronize this worker's key set from its peers' — the
-        claim blocks for the in-flight submissions instead."""
-        if self.broken:
-            timeout = 0.0
+        shard-planned leaves. ``final=False`` just peeks and returns
+        None when the ingest hasn't fired within ``timeout`` (the loop
+        then blocks on the leaf itself, surfacing a compute error
+        promptly instead of stalling here). ``final=True`` is called
+        once the leaf's gradient is READY, so its tap was issued: a tap
+        that still has not STARTED ``timeout`` seconds later raises —
+        the callback path is dead. A tap that did start (its ingest is
+        materializing or submitting; for shard leaves, some shard keys
+        are already on the wire) is waited for."""
         ev = self._events[i]
         if not ev.wait(timeout):
             if not final:
@@ -443,11 +422,15 @@ class _StreamRound:
             with self._mu:
                 started = (i in self._done
                            or i in self._shard_started)
-                if not started:
-                    self._claimed.add(i)
-                    self.broken = True
-                    return None
-            ev.wait()  # fire won the race; submission completes shortly
+            if not started:
+                raise RuntimeError(
+                    f"streamed gradient export: the tap of "
+                    f"{self._names[i]!r} did not fire within "
+                    f"{timeout:.0f}s of its gradient being ready — the "
+                    f"io_callback path is dead on this backend. Set "
+                    f"BYTEPS_STREAM_EXPORT=0 to run the post-jit export "
+                    f"deliberately.")
+            ev.wait()  # ingest in flight; its submission completes
         err = self._errors.get(i)
         if err is not None:
             raise err
@@ -456,18 +439,6 @@ class _StreamRound:
                 return ("shards",
                         sorted(self._shard_waiters[i].items()))
         return self._waiters[i]
-
-    def any_submitted(self) -> bool:
-        """True when ANY submission reached the scheduler — including a
-        PARTIAL shard round (some of a leaf's shard keys on the wire,
-        the leaf not yet counted in ``streamed``). Read after
-        ``cancel()`` (the quiesce guarantees no ingest is mid-submit):
-        the dispatch-failure handler must not retry the round when
-        anything was pushed, or the resubmitted keys would double-push
-        and positionally shift every later aggregation."""
-        with self._mu:
-            return bool(self._waiters) or any(
-                ws for ws in self._shard_waiters.values())
 
     def handles(self):
         """Handles of every streamed submission, whole-leaf and
@@ -592,9 +563,11 @@ def make_ps_train_step(
     pinned from its measured first-export ordinal
     (scheduler.production_priority): production order, not flatten
     order, decides service order. Leaves that are bucket-fused
-    (sub-BYTEPS_FUSION_BYTES), rowsparse-routed or device-compressed,
-    and builds where callbacks are unavailable, fall back cleanly to
-    the post-jit copy_to_host_async loop — numerics identical.
+    (sub-BYTEPS_FUSION_BYTES), rowsparse-routed or device-compressed
+    leave through the post-jit copy_to_host_async loop — numerics
+    identical. That split is decided by configuration alone: a tapped
+    backward that fails to build or dispatch, or whose taps never fire,
+    raises; it is never swapped for the post-jit export at run time.
 
     ``sharded_apply`` (BYTEPS_SHARDED_APPLY, default on): split the
     monolithic apply jit into per-leaf donated partial updates
@@ -666,10 +639,8 @@ def make_ps_train_step(
     # destroyed native handle with a stale worker count
     comp_state = {"registry": None, "client": None, "device": None}
     # streamed-export machinery (one compiled tapped backward, rebuilt
-    # when the gradient tree or eligibility changes; "disabled" latches
-    # a build/dispatch failure so a broken callback path costs one
-    # warning, not one attempt per step)
-    stream_state: dict = {"fn": None, "key": None, "disabled": False,
+    # when the gradient tree or eligibility changes)
+    stream_state: dict = {"fn": None, "key": None,
                           "tag": 0, "holder": {"round": None},
                           # locality-shard plan (BYTEPS_LOCAL_SHARD_EXPORT):
                           # leaf index -> sizing/names, the declared shard
@@ -1080,25 +1051,6 @@ def make_ps_train_step(
             return submit(info["names"][dev], flat, priority=pr,
                           tag="shard")
 
-        def submit_shard_fallback(i, k, flat_piece):
-            """Post-jit shard submit (drain thread): a shard-planned
-            leaf whose taps never fired — or whose whole round runs on
-            the untapped grad_fn — STILL pushes its per-shard keys, so
-            this worker's key set never diverges from peers whose taps
-            are healthy (a whole-leaf submit here would stall every
-            worker's aggregation on both key sets). One device did the
-            whole D2H (accounted to device 0); wire and import stay
-            per-shard."""
-            from ..server.client import get_or_init_ctx
-            info = stream_state["shard_info"][i]
-            ctx = get_or_init_ctx(state, info["names"][k], flat_piece)
-            pr = state.scheduler.production_priority(
-                ctx, parent=info["parent"])
-            exp_shard_ctr.inc(flat_piece.nbytes)
-            exp_dev0_ctr.inc(flat_piece.nbytes)
-            return submit(info["names"][k], flat_piece, priority=pr,
-                          tag="shard")
-
         # Bucket fusion (BYTEPS_FUSION_BYTES; the group-push cure):
         # per-key cost (scheduler admission, handle, two syscall
         # round-trips, server queue hop) is flat, so sub-threshold
@@ -1180,16 +1132,12 @@ def make_ps_train_step(
         # jit is rebuilt only when the tree/eligibility changes.
         stream_cfg = stream_export if stream_export is not None \
             else getattr(state.config, "stream_export", True)
-        stream_on = (stream_cfg and state.scheduler is not None
-                     and not stream_state["disabled"])
-        # ``stream_avail`` is the DETERMINISTIC gate (config + topology
-        # — identical on every worker); ``stream_on`` additionally
-        # folds in this process's runtime latch (broken callbacks).
-        # The locality-shard PLAN below must key off stream_avail, not
-        # stream_on: the set of PS keys a worker pushes has to be a
-        # pure function of deterministic inputs, or one worker's
-        # runtime fallback would desynchronize the key sets and stall
-        # every peer's aggregation.
+        # a DETERMINISTIC gate (config + topology — identical on every
+        # worker): the set of PS keys a worker pushes, shard subranges
+        # included, has to be a pure function of deterministic inputs,
+        # or the key sets would diverge and stall every peer's
+        # aggregation. There is no runtime fallback behind it: a tapped
+        # backward that fails to build, dispatch or fire raises.
         stream_avail = (stream_cfg and state.scheduler is not None)
         eligible: tuple = ()
         if stream_avail:
@@ -1203,7 +1151,7 @@ def make_ps_train_step(
                     continue
                 el.append(i)
             eligible = tuple(el)
-        stream_on = stream_on and bool(eligible)
+        stream_on = stream_avail and bool(eligible)
         # ---- locality-shard plan (BYTEPS_LOCAL_SHARD_EXPORT): which
         # eligible leaves reduce-scatter so each local device exports
         # only its own 1/local_size shard. Host-compressed rounds keep
@@ -1212,8 +1160,7 @@ def make_ps_train_step(
         # multi-axis and single-device meshes have no locality axis to
         # shard over, and leaves below the size/pad thresholds are not
         # worth local_size extra key round-trips. All of these gates
-        # are deterministic across workers; a leaf in the plan rides
-        # its shard keys on EVERY path, streamed or fallback.
+        # are deterministic across workers.
         shard_cfg = local_shard_export if local_shard_export is not None \
             else getattr(state.config, "local_shard_export", True)
         n_shard = 0
@@ -1243,9 +1190,7 @@ def make_ps_train_step(
             # shard declared_keys agree across workers (tap-order
             # declaration would race per-device workers); the parent
             # name is declared too, as the production-order anchor all
-            # of a leaf's shards share. This runs even when the tap
-            # build below fails or is latched off: the fallback paths
-            # still push the SHARD keys.
+            # of a leaf's shards share.
             from ..core.types import DataType
             from ..ops.push_pull import shard_layout
             info: Dict[int, dict] = {}
@@ -1277,18 +1222,9 @@ def make_ps_train_step(
             if shard_set:
                 from jax.sharding import NamedSharding
                 stream_state["nsharding"] = NamedSharding(mesh, P(axis))
-            if stream_on:
-                try:
-                    stream_state["fn"] = _build_streamed_fn(
-                        eligible, shard_set, len(names))
-                except Exception as e:  # noqa: BLE001 - clean fallback
-                    stream_on = False
-                    _disable_stream(
-                        stream_state,
-                        "streamed gradient export unavailable (%s); "
-                        "falling back to post-jit export", e)
+            stream_state["fn"] = _build_streamed_fn(
+                eligible, shard_set, len(names)) if stream_on else None
             stream_state["key"] = plan_key
-        stream_on = stream_on and stream_state["fn"] is not None
 
         # ---- sharded-apply build (cached per tree structure) ----
         sharded_cfg = sharded_apply if sharded_apply is not None \
@@ -1361,7 +1297,6 @@ def make_ps_train_step(
 
         # ---- dispatch the backward (tapped when streaming) ----
         round_obj = None
-        loss = grads = None
         if stream_on:
             stream_state["tag"] += 1
             round_obj = _StreamRound(
@@ -1375,37 +1310,21 @@ def make_ps_train_step(
             try:
                 loss, grads = stream_state["fn"](
                     jnp.int32(stream_state["tag"]), params, batch)
-            except Exception as e:  # noqa: BLE001 - compile/dispatch
-                # failure of the TAPPED build only: quiesce the export
-                # worker, clean up whatever the partial round
-                # submitted, and latch the fallback
+            except BaseException:
+                # compile/dispatch failure of the tapped backward:
+                # quiesce the export worker, clean up whatever the
+                # partial round submitted, and surface the error — never
+                # retry on the untapped jit, which would hide a device
+                # or compile fault behind a slower step (and double-push
+                # any key the partial round already put on the wire)
                 stream_state["holder"]["round"] = None
                 round_obj.cancel()
-                streamed_any = round_obj.any_submitted()
                 for h in round_obj.handles():
                     state.handles.discard(h.id)
                 for lease in leases:
                     lease.abandon()
-                del leases[:]
-                round_obj = None
-                _disable_stream(
-                    stream_state,
-                    "streamed gradient export failed at dispatch "
-                    "(%s); falling back to post-jit export", e)
-                if streamed_any:
-                    # pushes for this round are already on the wire:
-                    # resubmitting the same keys in the fallback would
-                    # double-push them — the server counts pushes
-                    # positionally per worker per key, so that would
-                    # silently shift every later round's aggregation
-                    # (the corruption class _pin_priority guards).
-                    # Fail THIS round instead; the next step runs
-                    # cleanly on the plain jit.
-                    raise
-                # nothing left the worker (e.g. pure compile failure):
-                # retry this step on the plain jit — a genuine compute
-                # error will surface there on its own terms
-        if grads is None:
+                raise
+        else:
             loss, grads = grad_fn(params, batch)
         g_leaves = jax.tree.leaves(grads)
         streamed_set = set(eligible) if round_obj is not None else set()
@@ -1428,10 +1347,6 @@ def make_ps_train_step(
         # its pull completes; when the last shard of a leaf lands, the
         # shards assemble into one P(axis)-sharded array and the
         # shard update + all-gather dispatch
-        # the PLAN decides shard-key participation — with or without a
-        # live streamed round — so every path (streamed taps, broken-tap
-        # fallback, untapped grad_fn retry) pushes the same key set as
-        # every other worker
         active_shard = stream_state["shard_info"] if shard_set else {}
         shard_parts: Dict[int, list] = {}
         shard_left: Dict[int, int] = {}
@@ -1443,8 +1358,8 @@ def make_ps_train_step(
                     # a compute error then surfaces immediately instead
                     # of stalling a long claim — and give the ingest
                     # one more beat (it fires by program end unless the
-                    # callback path is truly dead, which the final
-                    # claim latches via round.broken)
+                    # callback path is dead, which the final claim
+                    # raises)
                     w = round_obj.claim(i, timeout=5.0, final=False)
                     if w is None:
                         # ready-or-raise WITHOUT materializing: a
@@ -1453,42 +1368,16 @@ def make_ps_train_step(
                         # it when the claim then succeeds
                         jax.block_until_ready(leaf)
                         w = round_obj.claim(i, timeout=30.0, final=True)
-                    if w is not None:
-                        if (isinstance(w, tuple) and len(w) == 2
-                                and w[0] == "shards"):
-                            shard_parts[i] = [None] * active_shard[i]["n"]
-                            shard_left[i] = active_shard[i]["n"]
-                            for dev, (fin, notif) in w[1]:
-                                waiters.append((("shard", i, dev),
-                                                fin, notif))
-                        else:
-                            waiters.append((i, *w))
-                        continue
-                    # claimed for fallback: export synchronously below
-                h = np.asarray(leaf)  # ready-or-wait for THIS leaf
-                if i in active_shard:
-                    # shard-planned leaf on a fallback path (taps dead,
-                    # or the whole round on the untapped grad_fn): keep
-                    # the SHARD keys — slice the host copy into the
-                    # same padded subranges the taps would have pushed.
-                    # From the tapped program the value is already the
-                    # reduce-scattered flat (concat of shards == padded
-                    # summed flat, bitwise); from grad_fn it is the
-                    # full psum'd leaf and pads here.
-                    info = active_shard[i]
-                    flat = h.reshape(-1)
-                    total = info["n"] * info["shard_len"]
-                    if flat.size != total:
-                        flat = np.pad(flat, (0, total - flat.size))
-                    flush_bucket()
-                    shard_parts[i] = [None] * info["n"]
-                    shard_left[i] = info["n"]
-                    slen = info["shard_len"]
-                    for k in range(info["n"]):
-                        w = submit_shard_fallback(
-                            i, k, flat[k * slen:(k + 1) * slen])
-                        waiters.append((("shard", i, k), *w))
+                    if w[0] == "shards":
+                        shard_parts[i] = [None] * active_shard[i]["n"]
+                        shard_left[i] = active_shard[i]["n"]
+                        for dev, (fin, notif) in w[1]:
+                            waiters.append((("shard", i, dev),
+                                            fin, notif))
+                    else:
+                        waiters.append((i, *w))
                     continue
+                h = np.asarray(leaf)  # ready-or-wait for THIS leaf
                 exp_whole_ctr.inc(h.nbytes)
                 exp_dev0_ctr.inc(h.nbytes)
                 if _route_rowsparse(name, h, state, rowsparse_params):
@@ -1788,15 +1677,6 @@ def make_ps_train_step(
                     state.handles.discard(h.id)
             raise
         stream_state["holder"]["round"] = None
-        if round_obj is not None and round_obj.broken:
-            # taps compiled but never fired at runtime: without this
-            # latch every FUTURE step would re-pay the full claim
-            # timeouts before falling back — the once-only cost the
-            # build/dispatch handlers already guarantee
-            _disable_stream(
-                stream_state,
-                "streamed gradient export taps never fired at "
-                "runtime; falling back to post-jit export")
         state.telemetry.record_export(
             round_obj.streamed if round_obj is not None else 0,
             len(names) - (round_obj.streamed

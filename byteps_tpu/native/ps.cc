@@ -68,7 +68,17 @@
 // (GCC >= 4.9 allows intrinsics inside target("avx2"/"avx512f")
 // functions regardless of the baseline -m flags), while the
 // compile-time __AVX2__ blocks in the codec keep their old gating.
+// g++ 12 (the installed toolchain) reports its own
+// _mm512_undefined_*() self-initialisation (`__m512i __Y = __Y;`,
+// avx512fintrin.h:206) as -Wuninitialized once the intrinsic is inlined
+// at -O3 (GCC PR105593, fixed in 13): silence exactly those two
+// diagnostics for locations INSIDE the intrinsics headers, so -Werror
+// keeps its full force over this file's own code.
+#pragma GCC diagnostic push
+#pragma GCC diagnostic ignored "-Wuninitialized"
+#pragma GCC diagnostic ignored "-Wmaybe-uninitialized"
 #include <immintrin.h>
+#pragma GCC diagnostic pop
 #endif
 
 #include <algorithm>

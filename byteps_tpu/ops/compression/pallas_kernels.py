@@ -110,13 +110,6 @@ def onebit_unpack(bits: jnp.ndarray, scale: jnp.ndarray, n: int,
     return out.reshape(-1)[:n]
 
 
-def tpu_available() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
-
-
 # ------------------------------------------------------------------ #
 # counter-based RNG codecs: dithering + randomk
 #

@@ -196,11 +196,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, 128), jnp.float32),  # running max
             pltpu.VMEM((block_q, 128), jnp.float32),  # running denom
         ],
-        # jax >= 0.5 renamed TPUCompilerParams -> CompilerParams; accept
-        # both so the kernel builds against either line
-        compiler_params=getattr(
-            pltpu, "CompilerParams",
-            getattr(pltpu, "TPUCompilerParams", None))(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -1,75 +1,56 @@
-"""Compatibility shims for the span of jax releases this repo meets in
-the wild.
+"""Process-level JAX set-up shared by the entry scripts and the tests:
+pinning the virtual CPU mesh (``force_cpu``) and placing the persistent
+compilation cache (``setup_compile_cache``).
 
-The package (and its tests/examples) target the current jax surface:
-``jax.shard_map`` with ``check_vma``, and the ``jax_num_cpu_devices``
-config option for virtual CPU meshes. Older still-deployed releases
-(<= 0.4.x) spell these ``jax.experimental.shard_map.shard_map`` with
-``check_rep`` and ``--xla_force_host_platform_device_count`` in
-XLA_FLAGS. One shim module keeps every call site on the modern
-spelling instead of scattering try/excepts through the codebase.
-
-``ensure()`` is idempotent and called from ``byteps_tpu/__init__`` (so
-any import of the package fixes up the session) and from test/child
-bootstraps that touch jax before importing the package.
+The package targets the one installed JAX (0.9.0: ``jax.shard_map``,
+``jax.lax.axis_size``, ``jax.distributed.is_initialized`` and the
+``jax_num_cpu_devices`` option all exist), so nothing here shims an
+older release.
 """
 
 from __future__ import annotations
 
 import os
+import re
+from typing import Optional
+
+# <checkout>/.jax_cache (listed in .gitignore); a fixed path because the
+# cache directory is part of JAX's cache key — a directory that moves
+# (tempfile, pid, time) never hits
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def ensure() -> None:
-    """Install ``jax.shard_map`` when this jax only ships the
-    experimental spelling, translating ``check_vma`` to the old
-    ``check_rep`` knob."""
+def setup_compile_cache() -> Optional[str]:
+    """Place JAX's persistent compilation cache; returns its directory
+    (None when no cache is placed). Call before the first compile.
+
+    - ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and this
+      sets NO directory in code — the machine's owner placed the cache
+      from outside;
+    - ``JAX_PLATFORMS=cpu``: no cache. Nothing compiled for the CPU mesh
+      is worth keeping, and this jaxlib's XLA:CPU loader reports every
+      cached executable it reads back as a machine-feature mismatch;
+    - otherwise ``<checkout>/.jax_cache``."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    if os.environ.get("JAX_PLATFORMS", "").lower() == "cpu":
+        return None
     import jax
 
-    if not hasattr(jax.lax, "axis_size"):
-        def axis_size(axis_name):
-            # psum of a constant folds to the (static) mesh axis size
-            return jax.lax.psum(1, axis_name)
-
-        jax.lax.axis_size = axis_size
-
-    if not hasattr(jax.distributed, "is_initialized"):
-        def is_initialized():
-            try:
-                from jax._src.distributed import global_state
-                return global_state.client is not None
-            except Exception:  # noqa: BLE001 - internals moved: assume no
-                return False
-
-        jax.distributed.is_initialized = is_initialized
-
-    if hasattr(jax, "shard_map"):
-        return
-    import inspect
-
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _has_check_rep = "check_rep" in inspect.signature(_shard_map).parameters
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=None, **kw):
-        if check_vma is not None and _has_check_rep:
-            kw["check_rep"] = check_vma
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-    jax.shard_map = shard_map
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_CACHE_DIR)
+    return DEFAULT_CACHE_DIR
 
 
 def force_cpu(n_devices: int = 8) -> None:
-    """Pin jax to an ``n_devices``-wide virtual CPU mesh, whichever way
-    this jax spells it. Call before the first device query; sets
-    XLA_FLAGS first so a child process that has not imported jax yet
-    gets the device count even without the config option. An inherited
-    flag with a DIFFERENT count is rewritten, not kept — a pytest
-    parent's 8-device XLA_FLAGS must not override a worker child's
-    force_cpu(4) on a jax without the config option."""
-    import re
-
+    """Pin jax to an ``n_devices``-wide virtual CPU mesh. Call before the
+    first device query; sets XLA_FLAGS too so a child process that has
+    not imported jax yet inherits the device count. An inherited flag
+    with a DIFFERENT count is rewritten, not kept — a pytest parent's
+    8-device XLA_FLAGS must not override a worker child's
+    force_cpu(4)."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" in flags:
         flags = re.sub(r"--xla_force_host_platform_device_count=\d+",
@@ -83,8 +64,4 @@ def force_cpu(n_devices: int = 8) -> None:
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n_devices)
-    except AttributeError:  # pre-0.5 jax: the XLA_FLAGS path above applies
-        pass
-    ensure()
+    jax.config.update("jax_num_cpu_devices", n_devices)

@@ -72,10 +72,11 @@ else:
 PY
 
 # advisory (never fails the gate): noise-aware perf regression check of
-# the newest parsed driver artifact against the committed baseline —
-# the sample histories in ci/perf_baseline.json define the noise band
-# (ci/perf_gate.py; docs/performance.md "Perf regression gate")
-candidate=$(ls "$ROOT"/BENCH_r*.json 2>/dev/null | sort | tail -1)
+# a bench result (PERF_GATE_CANDIDATE=<json/jsonl file>) against the
+# committed baseline — the sample histories in ci/perf_baseline.json
+# define the noise band (ci/perf_gate.py; docs/performance.md "Perf
+# regression gate")
+candidate="${PERF_GATE_CANDIDATE:-}"
 echo
 if [ -n "$candidate" ]; then
   echo "=== [perf-gate] advisory: $(basename "$candidate") vs ci/perf_baseline.json"
@@ -87,7 +88,7 @@ if [ -n "$candidate" ]; then
     *) echo "[perf-gate] gate did not run (bad baseline/candidate; advisory)" ;;
   esac
 else
-  echo "=== [perf-gate] no BENCH_r*.json candidate; skipping (advisory)"
+  echo "=== [perf-gate] no PERF_GATE_CANDIDATE given; skipping (advisory)"
 fi
 
 # slow markers included: the sanitize tier IS the slow TSAN/ASAN burst

@@ -2,7 +2,7 @@
 """Noise-aware perf regression gate — the first machine check that a
 PR didn't quietly give back a measured win (PR 11's 1.9 GB/s class).
 
-Compares a candidate — a driver artifact (``BENCH_rNN.json``), a raw
+Compares a candidate — a driver artifact (``{"parsed": ...}``), a raw
 ``bench.py`` result line, or a step-ledger perf archive
 (``perf-*.jsonl``, ``BYTEPS_PERF_ARCHIVE``) — against a committed
 baseline (``ci/perf_baseline.json``) whose per-key SAMPLE LISTS carry
@@ -31,7 +31,7 @@ parent process never imports jax, and neither may this.
 
 Usage:
     python ci/perf_gate.py --baseline ci/perf_baseline.json \\
-        --candidate BENCH_r05.json [--rel-floor 0.10] [--noise-k 3.0]
+        --candidate RESULT.json [--rel-floor 0.10] [--noise-k 3.0]
 
 Exit codes: 0 = no regressions, 1 = regression(s), 2 = usage error.
 """

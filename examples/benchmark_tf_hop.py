@@ -5,8 +5,8 @@ The reference registers a native ``BytepsPushPull`` AsyncOpKernel
 without touching Python. This rebuild lowers the TF surface through
 ``tf.py_function`` (docstring divergence, byteps_tpu/tensorflow/__init__.py)
 — each comm op re-enters Python, serializing on the GIL and paying an
-eager-tensor->numpy hop. This harness puts a number on that divergence
-(round-4 verdict Next #5): a ResNet-50-shaped gradient set (~161 tensors,
+eager-tensor->numpy hop. This harness puts a number on that divergence:
+a ResNet-50-shaped gradient set (~161 tensors,
 ~25.5M params) is pushed through a loopback PS server three ways:
 
   raw       — numpy arrays straight into the core scheduler

@@ -35,6 +35,7 @@ from byteps_tpu.models import llama
 from byteps_tpu.parallel import sharding as sh
 from byteps_tpu.parallel.mesh import DP_AXIS, TP_AXIS, make_mesh
 from byteps_tpu.utils.checkpoint import Checkpointer
+from byteps_tpu.utils.jax_compat import setup_compile_cache
 
 
 def main() -> None:
@@ -71,6 +72,7 @@ def main() -> None:
             "server), so ZeRO-3 sharding would silently be undone after "
             "the first step. Use --fsdp on the GSPMD tier, or --ps.")
 
+    setup_compile_cache()
     bps.init()
     devices = jax.devices()
     dp = len(devices) // args.tp

@@ -34,18 +34,10 @@ os.environ.setdefault(
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
-# Force CPU even when the outer environment pre-imported jax against a TPU
-# platform (env vars are latched at jax import time, so config.update is the
-# only reliable override).
+# config.update as well as the env: a caller that imported jax before
+# pytest collected this file has already latched the env vars
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:  # pre-0.5 jax: XLA_FLAGS above already forced 8
-    pass
-
-from byteps_tpu.utils import jax_compat  # noqa: E402
-
-jax_compat.ensure()
+jax.config.update("jax_num_cpu_devices", 8)
 
 
 @pytest.fixture(scope="session")

@@ -1,0 +1,116 @@
+"""Ask the TPU compiler, with no chip attached, whether every Pallas
+kernel the package ships compiles at real widths.
+
+The TPU's compiler is installed beside the CPU backend and compiles for a
+chip that is described, not attached (``jax.experimental.topologies``):
+what it refuses here (a slice not aligned to the tiling, too much fast
+memory, an op Mosaic cannot lower) it would refuse on the chip, at no
+chip time. Interpret-mode tests cannot see any of that. One case per
+kernel x shape, about two seconds each; skipped where the topology
+cannot be described. Nothing runs, so results are checked elsewhere
+(tests/test_pallas*.py in interpret mode, chip_smoke.py on the chip).
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+# one BERT-large block leaf ([24, 1024, 1024] f32) — the size the codecs
+# meet on the model chip_smoke.py trains
+LEAF = 24 * 1024 * 1024
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip (device kind ``TPU v5 lite``)."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip (the next one warns), so the
+    cache is off around these cases."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _onebit_pack(sh):
+    from byteps_tpu.ops.compression.pallas_kernels import onebit_pack
+    return onebit_pack.lower(_sds((LEAF,), jnp.float32, sh), False)
+
+
+def _onebit_unpack(sh):
+    from byteps_tpu.ops.compression.pallas_kernels import (_LANES,
+                                                           _padded_rows,
+                                                           onebit_unpack)
+    words = _padded_rows(LEAF) * _LANES // 32
+    return onebit_unpack.lower(_sds((words,), jnp.uint32, sh),
+                               _sds((), jnp.float32, sh), LEAF, False)
+
+
+def _dithering(partition):
+    def lower(sh):
+        from byteps_tpu.ops.compression.pallas_kernels import \
+            dithering_levels
+        return dithering_levels.lower(
+            _sds((LEAF,), jnp.float32, sh), _sds((), jnp.float32, sh),
+            _sds((), jnp.uint32, sh), 127, partition, False)
+    return lower
+
+
+def _randomk(sh):
+    from byteps_tpu.ops.compression.pallas_kernels import randomk_indices
+    return randomk_indices.lower(_sds((), jnp.uint32, sh),
+                                 _sds((), jnp.int32, sh), LEAF // 100, False)
+
+
+def _flash(shape, hkv):
+    def lower(sh):
+        from byteps_tpu.ops.flash_attention import _flash_fwd
+        B, S, H, D = shape
+        q = _sds(shape, jnp.bfloat16, sh)
+        kv = _sds((B, S, hkv, D), jnp.bfloat16, sh)
+        return jax.jit(
+            lambda q_, k_, v_: _flash_fwd(q_, k_, v_, True, 512, 512)
+        ).lower(q, kv, kv)
+    return lower
+
+
+@pytest.mark.parametrize("lower", [
+    pytest.param(_onebit_pack, id="onebit_pack-bert_leaf"),
+    pytest.param(_onebit_unpack, id="onebit_unpack-bert_leaf"),
+    pytest.param(_dithering("linear"), id="dithering_linear-bert_leaf"),
+    pytest.param(_dithering("natural"), id="dithering_natural-bert_leaf"),
+    pytest.param(_randomk, id="randomk_indices-bert_leaf_1pct"),
+    pytest.param(_flash((2, 1024, 16, 64), 16), id="flash_fwd-mha_hd64"),
+    pytest.param(_flash((2, 1024, 6, 128), 2), id="flash_fwd-gqa_hd128"),
+])
+def test_kernel_compiles_for_v5e(v5e, lower):
+    compiled = lower(v5e).compile()
+    # the kernel itself must be in the program, not a portable rewrite
+    assert "tpu_custom_call" in compiled.as_text()

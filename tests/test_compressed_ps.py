@@ -61,7 +61,7 @@ def _two_worker_roundtrip(kwargs, x0, x1, partition_bytes=None):
     th.join(timeout=30)
     assert not th.is_alive()
     c0.close()
-    c1.close(shutdown_servers=False)
+    c1.close()
     t.join(timeout=10)
     return res["w0"], res["w1"]
 

@@ -70,7 +70,9 @@ def test_survivor_fails_fast_when_peer_dies(monkeypatch):
     assert result["outcome"] == "error"
     assert result["elapsed"] < 15, result   # ms-scale in practice, << 60s
     c0.close()
-    t.join(timeout=10)
+    # B never sent SHUTDOWN, so the (daemon) server thread stays up by
+    # design: nothing to wait for
+    t.join(timeout=0.5)
 
 
 def test_round_rearms_after_departure(monkeypatch):
@@ -116,7 +118,7 @@ def test_round_rearms_after_departure(monkeypatch):
     np.testing.assert_allclose(res["a"], 2 * x, rtol=1e-6)
     np.testing.assert_allclose(res["b"], 2 * x, rtol=1e-6)
     c0.close()
-    c1b.close(shutdown_servers=False)
+    c1b.close()
     t.join(timeout=10)
 
 

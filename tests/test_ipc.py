@@ -166,5 +166,7 @@ def test_ipc_failure_detection_still_fires():
     t.join(timeout=30)
     assert not t.is_alive() and err, "parked pull must fail fast"
     c0.close()
+    # worker 1 died without SHUTDOWN: the (daemon) server thread stays
+    # up by design, so there is nothing to wait for
     for th in threads:
-        th.join(timeout=10)
+        th.join(timeout=0.5)

@@ -3,8 +3,8 @@
 fallback, overlap-fraction math on synthetic span timelines, the
 device-kind peak table with env override, archive JSONL round-trip +
 SIGTERM flush, gate statistics (injected 20% regression on tight
-synthetic histories trips; run-to-run noise replayed from the real
-BENCH_r0x tails does not), and the loopback PS end-to-end: non-null
+synthetic histories trips; run-to-run noise replayed from the parsed
+samples of older driver rounds does not), and the loopback PS end-to-end: non-null
 ``mfu``/``overlap_frac``/``wire_efficiency`` in ``get_step_reports()``
 with the efficiency verdict in ``classify_step``."""
 
@@ -62,12 +62,12 @@ def test_peak_table_device_kinds():
     assert f == 197e12
 
 
-def test_peak_cpu_nominal_and_default():
+def test_peak_cpu_nominal():
+    """The CPU row exists for the CPU-mesh tests' pricing path only; the
+    unknown-kind error is pinned in tests/test_chip_smoke.py."""
     f, bw, src = detect_peak("cpu", env={})
     assert src == "cpu-nominal"
     assert f == (os.cpu_count() or 1) * 5e10
-    f2, bw2, src2 = detect_peak("quantum-accelerator-9000", env={})
-    assert src2 == "default" and f2 > 0 and bw2 > 0
 
 
 def test_peak_env_override_wins():
@@ -106,9 +106,11 @@ class _Lowered:
 
 
 def test_extract_cost_shapes_and_failures():
-    # legacy list-of-dicts shape
-    c = extract_cost(_Lowered([{"flops": 10.0, "bytes accessed": 4.0}]))
+    # the installed jax returns one dict, keyed "bytes accessed"
+    c = extract_cost(_Lowered({"flops": 10.0, "bytes accessed": 4.0}))
     assert c == {"flops": 10.0, "bytes_accessed": 4.0}
+    # a non-dict result (no list shape on this jax) is not a cost
+    assert extract_cost(_Lowered([{"flops": 10.0}])) is None
     # dict without usable keys -> None, not {}
     assert extract_cost(_Lowered({"transcendentals": 3.0})) is None
     # raising backend -> None
@@ -370,25 +372,32 @@ def test_gate_directionality_lower_is_better():
     assert rep["ok"] and rep["rows"][0]["verdict"] == "improvement"
 
 
-def test_gate_noise_replay_from_real_bench_tails():
-    """Run-to-run noise replayed from the REAL BENCH_r0x artifacts must
-    not trip the committed baseline: r03's dense 2.155 vs r04's 2.923
-    is a 26% historical swing, and the MAD band absorbs replaying
-    either round. A wedged round (r05, parsed null) reads as missing,
-    never as a loss."""
+def test_gate_noise_replay_from_real_bench_tails(tmp_path):
+    """Run-to-run noise replayed from the parsed samples of three older
+    driver rounds (tests/data/bench_round_samples.json; the records
+    themselves were removed in PR 21) must not trip the committed
+    baseline: one round's dense 2.155 vs the next's 2.923 is a 26%
+    historical swing, and the MAD band absorbs replaying either. A
+    round that parsed null reads as missing, never as a loss."""
     pg = _load_perf_gate()
     baseline = pg.load_baseline(
         os.path.join(REPO, "ci", "perf_baseline.json"))
-    for r in (3, 4, 5):
-        cand = pg.load_candidate(
-            os.path.join(REPO, f"BENCH_r0{r}.json"))
-        rep = pg.compare(cand, baseline)
-        assert rep["ok"], (r, rep["regressions"])
-    # r05 parsed null: every key missing, zero checked, still ok
-    rep = pg.compare(pg.load_candidate(
-        os.path.join(REPO, "BENCH_r05.json")), baseline)
-    assert rep["checked"] == 0
-    assert all(r["verdict"] == "missing" for r in rep["rows"])
+    with open(os.path.join(REPO, "tests", "data",
+                           "bench_round_samples.json")) as f:
+        rounds = json.load(f)["rounds"]
+    reports = []
+    for i, artifact in enumerate(rounds):
+        # through load_candidate, in the driver-artifact shape
+        path = tmp_path / f"round{i}.json"
+        path.write_text(json.dumps(artifact))
+        rep = pg.compare(pg.load_candidate(str(path)), baseline)
+        assert rep["ok"], (i, rep["regressions"])
+        reports.append(rep)
+    assert reports[0]["checked"] > 0 and reports[1]["checked"] > 0
+    # the null parse: every key missing, zero checked, still ok
+    assert rounds[-1]["parsed"] is None
+    assert reports[-1]["checked"] == 0
+    assert all(r["verdict"] == "missing" for r in reports[-1]["rows"])
 
 
 def test_gate_archive_candidate(tmp_path):

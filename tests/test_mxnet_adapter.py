@@ -187,7 +187,7 @@ def test_trainer_two_worker_average(mx, monkeypatch, tmp_path):
         np.testing.assert_allclose(res["grad"], mean_grad, rtol=1e-5)
         np.testing.assert_allclose(p._data[0].asnumpy(),
                                    w0 - lr * mean_grad, rtol=1e-5)
-        c1.close(shutdown_servers=False)
+        c1.close()
     finally:
         bpm.shutdown()
         server.join(timeout=10)
@@ -235,7 +235,7 @@ def test_distributed_optimizer_async_mode(mx, monkeypatch):
         th.join(timeout=60)
         assert not th.is_alive()
         np.testing.assert_allclose(w.asnumpy(), w0 - lr * g, rtol=1e-5)
-        c1.close(shutdown_servers=False)
+        c1.close()
     finally:
         bpm.shutdown()
         server.join(timeout=10)
@@ -278,7 +278,7 @@ def test_broadcast_parameters_two_workers(mx, monkeypatch):
         assert not th.is_alive()
         np.testing.assert_allclose(t.asnumpy(), vals)
         np.testing.assert_allclose(res["w1"], vals)
-        c1.close(shutdown_servers=False)
+        c1.close()
     finally:
         bpm.shutdown()
         server.join(timeout=10)

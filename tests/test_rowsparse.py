@@ -70,7 +70,7 @@ def test_two_workers_sparse_sum():
     np.testing.assert_allclose(res["w0"], want, rtol=1e-6)
     np.testing.assert_allclose(res["w1"], want, rtol=1e-6)
     c0.close()
-    c1.close(shutdown_servers=False)
+    c1.close()
     t.join(timeout=10)
 
 
@@ -129,7 +129,7 @@ def test_sparse_and_dense_pushes_mix_in_one_round():
     np.testing.assert_allclose(res["d"].reshape(rows, width), want,
                                rtol=1e-6)
     c0.close()
-    c1.close(shutdown_servers=False)
+    c1.close()
     t.join(timeout=10)
 
 

@@ -239,7 +239,7 @@ def test_two_worker_mean(monkeypatch):
         want = (x0 + x1) / 2
         np.testing.assert_allclose(out.numpy(), want, rtol=1e-5, atol=1e-6)
         np.testing.assert_allclose(res["w1"], want, rtol=1e-5, atol=1e-6)
-        c1.close(shutdown_servers=False)
+        c1.close()
     finally:
         bpt_mod.shutdown()
         server.join(timeout=10)
