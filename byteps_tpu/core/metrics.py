@@ -18,10 +18,11 @@ Three layers:
 - ``StepProfiler`` — per-train-step ``StepReport`` assembly: the PS
   train step opens a report, the scheduler's stage pool threads feed
   per-task stage samples into it, and ``end_step`` closes it into a
-  ring buffer of the last N reports, runs the straggler/stall detector
-  (one-line per-step diagnosis under ``BYTEPS_STALL_DIAG=1``) and
-  mirrors aggregate counters into the Chrome-trace ``Tracer`` as
-  counter events so Perfetto shows queue depth alongside spans.
+  ring buffer of the last N reports and runs the straggler/stall
+  detector (one-line per-step diagnosis under ``BYTEPS_STALL_DIAG=1``).
+  The program's spans (``utils/tracing.py span``) land in the open
+  builder too, and ``end_step`` reduces the export path's into the
+  report's ``dispatch_ms`` and ``export_*`` fields.
 - exposition — ``bps.get_metrics()`` structured snapshot, plus an
   opt-in stdlib-only Prometheus text endpoint
   (``BYTEPS_METRICS_PORT``, default off).
@@ -45,6 +46,8 @@ import dataclasses
 import threading
 import time
 from typing import Callable, Dict, List, Optional
+
+from ..utils import tracing
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
@@ -390,6 +393,25 @@ class StepReport:
     carry_drain_ms: Optional[float] = None
     staleness_lag: Optional[int] = None
     window_depth: Optional[int] = None
+    # The export path's own spans (utils/tracing.py span, reduced by
+    # export_span_fields below): where compute_ms goes between the
+    # backward's dispatch and the last leaf's submission. dispatch_ms =
+    # the backward jit's call on the train thread; export_tap_span_ms =
+    # first streamed tap's start to the last one's end on XLA's
+    # callback threads (how long the runtime took to hand the leaves
+    # over); export_router_busy_ms = the busiest export thread's time
+    # inside ingests (the router on one chip, a per-device worker under
+    # BYTEPS_LOCAL_SHARD_EXPORT), of which export_materialize_ms is
+    # np.asarray and export_submit_ms the scheduler submission;
+    # export_router_wait_max_ms = the longest any tap's leaf sat queued
+    # before its ingest began. All None when no leaf streamed this step
+    # — never a silent 0.
+    dispatch_ms: Optional[float] = None
+    export_tap_span_ms: Optional[float] = None
+    export_router_busy_ms: Optional[float] = None
+    export_materialize_ms: Optional[float] = None
+    export_submit_ms: Optional[float] = None
+    export_router_wait_max_ms: Optional[float] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -536,6 +558,49 @@ def classify_step(r: StepReport) -> str:
     return msg
 
 
+def export_span_fields(spans: List[tuple],
+                       round_tag: Optional[int]) -> dict:
+    """Reduce one step's spans — ``(stage, thread, start, end, args)``
+    on perf_counter, as ``span`` appended them — to the StepReport's
+    export fields. Only this round's streamed leaves count: the spans
+    whose ``step`` is ``round_tag``, and of the taps those that caused
+    an ingest (a duplicate fire from another mesh device caused none).
+    No ingest: ``{}``, so every field stays None."""
+    mine = [sp for sp in spans if sp[4].get("step") == round_tag]
+    ingests = [sp for sp in mine if sp[0] == tracing.EXPORT_INGEST]
+    if not ingests:
+        return {}
+    # busiest export thread: the router's routing of shard fires is its
+    # work too; materialize and submit are shares of an ingest
+    busy: Dict[str, float] = {}
+    for stage, thread, t0, t1, _ in mine:
+        if stage in (tracing.EXPORT_INGEST, tracing.EXPORT_ROUTE):
+            busy[thread] = busy.get(thread, 0.0) + (t1 - t0)
+    busiest = max(busy, key=busy.get)
+
+    def on_busiest(stage: str) -> float:
+        return sum(t1 - t0 for st, th, t0, t1, _ in mine
+                   if st == stage and th == busiest) * 1e3
+
+    out = {
+        "export_router_busy_ms": busy[busiest] * 1e3,
+        "export_materialize_ms": on_busiest(tracing.EXPORT_MATERIALIZE),
+        "export_submit_ms": on_busiest(tracing.EXPORT_SUBMIT),
+        "export_router_wait_max_ms": max(
+            sp[4].get("queued_us", 0.0) for sp in ingests) / 1e3,
+    }
+    causes = {sp[4].get("cause") for sp in ingests}
+    taps = [sp for sp in mine if sp[0] == tracing.EXPORT_TAP
+            and f"tap:{sp[4].get('seq')}" in causes]
+    if taps:
+        out["export_tap_span_ms"] = (max(sp[3] for sp in taps)
+                                     - min(sp[2] for sp in taps)) * 1e3
+    for sp in mine:
+        if sp[0] == tracing.STEP_DISPATCH:
+            out["dispatch_ms"] = (sp[3] - sp[2]) * 1e3
+    return out
+
+
 class _StepBuilder:
     """Mutable collection state for one in-flight step. Scheduler pool
     threads append stage samples concurrently with the train thread's
@@ -544,7 +609,8 @@ class _StepBuilder:
 
     __slots__ = ("step", "t0", "_mu", "stage_samples", "queue_peak",
                  "credit_stalls", "marks", "pull_wait_s", "fleet_base",
-                 "wire_spans", "wire_base", "monolithic", "lane_base")
+                 "wire_spans", "wire_base", "monolithic", "lane_base",
+                 "spans", "round_tag")
 
     def __init__(self, step: int):
         self.step = step
@@ -576,6 +642,14 @@ class _StepBuilder:
         # scheduler's completion callbacks — the ledger's overlap
         # timeline (core/ledger.py overlap_fraction)
         self.wire_spans: List[tuple] = []                # guarded-by: _mu
+        # every program span that ENDED while this step was open
+        # (utils/tracing.py span): (stage, thread, start, end, args) on
+        # perf_counter, from whichever thread ran it
+        self.spans: List[tuple] = []                     # guarded-by: _mu
+        # the streamed-export round's tag (train thread, set by
+        # jax/train.py before the backward is dispatched): the ``step``
+        # argument of this step's spans
+        self.round_tag: Optional[int] = None
         self.marks: Dict[str, float] = {}
         self.pull_wait_s = 0.0
 
@@ -588,6 +662,11 @@ class _StepBuilder:
         relative to step start for the ledger's overlap accounting."""
         with self._mu:
             self.wire_spans.append((start - self.t0, end - self.t0))
+
+    def add_span(self, stage: str, thread: str, start: float, end: float,
+                 args: dict) -> None:
+        with self._mu:
+            self.spans.append((stage, thread, start, end, args))
 
     def queue_depth(self, depth: int) -> None:
         with self._mu:
@@ -615,12 +694,11 @@ class StepProfiler:
     belong to no step's critical path."""
 
     def __init__(self, window: int = 64, enabled: bool = True,
-                 stall_diag: bool = False, tracer=None,
+                 stall_diag: bool = False,
                  fleet_probe=None, ledger=None, lane_probe=None):
         import collections
         self.enabled = enabled
         self.stall_diag = stall_diag
-        self._tracer = tracer
         # step efficiency ledger (core/ledger.py): prices each finished
         # step (MFU/roofline/overlap/wire-efficiency) from its
         # registered cost model + the wire spans/byte deltas this
@@ -649,6 +727,8 @@ class StepProfiler:
         self._reports = collections.deque(maxlen=max(1, window))  # guarded-by: _mu
         self._current: Optional[_StepBuilder] = None  # guarded-by: _mu
         self._step_no = 0                             # guarded-by: _mu
+        # the newest finished step's spans, as its builder held them
+        self._last_spans: List[tuple] = []            # guarded-by: _mu
         # step-boundary observers (the autoscaler plane's sensor tap):
         # called with each finished StepReport ON THE TRAIN THREAD at
         # end_step, after the report is in the ring — the one place a
@@ -757,6 +837,8 @@ class StepProfiler:
         with b._mu:
             samples = {k: list(v) for k, v in b.stage_samples.items()}
             queue_peak, stalls = b.queue_peak, b.credit_stalls
+            prog_spans = list(b.spans)
+        exp = export_span_fields(prog_spans, b.round_tag)
         # server attribution: delta the fleet's per-stage counters over
         # the step (ns -> ms); pull_total is the comparable worker-side
         # sum (each PULL sample is one partition's submit→completion)
@@ -838,9 +920,11 @@ class StepProfiler:
             carry_drain_ms=(xb or {}).get("carry_drain_ms"),
             staleness_lag=(xb or {}).get("staleness_lag"),
             window_depth=(xb or {}).get("window_depth"),
+            **exp,  # dispatch_ms and the export_* fields, or none
         )
         with self._mu:
             self._reports.append(r)
+            self._last_spans = prog_spans
             if self._current is b:
                 self._current = None
             observers = list(self._observers)
@@ -854,18 +938,6 @@ class StepProfiler:
             from ..utils.logging import log
             log.info("step %d [%.1fms] %s", r.step, r.wall_ms,
                      classify_step(r))
-        if self._tracer is not None:
-            # aggregate counters as Chrome-trace counter events: queue
-            # depth / stage p95s render as tracks alongside the spans in
-            # Perfetto (docs/timeline.md)
-            self._tracer.counter("bps:queue_depth_peak",
-                                 {"depth": r.queue_depth_peak})
-            self._tracer.counter("bps:step_ms", {
-                "wall": round(r.wall_ms, 3),
-                "compute": round(r.compute_ms, 3),
-                "pull_p95": round(r.pull_p95_ms or 0.0, 3),
-                "push_p95": round(r.push_p95_ms or 0.0, 3),
-            })
         return r
 
     def add_observer(self, fn) -> None:
@@ -881,6 +953,12 @@ class StepProfiler:
     def last(self) -> Optional[StepReport]:
         with self._mu:
             return self._reports[-1] if self._reports else None
+
+    def last_spans(self) -> List[tuple]:
+        """The newest finished step's program spans: ``(stage, thread,
+        start, end, args)`` on perf_counter, in the order they ended."""
+        with self._mu:
+            return list(self._last_spans)
 
     def snapshot(self) -> dict:
         with self._mu:

@@ -30,6 +30,7 @@ earlier-declared (front-of-model) tensors win ties in the backward flush
 
 from __future__ import annotations
 
+import ctypes
 import heapq
 import itertools
 import threading
@@ -38,6 +39,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
+from ..utils import tracing
 from ..utils.logging import log
 from .types import Partition, TensorContext, trunc_divide_inplace
 
@@ -134,11 +136,12 @@ class ScheduledQueue:
                     if prof is not None:
                         prof.credit_stall()
                 self._cv.wait(timeout=0.1)
+        task.admit_t = time.perf_counter()
         if self._admit_hist is not None:
             self._depth_gauge.set(depth)
             if task.enqueue_t is not None:
                 self._admit_hist.record_seconds(
-                    time.perf_counter() - task.enqueue_t)
+                    task.admit_t - task.enqueue_t)
         return task
 
     def _pop_admissible_locked(self) -> Optional["PartitionTask"]:
@@ -224,7 +227,7 @@ class PartitionTask:
     __slots__ = ("ctx", "partition", "priority", "version", "in_view",
                  "out_view", "group", "cmd", "stack", "step", "wire",
                  "cmd_pull", "pull_len", "push_len", "lease", "enqueue_t",
-                 "round_no", "attempt", "codec")
+                 "admit_t", "round_no", "attempt", "codec")
 
     def __init__(self, ctx, partition, priority, version, in_view, out_view,
                  group, cmd, stack=None, step=0, wire=None, cmd_pull=None,
@@ -245,6 +248,7 @@ class PartitionTask:
         self.push_len = None       # actual pushed bytes (set by _do_push)
         self.lease = None          # arena lease for reply scratch (if any)
         self.enqueue_t = None      # admission-wait clock (metrics)
+        self.admit_t = None        # when the queue admitted it
         self.round_no = 0          # per-key submission ordinal (epoch stamp)
         self.attempt = 0           # wire retries of this round so far
         # adaptive-codec wire tag (plan_epoch << 8 | codec_id): the
@@ -451,7 +455,7 @@ class PipelineScheduler:
     """
 
     def __init__(self, client, num_threads: int = 8,
-                 credit_bytes: int = 0, tracer=None, telemetry=None,
+                 credit_bytes: int = 0, telemetry=None,
                  config=None, arena=None, metrics=None, profiler=None,
                  registry=None):
         import concurrent.futures
@@ -492,7 +496,6 @@ class PipelineScheduler:
         self.xb_window = xb_window  # read by the train step's carry gate
         self._queue = ScheduledQueue(credit_bytes, metrics=metrics,
                                      profiler=profiler, window=xb_window)
-        self._tracer = tracer
         self._telemetry = telemetry
         self._config = config
         # measurement plane (core/metrics.py): per-(stage, key-class)
@@ -731,6 +734,14 @@ class PipelineScheduler:
         return f"{stage}.{task.partition.index}"
 
     @staticmethod
+    def _trace_span(stage: str, task, **args) -> "tracing.span":
+        """One scheduler stage of one partition as a program span
+        (utils/tracing.py): the stage is the span's fixed name, the
+        partition its arguments, the tensor its Chrome-trace row."""
+        return tracing.span(stage, tid=task.ctx.name, key=task.key,
+                            part=task.partition.index, **args)
+
+    @staticmethod
     def _key_class(task) -> str:
         """Traffic class for per-class stage metrics: "compressed" rides
         the host codec stages, "wire" is a prebuilt payload (device-
@@ -942,10 +953,7 @@ class PipelineScheduler:
             "survivors, re-routing in-flight retries", srv, len(migrated))
 
     def _do_compress(self, task: PartitionTask) -> None:
-        name = task.ctx.name
-        span = self._span(task, "COMPRESS")
-        if self._tracer:
-            self._tracer.begin(name, span)
+        sp = self._trace_span(tracing.CODEC_COMPRESS, task).start()
         t0 = time.perf_counter()
         try:
             from ..server.compressed import compress_partition
@@ -955,8 +963,7 @@ class PipelineScheduler:
             self._finish(task, e)
             return
         finally:
-            if self._tracer:  # end in finally: no dangling span on error
-                self._tracer.end(name, span)
+            sp.stop()  # in finally: no dangling span on error
             self._stage_done(task, "COMPRESS", t0)
         if self._fused:
             self._submit_stage(self._push_pool, self._do_wire, task)
@@ -972,12 +979,28 @@ class PipelineScheduler:
         continuation (DECOMPRESS/finish). Stage accounting moves onto
         completion timestamps: the PUSH sample is the send wall, the
         PULL sample is submit→completion (exactly what the blocking
-        pull used to measure: wire + server aggregation wait)."""
+        pull used to measure: wire + server aggregation wait).
+
+        On the trace the exchange is TWO same-thread spans that share
+        the request's wire ``rid``: ``bps.wire.send`` here (up to the
+        native send returning) and ``bps.wire.done`` around the
+        completion on the reactor; a reader pairs them, and what lies
+        between is the request's time in flight."""
+        wait = (task.admit_t - task.enqueue_t) * 1e6 \
+            if task.admit_t is not None and task.enqueue_t is not None \
+            else 0.0
+        with self._trace_span(
+                tracing.WIRE_SEND, task, admit_wait_us=round(wait, 1),
+                cause=f"submit:{task.ctx.declared_key}") as sp:
+            self._wire_send(task, sp)
+
+    def _wire_send(self, task: PartitionTask, sp: "tracing.span") -> None:
         name = task.ctx.name
         span = self._span(task, "PUSHPULL")
         try:
             buf = task.wire if task.wire is not None else task.in_view
             task.push_len = len(buf)  # actual bytes (varint wires vary)
+            sp.set(bytes=task.push_len)
             if (self._config is not None and task.stack is None
                     and task.in_view is not None):
                 from ..utils.logging import debug_sample
@@ -1003,15 +1026,11 @@ class PipelineScheduler:
         # reply must fail, not leave the output tail unwritten; wire
         # (device-compressed) and codec replies are variable-length
         exact = task.stack is None and task.pull_len is None
-        span_token = None
-        if self._tracer:
-            # end() runs on the reactor thread: skip the per-thread
-            # profiler-annotation mirror, keep the Chrome-trace span.
-            # The token pins the later rid annotation to THIS span
-            # incarnation (a fast reply can close it, and the next
-            # round can even reopen the key, before we annotate).
-            span_token = self._tracer.begin(name, span,
-                                            cross_thread=True)
+        # the request's wire rid, written by the native send BEFORE the
+        # request is on the wire: on a loopback fleet the reply can
+        # complete (and the reactor run on_done) before the send has
+        # even returned here, and the completion's span still reads it
+        rid_cell = ctypes.c_uint32(0)
         t0 = time.perf_counter()
 
         def _complete_dense(t: PartitionTask) -> None:
@@ -1030,8 +1049,11 @@ class PipelineScheduler:
             self._finish(t, None)
 
         def on_done(got: int, err) -> None:
-            if self._tracer:
-                self._tracer.end(name, span)
+            with self._trace_span(tracing.WIRE_DONE, task,
+                                  rid=rid_cell.value):
+                _complete(got, err)
+
+        def _complete(got: int, err) -> None:
             self._stage_done(task, "PULL", t0)
             if err is None and exact and got != len(reply):
                 err = RuntimeError(
@@ -1051,38 +1073,32 @@ class PipelineScheduler:
                 return
             self._submit_stage(self._pull_pool, _complete_dense, task)
 
+        # a client without the rid_out, codec and/or epoch kwargs (fake
+        # test clients, stale builds) degrades one kwarg at a time: no
+        # cell means the completion's span reads rid 0, an untagged
+        # push just skips server validation, an unstamped one falls
+        # back to positional counting
+        kwargs = {"epoch": task.epoch, "codec": task.codec,
+                  "rid_out": rid_cell}
         try:
-            try:
-                rid = self._client.zpushpull_async(
-                    task.partition.server, task.key, buf, reply, task.cmd,
-                    on_done, epoch=task.epoch, codec=task.codec)
-            except TypeError:
-                # client without the codec and/or epoch kwargs (fake
-                # test clients, stale builds): degrade one kwarg at a
-                # time — an untagged push just skips server validation,
-                # an unstamped one falls back to positional counting
+            for without in (None, "rid_out", "codec", "epoch"):
+                kwargs.pop(without, None)
                 try:
                     rid = self._client.zpushpull_async(
                         task.partition.server, task.key, buf, reply,
-                        task.cmd, on_done, epoch=task.epoch)
+                        task.cmd, on_done, **kwargs)
+                    break
                 except TypeError:
-                    rid = self._client.zpushpull_async(
-                        task.partition.server, task.key, buf, reply,
-                        task.cmd, on_done)
+                    if not kwargs:
+                        raise
         except Exception as e:  # noqa: BLE001
-            if self._tracer:
-                self._tracer.end(name, span)
             self._fail_or_retry(task, e)
             return
-        if self._tracer and span_token and isinstance(rid, int) and rid:
-            # the native send reported this request's wire rid: stamp
-            # it onto this round's span (open, or just closed by a fast
-            # reply — the token guarantees never a LATER round's span)
-            # — the id server-side trace spans carry, which the fused
-            # timeline flow-links on (docs/timeline.md). Fake/stale
-            # clients report none.
-            self._tracer.annotate(name, span, token=span_token, rid=rid,
-                                  server=task.partition.server)
+        # the id server-side trace spans carry, which the fused
+        # timeline flow-links on (docs/timeline.md). Fake/stale clients
+        # report none.
+        sp.set(rid=rid if isinstance(rid, int) else 0,
+               server=task.partition.server)
         # send wall only — the request is on the wire and this thread is
         # free; the aggregation wait shows up in the PULL sample above
         self._stage_done(task, "PUSH", t0)
@@ -1101,8 +1117,8 @@ class PipelineScheduler:
         except Exception as e:  # noqa: BLE001
             self._finish(task, e)
             return
-        if self._tracer:
-            self._tracer.begin(name, span)
+        sp = self._trace_span(tracing.WIRE_PUSH, task,
+                              bytes=task.push_len).start()
         t0 = time.perf_counter()
         try:
             # async push: the payload hits the wire and the stage ends —
@@ -1128,16 +1144,14 @@ class PipelineScheduler:
             self._fail_or_retry(task, e)
             return
         finally:
-            if self._tracer:
-                self._tracer.end(name, span)
+            sp.stop()
             self._stage_done(task, "PUSH", t0)
         self._submit_stage(self._pull_pool, self._do_pull, task)
 
     def _do_pull(self, task: PartitionTask) -> None:
         name = task.ctx.name
         span = self._span(task, "PULL")
-        if self._tracer:
-            self._tracer.begin(name, span)
+        sp = self._trace_span(tracing.WIRE_PULL, task).start()
         t0 = time.perf_counter()
         try:
             if task.stack is not None:
@@ -1169,8 +1183,7 @@ class PipelineScheduler:
             self._fail_or_retry(task, e)
             return
         finally:
-            if self._tracer:
-                self._tracer.end(name, span)
+            sp.stop()
             self._stage_done(task, "PULL", t0)
         if (task.stack is None and task.pull_len is None
                 and self._config is not None):
@@ -1190,10 +1203,7 @@ class PipelineScheduler:
             self._finish(task, None)
 
     def _do_decompress(self, task: PartitionTask) -> None:
-        name = task.ctx.name
-        span = self._span(task, "DECOMPRESS")
-        if self._tracer:
-            self._tracer.begin(name, span)
+        sp = self._trace_span(tracing.CODEC_DECOMPRESS, task).start()
         t0 = time.perf_counter()
         try:
             from ..server.compressed import decompress_partition
@@ -1202,8 +1212,7 @@ class PipelineScheduler:
             self._finish(task, e)
             return
         finally:
-            if self._tracer:
-                self._tracer.end(name, span)
+            sp.stop()
             self._stage_done(task, "DECOMPRESS", t0)
         self._finish(task, None)
 

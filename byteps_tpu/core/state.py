@@ -339,21 +339,18 @@ class GlobalState:
                 devices = jax.local_devices() if local_only else None
                 self.mesh = mesh_lib.make_mesh(
                     self.config.parsed_mesh() or None, devices)
-            if ((self.config.trace_on or self.config.jax_profiler_dir)
-                    and self.tracer is None):
-                # profiler-only mode still needs the Tracer: it carries
-                # the comm spans into the device trace as annotations
-                # (Chrome-trace events stay gated on trace_on's window)
+            if self.config.trace_on and self.tracer is None:
+                # the Chrome comm.json half only: the program's spans
+                # reach any open profiler session without it
+                # (utils/tracing.py span)
                 from ..utils.tracing import Tracer
                 self.tracer = Tracer(self.config)
             # per-step pipeline profiler rides the same lifecycle as the
-            # registry; the tracer reference mirrors aggregate counters
-            # into the Chrome trace as counter events
+            # registry
             self.profiler = StepProfiler(
                 window=self.config.step_report_window,
                 enabled=self.config.metrics_on,
                 stall_diag=self.config.stall_diag,
-                tracer=self.tracer,
                 fleet_probe=self._fleet_stage_probe,
                 lane_probe=self._lane_probe,
                 ledger=self.ledger)
@@ -383,8 +380,8 @@ class GlobalState:
                     self._collect_server_traces)
             if self.config.jax_profiler_dir and not self._jax_profiling:
                 # device (XLA) trace for TensorBoard/Perfetto alongside
-                # the Chrome comm timeline (SURVEY §5.1 TPU note); host
-                # comm spans appear inside it as TraceAnnotations
+                # the Chrome comm timeline (SURVEY §5.1 TPU note); the
+                # program's spans are in it as in any other session
                 try:
                     jax.profiler.start_trace(self.config.jax_profiler_dir)
                     self._jax_profiling = True
@@ -417,7 +414,7 @@ class GlobalState:
                 self.scheduler = PipelineScheduler(
                     self.ps_client,
                     credit_bytes=self.config.scheduling_credit,
-                    tracer=self.tracer, telemetry=self.telemetry,
+                    telemetry=self.telemetry,
                     config=self.config, arena=self.arena,
                     metrics=self.metrics, profiler=self.profiler,
                     registry=self.registry)

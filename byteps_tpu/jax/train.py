@@ -20,8 +20,10 @@ Two flavors:
 from __future__ import annotations
 
 import functools
+import itertools
 import os
 import threading
+import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -32,6 +34,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.push_pull import psum_tree, reduce_scatter_tree, all_gather_tree
 from ..parallel.mesh import DP_AXIS
+from ..utils import tracing
 
 
 def make_train_step(
@@ -165,6 +168,10 @@ def make_zero_train_step(
 
 _COMP_POOL = None
 _EXPORT_POOL = None
+# process-wide tap ordinal: a tap's identity on the trace (its ingest
+# names it as its cause) — the callback thread may not resolve the
+# step or device scalars it was handed (see _export_pool)
+_TAP_SEQ = itertools.count(1)
 _rowsparse_warned: set = set()  # names warned about dense fallback
 _chaos_nan_fired: set = set()   # BYTEPS_CHAOS_NAN_LEAF specs consumed
 
@@ -286,7 +293,7 @@ class _StreamRound:
                  shard_plan: Optional[dict] = None, submit_shard=None):
         self.tag = tag
         self._names = names
-        self._submit = submit_streamed  # (name, flat) -> (finish, notifier)
+        self._submit = submit_streamed  # (i, flat) -> (finish, notifier)
         self._submit_shard = submit_shard  # (i, dev, flat) -> waiter
         self._mark = mark_first_push
         # leaf index -> num shards expected (BYTEPS_LOCAL_SHARD_EXPORT);
@@ -311,53 +318,93 @@ class _StreamRound:
             self._shard_left[i] = n
             self._shard_waiters[i] = {}
 
-    def on_fire(self, i: int, step_no: int, dev: int, arr) -> None:
-        """One tap fire — runs on the export ROUTER; must never raise.
-        Whole leaves dedup per leaf (every device fires the identical
-        post-psum value; first wins) and materialize inline. Shard
-        leaves dedup per (leaf, device) — every device's fire carries a
-        DIFFERENT shard — and hand the materialization to that device's
-        own worker so the shards export in parallel."""
-        if self.dead or step_no != self.tag:
-            return  # cancelled round / stale fire from an earlier round
-        ev = self._events.get(i)
-        if ev is None:
-            return
-        if i in self._shard_plan:
-            with self._mu:
-                if (i, dev) in self._done:
-                    return
-                self._done.add((i, dev))
-                self._shard_started.add(i)
-            _shard_export_pool(dev).submit(self._ingest_shard, i, dev, arr)
-            return
-        with self._mu:
-            if i in self._done:
-                return
-            self._done.add(i)
-        try:
-            host = np.asarray(arr)  # materialize off the device threads
-            if self.dead:  # cancelled while materializing: no submit
-                return
-            self._mark()
-            w = self._submit(self._names[i], host.reshape(-1))
-            with self._mu:
-                self._waiters[i] = w
-            self.streamed += 1
-        except BaseException as e:  # noqa: BLE001 - surfaced via claim()
-            self._errors[i] = e
-        finally:
-            ev.set()
+    def on_fire(self, i: int, step_arr, dev_arr, arr, seq: int,
+                t_enq: float) -> None:
+        """One tap fire — runs on the export ROUTER. Whole leaves dedup
+        per leaf (every device fires the identical post-psum value;
+        first wins) and materialize inline. Shard leaves dedup per
+        (leaf, device) — every device's fire carries a DIFFERENT shard
+        — and hand the materialization to that device's own worker so
+        the shards export in parallel.
 
-    def _ingest_shard(self, i: int, dev: int, arr) -> None:
+        The fire is one program span on this thread, from here to the
+        leaf's submission (``bps.export.ingest``; ``bps.export.route``
+        for a shard fire, whose ingest runs on the device's worker): it
+        names the tap that caused it and how long the fire sat queued
+        behind the router's earlier work. A stale or duplicate fire is
+        nobody's work and drops out of the step's accounting."""
+        shard = i in self._shard_plan
+        sp = tracing.span(
+            tracing.EXPORT_ROUTE if shard else tracing.EXPORT_INGEST,
+            tid=self._names[i], step=self.tag, leaf=i,
+            bytes=arr.size * arr.dtype.itemsize, cause=f"tap:{seq}",
+            queued_us=round((time.perf_counter() - t_enq) * 1e6, 1)
+        ).start()
+        done_ev = None  # set AFTER the span closed: claim() may then
+        try:            # close the step, and the span must be in it
+            # int() materializes only the two scalars — the heavy
+            # payload is materialized below (whole leaves) or by the
+            # device's own worker (shards)
+            step_no, dev = int(step_arr), int(dev_arr)
+            sp.set(dev=dev)
+            ev = self._events.get(i)
+            mark = (i, dev) if shard else i
+            with self._mu:
+                # cancelled round / stale fire from an earlier round /
+                # another device's duplicate
+                fresh = not (self.dead or step_no != self.tag
+                             or ev is None or mark in self._done)
+                if fresh:
+                    self._done.add(mark)
+                    if shard:
+                        self._shard_started.add(i)
+            if not fresh:
+                sp.drop()
+                return
+            if shard:
+                _shard_export_pool(dev).submit(
+                    self._ingest_shard, i, dev, arr, seq, t_enq)
+                return
+            done_ev = ev
+            try:
+                with tracing.span(tracing.EXPORT_MATERIALIZE,
+                                  step=self.tag, leaf=i,
+                                  bytes=sp.args["bytes"]):
+                    # materialize off the device threads
+                    host = np.asarray(arr)
+                if self.dead:  # cancelled while materializing: no submit
+                    return
+                self._mark()
+                w = self._submit(i, host.reshape(-1))
+                with self._mu:
+                    self._waiters[i] = w
+                self.streamed += 1
+            except BaseException as e:  # noqa: BLE001 - surfaced via
+                self._errors[i] = e     # claim()
+        finally:
+            sp.stop()
+            if done_ev is not None:
+                done_ev.set()
+
+    def _ingest_shard(self, i: int, dev: int, arr, seq: int,
+                      t_enq: float) -> None:
         """Device ``dev``'s shard of leaf ``i`` — runs on that device's
         export worker; free to block on XLA, must never raise. The
         leaf's event fires when its LAST shard submission lands, so
         ``claim`` sees either the complete per-shard waiter set or an
         error."""
         ev = self._events.get(i)
+        nbytes = arr.size * arr.dtype.itemsize
+        sp = tracing.span(
+            tracing.EXPORT_INGEST, tid=self._names[i], step=self.tag,
+            leaf=i, dev=dev, bytes=nbytes, cause=f"tap:{seq}",
+            queued_us=round((time.perf_counter() - t_enq) * 1e6, 1)
+        ).start()
+        fire = False
         try:
-            host = np.asarray(arr)  # materialize this device's shard
+            with tracing.span(tracing.EXPORT_MATERIALIZE, step=self.tag,
+                              leaf=i, bytes=nbytes):
+                host = np.asarray(arr)  # materialize this device's shard
             if self.dead:  # cancelled while materializing: no submit
                 return
             self._mark()
@@ -374,11 +421,12 @@ class _StreamRound:
                     # A/B proof) reads
                     self.streamed += 1
                     self.shard_leaves += 1
-            if fire:
-                ev.set()
         except BaseException as e:  # noqa: BLE001 - surfaced via claim()
             self._errors[i] = e
-            if ev is not None:
+            fire = True
+        finally:
+            sp.stop()  # before the event: the span is in claim()'s step
+            if fire and ev is not None:
                 ev.set()
 
     def cancel(self) -> None:
@@ -702,21 +750,27 @@ def make_ps_train_step(
         holder = stream_state["holder"]
         shard_set = frozenset(shard_set)
 
-        def _ingest(i, step_arr, dev_arr, arr):
+        def _ingest(i, step_arr, dev_arr, arr, seq, t_enq):
             # round resolved at INGEST time: a stale fire then fails
-            # the tag check instead of resurrecting a finished round.
-            # int() here materializes only the two scalars — the heavy
-            # payload is materialized by whichever worker the round
-            # routes it to (router for whole leaves, per-device worker
-            # for shards)
+            # the tag check instead of resurrecting a finished round
             rnd = holder["round"]
             if rnd is not None:
-                rnd.on_fire(i, int(step_arr), int(dev_arr), arr)
+                rnd.on_fire(i, step_arr, dev_arr, arr, seq, t_enq)
 
         def _tap(i, step_arr, dev_arr, arr):
             # device thread: enqueue ONLY (see _export_pool — touching
-            # the lazy callback args here would self-deadlock)
-            _export_pool().submit(_ingest, i, step_arr, dev_arr, arr)
+            # the lazy callback args here would self-deadlock; shape
+            # and dtype are metadata). The span's start is the moment
+            # the runtime handed the leaf over; its ``step`` is the
+            # round open at that moment.
+            rnd = holder["round"]
+            seq = next(_TAP_SEQ)
+            with tracing.span(tracing.EXPORT_TAP,
+                              step=rnd.tag if rnd is not None else -1,
+                              leaf=i, seq=seq,
+                              bytes=arr.size * arr.dtype.itemsize):
+                _export_pool().submit(_ingest, i, step_arr, dev_arr, arr,
+                                      seq, time.perf_counter())
 
         def streamed_local(step_tag, params, batch):
             loss, grads = jax.value_and_grad(loss_fn)(params, batch)
@@ -795,6 +849,13 @@ def make_ps_train_step(
         # closes it into the StepReport ring (+ stall diagnosis when
         # BYTEPS_STALL_DIAG=1). None when metrics are off.
         prof = state.profiler.begin_step()
+        # the round's tag: threaded through the tapped program so a
+        # late fire is never mistaken for the next round's, and the
+        # ``step`` argument of every span of this step
+        stream_state["tag"] += 1
+        tag = stream_state["tag"]
+        if prof is not None:
+            prof.round_tag = tag
         # names/shapes come from the params tree (value_and_grad gives
         # gradients the identical structure), so the whole export plan
         # exists BEFORE the backward is dispatched — the streamed taps
@@ -847,7 +908,8 @@ def make_ps_train_step(
                       and device_compress is not False
                       and state.scheduler is not None)
         if use_device:
-            loss, grads = grad_fn(params, batch)
+            with tracing.span(tracing.STEP_DISPATCH, step=tag):
+                loss, grads = grad_fn(params, batch)
             grads = _device_compressed_round(
                 state, client, comp_state, compression,
                 min_compress_bytes, rowsparse_params, names,
@@ -1010,26 +1072,34 @@ def make_ps_train_step(
                                 out=obuf)
             return (lambda: res), None
 
-        def submit_streamed(name, flat):
-            """Tap-side submit (runs on the export worker) at
-            production-order priority. ``flat`` is the materialized
-            host view of the callback's array — its base keeps the
-            buffer alive through the PUSH stage, so no staging copy is
-            needed; the arena lease here is the EXPORT round's result
-            slot (tag="export" in the arena counters)."""
+        def submit_streamed(i, flat):
+            """Tap-side submit of leaf ``i`` (runs on the export
+            worker) at production-order priority. ``flat`` is the
+            materialized host view of the callback's array — its base
+            keeps the buffer alive through the PUSH stage, so no
+            staging copy is needed; the arena lease here is the EXPORT
+            round's result slot (tag="export" in the arena counters).
+            One program span, up to the scheduler's ``add_task``
+            returning for the last partition."""
             from ..server.client import get_or_init_ctx
-            if reg is not None:
-                # upcast BEFORE declaring: the compressed wire is f32,
-                # and initializing the ctx from a non-f32 view would
-                # re-partition it every round against the registry's
-                # f32 sizing — recreating the CompressedTensor and
-                # silently resetting its EF/momentum codec state
-                flat = flat.astype(np.float32, copy=False)
-            ctx = get_or_init_ctx(state, name, flat)
-            pr = state.scheduler.production_priority(ctx)
-            exp_whole_ctr.inc(flat.nbytes)
-            exp_dev0_ctr.inc(flat.nbytes)
-            return submit(name, flat, priority=pr, tag="export")
+            name = names[i]
+            with tracing.span(tracing.EXPORT_SUBMIT, tid=name, step=tag,
+                              leaf=i, bytes=flat.nbytes) as sp:
+                if reg is not None:
+                    # upcast BEFORE declaring: the compressed wire is
+                    # f32, and initializing the ctx from a non-f32 view
+                    # would re-partition it every round against the
+                    # registry's f32 sizing — recreating the
+                    # CompressedTensor and silently resetting its
+                    # EF/momentum codec state
+                    flat = flat.astype(np.float32, copy=False)
+                ctx = get_or_init_ctx(state, name, flat)
+                sp.set(key=ctx.declared_key,
+                       partitions=len(ctx.partitions))
+                pr = state.scheduler.production_priority(ctx)
+                exp_whole_ctr.inc(flat.nbytes)
+                exp_dev0_ctr.inc(flat.nbytes)
+                return submit(name, flat, priority=pr, tag="export")
 
         def submit_shard(i, dev, flat):
             """Shard-side submit (runs on device ``dev``'s export
@@ -1040,16 +1110,21 @@ def make_ps_train_step(
             slot (tag="shard" in the arena counters)."""
             from ..server.client import get_or_init_ctx
             info = stream_state["shard_info"][i]
-            ctx = get_or_init_ctx(state, info["names"][dev], flat)
-            pr = state.scheduler.production_priority(
-                ctx, parent=info["parent"])
-            exp_shard_ctr.inc(flat.nbytes)
-            metrics.counter(f"export/device_bytes/{dev}").inc(flat.nbytes)
-            _SHARD_INGESTS[dev] = _SHARD_INGESTS.get(dev, 0) + 1
-            metrics.gauge(f"export/worker_ingests/{dev}").set(
-                _SHARD_INGESTS[dev])
-            return submit(info["names"][dev], flat, priority=pr,
-                          tag="shard")
+            name = info["names"][dev]
+            with tracing.span(tracing.EXPORT_SUBMIT, tid=name, step=tag,
+                              leaf=i, dev=dev, bytes=flat.nbytes) as sp:
+                ctx = get_or_init_ctx(state, name, flat)
+                sp.set(key=ctx.declared_key,
+                       partitions=len(ctx.partitions))
+                pr = state.scheduler.production_priority(
+                    ctx, parent=info["parent"])
+                exp_shard_ctr.inc(flat.nbytes)
+                metrics.counter(f"export/device_bytes/{dev}").inc(
+                    flat.nbytes)
+                _SHARD_INGESTS[dev] = _SHARD_INGESTS.get(dev, 0) + 1
+                metrics.gauge(f"export/worker_ingests/{dev}").set(
+                    _SHARD_INGESTS[dev])
+                return submit(name, flat, priority=pr, tag="shard")
 
         # Bucket fusion (BYTEPS_FUSION_BYTES; the group-push cure):
         # per-key cost (scheduler admission, handle, two syscall
@@ -1298,9 +1373,8 @@ def make_ps_train_step(
         # ---- dispatch the backward (tapped when streaming) ----
         round_obj = None
         if stream_on:
-            stream_state["tag"] += 1
             round_obj = _StreamRound(
-                stream_state["tag"], names, submit_streamed,
+                tag, names, submit_streamed,
                 mark_first_push,
                 shard_plan={i: n_shard for i in shard_set},
                 submit_shard=submit_shard)
@@ -1308,8 +1382,9 @@ def make_ps_train_step(
                 round_obj.expect(i)
             stream_state["holder"]["round"] = round_obj
             try:
-                loss, grads = stream_state["fn"](
-                    jnp.int32(stream_state["tag"]), params, batch)
+                with tracing.span(tracing.STEP_DISPATCH, step=tag):
+                    loss, grads = stream_state["fn"](
+                        jnp.int32(tag), params, batch)
             except BaseException:
                 # compile/dispatch failure of the tapped backward:
                 # quiesce the export worker, clean up whatever the
@@ -1325,7 +1400,11 @@ def make_ps_train_step(
                     lease.abandon()
                 raise
         else:
-            loss, grads = grad_fn(params, batch)
+            with tracing.span(tracing.STEP_DISPATCH, step=tag):
+                loss, grads = grad_fn(params, batch)
+        # the train thread's two phases as spans: ``claim`` from here to
+        # the export_done mark, ``drain`` from there to drain_done
+        phase = tracing.span(tracing.STEP_CLAIM, step=tag).start()
         g_leaves = jax.tree.leaves(grads)
         streamed_set = set(eligible) if round_obj is not None else set()
         # start the D2H copies for the non-streamed leaves now; each
@@ -1396,11 +1475,13 @@ def make_ps_train_step(
                     flush_bucket()
                     waiters.append((i, *submit(name, h.reshape(-1))))
             flush_bucket()
+            phase.stop()
             if prof is not None:
                 # every leaf is now off the device and submitted (each
                 # np.asarray above blocked on ITS leaf): the compute +
                 # export wall of this step's report
                 prof.mark("export_done")
+            phase = tracing.span(tracing.STEP_DRAIN, step=tag).start()
             # ---- carried drain (BYTEPS_CROSS_BARRIER): the PREVIOUS
             # step's tail rounds land here, AFTER this step's backward
             # has been dispatched and its exports submitted — their
@@ -1492,23 +1573,26 @@ def make_ps_train_step(
             h2d_hist = state.metrics.histogram("step/h2d_update_us")
 
             def land(s, piece):
-                t0 = _time.perf_counter()
-                if hc is not None:
-                    hc.leaf(s, piece)  # health tap: stats off the drain
-                arr = jax.device_put(piece.reshape(shapes[s]))
-                imported[s] = arr
-                if sa_round is not None:
-                    ov = xb_over.pop(s, None) if xb_over else None
-                    if ov is not None:
-                        # this leaf's previous round was carried: chain
-                        # from the carried apply's result, not the
-                        # (one-step-stale) tree slices
-                        new_params[s], apply_parts[s] = sa.apply_with(
-                            ov[0], ov[1], sa_round.slice(s)[1], arr)
-                    else:
-                        new_params[s], apply_parts[s] = sa_round.apply(
-                            p_leaves[s], s, arr)
-                dt = _time.perf_counter() - t0
+                with tracing.span(tracing.APPLY_H2D_UPDATE, tid=names[s],
+                                  step=tag, leaf=s) as sp:
+                    if hc is not None:
+                        # health tap: stats off the drain
+                        hc.leaf(s, piece)
+                    arr = jax.device_put(piece.reshape(shapes[s]))
+                    imported[s] = arr
+                    if sa_round is not None:
+                        ov = xb_over.pop(s, None) if xb_over else None
+                        if ov is not None:
+                            # this leaf's previous round was carried:
+                            # chain from the carried apply's result, not
+                            # the (one-step-stale) tree slices
+                            new_params[s], apply_parts[s] = \
+                                sa.apply_with(ov[0], ov[1],
+                                              sa_round.slice(s)[1], arr)
+                        else:
+                            new_params[s], apply_parts[s] = \
+                                sa_round.apply(p_leaves[s], s, arr)
+                dt = sp.t1 - sp.t0
                 h2d_hist.record_seconds(dt)
                 if prof is not None:
                     prof.stage_sample("H2D_UPDATE", dt)
@@ -1517,14 +1601,16 @@ def make_ps_train_step(
                 # import shard `dev` of leaf `s` onto the device that
                 # owns it — 1/local_size of the H2D the whole-leaf
                 # import moved, overlapped with the remaining pulls
-                t0 = _time.perf_counter()
-                if hc is not None:
-                    hc.leaf(s, piece)  # shard pieces sum into the leaf
                 info = active_shard[s]
                 parts = shard_parts[s]
-                parts[dev] = jax.device_put(piece, axis_devs[dev])
-                shard_left[s] -= 1
-                dt = _time.perf_counter() - t0
+                with tracing.span(tracing.APPLY_H2D_UPDATE, tid=names[s],
+                                  step=tag, leaf=s, dev=dev) as sp:
+                    if hc is not None:
+                        # shard pieces sum into the leaf
+                        hc.leaf(s, piece)
+                    parts[dev] = jax.device_put(piece, axis_devs[dev])
+                    shard_left[s] -= 1
+                dt = sp.t1 - sp.t0
                 h2d_hist.record_seconds(dt)
                 if prof is not None:
                     prof.stage_sample("H2D_UPDATE", dt)
@@ -1537,27 +1623,29 @@ def make_ps_train_step(
                     (info["n"] * info["shard_len"],),
                     stream_state["nsharding"], parts)
                 imported[s] = garr
-                t_ag = _time.perf_counter()
-                if ssa is not None and sa_round is not None:
-                    pparts, shared = sa_round.slice(s)
-                    new_sh, npp_sh, n_shared = ssa.apply(
-                        p_leaves[s], pparts, shared, garr)
-                    fulls = ssa.gather((new_sh, *npp_sh),
-                                       [p_leaves[s], *pparts])
-                    new_params[s] = fulls[0]
-                    apply_parts[s] = (list(fulls[1:]), n_shared)
-                else:
-                    # transform not shard-separable (or fused apply):
-                    # gather the GRADIENT instead and apply full-leaf —
-                    # the D2H/wire/H2D savings stand, only the update
-                    # FLOPs stay replicated
-                    tmpl = jax.ShapeDtypeStruct(shapes[s], info["dtype"])
-                    full = sa_state["gather"]((garr,), [tmpl])[0]
-                    imported[s] = full
-                    if sa_round is not None:
-                        new_params[s], apply_parts[s] = sa_round.apply(
-                            p_leaves[s], s, full)
-                dt = _time.perf_counter() - t_ag
+                with tracing.span(tracing.APPLY_ALLGATHER, tid=names[s],
+                                  step=tag, leaf=s) as sp:
+                    if ssa is not None and sa_round is not None:
+                        pparts, shared = sa_round.slice(s)
+                        new_sh, npp_sh, n_shared = ssa.apply(
+                            p_leaves[s], pparts, shared, garr)
+                        fulls = ssa.gather((new_sh, *npp_sh),
+                                           [p_leaves[s], *pparts])
+                        new_params[s] = fulls[0]
+                        apply_parts[s] = (list(fulls[1:]), n_shared)
+                    else:
+                        # transform not shard-separable (or fused
+                        # apply): gather the GRADIENT instead and apply
+                        # full-leaf — the D2H/wire/H2D savings stand,
+                        # only the update FLOPs stay replicated
+                        tmpl = jax.ShapeDtypeStruct(shapes[s],
+                                                    info["dtype"])
+                        full = sa_state["gather"]((garr,), [tmpl])[0]
+                        imported[s] = full
+                        if sa_round is not None:
+                            new_params[s], apply_parts[s] = \
+                                sa_round.apply(p_leaves[s], s, full)
+                dt = sp.t1 - sp.t0
                 ag_hist.record_seconds(dt)
                 if prof is not None:
                     prof.stage_sample("ALLGATHER", dt)
@@ -1639,9 +1727,11 @@ def make_ps_train_step(
                 # idle before release
                 jax.block_until_ready([x for x in imported
                                        if x is not None])
+            phase.stop()
             if prof is not None:
                 prof.mark("drain_done")
         except BaseException:
+            phase.stop()
             # a failed round (submission OR drain) may leave pulls
             # mid-flight into these slots: abandon (drop from the
             # table) instead of recycling them under a late writer.

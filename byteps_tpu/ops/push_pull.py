@@ -282,8 +282,6 @@ def push_pull(tensor, name: Optional[str] = None, average: bool = True,
     if name is not None:
         from ..utils.logging import debug_sample
         debug_sample(state.config, name, "OUTPUT", out)
-    if state.tracer is not None and name is not None:
-        state.tracer.instant(name, "push_pull")
     return out
 
 

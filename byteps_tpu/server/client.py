@@ -815,7 +815,8 @@ class PSClient:
     def zpushpull_async(self, server: int, key: int, data: np.ndarray,
                         out: np.ndarray, cmd: int,
                         on_done: Callable[[int, Optional[Exception]], None],
-                        epoch: int = 0, codec: int = 0) -> int:
+                        epoch: int = 0, codec: int = 0,
+                        rid_out: Optional[ctypes.c_uint32] = None) -> int:
         """Fused push+pull in ONE wire round trip: push ``data``, and
         when the server's aggregation round completes, the aggregate
         lands in ``out`` and ``on_done(reply_len, error)`` runs on the
@@ -831,7 +832,10 @@ class PSClient:
 
         Returns the request's wire rid (0 on a native lib predating the
         reporting ABI) — the id server-side trace spans carry, which the
-        fused timeline uses to flow-link worker and server spans."""
+        fused timeline uses to flow-link worker and server spans. The
+        native send writes it into ``rid_out`` (the caller's cell)
+        before the request is on the wire, so ``on_done`` can read it
+        there even when the reply beats this call's return."""
         self._check_server(server)
         if not out.flags["C_CONTIGUOUS"]:
             raise ValueError(
@@ -847,7 +851,7 @@ class PSClient:
             self._fused[ticket] = (on_done, out)
         self._ensure_reactor()
         self._inflight_add(1)
-        rid = ctypes.c_uint32(0)
+        rid = rid_out if rid_out is not None else ctypes.c_uint32(0)
         if hasattr(self._lib, "bps_client_pushpull_async2"):
             rc = self._lib.bps_client_pushpull_async2(
                 self._handle, server, key, data.ctypes.data, data.nbytes,

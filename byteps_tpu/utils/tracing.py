@@ -3,11 +3,13 @@
 Reference: BYTEPS_TRACE_ON dumps per-(tensor, stage) spans to
 ``trace_dir/<local_rank>/comm.json`` in Chrome trace-event format
 (byteps/common/global.cc:448-564, docs/timeline.md). We reproduce the same
-file format, and additionally mirror spans into jax.profiler trace
-annotations so they appear in TensorBoard/Perfetto device traces.
+file format. Every span goes through ONE primitive, ``span``, which
+also enters a ``jax.profiler.TraceAnnotation`` (so the span is in any
+open profiler session's device trace) and feeds the open step's
+StepReport (core/metrics.py).
 
 Beyond the reference: ``Tracer.dump()`` emits ONE fused timeline — the
-worker's PUSH/PULL spans plus every server's wire-sampled stage spans
+worker's wire spans plus every server's wire-sampled stage spans
 (recv → queue-wait → fold → reply, drained over the TRACE_DRAIN control
 op), clock-aligned via NTP-style offset estimation
 (``estimate_clock_offset``) and rid-linked with Chrome flow events, so
@@ -21,7 +23,9 @@ import json
 import os
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, List, Optional, Sequence, Tuple
+
+from jax.profiler import TraceAnnotation as _trace_me
 
 from ..config import Config
 
@@ -62,30 +66,138 @@ def estimate_clock_offset(
     return int(best[0]), int(best[1] // 2 + 1)
 
 
+# --------------------------------------------------------------------- #
+# the span primitive: one begin/end path for every program span
+# --------------------------------------------------------------------- #
+
+# Fixed span names (PERF.md section 3 lists them with the thread each
+# runs on and the StepReport field or metric that reads it). The
+# identifiers of one span (step, leaf, key, rid, bytes, cause) are its
+# ARGUMENTS, never part of its name.
+STEP_DISPATCH = "bps.step.dispatch"
+STEP_CLAIM = "bps.step.claim"
+STEP_DRAIN = "bps.step.drain"
+EXPORT_TAP = "bps.export.tap"
+EXPORT_ROUTE = "bps.export.route"
+EXPORT_INGEST = "bps.export.ingest"
+EXPORT_MATERIALIZE = "bps.export.materialize"
+EXPORT_SUBMIT = "bps.export.submit"
+WIRE_SEND = "bps.wire.send"
+WIRE_DONE = "bps.wire.done"
+WIRE_PUSH = "bps.wire.push"
+WIRE_PULL = "bps.wire.pull"
+CODEC_COMPRESS = "bps.codec.compress"
+CODEC_DECOMPRESS = "bps.codec.decompress"
+APPLY_H2D_UPDATE = "bps.apply.h2d_update"
+APPLY_ALLGATHER = "bps.apply.allgather"
+
+_get_state = None  # core.state.get_state, imported on first use (cycle)
+
+
+class span:
+    """One program span, begun and ended on ONE thread:
+
+        with span(EXPORT_INGEST, step=tag, leaf=i) as sp:
+            ...
+            sp.set(partitions=n)     # what is only known inside
+
+    (``start()``/``stop()`` where a ``with`` block does not fit; ``stop``
+    is idempotent.) Always on, it lands in three places from this one
+    call:
+
+    - a ``jax.profiler.TraceAnnotation`` named ``stage`` with the
+      arguments as its metadata: a flag test when no profiler session is
+      open, and an event on this thread's host line, on the clock of the
+      ``XLA Ops`` lines, of whichever session is (the benchmark's traced
+      window, ``BYTEPS_JAX_PROFILER_DIR``, an operator's own
+      ``jax.profiler.trace``);
+    - ``(stage, thread, start, end, args)`` on ``time.perf_counter`` in
+      the open step's ``_StepBuilder`` (``state.profiler.current()``),
+      which ``end_step`` reduces into the StepReport's export fields;
+      nothing with ``BYTEPS_METRICS=0`` (no builder);
+    - a Chrome ``comm.json`` event (row ``tid``, default the thread's
+      name) where a ``Tracer`` exists and its step window is open.
+
+    ``drop()`` keeps the span out of the builder and the Chrome trace
+    (a duplicate tap fire that turned out to be nobody's work); the
+    annotation, already entered, still closes and carries ``dropped=1``.
+    """
+
+    __slots__ = ("stage", "tid", "args", "t0", "t1", "_ann", "_dropped")
+
+    def __init__(self, stage: str, tid: Optional[str] = None, **args):
+        self.stage = stage
+        self.tid = tid
+        self.args = args
+        self.t0: Optional[float] = None
+        self.t1: Optional[float] = None
+        self._ann = None
+        self._dropped = False
+
+    def __enter__(self) -> "span":
+        if _trace_me.is_enabled():
+            self._ann = _trace_me(self.stage, **self.args)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    start = __enter__
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
+    def drop(self) -> None:
+        self._dropped = True
+        if self._ann is not None:
+            self._ann.set_metadata(dropped=1)
+
+    def __exit__(self, *exc) -> bool:
+        if self.t0 is None or self.t1 is not None:
+            return False
+        self.t1 = t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        if self._dropped:
+            return False
+        global _get_state
+        if _get_state is None:
+            from ..core.state import get_state
+            _get_state = get_state
+        state = _get_state()
+        builder = state.profiler.current()
+        tracer = state.tracer
+        if builder is not None or tracer is not None:
+            thread = threading.current_thread().name
+            if builder is not None:
+                builder.add_span(self.stage, thread, self.t0, t1, self.args)
+            if tracer is not None:
+                tracer.record(self.stage, self.tid or thread, self.t0, t1,
+                              self.args)
+        return False
+
+    def stop(self) -> None:
+        self.__exit__(None, None, None)
+
+
 class Tracer:
+    """The Chrome-trace half: ``comm.json`` events of the spans that end
+    inside the step window (``BYTEPS_TRACE_START_STEP``..``END_STEP``),
+    and the fused fleet dump. Spans reach it through ``span`` alone."""
+
     def __init__(self, config: Config):
         self._config = config
         self._lock = threading.Lock()
         self._events: List[dict] = []
         self._step = 0
+        # one origin on both clocks: spans are timed on perf_counter,
+        # server stamps arrive on the steady (monotonic) clock
         self._t0_ns = time.monotonic_ns()
-        # (tensor, stage) -> (start_us, entered TraceAnnotation or
-        # None, extra args dict or None, span seq)
-        self._open_spans: Dict[tuple, tuple] = {}
-        # (tensor, stage) -> (seq, most recently RECORDED event dict):
-        # a late annotate() (rid racing a fast reply's end()) patches
-        # the event instead of vanishing; bounded by distinct spans
-        self._last_closed: Dict[tuple, tuple] = {}
-        # per-begin incarnation counter: annotate() callers hold the
-        # token of the span THEY opened, so a late annotate can never
-        # stamp the NEXT round's span for the same key
-        self._span_seq = 0
+        self._t0_pc = time.perf_counter()
         # fused-dump hook (core/state.py): () -> [{"server": idx,
         # "offset_ns": o, "err_ns": e, "records": [TraceRec dicts]}]
         self._server_collector: Optional[Callable[[], list]] = None
-
-    def _us(self) -> float:
-        return (time.monotonic_ns() - self._t0_ns) / 1e3
 
     def _active(self) -> bool:
         return (self._config.trace_on and
@@ -100,143 +212,21 @@ class Tracer:
         if do_flush:
             self.flush()
 
-    def begin(self, name: str, stage: str,
-              cross_thread: bool = False) -> Optional[int]:
-        """Mark the start of a (tensor, stage) span
-        (reference: scheduled_queue.cc:105-123). begin/end normally pair
-        on ONE thread (the stage's pool thread), which lets the span
-        mirror into a jax.profiler.TraceAnnotation — visible in
-        Perfetto/TensorBoard when a jax profiler trace is running
-        (BYTEPS_JAX_PROFILER_DIR). ``cross_thread=True`` declares that
-        end() will run on a DIFFERENT thread (the fused wire op: begin
-        on the stage thread, end in the completion reactor) — the
-        Chrome-trace event still records, but the TraceAnnotation
-        mirror is skipped, since annotations stack per thread and an
-        exit on another thread would unwind someone else's stack.
-
-        Returns this span incarnation's token (None when nothing was
-        opened) — pass it to ``annotate`` so a late annotation can
-        never land on a LATER span of the same key."""
-        # annotations mirror whenever a profiler dir is configured —
-        # independent of the Chrome-trace window, which only gates the
-        # comm.json events (a profiler session spans init()->shutdown())
-        mirror = bool(self._config.jax_profiler_dir) and not cross_thread
-        if not (mirror or self._active()):
-            return None
-        with self._lock:
-            prev = self._open_spans.pop((name, stage), None)
-        if prev is not None and prev[1] is not None:
-            # double-begin without an end: close the orphan annotation
-            # BEFORE entering the new one (annotations stack per thread;
-            # exiting it later would unwind out of order and every
-            # subsequent annotation would nest inside the orphan)
-            try:
-                prev[1].__exit__(None, None, None)
-            except Exception:  # noqa: BLE001
-                pass
-        ann = None
-        if mirror:
-            try:
-                import jax
-                ann = jax.profiler.TraceAnnotation(f"bps:{stage}:{name}")
-                ann.__enter__()
-            except Exception:  # noqa: BLE001 - profiler mirroring is aux
-                ann = None
-        with self._lock:
-            self._span_seq += 1
-            seq = self._span_seq
-            self._open_spans[(name, stage)] = (self._us(), ann, None,
-                                               seq)
-        return seq
-
-    def annotate(self, name: str, stage: str, token: Optional[int] = None,
-                 **args) -> None:
-        """Attach args to the (name, stage) span — how the wire stage
-        stamps the request's rid onto its span after the send assigned
-        one (the flow-link id the fused dump joins on). The span may
-        already be CLOSED: on a loopback fleet the reply can complete
-        (and the reactor run ``end()``) before the submitting thread
-        even returns from the native send — so a just-closed span's
-        recorded event is patched in place (the events list holds the
-        dict itself). ``token`` (begin()'s return) pins the annotation
-        to the caller's OWN span incarnation: a maximally-late annotate
-        racing the next round's ``begin`` for the same key must drop,
-        not stamp this round's rid onto the next round's span. A no-op
-        when the target span no longer exists (window closed, fallback
-        clients that report no rid)."""
-        if not args:
-            return
-        with self._lock:
-            entry = self._open_spans.get((name, stage))
-            if entry is not None:
-                start, ann, extra, seq = entry
-                if token is not None and token != seq:
-                    entry = None  # a later incarnation: fall through
-                else:
-                    merged = dict(extra) if extra else {}
-                    merged.update(args)
-                    self._open_spans[(name, stage)] = (start, ann,
-                                                       merged, seq)
-                    return
-            closed = self._last_closed.get((name, stage))
-            if closed is not None:
-                seq, ev = closed
-                if token is None or token == seq:
-                    ev["args"].update(args)
-
-    def end(self, name: str, stage: str) -> None:
-        """Record span duration (reference: core_loops.cc:69-91). The
-        annotation exit is NOT gated on the trace window: a span that
-        straddles trace_end_step must still close its TraceAnnotation on
-        this (long-lived pool) thread or every later annotation nests
-        inside the orphan forever."""
-        with self._lock:
-            entry = self._open_spans.pop((name, stage), None)
-        if entry is None:
-            return
-        start, ann, extra, seq = entry
-        if ann is not None:
-            try:
-                ann.__exit__(None, None, None)
-            except Exception:  # noqa: BLE001
-                pass
+    def record(self, stage: str, tid: str, t0: float, t1: float,
+               args: dict) -> None:
+        """One finished span (``span.__exit__``): a complete (``ph:
+        "X"``) event on row ``tid`` when the step window is open
+        (reference: core_loops.cc:69-91). The wire stage's ``rid``
+        argument is what the fused dump flow-links on."""
         if not self._active():
             return
-        args = {"tensor": name}
-        if extra:
-            args.update(extra)
         ev = {
             "name": stage, "cat": "comm", "ph": "X",
-            "ts": start, "dur": self._us() - start,
-            "pid": os.getpid(), "tid": name, "args": args,
+            "ts": (t0 - self._t0_pc) * 1e6, "dur": (t1 - t0) * 1e6,
+            "pid": os.getpid(), "tid": tid, "args": dict(args),
         }
         with self._lock:
             self._events.append(ev)
-            self._last_closed[(name, stage)] = (seq, ev)
-
-    def counter(self, name: str, values: dict) -> None:
-        """Chrome-trace counter event (``ph: "C"``): Perfetto renders
-        each key of ``values`` as a stacked counter track alongside the
-        comm spans — how queue depth and per-step stage aggregates from
-        the metrics plane (core/metrics.py StepProfiler) appear in the
-        same timeline. Gated on the trace window like span events."""
-        if not self._active():
-            return
-        with self._lock:
-            self._events.append({
-                "name": name, "cat": "comm", "ph": "C",
-                "ts": self._us(), "pid": os.getpid(),
-                "args": dict(values),
-            })
-
-    def instant(self, name: str, stage: str) -> None:
-        if not self._active():
-            return
-        with self._lock:
-            self._events.append({
-                "name": stage, "cat": "comm", "ph": "i",
-                "ts": self._us(), "pid": os.getpid(), "tid": name, "s": "t",
-            })
 
     def flush(self, path: Optional[str] = None) -> Optional[str]:
         """Dump comm.json (reference: global.cc:448-564)."""
@@ -286,24 +276,20 @@ class Tracer:
         and returns it; returns None when there is nothing at all to
         dump (no worker events AND no server records)."""
         with self._lock:
-            # COPY the event dicts (args included) under the lock: a
-            # stage thread's late annotate() mutates the originals in
-            # place, and json.dump iterating a dict that grows a key
-            # mid-serialization raises — the dump must read a frozen
-            # snapshot (flush() is safe already: it serializes while
-            # holding the lock)
-            events = [dict(e, args=dict(e["args"])) if "args" in e
-                      else dict(e) for e in self._events]
+            # a recorded event is never touched again: a shallow copy of
+            # the list is a frozen snapshot
+            events = list(self._events)
         fused: List[dict] = [{
             "name": "process_name", "ph": "M", "pid": os.getpid(),
             "args": {"name": f"bps-worker rank "
                              f"{self._config.local_rank}"},
         }]
         fused += events
-        # worker spans by rid: the flow arrows start inside them
+        # worker spans by rid: the flow arrows start inside the span
+        # that put the request on the wire (its completion's span,
+        # bps.wire.done, carries the same rid)
         rid_spans = {e["args"]["rid"]: e for e in events
-                     if e.get("ph") == "X"
-                     and isinstance(e.get("args"), dict)
+                     if e.get("ph") == "X" and e["name"] != WIRE_DONE
                      and e["args"].get("rid")}
         flows = 0
         collected = self._server_collector() if self._server_collector \

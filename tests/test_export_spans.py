@@ -1,0 +1,364 @@
+"""The program's spans on the gradient-export path (utils/tracing.py
+``span``; jax/train.py, core/scheduler.py, core/metrics.py): every span
+of PERF.md's table is recorded on the thread the table names, with the
+round's tag as its ``step``; the StepReport's export fields are reduced
+from them and hold their identities; nothing is read where nothing
+streamed or metrics are off; and a profiler session opened by anybody
+holds the spans on its host lines, ``bps.wire.send`` and
+``bps.wire.done`` pairing by ``rid``."""
+
+import contextlib
+import glob
+import os
+import threading
+
+import numpy as np
+import optax
+import pytest
+
+from byteps_tpu.config import Config
+from byteps_tpu.core.metrics import (StepProfiler, _StepBuilder,
+                                     export_span_fields)
+from byteps_tpu.server import run_server
+from byteps_tpu.utils import tracing
+
+_PORT = [24650]
+
+EXPORT_FIELDS = ("dispatch_ms", "export_tap_span_ms",
+                 "export_router_busy_ms", "export_materialize_ms",
+                 "export_submit_ms", "export_router_wait_max_ms")
+
+
+@contextlib.contextmanager
+def _ps_env(extra_env: dict = None):
+    from byteps_tpu.core.state import GlobalState
+
+    port = _PORT[0]
+    _PORT[0] += 1
+    env = {
+        "DMLC_NUM_WORKER": "1", "DMLC_NUM_SERVER": "1",
+        "DMLC_PS_ROOT_URI": "127.0.0.1", "DMLC_PS_ROOT_PORT": str(port),
+        "BYTEPS_FORCE_DISTRIBUTED": "1", **(extra_env or {}),
+    }
+    saved = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    server = threading.Thread(
+        target=run_server,
+        args=(port, Config(num_workers=1, num_servers=1)), daemon=True)
+    server.start()
+    GlobalState._instance = None
+    import byteps_tpu as bps
+    bps.init()
+    try:
+        yield bps
+    finally:
+        bps.shutdown()
+        server.join(timeout=10)
+        GlobalState._instance = None
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
+def _stepper(**kw):
+    import jax
+    import jax.numpy as jnp
+
+    from byteps_tpu.core.state import get_state
+    from byteps_tpu.jax.train import make_ps_train_step
+    from byteps_tpu.models import mlp
+
+    cfg = mlp.MLPConfig(in_dim=64, hidden=(48, 32), n_classes=10)
+    params = mlp.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.RandomState(0)
+    batch = {"x": jnp.asarray(rng.rand(32, 64), jnp.float32),
+             "y": jnp.asarray(rng.randint(0, 10, 32), jnp.int32)}
+    tx = optax.adam(1e-2)
+    step = make_ps_train_step(lambda p, b: mlp.loss_fn(p, b, cfg), tx,
+                              get_state().mesh, **kw)
+    state = [params, tx.init(params)]
+
+    def run(n=1):
+        for _ in range(n):
+            p, o, loss = step(state[0], state[1], batch)
+            jax.block_until_ready((p, o, loss))
+            state[:] = [p, o]
+
+    return run, len(jax.tree.leaves(params))
+
+
+# whole-leaf: every leaf streams through the one router; shard: the
+# weights reduce-scatter and leave as per-device shards through the
+# bps-export-d{k} workers, the biases stay whole
+MODES = {
+    "whole-leaf": {"BYTEPS_FUSION_BYTES": "0",
+                   "BYTEPS_LOCAL_SHARD_EXPORT": "0"},
+    "shard": {"BYTEPS_FUSION_BYTES": "0",
+              "BYTEPS_LOCAL_SHARD_EXPORT": "1",
+              "BYTEPS_SHARD_MIN_BYTES": "1024"},
+}
+
+
+@pytest.fixture(scope="module", params=sorted(MODES))
+def streamed(request):
+    """Three PS steps in one mode; the last step's spans, every report
+    and the engagement counters."""
+    from byteps_tpu.core.state import get_state
+
+    with _ps_env(MODES[request.param]) as bps:
+        run, n_leaves = _stepper()
+        run(3)
+        out = {"mode": request.param,
+               "spans": get_state().profiler.last_spans(),
+               "reports": bps.get_step_reports()[-3:],
+               "arena": bps.get_arena_stats(), "n_leaves": n_leaves}
+    return out
+
+
+def _by_stage(spans):
+    out = {}
+    for sp in spans:
+        out.setdefault(sp[0], []).append(sp)
+    return out
+
+
+def test_every_span_runs_on_the_thread_the_table_names(streamed):
+    by = _by_stage(streamed["spans"])
+    train = threading.current_thread().name
+    for stage in (tracing.STEP_DISPATCH, tracing.STEP_CLAIM,
+                  tracing.STEP_DRAIN, tracing.APPLY_H2D_UPDATE):
+        assert by[stage], stage
+        assert {sp[1] for sp in by[stage]} == {train}, stage
+    # XLA's callback threads are not ours: not the train thread, not a
+    # bps- pool
+    taps = {sp[1] for sp in by[tracing.EXPORT_TAP]}
+    assert taps and train not in taps
+    assert not any(t.startswith("bps-") for t in taps)
+    ingest_threads = {sp[1] for sp in by[tracing.EXPORT_INGEST]}
+    if streamed["mode"] == "shard":
+        assert any(t.startswith("bps-export-d") for t in ingest_threads)
+        assert {sp[1] for sp in by[tracing.EXPORT_ROUTE]} == {"bps-export_0"}
+        assert by[tracing.APPLY_ALLGATHER]
+        assert {sp[1] for sp in by[tracing.APPLY_ALLGATHER]} == {train}
+    else:
+        assert ingest_threads == {"bps-export_0"}
+    for child in (tracing.EXPORT_MATERIALIZE, tracing.EXPORT_SUBMIT):
+        assert {sp[1] for sp in by[child]} <= ingest_threads, child
+    assert all(sp[1].startswith("bps-push")
+               for sp in by[tracing.WIRE_SEND])
+    assert {sp[1] for sp in by[tracing.WIRE_DONE]} == {"bps-cq-reactor"}
+
+
+def test_step_is_the_round_tag_and_one_ingest_per_leaf_or_shard(streamed):
+    by = _by_stage(streamed["spans"])
+    tag = by[tracing.STEP_DISPATCH][0][4]["step"]
+    assert tag == 3  # the third PS round of this closure
+    for stage in (tracing.STEP_CLAIM, tracing.STEP_DRAIN,
+                  tracing.EXPORT_INGEST, tracing.EXPORT_MATERIALIZE,
+                  tracing.EXPORT_SUBMIT, tracing.APPLY_H2D_UPDATE):
+        assert {sp[4]["step"] for sp in by[stage]} == {tag}, stage
+    ingests = by[tracing.EXPORT_INGEST]
+    marks = [(sp[4]["leaf"], sp[4].get("dev", 0)) for sp in ingests]
+    whole = [m for m in marks if m[0] not in
+             {sp[4]["leaf"] for sp in by.get(tracing.EXPORT_ROUTE, [])}]
+    # one ingest per whole leaf (the 8 devices' duplicate fires dropped
+    # out), one per (leaf, device) of a sharded leaf
+    assert len(set(marks)) == len(marks)
+    assert len({m[0] for m in marks}) == streamed["n_leaves"]
+    if streamed["mode"] == "shard":
+        shard_leaves = {sp[4]["leaf"] for sp in by[tracing.EXPORT_ROUTE]}
+        assert shard_leaves
+        for leaf in shard_leaves:
+            assert sorted(d for l, d in marks if l == leaf) == list(range(8))
+        assert streamed["arena"]["export_shard_leaves"] > 0
+    else:
+        assert len(whole) == streamed["n_leaves"]
+    # each ingest names a tap of this step as its cause, and says how
+    # long it sat queued
+    seqs = {f"tap:{sp[4]['seq']}" for sp in by[tracing.EXPORT_TAP]}
+    for sp in ingests:
+        assert sp[4]["cause"] in seqs
+        assert sp[4]["queued_us"] >= 0 and sp[4]["bytes"] > 0
+    # children nest inside their ingest, on its thread
+    for child in by[tracing.EXPORT_MATERIALIZE] + by[tracing.EXPORT_SUBMIT]:
+        assert any(p[1] == child[1] and p[2] <= child[2]
+                   and child[3] <= p[3] for p in ingests)
+    assert all(sp[4]["partitions"] >= 1 and sp[4]["key"] >= 0
+               for sp in by[tracing.EXPORT_SUBMIT])
+
+
+def test_wire_send_and_done_pair_by_rid(streamed):
+    by = _by_stage(streamed["spans"])
+    sends = {sp[4]["rid"]: sp for sp in by[tracing.WIRE_SEND]}
+    dones = {sp[4]["rid"]: sp for sp in by[tracing.WIRE_DONE]}
+    assert sends and 0 not in sends
+    assert set(sends) == set(dones)
+    submits = {f"submit:{sp[4]['key']}" for sp in by[tracing.EXPORT_SUBMIT]}
+    for rid, send in sends.items():
+        assert send[4]["key"] == dones[rid][4]["key"]
+        assert send[4]["bytes"] > 0 and send[4]["admit_wait_us"] >= 0
+        assert send[4]["cause"] in submits
+        assert send[2] <= dones[rid][3]
+
+
+def test_the_export_fields_hold_their_identities_on_every_report(streamed):
+    for r in streamed["reports"]:
+        for f in EXPORT_FIELDS:
+            assert r[f] is not None and r[f] >= 0, (f, r)
+        eps = 1e-6
+        assert (r["export_materialize_ms"] + r["export_submit_ms"]
+                <= r["export_router_busy_ms"] + eps)
+        assert r["export_router_busy_ms"] <= r["compute_ms"] + eps
+        assert r["export_tap_span_ms"] <= r["compute_ms"] + eps
+        assert r["dispatch_ms"] <= r["compute_ms"] + eps
+
+
+def test_fields_are_none_on_a_step_with_no_streamed_leaf():
+    with _ps_env({"BYTEPS_STREAM_EXPORT": "0"}) as bps:
+        from byteps_tpu.core.state import get_state
+
+        run, _ = _stepper()
+        run(2)
+        r = bps.get_step_reports()[-1]
+        assert r["streamed_leaves"] == 0 and r["compute_ms"] > 0
+        assert all(r[f] is None for f in EXPORT_FIELDS), r
+        by = _by_stage(get_state().profiler.last_spans())
+        # the train thread's spans are there all the same; no export's
+        assert by[tracing.STEP_DISPATCH] and by[tracing.STEP_CLAIM]
+        assert tracing.EXPORT_INGEST not in by
+        assert tracing.EXPORT_TAP not in by
+
+
+def test_no_builder_and_no_report_with_metrics_off():
+    with _ps_env({"BYTEPS_METRICS": "0",
+                  "BYTEPS_FUSION_BYTES": "0"}) as bps:
+        from byteps_tpu.core.state import get_state
+
+        run, _ = _stepper()
+        run(2)
+        assert bps.get_step_reports() == []
+        assert get_state().profiler.last_spans() == []
+        assert bps.get_arena_stats()["export_streamed_leaves"] > 0
+
+
+def test_the_fused_step_takes_no_span(bps):
+    """``client is None``: the fused control's path through the PS step
+    closure opens no builder and records nothing."""
+    from byteps_tpu.core.state import get_state
+
+    run, _ = _stepper()
+    run(2)
+    assert get_state().ps_client is None
+    assert get_state().profiler.last_spans() == []
+    assert bps.get_step_reports() == []
+
+
+def test_an_open_profiler_session_holds_the_spans(tmp_path):
+    """A session opened by the caller, with no BYTEPS_* tracing setting:
+    the host lines of its .xplane.pb hold the program's spans with
+    their arguments, ``send`` and ``done`` pairing by rid."""
+    import jax
+    from jax.profiler import ProfileData
+
+    with _ps_env({"BYTEPS_FUSION_BYTES": "0",
+                  "BYTEPS_LOCAL_SHARD_EXPORT": "0"}):
+        from byteps_tpu.core.state import get_state
+
+        assert get_state().tracer is None
+        assert not get_state().config.jax_profiler_dir
+        run, n_leaves = _stepper()
+        run(1)  # compile outside the session
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            run(2)
+        finally:
+            jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for li, line in enumerate(plane.lines):
+            for ev in line.events:
+                if ev.name.startswith("bps."):
+                    events.setdefault(ev.name, []).append(
+                        (li, dict(ev.stats)))
+    for stage in (tracing.STEP_DISPATCH, tracing.STEP_CLAIM,
+                  tracing.STEP_DRAIN, tracing.EXPORT_TAP,
+                  tracing.EXPORT_INGEST, tracing.EXPORT_MATERIALIZE,
+                  tracing.EXPORT_SUBMIT, tracing.WIRE_SEND,
+                  tracing.WIRE_DONE, tracing.APPLY_H2D_UPDATE):
+        assert events.get(stage), f"no {stage} event in the session"
+    ingests = [a for _, a in events[tracing.EXPORT_INGEST]
+               if not a.get("dropped")]
+    assert len(ingests) == 2 * n_leaves
+    assert {a["step"] for a in ingests} == {2, 3}
+    assert all(a["cause"].startswith("tap:") for a in ingests)
+    # ingests on one line (the router), sends on other lines than dones
+    assert len({li for li, a in events[tracing.EXPORT_INGEST]}) == 1
+    send_rids = [a["rid"] for _, a in events[tracing.WIRE_SEND]]
+    done_rids = [a["rid"] for _, a in events[tracing.WIRE_DONE]]
+    assert send_rids and 0 not in send_rids
+    assert sorted(send_rids) == sorted(done_rids)
+    assert not ({li for li, _ in events[tracing.WIRE_SEND]}
+                & {li for li, _ in events[tracing.WIRE_DONE]})
+
+
+# --------------------------------------------------------------------- #
+# the reduction alone
+# --------------------------------------------------------------------- #
+
+
+def _sp(stage, thread, t0, t1, **args):
+    return (stage, thread, t0, t1, args)
+
+
+def test_reduction_takes_the_busiest_thread_and_this_round_only():
+    spans = [
+        _sp("bps.step.dispatch", "main", 0.0, 0.010, step=5),
+        _sp("bps.export.tap", "cb", 0.020, 0.021, step=5, seq=1),
+        _sp("bps.export.tap", "cb", 0.050, 0.051, step=5, seq=2),
+        # another device's duplicate fire: caused no ingest
+        _sp("bps.export.tap", "cb2", 0.300, 0.301, step=5, seq=3),
+        # the round before's late fire and ingest
+        _sp("bps.export.tap", "cb", 0.001, 0.002, step=4, seq=9),
+        _sp("bps.export.ingest", "bps-export_0", 0.002, 0.004, step=4,
+            cause="tap:9", queued_us=5.0),
+        _sp("bps.export.route", "bps-export_0", 0.021, 0.022, step=5),
+        _sp("bps.export.materialize", "bps-export-d1_0", 0.030, 0.050,
+            step=5),
+        _sp("bps.export.submit", "bps-export-d1_0", 0.050, 0.055, step=5),
+        _sp("bps.export.ingest", "bps-export-d1_0", 0.030, 0.060, step=5,
+            cause="tap:1", queued_us=9000.0),
+        _sp("bps.export.materialize", "bps-export-d0_0", 0.052, 0.056,
+            step=5),
+        _sp("bps.export.ingest", "bps-export-d0_0", 0.052, 0.058, step=5,
+            cause="tap:2", queued_us=1000.0),
+    ]
+    f = export_span_fields(spans, 5)
+    assert f["dispatch_ms"] == pytest.approx(10.0)
+    assert f["export_tap_span_ms"] == pytest.approx(31.0)
+    assert f["export_router_busy_ms"] == pytest.approx(30.0)  # d1
+    assert f["export_materialize_ms"] == pytest.approx(20.0)
+    assert f["export_submit_ms"] == pytest.approx(5.0)
+    assert f["export_router_wait_max_ms"] == pytest.approx(9.0)
+    assert export_span_fields(spans, 6) == {}
+    assert export_span_fields([], None) == {}
+
+
+def test_end_step_keeps_the_spans_and_none_means_none():
+    prof = StepProfiler()
+    b = prof.begin_step()
+    assert isinstance(b, _StepBuilder)
+    b.round_tag = 1
+    b.add_span("bps.step.dispatch", "main", b.t0, b.t0 + 0.001,
+               {"step": 1})
+    b.mark("export_done")
+    r = prof.end_step(b)
+    assert all(getattr(r, f) is None for f in EXPORT_FIELDS)
+    assert [sp[0] for sp in prof.last_spans()] == ["bps.step.dispatch"]

@@ -153,10 +153,16 @@ def _cfg(tmp_path):
 def test_fused_dump_aligns_and_links(tmp_path):
     tr = Tracer(_cfg(tmp_path))
     tr.step()
-    tr.begin("t0", "PUSHPULL.0")
-    tr.annotate("t0", "PUSHPULL.0", rid=42, server=0)
+    # the request's two spans as utils/tracing.py span records them:
+    # the send (2 ms, ending now) and, later, the completion; the flow
+    # link must start in the send
+    t_done = time.perf_counter() + 0.004
+    tr.record("bps.wire.done", "t0", t_done, t_done + 0.0001,
+              {"key": 7, "rid": 42})
+    t_send = time.perf_counter()
     time.sleep(0.002)
-    tr.end("t0", "PUSHPULL.0")
+    tr.record("bps.wire.send", "t0", t_send, time.perf_counter(),
+              {"key": 7, "rid": 42, "server": 0})
     # synthetic server record INSIDE the worker span, on a server clock
     # 2s ahead of ours
     offset = 2 * 10**9
@@ -179,7 +185,8 @@ def test_fused_dump_aligns_and_links(tmp_path):
     assert names == {"recv", "queue-wait", "fold", "reply"}
     # clock alignment: mapped server ts sits inside the worker span
     wspan = next(e for e in evs if e.get("ph") == "X"
-                 and e.get("args", {}).get("rid") == 42)
+                 and e["name"] == "bps.wire.send")
+    assert wspan["args"]["rid"] == 42
     recv = next(e for e in srv if e["name"] == "recv")
     assert wspan["ts"] <= recv["ts"] <= wspan["ts"] + wspan["dur"]
     # server rows are their own pid, named via metadata
@@ -191,14 +198,16 @@ def test_fused_dump_aligns_and_links(tmp_path):
     flows = [e for e in evs if e.get("cat") == "bps-rid"]
     assert {e["ph"] for e in flows} == {"s", "f"}
     assert all(e["id"] == 42 for e in flows)
+    start = next(e for e in flows if e["ph"] == "s")
+    assert wspan["ts"] <= start["ts"] <= wspan["ts"] + wspan["dur"]
     assert doc["metadata"]["rid_flow_links"] == 1
 
 
 def test_fused_dump_without_servers_still_writes(tmp_path):
     tr = Tracer(_cfg(tmp_path))
     tr.step()
-    tr.begin("t0", "PUSH.0")
-    tr.end("t0", "PUSH.0")
+    t0 = time.perf_counter()
+    tr.record("bps.wire.push", "t0", t0, t0 + 0.001, {"key": 1})
     path = tr.dump(str(tmp_path / "fused.json"))
     with open(path) as f:
         doc = json.load(f)

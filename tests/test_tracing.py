@@ -1,30 +1,34 @@
-"""Chrome-trace Tracer window edges (utils/tracing.py): a span that
-straddles trace_end_step must still close its TraceAnnotation (or every
-later annotation on that pool thread nests inside the orphan forever),
-the dump must stay valid JSON after an abnormal (exception) span exit,
-and counter events ride the same window as spans."""
+"""The span primitive and the Chrome-trace Tracer (utils/tracing.py):
+one call is a fixed-name profiler annotation carrying the identifiers
+as arguments, a record in the open step, and (inside the Tracer's step
+window) a comm.json event; the annotation closes on every exit, a
+window that ends under an open span loses the event and nothing else,
+and the dump stays valid JSON."""
 
-import json
-import os
+import threading
 
-import jax
 import pytest
 
 from byteps_tpu.config import Config
-from byteps_tpu.utils.tracing import Tracer
+from byteps_tpu.core.metrics import StepProfiler
+from byteps_tpu.utils import tracing
 
 
 class _FakeAnnotation:
-    """Stand-in for jax.profiler.TraceAnnotation that records its
-    enter/exit balance (the real one is opaque)."""
-
     instances = []
+    enabled = True
 
-    def __init__(self, name):
-        self.name = name
-        self.entered = 0
-        self.exited = 0
+    def __init__(self, name, **kw):
+        self.name, self.kw = name, dict(kw)
+        self.entered = self.exited = 0
         _FakeAnnotation.instances.append(self)
+
+    @staticmethod
+    def is_enabled():
+        return _FakeAnnotation.enabled
+
+    def set_metadata(self, **kw):
+        self.kw.update(kw)
 
     def __enter__(self):
         self.entered += 1
@@ -35,101 +39,116 @@ class _FakeAnnotation:
         return False
 
 
-@pytest.fixture(autouse=True)
-def _fresh_annotations(monkeypatch):
+@pytest.fixture()
+def fake_annotation(monkeypatch):
     _FakeAnnotation.instances = []
-    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _FakeAnnotation)
-    yield
+    _FakeAnnotation.enabled = True
+    monkeypatch.setattr(tracing, "_trace_me", _FakeAnnotation)
+    yield _FakeAnnotation
 
 
-def _tracer(tmp_path, **kw):
-    cfg = Config(trace_on=True, trace_start_step=0, trace_end_step=2,
-                 trace_dir=str(tmp_path), jax_profiler_dir=str(tmp_path),
-                 **kw)
-    return Tracer(cfg)
+@pytest.fixture()
+def open_step(monkeypatch):
+    """A bare state with one open step, in place of the global one."""
+    class _State:
+        profiler = StepProfiler()
+        tracer = None
+
+    state = _State()
+    monkeypatch.setattr(tracing, "_get_state", lambda: state)
+    builder = state.profiler.begin_step()
+    yield state, builder
 
 
-def test_span_straddling_window_end_closes_annotation(tmp_path):
-    tr = _tracer(tmp_path)
-    tr.step()  # step 1, inside window
-    tr.begin("t0", "PUSH.0")
-    assert len(_FakeAnnotation.instances) == 1
-    ann = _FakeAnnotation.instances[0]
-    assert ann.entered == 1
-    # the window closes while the span is still open (a slow partition
-    # finishing after trace_end_step — the straddle case)
+def test_span_annotation_has_a_fixed_name_and_carries_the_arguments(
+        fake_annotation, open_step):
+    _, builder = open_step
+    with tracing.span(tracing.WIRE_SEND, tid="grad/w", key=7) as sp:
+        sp.set(rid=41)
+    (ann,) = fake_annotation.instances
+    assert ann.name == "bps.wire.send"  # no tensor, no partition in it
+    assert ann.kw == {"key": 7, "rid": 41}
+    assert (ann.entered, ann.exited) == (1, 1)
+    ((stage, thread, t0, t1, args),) = builder.spans
+    assert stage == "bps.wire.send" and t1 >= t0
+    assert thread == threading.current_thread().name
+    assert args == {"key": 7, "rid": 41}
+
+
+def test_span_with_no_session_costs_no_annotation(fake_annotation,
+                                                  open_step):
+    _, builder = open_step
+    fake_annotation.enabled = False
+    with tracing.span(tracing.EXPORT_TAP, leaf=1):
+        pass
+    assert fake_annotation.instances == []
+    assert [sp[0] for sp in builder.spans] == ["bps.export.tap"]
+
+
+def test_span_closes_its_annotation_when_the_body_raises_and_once(
+        fake_annotation, open_step):
+    _, builder = open_step
+    sp = tracing.span(tracing.STEP_CLAIM, step=1).start()
+    with pytest.raises(RuntimeError):
+        with tracing.span(tracing.EXPORT_SUBMIT, step=1):
+            raise RuntimeError("stage exploded")
+    sp.stop()
+    sp.stop()  # idempotent: the error path stops what the body stopped
+    outer, inner = fake_annotation.instances
+    assert (inner.entered, inner.exited) == (1, 1)
+    assert (outer.entered, outer.exited) == (1, 1)
+    assert [s[0] for s in builder.spans] == ["bps.export.submit",
+                                             "bps.step.claim"]
+
+
+def test_a_dropped_span_is_in_no_step_but_closes_its_annotation(
+        fake_annotation, open_step):
+    _, builder = open_step
+    with tracing.span(tracing.EXPORT_INGEST, step=1) as sp:
+        sp.drop()
+    (ann,) = fake_annotation.instances
+    assert ann.exited == 1 and ann.kw["dropped"] == 1
+    assert builder.spans == []
+
+
+def test_spans_reach_the_chrome_trace_inside_the_step_window(
+        fake_annotation, open_step, tmp_path):
+    """What replaced ``Tracer.begin/end`` and its counter events: a span
+    that ENDS inside the window is a complete event on its tensor's
+    row, one that ends after it is not, and the annotation closes
+    either way."""
+    import json
+
+    state, _ = open_step
+    state.tracer = tr = tracing.Tracer(Config(
+        trace_on=True, trace_start_step=0, trace_end_step=2,
+        trace_dir=str(tmp_path)))
     tr.step()
-    tr.step()  # step 3 > trace_end_step: flush fired, window closed
-    tr.end("t0", "PUSH.0")
-    assert ann.exited == 1, \
-        "annotation must close even though the trace window ended"
-    # and the flushed file is valid JSON
-    out = tr.flush()
-    if out is not None:  # events were flushed by step(); path may repeat
-        with open(out) as f:
-            json.load(f)
-
-
-def test_dump_valid_json_after_abnormal_span_exit(tmp_path):
-    tr = _tracer(tmp_path)
-    tr.step()
-    # normal complete span
-    tr.begin("good", "PULL.0")
-    tr.end("good", "PULL.0")
-    # abnormal exit: the stage body raises; end() still runs from the
-    # stage's finally (scheduler discipline) with the error in flight
-    tr.begin("bad", "PUSH.0")
-    try:
-        raise RuntimeError("stage exploded")
-    except RuntimeError:
-        tr.end("bad", "PUSH.0")
-    # orphan: begin with NO end at all (a crashed pool thread)
-    tr.begin("orphan", "COMPRESS.0")
-    out = tr.flush()
-    assert out is not None and os.path.exists(out)
-    with open(out) as f:
-        data = json.load(f)
-    names = {(e["tid"], e["name"]) for e in data["traceEvents"]
-             if e["ph"] == "X"}
-    assert ("good", "PULL.0") in names
-    assert ("bad", "PUSH.0") in names, \
-        "the abnormal-exit span must still record a complete event"
-    assert ("orphan", "COMPRESS.0") not in names, \
-        "an orphan open span must not emit a bogus event"
-
-
-def test_double_begin_closes_orphan_annotation(tmp_path):
-    tr = _tracer(tmp_path)
-    tr.step()
-    tr.begin("t", "PUSH.0")
-    first = _FakeAnnotation.instances[0]
-    tr.begin("t", "PUSH.0")  # double-begin without end
-    assert first.exited == 1, \
-        "the orphan annotation must close before the new one enters"
-    second = _FakeAnnotation.instances[1]
-    tr.end("t", "PUSH.0")
-    assert second.exited == 1
-
-
-def test_counter_events_ride_the_window(tmp_path):
-    tr = _tracer(tmp_path)
-    tr.step()
-    tr.counter("bps:queue_depth_peak", {"depth": 7})
+    with tracing.span(tracing.WIRE_PULL, tid="good", key=1):
+        pass
+    with pytest.raises(RuntimeError):
+        with tracing.span(tracing.WIRE_PUSH, tid="bad", key=2):
+            raise RuntimeError("stage exploded")
+    late = tracing.span(tracing.WIRE_PUSH, tid="straddles", key=3).start()
     for _ in range(3):
-        tr.step()  # leave the window
-    tr.counter("bps:queue_depth_peak", {"depth": 99})  # dropped
-    out = tr.flush(path=str(tmp_path / "late"))
-    with open(out) as f:
-        data = json.load(f)
-    counters = [e for e in data["traceEvents"] if e["ph"] == "C"]
-    assert len(counters) == 1
-    assert counters[0]["args"] == {"depth": 7}
+        tr.step()  # step 4 > trace_end_step: flushed, window closed
+    late.stop()
+    assert [a.exited for a in fake_annotation.instances] == [1, 1, 1]
+    with open(tr.flush(path=str(tmp_path / "late"))) as f:
+        events = json.load(f)["traceEvents"]
+    assert [(e["tid"], e["name"], e["ph"]) for e in events] == [
+        ("good", "bps.wire.pull", "X"), ("bad", "bps.wire.push", "X")]
+    assert events[0]["args"] == {"key": 1} and events[0]["dur"] >= 0
 
 
-def test_flush_with_no_events_returns_none(tmp_path):
-    cfg = Config(trace_on=True, trace_start_step=5, trace_end_step=6,
-                 trace_dir=str(tmp_path))
-    tr = Tracer(cfg)
-    tr.begin("t", "PUSH.0")  # outside window, no profiler dir: no-op
-    tr.end("t", "PUSH.0")
+def test_flush_with_no_events_returns_none(fake_annotation, open_step,
+                                           tmp_path):
+    state, builder = open_step
+    state.tracer = tr = tracing.Tracer(Config(
+        trace_on=True, trace_start_step=5, trace_end_step=6,
+        trace_dir=str(tmp_path)))
+    with tracing.span(tracing.WIRE_PUSH, tid="t"):  # outside the window
+        pass
     assert tr.flush() is None
+    assert tr.dump(str(tmp_path / "fused.json")) is None
+    assert len(builder.spans) == 1  # the step has it all the same
