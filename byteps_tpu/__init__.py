@@ -239,14 +239,15 @@ def get_arena_stats() -> dict:
     pinned, allocations avoided, checkout conflicts, fresh fallbacks —
     plus the streamed-export stage counters (jax/train.py):
     ``export_streamed_leaves`` / ``export_fallback_leaves`` (gradient
-    leaves that left the backward via io_callback taps vs the post-jit
-    loop), ``export_checkouts`` (arena leases serving the export
-    stage), and ``export_ttfp_ms`` (the last round's time-to-first-
-    push). The steady-state PS train step should show
+    leaves that left the backward via io_callback taps vs the output
+    route: outputs of the backward, copied by the runtime and claimed
+    by the train thread), ``export_checkouts`` (arena leases serving
+    the tapped export), and ``export_ttfp_ms`` (the last round's
+    time-to-first-push). The steady-state PS train step should show
     ``allocs_avoided`` growing and ``slot_allocs`` flat after warmup;
-    with BYTEPS_STREAM_EXPORT on and leaves above the fusion
-    threshold, ``export_streamed_leaves`` growing proves the
-    COMPUTE/PUSH overlap engaged rather than silently falling back.
+    ``export_streamed_leaves`` grows by the leaves the plan shards on a
+    mesh (none on one device), and by every leaf above the fusion
+    threshold with BYTEPS_STREAM_EXPORT=1.
 
     Deprecated alias: this is ``get_metrics()["arena"]`` — the unified
     registry snapshot is the maintained surface; the keys here are

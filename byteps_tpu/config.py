@@ -26,7 +26,7 @@ def _env_int(name: str, default: int) -> int:
     return int(v) if v not in (None, "") else default
 
 
-def _env_bool(name: str, default: bool = False) -> bool:
+def _env_bool(name: str, default: Optional[bool] = False) -> Optional[bool]:
     v = os.environ.get(name)
     if v is None or v == "":
         return default
@@ -109,15 +109,17 @@ class Config:
     # --- streamed gradient export (rebuild addition; the reference's
     # COMPUTE/PUSH overlap: gradients of the last layers enter PUSH while
     # earlier layers are still in backprop, core_loops.cc + the priority
-    # scheduler's "last layer first"). On: the PS train step taps each
-    # eligible gradient leaf inside the compiled backward with
-    # jax.experimental.io_callback, so its PUSH is submitted the moment
-    # XLA produces it instead of after the whole backward; each key's
-    # priority is pinned from measured production order. Off (or when
-    # callbacks are unavailable / the leaf is device-compressed,
-    # rowsparse or bucket-fused): the post-jit copy_to_host_async loop
-    # (the pre-stream behavior; numerics identical). ---
-    stream_export: bool = True            # BYTEPS_STREAM_EXPORT
+    # scheduler's "last layer first"). Three states (numerics identical
+    # in all). Unset (None): the route follows the leaf's kind in the
+    # plan — a locality-shard leaf is tapped inside the compiled
+    # backward with jax.experimental.io_callback, its PUSH submitted the
+    # moment XLA produces it at measured production-order priority;
+    # every other leaf (whole-leaf key, bucket member, rowsparse) is an
+    # output of the backward that the runtime copies to the host and the
+    # claim loop submits (on the v5e a callback operand reaches the host
+    # at 0.4-0.75 GB/s, an output at 3.3-4.5: PERF.md, PR 25). On:
+    # whole-leaf keys are tapped too. Off: no taps and no shard plan. ---
+    stream_export: Optional[bool] = None  # BYTEPS_STREAM_EXPORT
 
     # --- sharded optimizer apply (rebuild addition; PAPERS.md "Automatic
     # Cross-Replica Sharding of Weight Update": the weight update
@@ -344,7 +346,7 @@ class Config:
             min_compress_bytes=_env_int("BYTEPS_MIN_COMPRESS_BYTES",
                                         DEFAULT_MIN_COMPRESS_BYTES),
             staging_arena=_env_bool("BYTEPS_STAGING_ARENA", True),
-            stream_export=_env_bool("BYTEPS_STREAM_EXPORT", True),
+            stream_export=_env_bool("BYTEPS_STREAM_EXPORT", None),
             sharded_apply=_env_bool("BYTEPS_SHARDED_APPLY", True),
             local_shard_export=_env_bool("BYTEPS_LOCAL_SHARD_EXPORT", True),
             shard_min_bytes=_env_int("BYTEPS_SHARD_MIN_BYTES",

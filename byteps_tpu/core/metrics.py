@@ -399,13 +399,15 @@ class StepReport:
     # the backward jit's call on the train thread; export_tap_span_ms =
     # first streamed tap's start to the last one's end on XLA's
     # callback threads (how long the runtime took to hand the leaves
-    # over); export_router_busy_ms = the busiest export thread's time
-    # inside ingests (the router on one chip, a per-device worker under
-    # BYTEPS_LOCAL_SHARD_EXPORT), of which export_materialize_ms is
-    # np.asarray and export_submit_ms the scheduler submission;
-    # export_router_wait_max_ms = the longest any tap's leaf sat queued
-    # before its ingest began. All None when no leaf streamed this step
-    # — never a silent 0.
+    # over); export_router_busy_ms = the busiest ingesting thread's time
+    # inside ingests (the train thread where whole leaves leave as
+    # program outputs, the router where they are tapped, a per-device
+    # worker under BYTEPS_LOCAL_SHARD_EXPORT), of which
+    # export_materialize_ms is np.asarray and export_submit_ms the
+    # scheduler submission; export_router_wait_max_ms = the longest any
+    # tap's leaf sat queued before its ingest began. The two tap fields
+    # are None on a step with no tap, all six when no leaf or shard
+    # rode a key of its own — never a silent 0.
     dispatch_ms: Optional[float] = None
     export_tap_span_ms: Optional[float] = None
     export_router_busy_ms: Optional[float] = None
@@ -562,10 +564,16 @@ def export_span_fields(spans: List[tuple],
                        round_tag: Optional[int]) -> dict:
     """Reduce one step's spans — ``(stage, thread, start, end, args)``
     on perf_counter, as ``span`` appended them — to the StepReport's
-    export fields. Only this round's streamed leaves count: the spans
-    whose ``step`` is ``round_tag``, and of the taps those that caused
-    an ingest (a duplicate fire from another mesh device caused none).
-    No ingest: ``{}``, so every field stays None."""
+    export fields. Only this round's leaves count: the spans whose
+    ``step`` is ``round_tag``, and of the taps those that caused an
+    ingest (a duplicate fire from another mesh device caused none). An
+    ingest is a leaf's or a shard's way to the scheduler under a key of
+    its own, on either route: a tap's, on an export thread, or an output
+    leaf's, on the train thread (``cause`` ``out:<leaf>``), which no tap
+    caused and nothing queued, so a step whose leaves all left as
+    outputs has neither ``export_tap_span_ms`` nor
+    ``export_router_wait_max_ms``. No ingest: ``{}``, so every field
+    stays None."""
     mine = [sp for sp in spans if sp[4].get("step") == round_tag]
     ingests = [sp for sp in mine if sp[0] == tracing.EXPORT_INGEST]
     if not ingests:
@@ -586,9 +594,10 @@ def export_span_fields(spans: List[tuple],
         "export_router_busy_ms": busy[busiest] * 1e3,
         "export_materialize_ms": on_busiest(tracing.EXPORT_MATERIALIZE),
         "export_submit_ms": on_busiest(tracing.EXPORT_SUBMIT),
-        "export_router_wait_max_ms": max(
-            sp[4].get("queued_us", 0.0) for sp in ingests) / 1e3,
     }
+    queued = [sp[4]["queued_us"] for sp in ingests if "queued_us" in sp[4]]
+    if queued:
+        out["export_router_wait_max_ms"] = max(queued) / 1e3
     causes = {sp[4].get("cause") for sp in ingests}
     taps = [sp for sp in mine if sp[0] == tracing.EXPORT_TAP
             and f"tap:{sp[4].get('seq')}" in causes]

@@ -101,12 +101,12 @@ class _Telemetry:
                       shard_leaves: int = 0) -> None:
         """One PS train round's export accounting: how many gradient
         leaves were streamed out of the backward by io_callback taps vs
-        served by the post-jit fallback loop, and the round's
-        time-to-first-push (first submit entering the scheduler,
-        measured from the backward's dispatch). Cumulative counters +
-        the last round's TTFP let tests and the bench assert the
-        COMPUTE/PUSH overlap actually engaged instead of silently
-        falling back."""
+        left on the output route (outputs of the backward, claimed by
+        the train thread), and the round's time-to-first-push (first
+        submit entering the scheduler, measured from the backward's
+        dispatch). Cumulative counters + the last round's TTFP let
+        tests and the bench assert each route ran as often as the plan
+        says."""
         with self._lock:
             self._export_streamed = \
                 getattr(self, "_export_streamed", 0) + int(streamed)

@@ -262,9 +262,12 @@ def _release_pool():
 
 
 class _StreamRound:
-    """One PS train step's streamed-export state (BYTEPS_STREAM_EXPORT).
+    """One PS train step's tapped-export state: the shard leaves of a
+    mesh, and whole leaves too where BYTEPS_STREAM_EXPORT=1 asked for
+    their taps (unset, a whole leaf is an output of the backward and
+    never comes here).
 
-    The io_callback taps planted on each eligible gradient leaf inside
+    The io_callback taps planted on each tapped gradient leaf inside
     the compiled backward fire while XLA is still producing later
     gradients; each fire is enqueued (never executed — see
     ``_export_pool``) to the export worker, whose ingest:
@@ -282,11 +285,11 @@ class _StreamRound:
       "last layer first" is measured, not assumed;
     - publishes the waiter for the step's completion-ordered drain.
 
-    The main thread ``claim``s each eligible leaf, which collects the
+    The main thread ``claim``s each tapped leaf, which collects the
     ingest's waiter. A tap that has not fired long after its gradient
     was ready means the callback path is dead: that is an error, never
-    a quiet switch to the post-jit export (the streamed export is the
-    COMPUTE/PUSH overlap this step exists for).
+    a quiet switch to the output route (the key set and the per-device
+    bytes are the plan's, on every worker).
     """
 
     def __init__(self, tag: int, names, submit_streamed, mark_first_push,
@@ -475,9 +478,13 @@ class _StreamRound:
                     f"streamed gradient export: the tap of "
                     f"{self._names[i]!r} did not fire within "
                     f"{timeout:.0f}s of its gradient being ready — the "
-                    f"io_callback path is dead on this backend. Set "
-                    f"BYTEPS_STREAM_EXPORT=0 to run the post-jit export "
-                    f"deliberately.")
+                    f"io_callback path is dead on this backend. Whole "
+                    f"leaves are tapped only under BYTEPS_STREAM_EXPORT=1 "
+                    f"(unset it: they then leave as program outputs); a "
+                    f"mesh's shard leaves are tapped unless "
+                    f"BYTEPS_STREAM_EXPORT=0 or "
+                    f"BYTEPS_LOCAL_SHARD_EXPORT=0 turns the shard plan "
+                    f"off.")
             ev.wait()  # ingest in flight; its submission completes
         err = self._errors.get(i)
         if err is not None:
@@ -595,27 +602,42 @@ def make_ps_train_step(
     path — the reference's actual architecture (docs/architecture.md
     "General Workflow") with BOTH of its pipeline overlaps: the compiled
     program reduces gradients over the local slice (ICI psum == the NCCL
-    ReduceScatter tier); gradients exit to host AS XLA PRODUCES THEM
-    (streamed export: the last layers enter PUSH while earlier layers
-    are still in backprop); the PS client push_pulls each declared
+    ReduceScatter tier); gradients exit to host by the route their kind
+    in the export plan gives them (below: program outputs copied by the
+    runtime, or taps inside the backward); the PS client push_pulls each declared
     tensor across workers in priority order (the PUSH/PULL stages over
     DCN); and the optimizer update is applied per leaf from the
     completion-ordered drain, so UPDATE(k) overlaps PULL(k+1) (servers
     only sum — the update stays on the worker).
 
-    ``stream_export`` (BYTEPS_STREAM_EXPORT, default on when a scheduler
-    is running): tap each eligible gradient leaf inside the compiled
-    backward with jax.experimental.io_callback and hand it straight to
-    the scheduler — time-to-first-push drops from "after the whole
-    backward" to "after the first gradient". Each key's priority is
-    pinned from its measured first-export ordinal
-    (scheduler.production_priority): production order, not flatten
-    order, decides service order. Leaves that are bucket-fused
-    (sub-BYTEPS_FUSION_BYTES), rowsparse-routed or device-compressed
-    leave through the post-jit copy_to_host_async loop — numerics
-    identical. That split is decided by configuration alone: a tapped
+    ``stream_export`` (BYTEPS_STREAM_EXPORT; three states, needs a
+    running scheduler, numerics identical in all): the route by which a
+    gradient leaf leaves the chip.
+
+    - ``None``, nobody set it: the leaf's kind in the plan decides. A
+      shard leaf of the locality-shard plan (``local_shard_export``,
+      mesh axis > 1) is TAPPED inside the compiled backward with
+      jax.experimental.io_callback, each device's shard handed straight
+      to the scheduler while later gradients are still being produced,
+      its key's priority pinned from its measured first-export ordinal
+      (scheduler.production_priority). Every other leaf — a whole-leaf
+      key (dense or host-compressed), a bucket member
+      (sub-BYTEPS_FUSION_BYTES), a rowsparse or device-compressed leaf —
+      is an OUTPUT of the backward: its ``copy_to_host_async()`` is
+      issued right after dispatch, in flatten order, and the train
+      thread's claim loop takes each with ``np.asarray`` and submits
+      it. On a one-device mesh nothing is tapped: the program that runs
+      is the untapped ``grad_fn``, no tapped program is built. (On the
+      v5e a callback operand of a whole BERT-large leaf reaches the host
+      at 0.4-0.75 GB/s, a program output at 3.3-4.5: PERF.md section 6,
+      PR 24 and PR 25.)
+    - ``True``: whole-leaf keys are tapped too (time-to-first-push drops
+      from "after the whole backward" to "after the first gradient").
+    - ``False``: no taps and no shard plan; every leaf is an output.
+
+    The split is decided by configuration and topology alone: a tapped
     backward that fails to build or dispatch, or whose taps never fire,
-    raises; it is never swapped for the post-jit export at run time.
+    raises; it is never swapped for the output route at run time.
 
     ``sharded_apply`` (BYTEPS_SHARDED_APPLY, default on): split the
     monolithic apply jit into per-leaf donated partial updates
@@ -632,7 +654,7 @@ def make_ps_train_step(
     retrying with the same trees.
 
     ``local_shard_export`` (BYTEPS_LOCAL_SHARD_EXPORT, default on;
-    requires streaming): the hierarchical exchange —
+    off with ``stream_export=False``): the hierarchical exchange —
     reduce-scatter → push shard → update shard → all-gather. Eligible
     leaves are reduce-SCATTERED instead of psum'd, so each local
     device taps and exports only its own flat 1/local_size shard
@@ -1206,14 +1228,17 @@ def make_ps_train_step(
         # sub-fusion leaves belong to a bucket (see above). The tapped
         # jit is rebuilt only when the tree/eligibility changes.
         stream_cfg = stream_export if stream_export is not None \
-            else getattr(state.config, "stream_export", True)
+            else getattr(state.config, "stream_export", None)
         # a DETERMINISTIC gate (config + topology — identical on every
         # worker): the set of PS keys a worker pushes, shard subranges
         # included, has to be a pure function of deterministic inputs,
         # or the key sets would diverge and stall every peer's
         # aggregation. There is no runtime fallback behind it: a tapped
         # backward that fails to build, dispatch or fire raises.
-        stream_avail = (stream_cfg and state.scheduler is not None)
+        # ``stream_cfg`` False: no taps and no shard plan; None (nobody
+        # set it) and True differ only in which leaves are tapped, below.
+        stream_avail = (stream_cfg is not False
+                        and state.scheduler is not None)
         eligible: tuple = ()
         if stream_avail:
             el = []
@@ -1226,7 +1251,6 @@ def make_ps_train_step(
                     continue
                 el.append(i)
             eligible = tuple(el)
-        stream_on = stream_avail and bool(eligible)
         # ---- locality-shard plan (BYTEPS_LOCAL_SHARD_EXPORT): which
         # eligible leaves reduce-scatter so each local device exports
         # only its own 1/local_size shard. Host-compressed rounds keep
@@ -1258,7 +1282,19 @@ def make_ps_train_step(
                     continue  # padding beyond 1/8: not worth the wire
                 ss.append(i)
             shard_set = tuple(ss)
-        plan_key = (treedef, eligible, shard_set, n_shard)
+        # ---- the route off the chip, by the leaf's kind in the plan:
+        # a shard leaf is tapped (its per-device shards cross in
+        # parallel, inside the backward); a leaf on a whole-leaf key
+        # leaves as an OUTPUT of the backward, copied by the runtime and
+        # claimed below like the bucket members and rowsparse leaves —
+        # on the v5e a callback operand of that size reaches the host
+        # at 0.4-0.75 GB/s, a program output at 3.3-4.5 (PERF.md
+        # section 6, PR 24 and PR 25) — unless the
+        # caller asked for taps on whole leaves too (stream_cfg True).
+        # With nothing tapped the program that runs is ``grad_fn``.
+        tapped = eligible if stream_cfg else shard_set
+        stream_on = stream_avail and bool(tapped)
+        plan_key = (treedef, tapped, shard_set, n_shard)
         if stream_avail and stream_state["key"] != plan_key:
             # declare the per-shard subrange keys FIRST, in flatten
             # order — every worker flattens the same tree, so the
@@ -1298,7 +1334,7 @@ def make_ps_train_step(
                 from jax.sharding import NamedSharding
                 stream_state["nsharding"] = NamedSharding(mesh, P(axis))
             stream_state["fn"] = _build_streamed_fn(
-                eligible, shard_set, len(names)) if stream_on else None
+                tapped, shard_set, len(names)) if stream_on else None
             stream_state["key"] = plan_key
 
         # ---- sharded-apply build (cached per tree structure) ----
@@ -1378,7 +1414,7 @@ def make_ps_train_step(
                 mark_first_push,
                 shard_plan={i: n_shard for i in shard_set},
                 submit_shard=submit_shard)
-            for i in eligible:
+            for i in tapped:
                 round_obj.expect(i)
             stream_state["holder"]["round"] = round_obj
             try:
@@ -1406,13 +1442,17 @@ def make_ps_train_step(
         # the export_done mark, ``drain`` from there to drain_done
         phase = tracing.span(tracing.STEP_CLAIM, step=tag).start()
         g_leaves = jax.tree.leaves(grads)
-        streamed_set = set(eligible) if round_obj is not None else set()
-        # start the D2H copies for the non-streamed leaves now; each
-        # np.asarray below then only waits for ITS leaf, so the
-        # transfer of leaf k+1 rides the bus while leaf k is already
-        # in PUSH — the reference's per-partition COPYD2H/PUSH overlap
-        # (core_loops.cc:378-443). Streamed leaves already crossed in
-        # their tap.
+        streamed_set = set(tapped) if round_obj is not None else set()
+        # start the D2H copies of the output-route leaves now, all of
+        # them, in flatten order (a pure function of the plan: every
+        # worker issues and claims them alike); each np.asarray below
+        # then only waits for ITS leaf. The TPU runtime works on the
+        # copies side by side (it de-tiles each on host threads) and
+        # the large ones finish close together, late in the claim; a
+        # bounded window of copies in flight does overlap the PUSH with
+        # the transfers but slows the transfers by as much, on one
+        # host's cores (PERF.md section 6, PR 25). Tapped leaves cross
+        # in their tap.
         for i, leaf in enumerate(g_leaves):
             if i not in streamed_set and hasattr(leaf,
                                                  "copy_to_host_async"):
@@ -1456,24 +1496,53 @@ def make_ps_train_step(
                     else:
                         waiters.append((i, *w))
                     continue
-                h = np.asarray(leaf)  # ready-or-wait for THIS leaf
-                exp_whole_ctr.inc(h.nbytes)
-                exp_dev0_ctr.inc(h.nbytes)
-                if _route_rowsparse(name, h, state, rowsparse_params):
-                    flush_bucket()
-                    # non-f32 grads upcast for the wire, cast back
-                    waiters.append((i, *submit_sparse(name, h,
-                                                      h.dtype)))
-                elif h.nbytes < fusion:
+                nb = leaf.nbytes
+                exp_whole_ctr.inc(nb)
+                exp_dev0_ctr.inc(nb)
+                sparse = _route_rowsparse(name, leaf, state,
+                                          rowsparse_params)
+                if not sparse and nb < fusion:
+                    # bucket member: a cross-leaf artifact, submitted
+                    # by whichever later leaf flushes the bucket
+                    h = np.asarray(leaf)
                     if bucket and (bucket[0][2].dtype != h.dtype
-                                   or bucket_bytes + h.nbytes
-                                   > bucket_cap):
+                                   or bucket_bytes + nb > bucket_cap):
                         flush_bucket()
                     bucket.append((i, name, h))
-                    bucket_bytes += h.nbytes
-                else:
-                    flush_bucket()
-                    waiters.append((i, *submit(name, h.reshape(-1))))
+                    bucket_bytes += nb
+                    continue
+                # a leaf on a key of its own, on the output route: the
+                # same three spans its tap's ingest would be on the
+                # router, on this thread (no tap caused it and nothing
+                # queued it: ``cause`` names the output, no
+                # ``queued_us``)
+                with tracing.span(tracing.EXPORT_INGEST, tid=name,
+                                  step=tag, leaf=i, bytes=nb,
+                                  cause=f"out:{i}"):
+                    with tracing.span(tracing.EXPORT_MATERIALIZE,
+                                      step=tag, leaf=i, bytes=nb):
+                        # ready-or-wait for THIS leaf's transfer
+                        h = np.asarray(leaf)
+                    if sparse:
+                        flush_bucket()
+                    # a whole-leaf key does not close the bucket: its
+                    # members, and so its digest, are the same whether
+                    # the whole leaves between them are tapped or not
+                    with tracing.span(tracing.EXPORT_SUBMIT, tid=name,
+                                      step=tag, leaf=i, bytes=nb) as sp:
+                        if sparse:
+                            # non-f32 grads upcast for the wire, cast
+                            # back
+                            w = submit_sparse(name, h, h.dtype)
+                        else:
+                            w = submit(name, h.reshape(-1))
+                            ctx = state.registry.get(name)
+                            if ctx is not None:
+                                # what the wire's sends name as their
+                                # cause
+                                sp.set(key=ctx.declared_key,
+                                       partitions=len(ctx.partitions))
+                    waiters.append((i, *w))
             flush_bucket()
             phase.stop()
             if prof is not None:

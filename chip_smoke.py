@@ -64,7 +64,7 @@ BATCH_PER_CHIP = 32
 LR, EPS, WEIGHT_DECAY = 1e-4, 1e-6, 0.01
 
 # Tolerances. The two runs execute the same model code on the same batch
-# from the same parameters, and with one worker the PS transport (tap ->
+# from the same parameters, and with one worker the PS transport (export ->
 # D2H -> wire -> server sum -> arena -> H2D) is the identity: on the v5e
 # (PR 21) every byte it moved came back bitwise equal. What differs is
 # the compiled program. The control is ONE
@@ -303,7 +303,7 @@ def model_and_batch(rehearse: bool, n_chips: int):
     from byteps_tpu.models import bert
 
     if rehearse:
-        # smallest shape that still has stream-eligible (>= 2 MiB) and
+        # smallest shape that still has whole-leaf (>= 2 MiB) and
         # bucket-fused leaves under the default thresholds
         cfg = bert.BertConfig(vocab_size=2048, dim=256, n_layers=2,
                               n_heads=4, ffn_dim=1024, max_seq_len=128,
@@ -515,22 +515,31 @@ def check_sharding_spans(tree, n: int) -> None:
 
 
 def check_engagement(bps, state, snaps, init_host, n: int) -> None:
-    """The engagement counters: the streamed export really ran (no
-    fallback for stream-eligible leaves), the arena served every
+    """The engagement counters: every leaf left by the route its kind
+    in the plan gives it (a leaf the plan shards across the ``n`` chips
+    is tapped and counts as streamed: none on one chip; every other
+    leaf is an output of the backward), the arena served every
     checkout, the wire carried fused PUSHPULLs and the server folded
     exactly the bytes that were pushed."""
     import jax
 
+    from byteps_tpu.ops.push_pull import shard_layout
+
     reports = bps.get_step_reports()
     last = reports[-1]
     fusion = state.config.fusion_bytes
+    floor = max(fusion, state.config.shard_min_bytes)
     leaves = jax.tree.leaves(init_host)
-    eligible = sum(1 for v in leaves if v.nbytes and v.nbytes >= fusion)
+    # jax/train.py's shard plan: large enough, and padded by an eighth
+    # at most
+    sharded = 0 if n == 1 else sum(
+        1 for v in leaves if v.nbytes >= floor
+        and shard_layout(v.size, n)[1] * 8 <= v.size)
     grad_bytes = sum(v.nbytes for v in leaves)
     log(f"last StepReport: streamed={last['streamed_leaves']} "
         f"fallback={last['fallback_leaves']} ttfp_ms={last.get('ttfp_ms')} of "
-        f"{len(leaves)} leaves, {eligible} stream-eligible "
-        f"(>= {fusion} B)")
+        f"{len(leaves)} leaves, {sharded} sharded over {n} chip(s) "
+        f"(>= {floor} B)")
     stages = ("wall_ms", "compute_ms", "drain_ms", "tail_ms",
               "pull_wait_ms", "allgather_ms", "push_p95_ms", "pull_p95_ms",
               "h2d_update_p95_ms", "server_recv_ms", "server_queue_ms",
@@ -538,24 +547,23 @@ def check_engagement(bps, state, snaps, init_host, n: int) -> None:
     log("last StepReport host-clock walls (observations, not metrics): "
         + ", ".join(f"{k}={last[k]:.1f}" for k in stages
                     if last.get(k) is not None))
-    if not (last["streamed_leaves"] > 0
-            and last["streamed_leaves"] == eligible):
+    if last["streamed_leaves"] != sharded:
         raise AssertionError(
-            f"streamed export fell back: streamed={last['streamed_leaves']} of "
-            f"{eligible} eligible leaves")
-    if last["fallback_leaves"] != len(leaves) - eligible:
+            f"streamed={last['streamed_leaves']}, want the {sharded} "
+            f"leaves the plan shards")
+    if last["fallback_leaves"] != len(leaves) - sharded:
         raise AssertionError(
-            f"fallback={last['fallback_leaves']} != {len(leaves) - eligible} "
-            f"non-eligible (bucket-fused) leaves")
+            f"fallback={last['fallback_leaves']} != {len(leaves) - sharded} "
+            f"leaves on the output route")
     first, end = snaps[0], snaps[-1]
     a0, a1 = first["arena"], end["arena"]
     log(f"arena after step 1: {a0}")
     log(f"arena after step {len(snaps)}: {a1}")
     grew = a1["export_streamed_leaves"] - a0["export_streamed_leaves"]
-    if grew != eligible * (len(snaps) - 1):
+    if grew != sharded * (len(snaps) - 1):
         raise AssertionError(
             f"export_streamed_leaves grew {grew}, want "
-            f"{eligible * (len(snaps) - 1)}")
+            f"{sharded * (len(snaps) - 1)}")
     for key in ("fresh_allocs", "checkout_conflicts"):
         if a1[key] != 0:
             raise AssertionError(f"arena {key} = {a1[key]}, want 0")

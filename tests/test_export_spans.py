@@ -1,9 +1,11 @@
 """The program's spans on the gradient-export path (utils/tracing.py
 ``span``; jax/train.py, core/scheduler.py, core/metrics.py): every span
 of PERF.md's table is recorded on the thread the table names, with the
-round's tag as its ``step``; the StepReport's export fields are reduced
-from them and hold their identities; nothing is read where nothing
-streamed or metrics are off; and a profiler session opened by anybody
+round's tag as its ``step``, on the tapped route (asked for with
+``BYTEPS_STREAM_EXPORT=1``) and on the output route (what unset means on
+one device); the StepReport's export fields are reduced
+from them and hold their identities; nothing is read where no leaf
+rides a key of its own or metrics are off; and a profiler session opened by anybody
 holds the spans on its host lines, ``bps.wire.send`` and
 ``bps.wire.done`` pairing by ``rid``."""
 
@@ -62,7 +64,7 @@ def _ps_env(extra_env: dict = None):
                 os.environ[k] = v
 
 
-def _stepper(**kw):
+def _stepper(mesh=None, **kw):
     import jax
     import jax.numpy as jnp
 
@@ -77,7 +79,7 @@ def _stepper(**kw):
              "y": jnp.asarray(rng.randint(0, 10, 32), jnp.int32)}
     tx = optax.adam(1e-2)
     step = make_ps_train_step(lambda p, b: mlp.loss_fn(p, b, cfg), tx,
-                              get_state().mesh, **kw)
+                              mesh or get_state().mesh, **kw)
     state = [params, tx.init(params)]
 
     def run(n=1):
@@ -91,11 +93,12 @@ def _stepper(**kw):
 
 # whole-leaf: every leaf streams through the one router; shard: the
 # weights reduce-scatter and leave as per-device shards through the
-# bps-export-d{k} workers, the biases stay whole
+# bps-export-d{k} workers, the biases stay whole. Both ask for taps on
+# whole leaves: unset, a whole leaf is an output (the tests at the end)
 MODES = {
-    "whole-leaf": {"BYTEPS_FUSION_BYTES": "0",
+    "whole-leaf": {"BYTEPS_STREAM_EXPORT": "1", "BYTEPS_FUSION_BYTES": "0",
                    "BYTEPS_LOCAL_SHARD_EXPORT": "0"},
-    "shard": {"BYTEPS_FUSION_BYTES": "0",
+    "shard": {"BYTEPS_STREAM_EXPORT": "1", "BYTEPS_FUSION_BYTES": "0",
               "BYTEPS_LOCAL_SHARD_EXPORT": "1",
               "BYTEPS_SHARD_MIN_BYTES": "1024"},
 }
@@ -215,7 +218,9 @@ def test_the_export_fields_hold_their_identities_on_every_report(streamed):
         assert r["dispatch_ms"] <= r["compute_ms"] + eps
 
 
-def test_fields_are_none_on_a_step_with_no_streamed_leaf():
+def test_fields_are_none_on_a_step_with_no_leaf_on_a_key_of_its_own():
+    # under the fusion size every leaf is a bucket member: no tap and
+    # no ingest, whatever the route
     with _ps_env({"BYTEPS_STREAM_EXPORT": "0"}) as bps:
         from byteps_tpu.core.state import get_state
 
@@ -232,7 +237,7 @@ def test_fields_are_none_on_a_step_with_no_streamed_leaf():
 
 
 def test_no_builder_and_no_report_with_metrics_off():
-    with _ps_env({"BYTEPS_METRICS": "0",
+    with _ps_env({"BYTEPS_METRICS": "0", "BYTEPS_STREAM_EXPORT": "1",
                   "BYTEPS_FUSION_BYTES": "0"}) as bps:
         from byteps_tpu.core.state import get_state
 
@@ -262,7 +267,7 @@ def test_an_open_profiler_session_holds_the_spans(tmp_path):
     import jax
     from jax.profiler import ProfileData
 
-    with _ps_env({"BYTEPS_FUSION_BYTES": "0",
+    with _ps_env({"BYTEPS_STREAM_EXPORT": "1", "BYTEPS_FUSION_BYTES": "0",
                   "BYTEPS_LOCAL_SHARD_EXPORT": "0"}):
         from byteps_tpu.core.state import get_state
 
@@ -310,6 +315,73 @@ def test_an_open_profiler_session_holds_the_spans(tmp_path):
 
 
 # --------------------------------------------------------------------- #
+# the output route: whole leaves as outputs of the untapped backward
+# --------------------------------------------------------------------- #
+
+TAP_FIELDS = ("export_tap_span_ms", "export_router_wait_max_ms")
+
+
+# unset on a one-device mesh (what the one-chip cells run) and the
+# caller's "0" are the same route: no leaf is tapped
+@pytest.mark.parametrize("setting", [None, "0"], ids=["unset", "off"])
+def test_the_output_route_fills_four_fields_and_leaves_the_tap_fields_none(
+        setting):
+    import jax
+    from jax.sharding import Mesh
+
+    env = {"BYTEPS_FUSION_BYTES": "0"}
+    if setting is not None:
+        env["BYTEPS_STREAM_EXPORT"] = setting
+    with _ps_env(env) as bps:
+        from byteps_tpu.core.state import get_state
+
+        assert get_state().config.stream_export is (
+            None if setting is None else False)
+        run, n_leaves = _stepper(
+            mesh=Mesh(np.array(jax.devices()[:1]), ("dp",)))
+        run(3)
+        reports = bps.get_step_reports()[-3:]
+        spans = get_state().profiler.last_spans()
+        whole_bytes = bps.get_metrics()["counters"]["export/whole_bytes"]
+    eps = 1e-6
+    for r in reports:
+        assert r["streamed_leaves"] == 0
+        assert r["fallback_leaves"] == n_leaves
+        for f in EXPORT_FIELDS:
+            if f in TAP_FIELDS:
+                assert r[f] is None, (f, r)
+            else:
+                assert r[f] is not None and r[f] >= 0, (f, r)
+        assert (r["export_materialize_ms"] + r["export_submit_ms"]
+                <= r["export_router_busy_ms"] + eps)
+        assert r["export_router_busy_ms"] <= r["compute_ms"] + eps
+        assert r["dispatch_ms"] <= r["compute_ms"] + eps
+    by = _by_stage(spans)
+    train = threading.current_thread().name
+    assert tracing.EXPORT_TAP not in by and tracing.EXPORT_ROUTE not in by
+    ingests = by[tracing.EXPORT_INGEST]
+    # one ingest a leaf, in flatten order, on the thread that claims
+    assert [sp[4]["leaf"] for sp in ingests] == list(range(n_leaves))
+    assert [sp[4]["cause"] for sp in ingests] == [
+        f"out:{i}" for i in range(n_leaves)]
+    assert all("queued_us" not in sp[4] and sp[4]["bytes"] > 0
+               and sp[4]["step"] == 3 for sp in ingests)
+    (claim,) = by[tracing.STEP_CLAIM]
+    for stage in (tracing.EXPORT_INGEST, tracing.EXPORT_MATERIALIZE,
+                  tracing.EXPORT_SUBMIT):
+        assert len(by[stage]) == n_leaves, stage
+        assert {sp[1] for sp in by[stage]} == {train}, stage
+        assert all(claim[2] <= sp[2] and sp[3] <= claim[3]
+                   for sp in by[stage]), stage
+    for child in by[tracing.EXPORT_MATERIALIZE] + by[tracing.EXPORT_SUBMIT]:
+        assert any(p[2] <= child[2] and child[3] <= p[3] for p in ingests)
+    # every byte of every step is counted as a whole-leaf export
+    assert whole_bytes == 3 * sum(sp[4]["bytes"] for sp in ingests)
+    # the wire's sends still name no tap's submit but a key's
+    assert by[tracing.WIRE_SEND] and by[tracing.WIRE_DONE]
+
+
+# --------------------------------------------------------------------- #
 # the reduction alone
 # --------------------------------------------------------------------- #
 
@@ -349,6 +421,36 @@ def test_reduction_takes_the_busiest_thread_and_this_round_only():
     assert f["export_router_wait_max_ms"] == pytest.approx(9.0)
     assert export_span_fields(spans, 6) == {}
     assert export_span_fields([], None) == {}
+
+
+def test_reduction_of_an_output_route_step_has_no_tap_field():
+    spans = [
+        _sp("bps.step.dispatch", "main", 0.0, 0.004, step=7),
+        _sp("bps.export.materialize", "main", 0.010, 0.030, step=7),
+        _sp("bps.export.submit", "main", 0.030, 0.031, step=7),
+        _sp("bps.export.ingest", "main", 0.010, 0.032, step=7,
+            cause="out:0"),
+        _sp("bps.export.materialize", "main", 0.032, 0.040, step=7),
+        _sp("bps.export.submit", "main", 0.041, 0.043, step=7),
+        _sp("bps.export.ingest", "main", 0.032, 0.044, step=7,
+            cause="out:3"),
+    ]
+    f = export_span_fields(spans, 7)
+    assert sorted(f) == ["dispatch_ms", "export_materialize_ms",
+                         "export_router_busy_ms", "export_submit_ms"]
+    assert f["dispatch_ms"] == pytest.approx(4.0)
+    assert f["export_router_busy_ms"] == pytest.approx(34.0)
+    assert f["export_materialize_ms"] == pytest.approx(28.0)
+    assert f["export_submit_ms"] == pytest.approx(3.0)
+    # a mesh's step: the shard's ingest was queued, the output's was not
+    mixed = spans + [
+        _sp("bps.export.tap", "cb", 0.005, 0.006, step=7, seq=4),
+        _sp("bps.export.ingest", "bps-export-d1_0", 0.006, 0.009, step=7,
+            cause="tap:4", queued_us=250.0)]
+    g = export_span_fields(mixed, 7)
+    assert g["export_router_wait_max_ms"] == pytest.approx(0.25)
+    assert g["export_tap_span_ms"] == pytest.approx(1.0)
+    assert g["export_router_busy_ms"] == pytest.approx(34.0)  # main
 
 
 def test_end_step_keeps_the_spans_and_none_means_none():

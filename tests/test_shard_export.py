@@ -205,7 +205,11 @@ def test_odd_shapes_pad_parity():
         np.testing.assert_array_equal(a, b)
 
 
-def test_pad_threshold_falls_back():
+# a whole leaf beside shard leaves is an output of the backward unless
+# the caller asked for taps on whole leaves too
+@pytest.mark.parametrize("stream,streamed", [(None, 2), ("1", 4)],
+                         ids=["unset", "taps-asked"])
+def test_pad_threshold_falls_back(stream, streamed):
     """A leaf whose padding would exceed 1/8 of its size keeps the
     whole-leaf path (with 8 shards that can only happen to sub-56-elem
     leaves, so the floor is dropped to expose the gate)."""
@@ -226,17 +230,21 @@ def test_pad_threshold_falls_back():
                 + 0.0 * jnp.sum(b["x"]))
 
     tx = optax.sgd(1e-2)
-    with _ps_env({"BYTEPS_FUSION_BYTES": "0",
-                  "BYTEPS_SHARD_MIN_BYTES": "8"}) as bps:
+    env = {"BYTEPS_FUSION_BYTES": "0", "BYTEPS_SHARD_MIN_BYTES": "8"}
+    if stream is not None:
+        env["BYTEPS_STREAM_EXPORT"] = stream
+    with _ps_env(env) as bps:
         p = jax.tree.map(jnp.array, params)
         opt = tx.init(p)
         step = make_ps_train_step(loss_fn, tx, get_state().mesh)
         for _ in range(2):
             p, opt, _ = step(p, opt, batch)
         stats = bps.get_arena_stats()
-        # exactly ONE leaf per step sharded (big); frag exported whole
+        # exactly ONE leaf per step sharded (big); frag exported whole,
+        # tapped only where taps on whole leaves were asked for
         assert stats["export_shard_leaves"] == 2
-        assert stats["export_streamed_leaves"] == 4
+        assert stats["export_streamed_leaves"] == streamed
+        assert stats["export_fallback_leaves"] == 4 - streamed
 
 
 def test_local_size_one_degenerate_is_whole_leaf():

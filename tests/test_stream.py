@@ -2,7 +2,8 @@
 (BYTEPS_STREAM_EXPORT / BYTEPS_SHARDED_APPLY, jax/train.py +
 jax/optim.py): numerics parity of stream-export on vs off vs the
 single-process baseline (dense, fused-bucket and compression-enabled
-configs), bitwise parity of the sharded apply against the fused optax
+configs), of the route that unset chooses against both, which leaves
+that route taps on a mesh and on one device, bitwise parity of the sharded apply against the fused optax
 apply for adam/sgd, the non-separable fallback, export-stage telemetry
 (streamed-leaf counters + time-to-first-push), and production-order
 priority pinning end to end."""
@@ -68,7 +69,8 @@ def _setup():
     return cfg, params, batch
 
 
-def _run_steps(params, batch, cfg, steps=3, tx=None, **kw):
+def _run_steps(params, batch, cfg, steps=3, tx=None, mesh=None,
+               losses=None, **kw):
     import jax
     import jax.numpy as jnp
 
@@ -80,9 +82,11 @@ def _run_steps(params, batch, cfg, steps=3, tx=None, **kw):
     tx = tx or optax.adam(1e-2)
     opt = tx.init(params)
     step = make_ps_train_step(lambda p, b: mlp.loss_fn(p, b, cfg), tx,
-                              get_state().mesh, **kw)
+                              mesh or get_state().mesh, **kw)
     for _ in range(steps):
         params, opt, loss = step(params, opt, batch)
+        if losses is not None:
+            losses.append(np.asarray(loss))
     return ([np.asarray(x) for x in jax.tree.leaves(params)],
             float(loss))
 
@@ -143,6 +147,164 @@ def test_stream_on_off_parity(fusion, kw):
         base = _local_steps(params, batch, cfg)
         for a, b in zip(on, base):
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("fusion,kw", [
+    ("0", {}),
+    ("4096", {}),
+    ("0", dict(compression={"compressor": "onebit", "ef": "vanilla"},
+               min_compress_bytes=0, device_compress=False)),
+], ids=["dense", "fused-bucket", "onebit"])
+def test_unset_route_parity_with_taps_and_without(fusion, kw):
+    """Nobody set BYTEPS_STREAM_EXPORT: whole leaves leave as outputs of
+    the untapped backward. Every loss and every parameter is bitwise
+    what ``=1`` (whole leaves tapped) and ``=0`` give; no leaf streams
+    (these leaves are too small to shard), the same bytes are counted
+    as whole-leaf exports, and the same keys are declared: a whole leaf
+    between two bucket members closes the bucket on no route, so the
+    bucket's digest is one."""
+    cfg, params, batch = _setup()
+    got = {}
+    for arm in (None, "1", "0"):
+        env = {"BYTEPS_FUSION_BYTES": fusion}
+        if arm is not None:
+            env["BYTEPS_STREAM_EXPORT"] = arm
+        with _ps_env(env) as bps:
+            from byteps_tpu.core.state import get_state
+
+            assert get_state().config.stream_export is {
+                None: None, "1": True, "0": False}[arm]
+            losses = []
+            leaves, _ = _run_steps(params, batch, cfg, losses=losses, **kw)
+            stats = bps.get_arena_stats()
+            got[arm] = (leaves, losses, stats["export_streamed_leaves"],
+                        stats["export_fallback_leaves"],
+                        bps.get_metrics()["counters"]["export/whole_bytes"],
+                        sorted(c.name for c in
+                               get_state().registry.contexts_in_order()))
+    n_leaves = len(got[None][0])
+    assert got[None][2] == 0 and got["0"][2] == 0 and got["1"][2] > 0
+    assert got[None][3] == 3 * n_leaves
+    assert got[None][4] == got["1"][4] == got["0"][4] > 0
+    assert got[None][5] == got["1"][5] == got["0"][5]
+    assert any(n.startswith("fused/") for n in got[None][5]) == (
+        fusion != "0")
+    for arm in ("1", "0"):
+        for a, b in zip(got[None][0], got[arm][0]):
+            np.testing.assert_array_equal(a, b)
+        for a, b in zip(got[None][1], got[arm][1]):
+            np.testing.assert_array_equal(a, b)
+
+
+def _export_plan_run(env, mesh_devices=None, steps=3):
+    """PS steps under ``env``; what the export plan did, read from the
+    counters and the last step's spans."""
+    import jax
+    from jax.sharding import Mesh
+
+    from byteps_tpu.utils import tracing
+
+    cfg, params, batch = _setup()
+    with _ps_env(env) as bps:
+        from byteps_tpu.core.state import get_state
+
+        mesh = None if mesh_devices is None else Mesh(
+            np.array(jax.devices()[:mesh_devices]), ("dp",))
+        leaves, _ = _run_steps(params, batch, cfg, steps=steps, mesh=mesh)
+        spans = get_state().profiler.last_spans()
+        ctr = bps.get_metrics()["counters"]
+        out = {
+            "leaves": leaves, "report": bps.get_step_reports()[-1],
+            "arena": bps.get_arena_stats(),
+            "shard_bytes": ctr.get("export/shard_bytes", 0),
+            "whole_bytes": ctr.get("export/whole_bytes", 0),
+            "device_bytes": {int(k.rsplit("/", 1)[1]): v
+                             for k, v in ctr.items()
+                             if k.startswith("export/device_bytes/")},
+            "taps": sorted(sp[4]["leaf"] for sp in spans
+                           if sp[0] == tracing.EXPORT_TAP),
+            "ingests": sorted(
+                (sp[4]["leaf"], sp[4].get("dev", -1),
+                 sp[4]["cause"].split(":")[0], sp[1].rsplit("_", 1)[0])
+                for sp in spans if sp[0] == tracing.EXPORT_INGEST),
+            "order": dict(get_state().scheduler.export_order()),
+        }
+    return out
+
+
+def test_unset_plan_on_the_mesh_taps_the_shard_leaves_only():
+    """Eight devices, nobody set BYTEPS_STREAM_EXPORT: the weights shard
+    and are tapped exactly as ``=1`` taps them (same per-device bytes,
+    exactly even; same shard keys at production-order priority; same
+    parameters), the biases ride whole-leaf keys and leave as outputs,
+    claimed on the train thread."""
+    env = {"BYTEPS_FUSION_BYTES": "0", "BYTEPS_SHARD_MIN_BYTES": "1024"}
+    unset = _export_plan_run(env)
+    asked = _export_plan_run({**env, "BYTEPS_STREAM_EXPORT": "1"})
+    train = threading.current_thread().name
+    import jax
+    ndims = [x.ndim for x in jax.tree.leaves(_setup()[1])]
+    weights = [i for i, n in enumerate(ndims) if n == 2]
+    biases = [i for i, n in enumerate(ndims) if n == 1]
+    assert len(weights) == len(biases) == 3
+    # shard leaves: the parent's program and bytes
+    assert unset["report"]["streamed_leaves"] == len(weights)
+    assert unset["report"]["fallback_leaves"] == len(biases)
+    assert asked["report"]["streamed_leaves"] == len(weights + biases)
+    assert unset["arena"]["export_shard_leaves"] == \
+        asked["arena"]["export_shard_leaves"] == 3 * len(weights)
+    assert unset["shard_bytes"] == asked["shard_bytes"] > 0
+    assert unset["whole_bytes"] == asked["whole_bytes"] > 0
+    per_dev = [unset["device_bytes"][d] for d in range(1, 8)]
+    assert len(set(per_dev)) == 1 and per_dev[0] * 8 == unset["shard_bytes"]
+    assert unset["device_bytes"] == asked["device_bytes"]
+    # every device fires every tap; only the weights have one
+    assert sorted(set(unset["taps"])) == weights
+    assert sorted(set(asked["taps"])) == sorted(weights + biases)
+    shard_ingests = [m for m in unset["ingests"] if m[0] in weights]
+    assert shard_ingests == [m for m in asked["ingests"] if m[0] in weights]
+    assert [m[:3] for m in shard_ingests] == [
+        (w, d, "tap") for w in weights for d in range(8)]
+    assert all(m[3].startswith("bps-export-d") for m in shard_ingests)
+    # whole leaves: outputs, on the thread that claims
+    assert [m for m in unset["ingests"] if m[0] in biases] == [
+        (b, -1, "out", train.rsplit("_", 1)[0]) for b in biases]
+    # the shards' keys keep their measured production order; a whole
+    # leaf on the output route is not in that order any more
+    assert len(asked["order"]) > len(unset["order"]) > 0
+    assert set(unset["order"]) <= set(asked["order"])
+    for a, b in zip(unset["leaves"], asked["leaves"]):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_unset_on_one_device_builds_no_tapped_program(monkeypatch):
+    """A one-device mesh has nothing to shard, so nothing is tapped:
+    the program that runs is ``grad_fn``, and no ``io_callback`` is ever
+    planted (asked for with ``=1``, it is)."""
+    import jax.experimental
+
+    planted = []
+    real = jax.experimental.io_callback
+
+    def spy(*a, **kw):
+        planted.append(1)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(jax.experimental, "io_callback", spy)
+    env = {"BYTEPS_FUSION_BYTES": "0", "BYTEPS_SHARD_MIN_BYTES": "1024"}
+    unset = _export_plan_run(env, mesh_devices=1)
+    assert planted == []
+    assert unset["taps"] == [] and unset["shard_bytes"] == 0
+    assert unset["report"]["streamed_leaves"] == 0
+    assert unset["report"]["fallback_leaves"] == 6
+    assert [m[2] for m in unset["ingests"]] == ["out"] * 6
+    assert unset["order"] == {}
+    asked = _export_plan_run({**env, "BYTEPS_STREAM_EXPORT": "1"},
+                             mesh_devices=1)
+    assert len(planted) == 6 and asked["report"]["streamed_leaves"] == 6
+    assert unset["whole_bytes"] == asked["whole_bytes"] > 0
+    for a, b in zip(unset["leaves"], asked["leaves"]):
+        np.testing.assert_array_equal(a, b)
 
 
 def test_sharded_apply_on_off_parity():
