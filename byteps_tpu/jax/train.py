@@ -37,6 +37,47 @@ from ..parallel.mesh import DP_AXIS
 from ..utils import tracing
 
 
+def _loss_and_stats(loss_fn: Callable) -> Callable:
+    """``loss_fn(params, batch)`` may return the scalar loss or ``(loss,
+    stats)``; either way the step programs differentiate ``(loss,
+    stats)``. ``stats`` is a small pytree (a dict by counter name) of
+    device scalars or arrays the model counted on the chip, per-expert
+    token counts say. They leave the chip as one more output of the
+    step program beside the loss, SUMMED over the data-parallel axis,
+    so every statistic must be additive across data shards: a count,
+    never a maximum or a mean (those are derived from the counts by
+    whoever reads the counters). They are added to the metrics
+    registry's counters after the step (``_fold_stats``): no host
+    callback enters the program, so it stays servable from the
+    persistent compile cache. A scalar loss gives an empty ``stats``
+    and costs nothing."""
+
+    def fn(params, batch):
+        out = loss_fn(params, batch)
+        return out if isinstance(out, tuple) else (out, {})
+
+    return fn
+
+
+def _fold_stats(stats) -> None:
+    """Add one step's device statistics to the registry: the scalar at
+    path ``a/b`` goes into counter ``a/b``, element ``[i, j]`` of an
+    array there into counter ``a/b/i/j``. Reads the values to the host,
+    so the step they belong to must be done or nothing else may wait on
+    this thread."""
+    if not stats:
+        return
+    from ..core.state import get_state
+
+    registry = get_state().metrics
+    for path, leaf in jax.tree_util.tree_flatten_with_path(stats)[0]:
+        name = "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                        for k in path)
+        for index, value in np.ndenumerate(np.asarray(leaf)):
+            registry.counter("/".join([name, *map(str, index)])).inc(
+                value.item())
+
+
 def make_train_step(
     loss_fn: Callable,
     tx: optax.GradientTransformation,
@@ -49,7 +90,10 @@ def make_train_step(
 ):
     """Build a jitted SPMD train step.
 
-    ``loss_fn(params, batch) -> scalar`` computed on the local batch shard;
+    ``loss_fn(params, batch) -> scalar`` (or ``(scalar, stats)``, see
+    ``_loss_and_stats``: the statistics of step k reach the registry
+    once step k + 1 is dispatched, ``step.fold_stats()`` folds the last
+    step's) computed on the local batch shard;
     ``tx`` should be ``byteps_tpu.jax.distributed_optimizer(...)`` so the
     gradient push_pull happens inside its update (or pass a plain optax tx
     plus ``grads_transform=lambda g: psum_tree(g, axis)``).
@@ -68,43 +112,63 @@ def make_train_step(
     if opt_specs is None:
         opt_specs = P()
 
+    loss_and_stats = _loss_and_stats(loss_fn)
+
     def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        (loss, stats), grads = jax.value_and_grad(
+            loss_and_stats, has_aux=True)(params, batch)
         if grads_transform is not None:
             grads = grads_transform(grads)
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         loss = jax.lax.pmean(loss, axis)
-        return params, opt_state, loss
+        stats = jax.tree.map(lambda x: jax.lax.psum(x, axis), stats)
+        return params, opt_state, loss, stats
 
     smapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(), opt_specs, batch_spec),
-        out_specs=(P(), opt_specs, P()),
+        out_specs=(P(), opt_specs, P(), P()),
         check_vma=False,
     )
     donate_argnums = (0, 1) if donate else ()
     jitted = jax.jit(smapped, donate_argnums=donate_argnums)
-    return _with_tracer_tick(jitted)
+    return _with_tracer_tick(jitted, stats=True)
 
 
-def _with_tracer_tick(jitted):
+def _with_tracer_tick(jitted, stats: bool = False):
     """Tick the Chrome-trace step counter per training step (the reference
     counts steps to window tracing between BYTEPS_TRACE_START/END_STEP,
-    global.cc:113-124)."""
+    global.cc:113-124). ``stats``: the program's fourth output is the
+    step's device statistics; they are taken off the result and folded
+    into the registry one step late, after the next dispatch, so the
+    host never waits on the step it has just queued."""
     import functools as _functools
 
     from ..core.state import get_state
+
+    waiting: list = []
+
+    def fold_stats():
+        while waiting:
+            _fold_stats(waiting.pop())
 
     @_functools.wraps(jitted)
     def stepper(*args, **kw):
         tracer = get_state().tracer
         if tracer is not None:
             tracer.step()
-        return jitted(*args, **kw)
+        out = jitted(*args, **kw)
+        if not stats:
+            return out
+        fold_stats()
+        if out[3]:
+            waiting.append(out[3])
+        return out[:3]
 
     # keep access to the underlying jitted fn (e.g. for AOT lowering)
     stepper.jitted = jitted
+    stepper.fold_stats = fold_stats
     return stepper
 
 
@@ -694,6 +758,10 @@ def make_ps_train_step(
     are mostly zero rows). Takes precedence over ``compression`` for the
     matching leaves.
 
+    ``loss_fn`` may return ``(loss, stats)`` (``_loss_and_stats``): the
+    statistics are outputs of the backward beside the loss and are in
+    the registry's counters when the step returns.
+
     Returns ``step(params, opt_state, batch) -> (params, opt_state, loss)``;
     reads the PS client + registry from the global state at call time, so
     it composes with suspend/resume.
@@ -735,11 +803,28 @@ def make_ps_train_step(
     # arena slots. All touched from the step thread only.
     xb_state: dict = {"carry": None, "over": {}, "par": 0, "seq": 0}
 
+    loss_and_stats = _loss_and_stats(loss_fn)
+
     def local_grads(params, batch):
-        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+        # ``loss`` is the pair (loss, stats) from here to the step's
+        # end, where the statistics are folded into the registry
+        loss, grads = jax.value_and_grad(
+            loss_and_stats, has_aux=True)(params, batch)
         grads = psum_tree(grads, axis=axis, average=True)
-        loss = jax.lax.pmean(loss, axis)
-        return loss, grads
+        return _reduce_loss(loss), grads
+
+    def _reduce_loss(pair):
+        loss, stats = pair
+        return (jax.lax.pmean(loss, axis),
+                jax.tree.map(lambda x: jax.lax.psum(x, axis), stats))
+
+    def _finish(params, opt_state, pair):
+        """The step's result: the statistics go to the registry (the
+        step has waited for its gradients, so they are there), the loss
+        to the caller."""
+        loss, stats = pair
+        _fold_stats(stats)
+        return params, opt_state, loss
 
     grad_fn = jax.jit(jax.shard_map(
         local_grads, mesh=mesh, in_specs=(P(), P(axis)),
@@ -795,7 +880,8 @@ def make_ps_train_step(
                                       seq, time.perf_counter())
 
         def streamed_local(step_tag, params, batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            loss, grads = jax.value_and_grad(
+                loss_and_stats, has_aux=True)(params, batch)
             leaves = jax.tree.leaves(grads)
             # ONE psum over the whole-leaf subtree (identical reduction
             # grouping to the untapped grad_fn's full-tree psum), RS
@@ -819,8 +905,7 @@ def make_ps_train_step(
                         io_callback(functools.partial(_tap, i), None,
                                     step_tag, idx, g, ordered=False)
                     outs.append(g)
-            loss = jax.lax.pmean(loss, axis)
-            return loss, tuple(outs)
+            return _reduce_loss(loss), tuple(outs)
 
         out_leaf_specs = tuple(
             P(axis) if i in shard_set else P()
@@ -865,7 +950,7 @@ def make_ps_train_step(
         if client is None:
             loss, grads = grad_fn(params, batch)
             params, opt_state = apply_fn(params, opt_state, grads)
-            return params, opt_state, loss
+            return _finish(params, opt_state, loss)
         # per-step pipeline profile (core/metrics.py): the scheduler's
         # stage threads feed samples into this builder; end_step below
         # closes it into the StepReport ring (+ stall diagnosis when
@@ -947,7 +1032,7 @@ def make_ps_train_step(
                 prof.mark("drain_done")
             params, opt_state = apply_fn(params, opt_state, grads)
             state.profiler.end_step(prof, fallback=len(names))
-            return params, opt_state, loss
+            return _finish(params, opt_state, loss)
         # ---- training-health collection (core/health.py,
         # BYTEPS_HEALTH): per-leaf gradient statistics accumulate off
         # the drain as each pulled aggregate lands; the param-norm
@@ -1908,7 +1993,7 @@ def make_ps_train_step(
             health=health_fields, xb=xb_fields)
         if hplane is not None:
             hplane.raise_if_fatal()
-        return params, opt_state, loss
+        return _finish(params, opt_state, loss)
 
     def flush(params, opt_state):
         """Drain the cross-barrier carry and fold every outstanding

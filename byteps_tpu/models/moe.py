@@ -1,27 +1,39 @@
 """Mixtral-style sparse Mixture-of-Experts transformer with expert
-parallelism over the ``ep`` mesh axis.
+parallelism over the ``ep`` mesh axis, and the expert layer other sparse
+models (models/mellum.py) are built from.
 
 The reference has no MoE / expert parallelism (SURVEY.md §2.8 — absent);
-this is green-field TPU design following the GShard/Switch SPMD recipe:
+this is green-field TPU design:
 
-- routing is dense math (top-k gating, capacity-bounded dispatch masks) so
-  everything stays static-shaped for XLA — no data-dependent gather loops;
-- dispatch/combine are einsums against a [tokens, experts, capacity] mask,
-  which XLA fuses onto the MXU;
-- expert parallelism = shard the experts dim over ``ep`` and move tokens
-  with two ``lax.all_to_all`` calls (dispatch there, combine back), the
-  collective riding ICI inside shard_map;
+- the router scores every token against ALL experts (float32 softmax,
+  top-k, the k weights renormalised to sum to one);
+- the layer is TOLD which experts it holds (``first`` and the leading
+  dim of its expert leaves) and computes exactly the (token, expert)
+  pairs routed to those: no capacity, so no pair is ever dropped,
+  whatever the imbalance. What absent experts would add is left out of
+  the sum, and nothing stands in for it;
+- the held experts' three products are ONE grouped matrix product each
+  (``jax.lax.ragged_dot``) over the pairs sorted by expert — work
+  follows the pairs routed here, not tokens x experts. XLA:TPU lowers
+  ``ragged_dot`` to its own Mosaic kernel that walks only the row tiles
+  inside a group, which is why it is used and not a Pallas kernel of
+  this package; the same call runs on the CPU mesh. Shapes stay static:
+  the sorted pair buffer has room for every pair (the worst routing),
+  rows past the routed ones are masked, and ``chunk`` bounds the
+  buffer by walking the tokens in slices;
+- expert parallelism = the experts dim sharded over ``ep``: the same
+  grouped product sits between two ``lax.all_to_all`` calls (pairs to
+  their expert's owner, results back); on one device it runs without
+  the exchange;
 - attention/embedding reuse the Llama building blocks (models/llama.py).
 
-Tokens dropped beyond expert capacity pass through the residual unchanged
-(standard Switch behavior). The router adds the Switch load-balancing
-auxiliary loss.
+The Mixtral model below adds the Switch load-balancing auxiliary loss.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -41,7 +53,6 @@ class MoEConfig:
     n_experts: int = 8
     top_k: int = 2
     expert_hidden: int = 14336
-    capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
     max_seq_len: int = 8192
     rope_theta: float = 500000.0
@@ -68,8 +79,7 @@ class MoEConfig:
     def tiny(vocab_size: int = 256, seq: int = 64) -> "MoEConfig":
         return MoEConfig(vocab_size=vocab_size, dim=64, n_layers=2,
                          n_heads=4, n_kv_heads=2, n_experts=4, top_k=2,
-                         expert_hidden=128, max_seq_len=seq, remat=False,
-                         capacity_factor=2.0)
+                         expert_hidden=128, max_seq_len=seq, remat=False)
 
     @staticmethod
     def small(vocab_size: int = 32000) -> "MoEConfig":
@@ -77,12 +87,6 @@ class MoEConfig:
         return MoEConfig(vocab_size=vocab_size, dim=768, n_layers=12,
                          n_heads=12, n_kv_heads=4, n_experts=8, top_k=2,
                          expert_hidden=2048, max_seq_len=2048)
-
-
-def capacity(cfg: MoEConfig, n_tokens: int) -> int:
-    """Static per-expert token capacity for a batch of n_tokens."""
-    return max(1, int(math.ceil(
-        cfg.top_k * n_tokens / cfg.n_experts * cfg.capacity_factor)))
 
 
 # --------------------------------------------------------------------- #
@@ -129,85 +133,202 @@ def param_count(params: Dict[str, Any]) -> int:
 # routing + expert layer
 # --------------------------------------------------------------------- #
 
-def _route(x_flat: jnp.ndarray, router_w: jnp.ndarray, cfg: MoEConfig,
-           cap: int) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Top-k capacity-bounded routing.
-
-    x_flat: [T, d]. Returns (dispatch [T, E, C] float mask,
-    combine [T, E, C] gate-weighted mask, aux_loss scalar).
-    """
-    T = x_flat.shape[0]
-    E, k = cfg.n_experts, cfg.top_k
-    logits = (x_flat.astype(jnp.float32)
-              @ router_w.astype(jnp.float32))            # [T, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, gate_idx = jax.lax.top_k(probs, k)        # [T, k]
-    gate_vals = gate_vals / jnp.sum(gate_vals, axis=-1, keepdims=True)
-
-    # Choice slots flattened k-major: slot 0 of every token claims capacity
-    # before any slot 1 (Switch priority: primary routes never lose space
-    # to secondary ones).
-    idx_flat = gate_idx.T.reshape(-1)                    # [k*T]
-    # int32 cumsum: positions are exact for any token count (a float32
-    # cumsum stops representing consecutive integers past 2^24 routed
-    # slots, silently corrupting capacity assignment)
-    onehot_i = jax.nn.one_hot(idx_flat, E, dtype=jnp.int32)   # [k*T, E]
-    onehot = onehot_i.astype(jnp.float32)
-    pos_in_expert = (jnp.cumsum(onehot_i, axis=0) - onehot_i)  # exclusive
-    pos = jnp.sum(pos_in_expert * onehot_i, axis=-1)     # [k*T]
-    keep = pos < cap
-
-    slot = jax.nn.one_hot(pos.astype(jnp.int32), cap,
-                          dtype=jnp.float32)             # [k*T, C]
-    mask = (onehot * keep[:, None])[:, :, None] * slot[:, None, :]
-    mask = mask.reshape(k, T, E, cap)                    # [k, T, E, C]
-    dispatch = jnp.sum(mask, axis=0)                     # [T, E, C]
-    combine = jnp.sum(mask * gate_vals.T.reshape(k, T, 1, 1), axis=0)
-
-    # Switch aux loss: E * sum_e f_e * p_e  (f = token fraction routed to e
-    # on the primary choice, p = mean router prob)
-    prime = jax.nn.one_hot(gate_idx[:, 0], E, dtype=jnp.float32)
-    aux = E * jnp.sum(jnp.mean(prime, axis=0) * jnp.mean(probs, axis=0))
-    return dispatch, combine, aux
+def route(x_flat: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
+          router_dtype: Any = jnp.float32):
+    """Top-k routing over ALL experts. x_flat [T, d], router_w [d, E].
+    Returns (gates [T, k] f32, renormalised to sum to one; idx [T, k]
+    int32; probs [T, E] f32). ``router_dtype`` is the matmul's and the
+    softmax's type: float32, always, outside a precision control."""
+    with jax.named_scope("bps.moe.route"):
+        logits = jnp.matmul(x_flat.astype(router_dtype),
+                            router_w.astype(router_dtype),
+                            preferred_element_type=router_dtype)
+        probs = jax.nn.softmax(logits, axis=-1).astype(jnp.float32)
+        gates, idx = jax.lax.top_k(probs, top_k)
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, idx.astype(jnp.int32), probs
 
 
-def _expert_ffn(h: jnp.ndarray, w_gate, w_up, w_down,
-                dtype) -> jnp.ndarray:
-    """SwiGLU per expert. h: [E_local, C', d]."""
-    g = jax.nn.silu(jnp.einsum("ecd,edh->ech", h, w_gate.astype(dtype)))
-    u = jnp.einsum("ecd,edh->ech", h, w_up.astype(dtype))
-    return jnp.einsum("ech,ehd->ecd", g * u, w_down.astype(dtype))
+def switch_aux_loss(probs: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """Switch aux loss: E * sum_e f_e * p_e (f = token fraction routed
+    to e on the primary choice, p = mean router prob)."""
+    E = probs.shape[-1]
+    prime = jax.nn.one_hot(idx[:, 0], E, dtype=jnp.float32)
+    return E * jnp.sum(jnp.mean(prime, axis=0) * jnp.mean(probs, axis=0))
 
 
-def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], cfg: MoEConfig,
-              ep_axis: Optional[str] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The MoE FFN: route, dispatch, expert-compute, combine.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_in_order(x, order, inv, k):
+    """``x[order // k]``: row ``r`` of the result is the token of the
+    ``r``-th pair in sorted order (pairs are token-major, ``k`` a
+    token). ``order`` is a permutation and ``inv`` its inverse, so the
+    transpose is a gather too (never a scatter-add): un-sort the
+    cotangent and sum each token's ``k`` rows."""
+    return x[order // k]
 
-    x: [B, S, d]. ``p`` holds ONE layer's params; with ``ep_axis`` set (call
-    inside shard_map), p's expert leaves (w_gate/w_up/w_down) carry only the
-    E_local = E/P local experts and tokens travel via all_to_all. Returns
-    (output [B, S, d], aux_loss).
+
+def _rows_in_order_fwd(x, order, inv, k):
+    return x[order // k], (inv, x.shape[0])
+
+
+def _rows_in_order_bwd(k, res, g):
+    inv, T = res
+    return g[inv].reshape(T, k, g.shape[-1]).sum(axis=1), None, None
+
+
+_rows_in_order.defvjp(_rows_in_order_fwd, _rows_in_order_bwd)
+
+
+def grouped_ffn(x: jnp.ndarray, key: jnp.ndarray, k: int, w_gate, w_up,
+                w_down, dtype
+                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """SwiGLU of the held experts over the pairs routed to them.
+
+    x [T, d]; ``key`` [T * k] int32, token-major: the held expert (0 ..
+    n_held - 1) pair ``t * k + c`` is routed to, or ``n_held`` where
+    that pair's expert is not held here. Returns (y [T * k, d] in pair
+    order, zero rows where ``key == n_held``; load [n_held] int32, the
+    pairs per held expert; dropped: the held pairs whose sorted row
+    lies outside their expert's group). Nothing is dropped: the sorted
+    buffer has a row for every pair."""
+    n_held = w_gate.shape[0]
+    N = key.shape[0]
+    with jax.named_scope("bps.moe.experts"):
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inv = jnp.zeros((N,), jnp.int32).at[order].set(
+            jnp.arange(N, dtype=jnp.int32))
+        load = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                       axis=0, dtype=jnp.int32)
+        # rows past the routed pairs belong to no group: the grouped
+        # product may leave them unwritten, so they are masked on the
+        # way in, between the products and on the way out (the
+        # cotangents with them)
+        routed = (jnp.arange(N) < jnp.sum(load))[:, None]
+        xs = jnp.where(routed, _rows_in_order(x, order, inv, k), 0)
+        gate = jax.lax.ragged_dot(xs, w_gate.astype(dtype), load)
+        up = jax.lax.ragged_dot(xs, w_up.astype(dtype), load)
+        h = jnp.where(routed, jax.nn.silu(gate) * up, 0)
+        ys = jnp.where(routed,
+                       jax.lax.ragged_dot(h, w_down.astype(dtype), load), 0)
+        # back to pair order: again a permutation, k = 1
+        y = _rows_in_order(ys, inv, order, 1)
+        # the router's choice against the product's groups: a pair is
+        # computed where its sorted row lies among the rows the grouped
+        # product is told to give that pair's expert
+        end = jnp.cumsum(load)
+        group = jnp.sum(inv[:, None] >= end[None, :], axis=1)
+        held = key < n_held
+        dropped = jnp.sum(held & (group != key), dtype=jnp.int32)
+    return y, load, dropped
+
+
+def _held_chunk(x, gates, idx, first, w_gate, w_up, w_down, dtype):
+    """The held experts' part of the layer's output for one slice of
+    tokens: x [T, d], gates/idx [T, k]. Returns (y [T, d], load,
+    dropped)."""
+    T, k = idx.shape
+    n_held = w_gate.shape[0]
+    local = idx - first
+    here = (local >= 0) & (local < n_held)
+    key = jnp.where(here, local, n_held).reshape(-1)
+    y, load, dropped = grouped_ffn(x, key, k, w_gate, w_up, w_down, dtype)
+    w = jnp.where(here, gates, 0.0)
+    out = jnp.einsum("tkd,tk->td", y.reshape(T, k, -1), w.astype(dtype),
+                     preferred_element_type=jnp.float32)
+    return out.astype(dtype), load, dropped
+
+
+def _exchanged(x, gates, idx, w_gate, w_up, w_down, dtype, ep_axis):
+    """The same grouped product between two ``all_to_all`` calls: the
+    experts dim is sharded over ``ep_axis`` (this device holds experts
+    ``[me * E_local, (me + 1) * E_local)``), every pair travels to its
+    expert's owner and its result comes back. Buffers have room for the
+    worst routing (every pair to one peer): nothing is dropped."""
+    T, k = idx.shape
+    d = x.shape[-1]
+    E_local = w_gate.shape[0]
+    n_peers = jax.lax.axis_size(ep_axis)
+    N = T * k
+    C = T * min(k, E_local)                 # rows a peer can get from me
+    flat = idx.reshape(-1)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inv = jnp.zeros((N,), jnp.int32).at[order].set(
+        jnp.arange(N, dtype=jnp.int32))
+    eid = flat[order]                       # sorted global expert ids
+    peer = eid // E_local
+    sent = jnp.sum(peer[:, None] == jnp.arange(n_peers)[None, :], axis=0)
+    first_row = jnp.cumsum(sent) - sent     # my sorted pairs, by peer
+    slot = jnp.arange(N) - first_row[peer]  # row in that peer's buffer
+    rows = _rows_in_order(x, order, inv, k)                   # [N, d]
+    send = jnp.zeros((n_peers, C, d), x.dtype).at[peer, slot].set(rows)
+    send_key = jnp.full((n_peers, C), E_local, jnp.int32).at[
+        peer, slot].set(eid - peer * E_local)
+    recv = jax.lax.all_to_all(send, ep_axis, 0, 0, tiled=True)
+    recv_key = jax.lax.all_to_all(send_key, ep_axis, 0, 0, tiled=True)
+    y, load, dropped = grouped_ffn(recv.reshape(n_peers * C, d),
+                                   recv_key.reshape(-1), 1, w_gate, w_up,
+                                   w_down, dtype)
+    back = jax.lax.all_to_all(y.reshape(n_peers, C, d), ep_axis, 0, 0,
+                              tiled=True)
+    y_pairs = _rows_in_order(back[peer, slot], inv, order, 1)  # [N, d]
+    out = jnp.einsum("tkd,tk->td", y_pairs.reshape(T, k, d),
+                     gates.astype(dtype),
+                     preferred_element_type=jnp.float32)
+    return out.astype(dtype), load, dropped
+
+
+def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
+              dtype: Any, first: int = 0, ep_axis: Optional[str] = None,
+              chunk: Optional[int] = None,
+              router_dtype: Any = jnp.float32
+              ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """The MoE FFN of the experts this device holds.
+
+    x: [B, S, d]. ``p`` holds ONE layer's params: ``router`` [d, E] over
+    all ``E`` experts, and the expert leaves (w_gate/w_up/w_down) of the
+    ``n_held`` experts ``first .. first + n_held - 1``. Every token is
+    routed over all ``E``; the output is the renormalised-weight sum of
+    the held experts' terms only (all of the layer where every expert
+    is held). With ``ep_axis`` set (call inside shard_map) the expert
+    leaves carry the device's ``E / P`` and pairs travel by
+    ``all_to_all``. ``chunk``: tokens per slice of the grouped product
+    (bounds the sorted pair buffer at ``chunk * top_k`` rows); it must
+    divide the tokens where there are more of them than a slice.
+
+    Returns (output [B, S, d], stats): ``load`` [n_held] int32 (pairs
+    per held expert in the grouped product), ``dropped`` (pairs the
+    router sent to a held expert whose sorted row lies outside that
+    expert's group of the product: 0, there is no capacity; it checks
+    the sort's bookkeeping, the arithmetic is ``correct``'s to check),
+    ``aux`` (the Switch balancing loss, for models that use it).
     """
     B, S, d = x.shape
-    dt = cfg.dtype
-    x_flat = x.reshape(B * S, d)
-    cap = capacity(cfg, B * S)
-    dispatch, combine, aux = _route(x_flat, p["router"], cfg, cap)
-
-    # [T, E, C] x [T, d] -> [E, C, d]
-    h = jnp.einsum("tec,td->ecd", dispatch.astype(dt), x_flat)
-    if ep_axis is None:
-        out_e = _expert_ffn(h, p["w_gate"], p["w_up"], p["w_down"], dt)
+    T = B * S
+    x_flat = x.reshape(T, d)
+    gates, idx, probs = route(x_flat, p["router"], top_k, router_dtype)
+    w = (p["w_gate"], p["w_up"], p["w_down"])
+    if ep_axis is not None:
+        out, load, dropped = _exchanged(x_flat, gates, idx, *w, dtype,
+                                        ep_axis)
+    elif chunk is None or chunk >= T:
+        out, load, dropped = _held_chunk(x_flat, gates, idx, first, *w,
+                                         dtype)
+    elif T % chunk:
+        raise ValueError(f"{T} tokens do not divide into slices of {chunk}")
     else:
-        # E -> E_local chunks scattered to their owner, each expert now sees
-        # P*C token slots (C from every ep peer)
-        h = jax.lax.all_to_all(h, ep_axis, split_axis=0, concat_axis=1,
-                               tiled=True)               # [E_local, P*C, d]
-        out_e = _expert_ffn(h, p["w_gate"], p["w_up"], p["w_down"], dt)
-        out_e = jax.lax.all_to_all(out_e, ep_axis, split_axis=1,
-                                   concat_axis=0, tiled=True)  # [E, C, d]
-    out = jnp.einsum("tec,ecd->td", combine.astype(dt), out_e)
-    return out.reshape(B, S, d), aux
+        n = T // chunk
+
+        @jax.checkpoint
+        def one(args):
+            return _held_chunk(*args, first, *w, dtype)
+
+        out, loads, drops = jax.lax.map(one, (
+            x_flat.reshape(n, chunk, d), gates.reshape(n, chunk, top_k),
+            idx.reshape(n, chunk, top_k)))
+        out, load, dropped = (out.reshape(T, d), jnp.sum(loads, axis=0),
+                              jnp.sum(drops))
+    return out.reshape(B, S, d), {"load": load, "dropped": dropped,
+                                  "aux": switch_aux_loss(probs, idx)}
 
 
 # --------------------------------------------------------------------- #
@@ -220,8 +341,8 @@ def _moe_block(x, p, cos, sin, cfg: MoEConfig,
     params."""
     x = L.attn_sublayer(x, p, cos, sin, cfg.as_llama())
     h = L._rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
-    ffn, aux = moe_layer(h, p, cfg, ep_axis)
-    return x + ffn, aux
+    ffn, stats = moe_layer(h, p, cfg.top_k, cfg.dtype, ep_axis=ep_axis)
+    return x + ffn, stats["aux"]
 
 
 def forward(params: Dict[str, Any], tokens: jnp.ndarray, cfg: MoEConfig,

@@ -89,16 +89,45 @@ def _randomk(sh):
                                  _sds((), jnp.int32, sh), LEAF // 100, False)
 
 
-def _flash(shape, hkv):
+def _flash(shape, hkv, window=None):
     def lower(sh):
         from byteps_tpu.ops.flash_attention import _flash_fwd
         B, S, H, D = shape
         q = _sds(shape, jnp.bfloat16, sh)
         kv = _sds((B, S, hkv, D), jnp.bfloat16, sh)
         return jax.jit(
-            lambda q_, k_, v_: _flash_fwd(q_, k_, v_, True, 512, 512)
+            lambda q_, k_, v_: _flash_fwd(q_, k_, v_, True, 512, 512,
+                                          window=window)
         ).lower(q, kv, kv)
     return lower
+
+
+def _flash_bwd(shape, hkv, window=None):
+    def lower(sh):
+        from byteps_tpu.ops.flash_attention import _flash_bwd as bwd
+        B, S, H, D = shape
+        q = _sds(shape, jnp.bfloat16, sh)
+        kv = _sds((B, S, hkv, D), jnp.bfloat16, sh)
+        lse = _sds((B, H, S, 128), jnp.float32, sh)
+        return jax.jit(
+            lambda q_, k_, v_, o_, l_, g_: bwd(q_, k_, v_, o_, l_, g_, True,
+                                               512, 512, window)
+        ).lower(q, kv, kv, q, lse, q)
+    return lower
+
+
+def _grouped_products(sh):
+    """The held experts' products at the benchmark's sparse decoder's
+    widths: 8 experts of 2304 x 896 over one slice's sorted pair buffer
+    (8192 tokens x 8 pairs)."""
+    from byteps_tpu.models import moe
+    T, k, d, h, H = 8192, 8, 2304, 896, 8
+    return jax.jit(
+        lambda x, key, wg, wu, wd: moe.grouped_ffn(
+            x, key, k, wg, wu, wd, jnp.bfloat16)
+    ).lower(_sds((T, d), jnp.bfloat16, sh), _sds((T * k,), jnp.int32, sh),
+            _sds((H, d, h), jnp.float32, sh), _sds((H, d, h), jnp.float32, sh),
+            _sds((H, h, d), jnp.float32, sh))
 
 
 @pytest.mark.parametrize("lower", [
@@ -109,6 +138,14 @@ def _flash(shape, hkv):
     pytest.param(_randomk, id="randomk_indices-bert_leaf_1pct"),
     pytest.param(_flash((2, 1024, 16, 64), 16), id="flash_fwd-mha_hd64"),
     pytest.param(_flash((2, 1024, 6, 128), 2), id="flash_fwd-gqa_hd128"),
+    pytest.param(_flash((1, 8192, 32, 128), 4, window=1024),
+                 id="flash_fwd-window1024_8k_gqa32x4"),
+    pytest.param(_flash((1, 8192, 32, 128), 4), id="flash_fwd-full_8k_gqa32x4"),
+    pytest.param(_flash_bwd((1, 8192, 32, 128), 4, window=1024),
+                 id="flash_bwd-window1024_8k_gqa32x4"),
+    pytest.param(_flash_bwd((1, 8192, 32, 128), 4),
+                 id="flash_bwd-full_8k_gqa32x4"),
+    pytest.param(_grouped_products, id="grouped_ffn-8x2304x896"),
 ])
 def test_kernel_compiles_for_v5e(v5e, lower):
     compiled = lower(v5e).compile()

@@ -27,7 +27,7 @@ from byteps_tpu.core.timeseries import TimeSeriesPlane, _TS_STEP_FIELDS
 from byteps_tpu.server import run_server
 from byteps_tpu.tools import top
 
-_PORT = [24700]
+_PORT = [24760]  # test_ledger.py counts up from 24700 in another worker
 
 
 def _report(step, **kw):
