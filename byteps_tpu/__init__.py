@@ -245,9 +245,10 @@ def get_arena_stats() -> dict:
     the tapped export), and ``export_ttfp_ms`` (the last round's
     time-to-first-push). The steady-state PS train step should show
     ``allocs_avoided`` growing and ``slot_allocs`` flat after warmup;
-    ``export_streamed_leaves`` grows by the leaves the plan shards on a
-    mesh (none on one device), and by every leaf above the fusion
-    threshold with BYTEPS_STREAM_EXPORT=1.
+    ``export_streamed_leaves`` stays 0 unless BYTEPS_STREAM_EXPORT=1
+    asks for taps (then it grows by every leaf above the fusion
+    threshold), and ``export_shard_leaves`` grows by the leaves the
+    plan shards on a mesh (none on one device) on either route.
 
     Deprecated alias: this is ``get_metrics()["arena"]`` — the unified
     registry snapshot is the maintained surface; the keys here are

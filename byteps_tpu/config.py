@@ -110,15 +110,15 @@ class Config:
     # COMPUTE/PUSH overlap: gradients of the last layers enter PUSH while
     # earlier layers are still in backprop, core_loops.cc + the priority
     # scheduler's "last layer first"). Three states (numerics identical
-    # in all). Unset (None): the route follows the leaf's kind in the
-    # plan — a locality-shard leaf is tapped inside the compiled
+    # in all). Unset (None): nothing is tapped — every leaf is an output
+    # of the backward that the runtime copies to the host and the claim
+    # loop submits, a locality-shard leaf of a mesh as one flat shard a
+    # device (on the v5e a callback operand reaches the host at 0.4-1.0
+    # GB/s, an output at 3.0-4.5: PERF.md, PRs 25 and 27). On: every
+    # eligible leaf, shard leaves too, is tapped inside the compiled
     # backward with jax.experimental.io_callback, its PUSH submitted the
-    # moment XLA produces it at measured production-order priority;
-    # every other leaf (whole-leaf key, bucket member, rowsparse) is an
-    # output of the backward that the runtime copies to the host and the
-    # claim loop submits (on the v5e a callback operand reaches the host
-    # at 0.4-0.75 GB/s, an output at 3.3-4.5: PERF.md, PR 25). On:
-    # whole-leaf keys are tapped too. Off: no taps and no shard plan. ---
+    # moment XLA produces it at measured production-order priority.
+    # Off: no taps and no shard plan. ---
     stream_export: Optional[bool] = None  # BYTEPS_STREAM_EXPORT
 
     # --- sharded optimizer apply (rebuild addition; PAPERS.md "Automatic
@@ -138,8 +138,9 @@ class Config:
     # core_loops.cc:216-268, layered with the weight-update sharding of
     # "Automatic Cross-Replica Sharding of Weight Update" (PAPERS.md)).
     # On: the PS train step reduce-SCATTERS eligible gradient leaves
-    # instead of psum'ing them, each local device taps and exports ONLY
-    # its own 1/local_size shard (per-device export workers), each shard
+    # instead of psum'ing them, each local device exports ONLY its own
+    # 1/local_size shard (a per-device program output; a tap and a
+    # per-device export worker under stream_export on), each shard
     # rides its own PS key spread across servers, the drain imports
     # shard k back into the device that owns it, the optimizer update
     # runs on the shard alone, and a jitted all-gather rebuilds
@@ -147,7 +148,7 @@ class Config:
     # bytes by local_size. Leaves below shard_min_bytes, non-divisible
     # leaves past the pad threshold, rowsparse/compressed/bucket-fused
     # leaves and single-device meshes fall back to the whole-leaf path
-    # (numerics bitwise identical). Requires stream_export. ---
+    # (numerics bitwise identical). Off with stream_export off. ---
     local_shard_export: bool = True       # BYTEPS_LOCAL_SHARD_EXPORT
     shard_min_bytes: int = DEFAULT_SHARD_MIN_BYTES  # BYTEPS_SHARD_MIN_BYTES
 

@@ -114,9 +114,10 @@ class _Telemetry:
                 getattr(self, "_export_fallback", 0) + int(fallback)
             self._export_rounds = getattr(self, "_export_rounds", 0) + 1
             # leaves that left the device as per-device reduce-scatter
-            # shards (BYTEPS_LOCAL_SHARD_EXPORT) — a subset of
-            # ``streamed``; the shard A/B asserts this engaged instead
-            # of silently riding the whole-leaf path
+            # shards (BYTEPS_LOCAL_SHARD_EXPORT), as program outputs or
+            # (BYTEPS_STREAM_EXPORT=1: a subset of ``streamed``) as
+            # taps; the shard A/B asserts this engaged instead of
+            # silently riding the whole-leaf path
             self._export_shard_leaves = \
                 getattr(self, "_export_shard_leaves", 0) + int(shard_leaves)
             if ttfp_s is not None:

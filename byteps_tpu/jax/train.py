@@ -268,7 +268,9 @@ def _chaos_nan_poison(spec: str, name: str, flat, step_no: int):
 
 
 def _export_pool():
-    """The stream-export ROUTER worker. The io_callback tap itself only
+    """The stream-export ROUTER worker (BYTEPS_STREAM_EXPORT=1 only:
+    unset, nothing is tapped and no export pool is built). The
+    io_callback tap itself only
     enqueues here: a callback arg is a lazy jax.Array whose
     materialization needs the very executor running the tapped program
     — touching it on the callback (= device) thread self-deadlocks the
@@ -289,12 +291,14 @@ def _export_pool():
     return _EXPORT_POOL
 
 
-# per-LOCAL-DEVICE shard-export workers (BYTEPS_LOCAL_SHARD_EXPORT):
-# device k's reduce-scatter shard is materialized and submitted by
-# worker k — one thread per device keeps each device's fires in order
-# (the per-shard analogue of the single router's FIFO guarantee) while
-# devices proceed independently, parallelizing the D2H export across
-# the local slice exactly as BytePS's per-GPU copy threads do
+# per-LOCAL-DEVICE shard-export workers (BYTEPS_LOCAL_SHARD_EXPORT under
+# BYTEPS_STREAM_EXPORT=1 only: unset, a device's shard is a program
+# output the train thread claims): a TAPPED shard of device k is
+# materialized and submitted by worker k — one thread per device keeps
+# each device's fires in order (the per-shard analogue of the single
+# router's FIFO guarantee) while devices proceed independently,
+# parallelizing the D2H export across the local slice exactly as
+# BytePS's per-GPU copy threads do
 _SHARD_POOLS: Dict[int, Any] = {}
 _SHARD_INGESTS: Dict[int, int] = {}  # per-device ingest totals (gauges)
 
@@ -326,10 +330,10 @@ def _release_pool():
 
 
 class _StreamRound:
-    """One PS train step's tapped-export state: the shard leaves of a
-    mesh, and whole leaves too where BYTEPS_STREAM_EXPORT=1 asked for
-    their taps (unset, a whole leaf is an output of the backward and
-    never comes here).
+    """One PS train step's tapped-export state, built only where
+    BYTEPS_STREAM_EXPORT=1 asked for taps (unset, every leaf, a mesh's
+    shard leaves included, is an output of the backward and nothing
+    comes here).
 
     The io_callback taps planted on each tapped gradient leaf inside
     the compiled backward fire while XLA is still producing later
@@ -542,13 +546,10 @@ class _StreamRound:
                     f"streamed gradient export: the tap of "
                     f"{self._names[i]!r} did not fire within "
                     f"{timeout:.0f}s of its gradient being ready — the "
-                    f"io_callback path is dead on this backend. Whole "
-                    f"leaves are tapped only under BYTEPS_STREAM_EXPORT=1 "
-                    f"(unset it: they then leave as program outputs); a "
-                    f"mesh's shard leaves are tapped unless "
-                    f"BYTEPS_STREAM_EXPORT=0 or "
-                    f"BYTEPS_LOCAL_SHARD_EXPORT=0 turns the shard plan "
-                    f"off.")
+                    f"io_callback path is dead on this backend. Leaves "
+                    f"are tapped only under BYTEPS_STREAM_EXPORT=1: unset "
+                    f"it, and every leaf, a mesh's shard leaves included, "
+                    f"leaves the chip as a program output.")
             ev.wait()  # ingest in flight; its submission completes
         err = self._errors.get(i)
         if err is not None:
@@ -649,6 +650,67 @@ def _device_compressed_round(state, client, comp_state, compression,
     return treedef.unflatten(results)
 
 
+def _reduce_loss(pair, axis: str):
+    """``(loss, stats)`` of one data shard -> the mean loss and the
+    summed statistics over ``axis``."""
+    loss, stats = pair
+    return (jax.lax.pmean(loss, axis),
+            jax.tree.map(lambda x: jax.lax.psum(x, axis), stats))
+
+
+def _scatter_backward(loss_and_stats: Callable, mesh: Mesh, axis: str,
+                      shard_set, n_leaves: int, tapped=(), plant=None):
+    """The backward of a PS step whose export plan shards leaves or
+    taps them: identical math to ``make_ps_train_step``'s ``grad_fn``,
+    with the leaves in ``shard_set`` (BYTEPS_LOCAL_SHARD_EXPORT) riding
+    ``reduce_scatter`` instead of the psum. The program returns those
+    leaves as flat padded ``P(axis)``-sharded outputs, so each device
+    holds only ITS 1/local_size shard and only that ever crosses
+    device->host per device — BytePS's hierarchical "the intra-machine
+    reduce puts 1/local_size on the wire". The remaining leaves keep
+    the exact whole-leaf path (one psum over their subtree, replicated
+    output), so disabling sharding per leaf is bitwise-invisible.
+
+    ``tapped`` empty (what BYTEPS_STREAM_EXPORT unset means): the
+    program is ``fn(params, batch)`` and holds no host callback, so the
+    persistent compile cache can serve it. Otherwise ``plant(i,
+    step_tag, device_index, value)`` plants a tap on each leaf in
+    ``tapped`` INSIDE the shard_mapped body and the program is
+    ``fn(step_tag, params, batch)``."""
+    from ..ops.push_pull import scatter_leaf
+
+    shard_set = frozenset(shard_set)
+    tapped = frozenset(tapped)
+
+    def local(step_tag, params, batch):
+        loss, grads = jax.value_and_grad(
+            loss_and_stats, has_aux=True)(params, batch)
+        leaves = jax.tree.leaves(grads)
+        # ONE psum over the whole-leaf subtree (identical reduction
+        # grouping to the untapped grad_fn's full-tree psum), RS
+        # per shard leaf
+        whole_idx = [i for i in range(len(leaves)) if i not in shard_set]
+        whole = psum_tree([leaves[i] for i in whole_idx],
+                          axis=axis, average=True)
+        whole_map = dict(zip(whole_idx, whole))
+        idx = jax.lax.axis_index(axis) if tapped else None
+        outs = []
+        for i in range(len(leaves)):
+            g = (scatter_leaf(leaves[i], axis=axis, average=True)
+                 if i in shard_set else whole_map[i])
+            if i in tapped:
+                plant(i, step_tag, idx, g)
+            outs.append(g)
+        return _reduce_loss(loss, axis), tuple(outs)
+
+    out_specs = (P(), tuple(P(axis) if i in shard_set else P()
+                            for i in range(n_leaves)))
+    body, in_specs = (local, (P(), P(), P(axis))) if tapped else (
+        functools.partial(local, None), (P(), P(axis)))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
+
+
 def make_ps_train_step(
     loss_fn: Callable,
     tx: optax.GradientTransformation,
@@ -666,9 +728,9 @@ def make_ps_train_step(
     path — the reference's actual architecture (docs/architecture.md
     "General Workflow") with BOTH of its pipeline overlaps: the compiled
     program reduces gradients over the local slice (ICI psum == the NCCL
-    ReduceScatter tier); gradients exit to host by the route their kind
-    in the export plan gives them (below: program outputs copied by the
-    runtime, or taps inside the backward); the PS client push_pulls each declared
+    ReduceScatter tier); gradients exit to host as outputs of that
+    program, copied by the runtime (or, asked for, through taps inside
+    the backward: below); the PS client push_pulls each declared
     tensor across workers in priority order (the PUSH/PULL stages over
     DCN); and the optimizer update is applied per leaf from the
     completion-ordered drain, so UPDATE(k) overlaps PULL(k+1) (servers
@@ -678,26 +740,32 @@ def make_ps_train_step(
     running scheduler, numerics identical in all): the route by which a
     gradient leaf leaves the chip.
 
-    - ``None``, nobody set it: the leaf's kind in the plan decides. A
-      shard leaf of the locality-shard plan (``local_shard_export``,
-      mesh axis > 1) is TAPPED inside the compiled backward with
-      jax.experimental.io_callback, each device's shard handed straight
-      to the scheduler while later gradients are still being produced,
-      its key's priority pinned from its measured first-export ordinal
-      (scheduler.production_priority). Every other leaf — a whole-leaf
-      key (dense or host-compressed), a bucket member
-      (sub-BYTEPS_FUSION_BYTES), a rowsparse or device-compressed leaf —
-      is an OUTPUT of the backward: its ``copy_to_host_async()`` is
-      issued right after dispatch, in flatten order, and the train
-      thread's claim loop takes each with ``np.asarray`` and submits
-      it. On a one-device mesh nothing is tapped: the program that runs
-      is the untapped ``grad_fn``, no tapped program is built. (On the
-      v5e a callback operand of a whole BERT-large leaf reaches the host
-      at 0.4-0.75 GB/s, a program output at 3.3-4.5: PERF.md section 6,
-      PR 24 and PR 25.)
-    - ``True``: whole-leaf keys are tapped too (time-to-first-push drops
-      from "after the whole backward" to "after the first gradient").
-    - ``False``: no taps and no shard plan; every leaf is an output.
+    - ``None``, nobody set it: every leaf is an OUTPUT of the backward,
+      on every topology, and no program holds a host callback. A leaf
+      on a whole-leaf key (dense or host-compressed), a bucket member
+      (sub-BYTEPS_FUSION_BYTES), a rowsparse or device-compressed leaf
+      is one replicated array; a shard leaf of the locality-shard plan
+      (``local_shard_export``, mesh axis > 1) is one flat 1/local_size
+      shard a device. ``copy_to_host_async()`` is issued on each right
+      after dispatch, in flatten order and, within a shard leaf, in
+      mesh-device order; the train thread's claim loop takes each with
+      ``np.asarray`` and submits it, a shard under its subrange key at
+      its parent's production-order priority. On a one-device mesh the
+      program that runs is ``grad_fn``; on a mesh with shard leaves,
+      the reduce-scatter backward (``_scatter_backward``) with no tap
+      in it. (On the v5e a callback operand reaches the host at
+      0.4-1.0 GB/s, a program output at 3.0-4.5: PERF.md section 6,
+      PRs 24, 25 and 27.)
+    - ``True``: every eligible leaf is TAPPED inside the compiled
+      backward with jax.experimental.io_callback, shard leaves too
+      (each device's shard handed to the scheduler by that device's
+      export worker while later gradients are still being produced;
+      time-to-first-push drops from "after the whole backward" to
+      "after the first gradient"), its key's priority pinned from its
+      measured first-export ordinal (scheduler.production_priority).
+      The arm the output route was measured against.
+    - ``False``: no taps and no shard plan; every leaf is a whole
+      output.
 
     The split is decided by configuration and topology alone: a tapped
     backward that fails to build or dispatch, or whose taps never fire,
@@ -721,8 +789,9 @@ def make_ps_train_step(
     off with ``stream_export=False``): the hierarchical exchange —
     reduce-scatter → push shard → update shard → all-gather. Eligible
     leaves are reduce-SCATTERED instead of psum'd, so each local
-    device taps and exports only its own flat 1/local_size shard
-    (per-device export workers parallelize the D2H); each shard rides
+    device holds and exports only its own flat 1/local_size shard (a
+    per-device program output; under ``stream_export=True`` a tap and
+    a per-device export worker); each shard rides
     its own PS key, spread across servers by the registry's
     load-balanced assignment; the completion-ordered drain imports
     shard k back into the device that owns it (1/local_size H2D per
@@ -811,12 +880,7 @@ def make_ps_train_step(
         loss, grads = jax.value_and_grad(
             loss_and_stats, has_aux=True)(params, batch)
         grads = psum_tree(grads, axis=axis, average=True)
-        return _reduce_loss(loss), grads
-
-    def _reduce_loss(pair):
-        loss, stats = pair
-        return (jax.lax.pmean(loss, axis),
-                jax.tree.map(lambda x: jax.lax.psum(x, axis), stats))
+        return _reduce_loss(loss, axis), grads
 
     def _finish(params, opt_state, pair):
         """The step's result: the statistics go to the registry (the
@@ -830,32 +894,18 @@ def make_ps_train_step(
         local_grads, mesh=mesh, in_specs=(P(), P(axis)),
         out_specs=(P(), P()), check_vma=False))
 
-    def _build_streamed_fn(eligible, shard_set=(), n_leaves=0):
-        """The tapped backward: identical math to ``grad_fn`` plus an
-        io_callback on each eligible gradient leaf INSIDE the
-        shard_mapped body — XLA schedules each tap right after its
-        leaf's collective, so the callback fires while later gradients
-        are still being produced (measured: first fire at ~1/3 of the
+    def _tap_planter():
+        """What plants one io_callback tap (BYTEPS_STREAM_EXPORT=1
+        only): XLA schedules each tap right after its leaf's
+        collective, so the callback fires while later gradients are
+        still being produced (measured: first fire at ~1/3 of the
         backward wall). The step tag rides through the program so a
         late duplicate fire can never be mistaken for the next round's
-        export.
-
-        Leaves in ``shard_set`` (BYTEPS_LOCAL_SHARD_EXPORT) ride
-        ``reduce_scatter`` instead of the psum: each device's tap then
-        carries only ITS flat 1/local_size shard (the device index
-        rides alongside), the program returns those leaves
-        P(axis)-sharded, and only 1/local_size of the leaf ever crosses
-        device->host per device — BytePS's hierarchical "the
-        intra-machine reduce puts 1/local_size on the wire". The
-        remaining leaves keep the exact whole-leaf path (one psum over
-        their subtree, replicated output), so disabling sharding per
-        leaf is bitwise-invisible."""
+        export; a shard leaf's tap carries only ITS device's shard (the
+        device index rides alongside)."""
         from jax.experimental import io_callback
 
-        from ..ops.push_pull import scatter_leaf
-
         holder = stream_state["holder"]
-        shard_set = frozenset(shard_set)
 
         def _ingest(i, step_arr, dev_arr, arr, seq, t_enq):
             # round resolved at INGEST time: a stale fire then fails
@@ -879,40 +929,11 @@ def make_ps_train_step(
                 _export_pool().submit(_ingest, i, step_arr, dev_arr, arr,
                                       seq, time.perf_counter())
 
-        def streamed_local(step_tag, params, batch):
-            loss, grads = jax.value_and_grad(
-                loss_and_stats, has_aux=True)(params, batch)
-            leaves = jax.tree.leaves(grads)
-            # ONE psum over the whole-leaf subtree (identical reduction
-            # grouping to the untapped grad_fn's full-tree psum), RS
-            # per shard leaf
-            whole_idx = [i for i in range(len(leaves))
-                         if i not in shard_set]
-            whole = psum_tree([leaves[i] for i in whole_idx],
-                              axis=axis, average=True)
-            whole_map = dict(zip(whole_idx, whole))
-            idx = jax.lax.axis_index(axis)
-            outs = []
-            for i in range(len(leaves)):
-                if i in shard_set:
-                    sh = scatter_leaf(leaves[i], axis=axis, average=True)
-                    io_callback(functools.partial(_tap, i), None,
-                                step_tag, idx, sh, ordered=False)
-                    outs.append(sh)
-                else:
-                    g = whole_map[i]
-                    if i in eligible:
-                        io_callback(functools.partial(_tap, i), None,
-                                    step_tag, idx, g, ordered=False)
-                    outs.append(g)
-            return _reduce_loss(loss), tuple(outs)
+        def plant(i, step_tag, idx, g):
+            io_callback(functools.partial(_tap, i), None,
+                        step_tag, idx, g, ordered=False)
 
-        out_leaf_specs = tuple(
-            P(axis) if i in shard_set else P()
-            for i in range(n_leaves))
-        return jax.jit(jax.shard_map(
-            streamed_local, mesh=mesh, in_specs=(P(), P(), P(axis)),
-            out_specs=(P(), out_leaf_specs), check_vma=False))
+        return plant
 
     def apply_updates_fn(params, opt_state, grads):
         updates, opt_state = tx.update(grads, opt_state, params)
@@ -1110,6 +1131,7 @@ def make_ps_train_step(
         exp_shard_ctr = metrics.counter("export/shard_bytes")
         exp_whole_ctr = metrics.counter("export/whole_bytes")
         exp_dev0_ctr = metrics.counter("export/device_bytes/0")
+        # export workers exist only where taps were asked for
         metrics.gauge("export/shard_workers").set(len(_SHARD_POOLS))
         metrics.gauge("export/worker_ingests/0").set(
             _SHARD_INGESTS.get(0, 0))
@@ -1209,8 +1231,9 @@ def make_ps_train_step(
                 return submit(name, flat, priority=pr, tag="export")
 
         def submit_shard(i, dev, flat):
-            """Shard-side submit (runs on device ``dev``'s export
-            worker): device ``dev``'s 1/local_size shard of leaf ``i``
+            """Shard-side submit (on the train thread's claim loop; on
+            device ``dev``'s export worker where the shard was tapped):
+            device ``dev``'s 1/local_size shard of leaf ``i``
             rides its own subrange key at the PARENT leaf's
             production-order priority (all shards of one leaf are one
             production event), with its own per-shard arena result
@@ -1367,17 +1390,17 @@ def make_ps_train_step(
                     continue  # padding beyond 1/8: not worth the wire
                 ss.append(i)
             shard_set = tuple(ss)
-        # ---- the route off the chip, by the leaf's kind in the plan:
-        # a shard leaf is tapped (its per-device shards cross in
-        # parallel, inside the backward); a leaf on a whole-leaf key
-        # leaves as an OUTPUT of the backward, copied by the runtime and
-        # claimed below like the bucket members and rowsparse leaves —
-        # on the v5e a callback operand of that size reaches the host
-        # at 0.4-0.75 GB/s, a program output at 3.3-4.5 (PERF.md
-        # section 6, PR 24 and PR 25) — unless the
-        # caller asked for taps on whole leaves too (stream_cfg True).
-        # With nothing tapped the program that runs is ``grad_fn``.
-        tapped = eligible if stream_cfg else shard_set
+        # ---- the route off the chip: every leaf is an OUTPUT of the
+        # backward, copied by the runtime and claimed below — a leaf on
+        # a whole-leaf key, a bucket member or a rowsparse leaf as one
+        # replicated array, a shard leaf as one flat shard a device, the
+        # devices' copies side by side — unless the caller asked for
+        # taps (stream_cfg True: every eligible leaf, shard leaves too).
+        # On the v5e a callback operand reaches the host at 0.4-1.0
+        # GB/s, a program output at 3.0-4.5 (PERF.md section 6, PRs 24,
+        # 25 and 27). With nothing tapped or sharded the program that
+        # runs is ``grad_fn``.
+        tapped = eligible if stream_cfg else ()
         stream_on = stream_avail and bool(tapped)
         plan_key = (treedef, tapped, shard_set, n_shard)
         if stream_avail and stream_state["key"] != plan_key:
@@ -1418,8 +1441,10 @@ def make_ps_train_step(
             if shard_set:
                 from jax.sharding import NamedSharding
                 stream_state["nsharding"] = NamedSharding(mesh, P(axis))
-            stream_state["fn"] = _build_streamed_fn(
-                tapped, shard_set, len(names)) if stream_on else None
+            stream_state["fn"] = _scatter_backward(
+                loss_and_stats, mesh, axis, shard_set, len(names), tapped,
+                _tap_planter() if tapped else None) \
+                if tapped or shard_set else None
             stream_state["key"] = plan_key
 
         # ---- sharded-apply build (cached per tree structure) ----
@@ -1521,31 +1546,16 @@ def make_ps_train_step(
                     lease.abandon()
                 raise
         else:
+            # untapped: the scatter backward where the plan shards
+            # leaves (no step tag: nothing in it fires), else ``grad_fn``
+            backward = stream_state["fn"] if shard_set else grad_fn
             with tracing.span(tracing.STEP_DISPATCH, step=tag):
-                loss, grads = grad_fn(params, batch)
+                loss, grads = backward(params, batch)
         # the train thread's two phases as spans: ``claim`` from here to
         # the export_done mark, ``drain`` from there to drain_done
         phase = tracing.span(tracing.STEP_CLAIM, step=tag).start()
         g_leaves = jax.tree.leaves(grads)
         streamed_set = set(tapped) if round_obj is not None else set()
-        # start the D2H copies of the output-route leaves now, all of
-        # them, in flatten order (a pure function of the plan: every
-        # worker issues and claims them alike); each np.asarray below
-        # then only waits for ITS leaf. The TPU runtime works on the
-        # copies side by side (it de-tiles each on host threads) and
-        # the large ones finish close together, late in the claim; a
-        # bounded window of copies in flight does overlap the PUSH with
-        # the transfers but slows the transfers by as much, on one
-        # host's cores (PERF.md section 6, PR 25). Tapped leaves cross
-        # in their tap.
-        for i, leaf in enumerate(g_leaves):
-            if i not in streamed_set and hasattr(leaf,
-                                                 "copy_to_host_async"):
-                leaf.copy_to_host_async()
-
-        imported: list = [None] * len(names)
-        new_params: list = [None] * len(names)
-        apply_parts: list = [None] * len(names)
         # per-leaf shard import state (BYTEPS_LOCAL_SHARD_EXPORT):
         # shard k of leaf i lands on the device that owns it the moment
         # its pull completes; when the last shard of a leaf lands, the
@@ -1555,6 +1565,54 @@ def make_ps_train_step(
         shard_parts: Dict[int, list] = {}
         shard_left: Dict[int, int] = {}
         axis_devs = list(mesh.devices.flat)
+        def device_parts(leaf):
+            by_dev = {s.device: s.data for s in leaf.addressable_shards}
+            return [by_dev[d] for d in axis_devs]
+
+        def claim_shards(i, name, parts):
+            """A shard leaf on the output route: each device's flat
+            shard is claimed and submitted as its tap's ingest would
+            have done on that device's worker (same subrange key,
+            parent anchor and counters: ``submit_shard``), in
+            mesh-device order."""
+            shard_parts[i] = [None] * len(parts)
+            shard_left[i] = len(parts)
+            for dev, part in enumerate(parts):
+                nb = part.nbytes
+                with tracing.span(tracing.EXPORT_INGEST, tid=name, step=tag,
+                                  leaf=i, dev=dev, bytes=nb,
+                                  cause=f"out:{i}/{dev}"):
+                    with tracing.span(tracing.EXPORT_MATERIALIZE, step=tag,
+                                      leaf=i, bytes=nb):
+                        h = np.asarray(part)
+                    w = submit_shard(i, dev, h.reshape(-1))
+                waiters.append((("shard", i, dev), *w))
+
+        # start the D2H copies of the output-route leaves now, all of
+        # them, in flatten order, a shard leaf's per-device arrays in
+        # mesh-device order (a pure function of the plan: every worker
+        # issues and claims them alike); each np.asarray below then
+        # only waits for ITS array. The TPU runtime works on the copies
+        # side by side (it de-tiles each on host threads) and the large
+        # ones finish close together, late in the claim; a bounded
+        # window of copies in flight does overlap the PUSH with the
+        # transfers but slows the transfers by as much, on one host's
+        # cores (PERF.md section 6, PR 25). Tapped leaves cross in
+        # their tap.
+        out_shards: Dict[int, list] = {}
+        for i, leaf in enumerate(g_leaves):
+            if i in streamed_set:
+                continue
+            if i in active_shard:
+                out_shards[i] = device_parts(leaf)
+                for part in out_shards[i]:
+                    part.copy_to_host_async()
+            elif hasattr(leaf, "copy_to_host_async"):
+                leaf.copy_to_host_async()
+
+        imported: list = [None] * len(names)
+        new_params: list = [None] * len(names)
+        apply_parts: list = [None] * len(names)
         try:
             for i, (name, leaf) in enumerate(zip(names, g_leaves)):
                 if i in streamed_set:
@@ -1580,6 +1638,9 @@ def make_ps_train_step(
                                             fin, notif))
                     else:
                         waiters.append((i, *w))
+                    continue
+                if i in out_shards:
+                    claim_shards(i, name, out_shards[i])
                     continue
                 nb = leaf.nbytes
                 exp_whole_ctr.inc(nb)
@@ -1921,13 +1982,11 @@ def make_ps_train_step(
                     state.handles.discard(h.id)
             raise
         stream_state["holder"]["round"] = None
+        n_streamed = round_obj.streamed if round_obj is not None else 0
         state.telemetry.record_export(
-            round_obj.streamed if round_obj is not None else 0,
-            len(names) - (round_obj.streamed
-                          if round_obj is not None else 0),
-            first_push[0],
-            shard_leaves=(round_obj.shard_leaves
-                          if round_obj is not None else 0))
+            n_streamed, len(names) - n_streamed, first_push[0],
+            shard_leaves=(round_obj.shard_leaves if round_obj is not None
+                          else len(out_shards)))
         if sa is not None:
             # UPDATEs are already in flight; the end-of-step barrier is
             # gone. The leases release on whichever fires first: the
@@ -1959,7 +2018,6 @@ def make_ps_train_step(
                 lease.release()
             grads = treedef.unflatten(imported)
             params, opt_state = apply_fn(params, opt_state, grads)
-        n_streamed = round_obj.streamed if round_obj is not None else 0
         # training-health finalize: close the step's per-leaf stats
         # into the StepReport fields (incl. the bounded HEALTH_PULL
         # fidelity sweep); the HealthPlane observer inside end_step

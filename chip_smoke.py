@@ -545,12 +545,12 @@ def check_sharding_spans(tree, n: int) -> None:
 
 
 def check_engagement(bps, state, snaps, init_host, n: int) -> None:
-    """The engagement counters: every leaf left by the route its kind
-    in the plan gives it (a leaf the plan shards across the ``n`` chips
-    is tapped and counts as streamed: none on one chip; every other
-    leaf is an output of the backward), the arena served every
-    checkout, the wire carried fused PUSHPULLs and the server folded
-    exactly the bytes that were pushed."""
+    """The engagement counters: every leaf left as an output of the
+    backward (nothing is tapped unless BYTEPS_STREAM_EXPORT=1 asks for
+    it: no leaf counts as streamed), a leaf the plan shards across the
+    ``n`` chips as one flat shard a chip (none on one chip), the arena
+    served every checkout, the wire carried fused PUSHPULLs and the
+    server folded exactly the bytes that were pushed."""
     import jax
 
     from byteps_tpu.ops.push_pull import shard_layout
@@ -562,13 +562,13 @@ def check_engagement(bps, state, snaps, init_host, n: int) -> None:
     leaves = jax.tree.leaves(init_host)
     # jax/train.py's shard plan: large enough, and padded by an eighth
     # at most
-    sharded = 0 if n == 1 else sum(
-        1 for v in leaves if v.nbytes >= floor
-        and shard_layout(v.size, n)[1] * 8 <= v.size)
+    sharded = [] if n == 1 else [
+        v for v in leaves if v.nbytes >= floor
+        and shard_layout(v.size, n)[1] * 8 <= v.size]
     grad_bytes = sum(v.nbytes for v in leaves)
     log(f"last StepReport: streamed={last['streamed_leaves']} "
         f"fallback={last['fallback_leaves']} ttfp_ms={last.get('ttfp_ms')} of "
-        f"{len(leaves)} leaves, {sharded} sharded over {n} chip(s) "
+        f"{len(leaves)} leaves, {len(sharded)} sharded over {n} chip(s) "
         f"(>= {floor} B)")
     stages = ("wall_ms", "compute_ms", "drain_ms", "tail_ms",
               "pull_wait_ms", "allgather_ms", "push_p95_ms", "pull_p95_ms",
@@ -577,23 +577,27 @@ def check_engagement(bps, state, snaps, init_host, n: int) -> None:
     log("last StepReport host-clock walls (observations, not metrics): "
         + ", ".join(f"{k}={last[k]:.1f}" for k in stages
                     if last.get(k) is not None))
-    if last["streamed_leaves"] != sharded:
+    if last["streamed_leaves"] != 0:
         raise AssertionError(
-            f"streamed={last['streamed_leaves']}, want the {sharded} "
-            f"leaves the plan shards")
-    if last["fallback_leaves"] != len(leaves) - sharded:
+            f"streamed={last['streamed_leaves']}: a leaf was tapped with "
+            f"BYTEPS_STREAM_EXPORT unset")
+    if last["fallback_leaves"] != len(leaves):
         raise AssertionError(
-            f"fallback={last['fallback_leaves']} != {len(leaves) - sharded} "
-            f"leaves on the output route")
+            f"fallback={last['fallback_leaves']} != {len(leaves)} leaves "
+            f"on the output route")
     first, end = snaps[0], snaps[-1]
     a0, a1 = first["arena"], end["arena"]
     log(f"arena after step 1: {a0}")
     log(f"arena after step {len(snaps)}: {a1}")
-    grew = a1["export_streamed_leaves"] - a0["export_streamed_leaves"]
-    if grew != sharded * (len(snaps) - 1):
+    if a1["export_streamed_leaves"] != 0:
         raise AssertionError(
-            f"export_streamed_leaves grew {grew}, want "
-            f"{sharded * (len(snaps) - 1)}")
+            f"export_streamed_leaves = {a1['export_streamed_leaves']}, "
+            f"want 0")
+    grew = a1["export_shard_leaves"] - a0["export_shard_leaves"]
+    if grew != len(sharded) * (len(snaps) - 1):
+        raise AssertionError(
+            f"export_shard_leaves grew {grew}, want "
+            f"{len(sharded) * (len(snaps) - 1)}")
     for key in ("fresh_allocs", "checkout_conflicts"):
         if a1[key] != 0:
             raise AssertionError(f"arena {key} = {a1[key]}, want 0")
@@ -627,13 +631,18 @@ def check_engagement(bps, state, snaps, init_host, n: int) -> None:
             raise AssertionError(
                 f"server fold_bytes {d_fold} != pushed {d_push} (gradient "
                 f"{steady * grad_bytes}) over {steady} steps")
-        check_shard_engagement(end, a1, n)
+        # a chip's share of the plan: its flat shard of every sharded
+        # leaf, padding included, every step
+        shard_step = sum(shard_layout(v.size, n)[0] * v.dtype.itemsize
+                         for v in sharded)
+        check_shard_engagement(end, a1, n, shard_step * len(snaps))
 
 
-def check_shard_engagement(end: dict, arena: dict, n: int) -> None:
+def check_shard_engagement(end: dict, arena: dict, n: int,
+                           want: int) -> None:
     """Four-chip form: leaves left the devices as per-device shards, and
-    each device exported the same number of shard bytes (device 0 also
-    carries the whole-leaf and bucket exports)."""
+    each device exported exactly the ``want`` shard bytes the plan gives
+    it (device 0 also carries the whole-leaf and bucket exports)."""
     log(f"export: shard_leaves={arena['export_shard_leaves']} "
         f"shard_bytes={end['shard_bytes']} whole_bytes="
         f"{end['whole_bytes']} device_bytes={end['device_bytes']}")
@@ -641,7 +650,9 @@ def check_shard_engagement(end: dict, arena: dict, n: int) -> None:
         raise AssertionError("export_shard_leaves == 0")
     per_dev = dict(end["device_bytes"])
     per_dev["0"] = per_dev.get("0", 0) - end["whole_bytes"]
-    want = end["shard_bytes"] // n
+    if end["shard_bytes"] != want * n:
+        raise AssertionError(
+            f"export/shard_bytes = {end['shard_bytes']}, want {want * n}")
     if sorted(per_dev) != [str(d) for d in range(n)] or \
             any(v != want for v in per_dev.values()):
         raise AssertionError(

@@ -1,5 +1,6 @@
 """Ask the TPU compiler, with no chip attached, whether every Pallas
-kernel the package ships compiles at real widths.
+kernel the package ships compiles at real widths, and whether the
+backward a whole v5e host runs under the PS step does.
 
 The TPU's compiler is installed beside the CPU backend and compiles for a
 chip that is described, not attached (``jax.experimental.topologies``):
@@ -14,6 +15,7 @@ cannot be described. Nothing runs, so results are checked elsewhere
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -28,8 +30,8 @@ LEAF = 24 * 1024 * 1024
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """One described v5e chip (device kind ``TPU v5 lite``)."""
+def v5e_host():
+    """The four described chips of one v5e host (``v5e:2x2``)."""
     from jax.experimental import topologies
 
     try:
@@ -37,7 +39,13 @@ def v5e():
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 - no TPU compiler installed
         pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_host):
+    """One described v5e chip (device kind ``TPU v5 lite``)."""
+    return SingleDeviceSharding(v5e_host[0])
 
 
 @pytest.fixture(autouse=True)
@@ -151,3 +159,56 @@ def test_kernel_compiles_for_v5e(v5e, lower):
     compiled = lower(v5e).compile()
     # the kernel itself must be in the program, not a portable rewrite
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_untapped_scatter_backward_compiles_for_a_v5e_host(v5e_host):
+    """The program a four-chip mesh runs with BYTEPS_STREAM_EXPORT
+    unset: BERT-large's widths (two of its 24 layers: the full depth
+    compiles in 100 s here), batch 64 a chip, the plan's shard leaves
+    reduce-scattered and returned as flat ``P(dp)`` outputs. The TPU's
+    compiler takes it, no host callback or host transfer is in it (so
+    the persistent cache can serve it), and a chip's outputs hold a
+    quarter of every shard leaf."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from byteps_tpu.config import Config
+    from byteps_tpu.jax import train
+    from byteps_tpu.models import bert
+    from byteps_tpu.ops.push_pull import shard_layout
+
+    n = len(v5e_host)
+    mesh = Mesh(np.array(v5e_host), ("dp",))
+    cfg = dataclasses.replace(bert.BertConfig.bert_large(), n_layers=2)
+    rep, dp = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    params = jax.tree.map(
+        lambda v: _sds(v.shape, v.dtype, rep),
+        jax.eval_shape(lambda: bert.init_params(jax.random.PRNGKey(0), cfg)))
+    leaves = jax.tree.leaves(params)
+    # jax/train.py's shard plan under the default thresholds
+    floor = max(Config().fusion_bytes, Config().shard_min_bytes)
+    nbytes = [v.size * v.dtype.itemsize for v in leaves]
+    shard_set = tuple(
+        i for i, v in enumerate(leaves)
+        if nbytes[i] >= floor and shard_layout(v.size, n)[1] * 8 <= v.size)
+    assert 0 < len(shard_set) < len(leaves)
+    batch = {k: _sds((64 * n, 128), jnp.int32, dp)
+             for k in ("tokens", "labels")}
+    fn = train._scatter_backward(
+        train._loss_and_stats(lambda p, b: bert.loss_fn(p, b, cfg)),
+        mesh, "dp", shard_set, len(leaves))
+    compiled = fn.lower(params, batch).compile()
+    text = compiled.as_text()
+    # a tap would be a host transfer or a callback custom call (the
+    # bare words also occur in the instructions' source locations)
+    assert "is_host_transfer=true" not in text
+    assert not [t for t in re.findall(r'custom_call_target="([^"]+)"', text)
+                if "callback" in t.lower()]
+    assert "all-reduce" in text or "reduce-scatter" in text
+    # a device's outputs: its quarter of each shard leaf, every other
+    # leaf whole, the loss
+    out = compiled.memory_analysis().output_size_in_bytes
+    want = sum(b // n if i in shard_set else b for i, b in enumerate(nbytes))
+    assert want <= out <= want + (1 << 20)

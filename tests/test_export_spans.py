@@ -2,8 +2,8 @@
 ``span``; jax/train.py, core/scheduler.py, core/metrics.py): every span
 of PERF.md's table is recorded on the thread the table names, with the
 round's tag as its ``step``, on the tapped route (asked for with
-``BYTEPS_STREAM_EXPORT=1``) and on the output route (what unset means on
-one device); the StepReport's export fields are reduced
+``BYTEPS_STREAM_EXPORT=1``) and on the output route (what unset means,
+on one device and on a mesh); the StepReport's export fields are reduced
 from them and hold their identities; nothing is read where no leaf
 rides a key of its own or metrics are off; and a profiler session opened by anybody
 holds the spans on its host lines, ``bps.wire.send`` and
@@ -93,8 +93,8 @@ def _stepper(mesh=None, **kw):
 
 # whole-leaf: every leaf streams through the one router; shard: the
 # weights reduce-scatter and leave as per-device shards through the
-# bps-export-d{k} workers, the biases stay whole. Both ask for taps on
-# whole leaves: unset, a whole leaf is an output (the tests at the end)
+# bps-export-d{k} workers, the biases stay whole. Both ask for taps:
+# unset, every leaf is an output (the tests at the end)
 MODES = {
     "whole-leaf": {"BYTEPS_STREAM_EXPORT": "1", "BYTEPS_FUSION_BYTES": "0",
                    "BYTEPS_LOCAL_SHARD_EXPORT": "0"},
@@ -322,14 +322,17 @@ TAP_FIELDS = ("export_tap_span_ms", "export_router_wait_max_ms")
 
 
 # unset on a one-device mesh (what the one-chip cells run) and the
-# caller's "0" are the same route: no leaf is tapped
-@pytest.mark.parametrize("setting", [None, "0"], ids=["unset", "off"])
+# caller's "0" are the same route: no leaf is tapped. Unset on the
+# eight-device mesh (what a whole host runs) is that route too, with
+# the weights as one flat shard a device
+@pytest.mark.parametrize("setting,devices", [(None, 1), ("0", 1), (None, 8)],
+                         ids=["unset", "off", "unset-mesh"])
 def test_the_output_route_fills_four_fields_and_leaves_the_tap_fields_none(
-        setting):
+        setting, devices):
     import jax
     from jax.sharding import Mesh
 
-    env = {"BYTEPS_FUSION_BYTES": "0"}
+    env = {"BYTEPS_FUSION_BYTES": "0", "BYTEPS_SHARD_MIN_BYTES": "1024"}
     if setting is not None:
         env["BYTEPS_STREAM_EXPORT"] = setting
     with _ps_env(env) as bps:
@@ -338,11 +341,12 @@ def test_the_output_route_fills_four_fields_and_leaves_the_tap_fields_none(
         assert get_state().config.stream_export is (
             None if setting is None else False)
         run, n_leaves = _stepper(
-            mesh=Mesh(np.array(jax.devices()[:1]), ("dp",)))
+            mesh=Mesh(np.array(jax.devices()[:devices]), ("dp",)))
         run(3)
         reports = bps.get_step_reports()[-3:]
         spans = get_state().profiler.last_spans()
-        whole_bytes = bps.get_metrics()["counters"]["export/whole_bytes"]
+        ctr = bps.get_metrics()["counters"]
+        shard_leaves = bps.get_arena_stats()["export_shard_leaves"]
     eps = 1e-6
     for r in reports:
         assert r["streamed_leaves"] == 0
@@ -360,23 +364,37 @@ def test_the_output_route_fills_four_fields_and_leaves_the_tap_fields_none(
     train = threading.current_thread().name
     assert tracing.EXPORT_TAP not in by and tracing.EXPORT_ROUTE not in by
     ingests = by[tracing.EXPORT_INGEST]
-    # one ingest a leaf, in flatten order, on the thread that claims
-    assert [sp[4]["leaf"] for sp in ingests] == list(range(n_leaves))
+    # one ingest a leaf, in flatten order, on the thread that claims; a
+    # weight the mesh shards has one a device, in mesh-device order
+    sharded = [3, 4, 5] if devices > 1 else []  # the 2-D leaves
+    assert shard_leaves == 3 * len(sharded)
+    want = [(i, d) for i in range(n_leaves)
+            for d in (range(devices) if i in sharded else [None])]
+    assert [(sp[4]["leaf"], sp[4].get("dev")) for sp in ingests] == want
     assert [sp[4]["cause"] for sp in ingests] == [
-        f"out:{i}" for i in range(n_leaves)]
+        f"out:{i}" if d is None else f"out:{i}/{d}" for i, d in want]
     assert all("queued_us" not in sp[4] and sp[4]["bytes"] > 0
                and sp[4]["step"] == 3 for sp in ingests)
     (claim,) = by[tracing.STEP_CLAIM]
     for stage in (tracing.EXPORT_INGEST, tracing.EXPORT_MATERIALIZE,
                   tracing.EXPORT_SUBMIT):
-        assert len(by[stage]) == n_leaves, stage
+        assert len(by[stage]) == len(want), stage
         assert {sp[1] for sp in by[stage]} == {train}, stage
         assert all(claim[2] <= sp[2] and sp[3] <= claim[3]
                    for sp in by[stage]), stage
     for child in by[tracing.EXPORT_MATERIALIZE] + by[tracing.EXPORT_SUBMIT]:
         assert any(p[2] <= child[2] and child[3] <= p[3] for p in ingests)
-    # every byte of every step is counted as a whole-leaf export
-    assert whole_bytes == 3 * sum(sp[4]["bytes"] for sp in ingests)
+    assert all(sp[4]["partitions"] >= 1 and sp[4]["key"] >= 0
+               for sp in by[tracing.EXPORT_SUBMIT])
+    # every byte of every step is counted, a whole leaf's as a
+    # whole-leaf export and a shard's to the device that held it
+    assert ctr["export/whole_bytes"] + ctr.get("export/shard_bytes", 0) \
+        == 3 * sum(sp[4]["bytes"] for sp in ingests)
+    for d in range(1, devices):
+        assert ctr[f"export/device_bytes/{d}"] == 3 * sum(
+            sp[4]["bytes"] for sp in ingests if sp[4].get("dev") == d)
+    if sharded:
+        assert by[tracing.APPLY_ALLGATHER]
     # the wire's sends still name no tap's submit but a key's
     assert by[tracing.WIRE_SEND] and by[tracing.WIRE_DONE]
 

@@ -4,8 +4,11 @@ subranges): bitwise parity of shard-export on vs off vs the
 single-process baseline for dense, fused-bucket and
 compression-fallback configs; odd (non-divisible) shapes with padding;
 the pad-threshold and local_size==1 fallbacks; shard keys sharing the
-parent's production ordinal; and a slow mixed-traffic churn asserting
-no arena-lease or handle leaks under per-shard checkouts.
+parent's production ordinal; the route BYTEPS_STREAM_EXPORT unset
+chooses on a mesh (each device's shard a program output: bitwise the
+tapped shards and the whole leaves, no host callback in the program,
+a failed claim cleaned up after); and a slow mixed-traffic churn
+asserting no arena-lease or handle leaks under per-shard checkouts.
 
 Bitwise parity relies on the conftest's
 ``--xla_cpu_enable_fast_math=false`` pin: XLA CPU fast-math
@@ -205,9 +208,9 @@ def test_odd_shapes_pad_parity():
         np.testing.assert_array_equal(a, b)
 
 
-# a whole leaf beside shard leaves is an output of the backward unless
-# the caller asked for taps on whole leaves too
-@pytest.mark.parametrize("stream,streamed", [(None, 2), ("1", 4)],
+# unset, nothing is tapped: the shard leaf leaves as per-device outputs,
+# the fragment as a whole one; asked for, both are tapped
+@pytest.mark.parametrize("stream,streamed", [(None, 0), ("1", 4)],
                          ids=["unset", "taps-asked"])
 def test_pad_threshold_falls_back(stream, streamed):
     """A leaf whose padding would exceed 1/8 of its size keeps the
@@ -240,8 +243,8 @@ def test_pad_threshold_falls_back(stream, streamed):
         for _ in range(2):
             p, opt, _ = step(p, opt, batch)
         stats = bps.get_arena_stats()
-        # exactly ONE leaf per step sharded (big); frag exported whole,
-        # tapped only where taps on whole leaves were asked for
+        # exactly ONE leaf per step sharded (big), on either route; frag
+        # exported whole; tapped only where taps were asked for
         assert stats["export_shard_leaves"] == 2
         assert stats["export_streamed_leaves"] == streamed
         assert stats["export_fallback_leaves"] == 4 - streamed
@@ -336,7 +339,9 @@ def test_broken_taps_raise(monkeypatch):
     def _dead_tap(*a, **k):
         raise RuntimeError("io_callback disabled for this test")
 
-    with _ps_env({"BYTEPS_FUSION_BYTES": "0"}) as bps:
+    # taps are planted only where they are asked for
+    with _ps_env({"BYTEPS_FUSION_BYTES": "0",
+                  "BYTEPS_STREAM_EXPORT": "1"}) as bps:
         monkeypatch.setattr(jax.experimental, "io_callback", _dead_tap)
         with pytest.raises(RuntimeError, match="io_callback disabled"):
             _run_steps(params, batch, cfg, local_shard_export=True)
@@ -344,6 +349,166 @@ def test_broken_taps_raise(monkeypatch):
         stats = bps.get_arena_stats()
         assert stats["export_streamed_leaves"] == 0
         assert bps.get_metrics()["counters"]["export/shard_bytes"] == 0
+
+
+# --------------------------------------------------------------------- #
+# the route unset chooses on a mesh: shard leaves as per-device outputs
+# --------------------------------------------------------------------- #
+
+
+def _route_run(arm, steps=4):
+    """``steps`` PS steps on the 8-device mesh with BYTEPS_STREAM_EXPORT
+    unset (``None``), "1" or "0": parameters, every loss, the declared
+    keys and the export counters."""
+    cfg, params, batch = _setup()
+    env = {"BYTEPS_FUSION_BYTES": "0"}
+    if arm is not None:
+        env["BYTEPS_STREAM_EXPORT"] = arm
+    with _ps_env(env) as bps:
+        import jax
+        import jax.numpy as jnp
+
+        from byteps_tpu.core.state import get_state
+        from byteps_tpu.jax.train import make_ps_train_step
+        from byteps_tpu.models import mlp
+
+        p = jax.tree.map(jnp.array, params)
+        tx = optax.adam(1e-2)
+        opt = tx.init(p)
+        step = make_ps_train_step(lambda q, b: mlp.loss_fn(q, b, cfg), tx,
+                                  get_state().mesh)
+        losses = []
+        for _ in range(steps):
+            p, opt, loss = step(p, opt, batch)
+            losses.append(np.asarray(loss))
+        ctr = bps.get_metrics()["counters"]
+        return {
+            "leaves": [np.asarray(x) for x in jax.tree.leaves(p)],
+            "losses": losses,
+            "keys": sorted(c.name for c in
+                           get_state().registry.contexts_in_order()),
+            "shard_bytes": ctr.get("export/shard_bytes", 0),
+            "device_bytes": {k: v for k, v in ctr.items()
+                             if k.startswith("export/device_bytes/")},
+            "arena": bps.get_arena_stats(),
+            "report": bps.get_step_reports()[-1],
+        }
+
+
+def test_unset_on_a_mesh_is_bitwise_the_taps_and_the_whole_leaves():
+    """Nobody set BYTEPS_STREAM_EXPORT, eight devices: the weights
+    reduce-scatter and each device's shard leaves as a program output.
+    Every loss and every parameter over four steps is bitwise what
+    ``=1`` (the same shards, tapped) and ``=0`` (no shard plan, whole
+    leaves) give; against ``=1`` the declared keys, the shard bytes and
+    each device's bytes are the same, and no leaf counts as streamed."""
+    unset, asked, off = _route_run(None), _route_run("1"), _route_run("0")
+    for other in (asked, off):
+        for a, b in zip(unset["leaves"], other["leaves"]):
+            np.testing.assert_array_equal(a, b)
+        for a, b in zip(unset["losses"], other["losses"]):
+            np.testing.assert_array_equal(a, b)
+    assert unset["keys"] == asked["keys"]
+    assert any("@shard" in k for k in unset["keys"])
+    assert not any("@shard" in k for k in off["keys"])
+    assert unset["shard_bytes"] == asked["shard_bytes"] > 0
+    assert off["shard_bytes"] == 0
+    assert unset["device_bytes"] == asked["device_bytes"]
+    assert sorted(unset["device_bytes"]) == [
+        f"export/device_bytes/{d}" for d in range(8)]
+    n_leaves = len(unset["leaves"])
+    assert unset["arena"]["export_shard_leaves"] == \
+        asked["arena"]["export_shard_leaves"] == 4 * 3
+    assert unset["arena"]["export_streamed_leaves"] == 0
+    assert unset["report"]["streamed_leaves"] == 0
+    assert unset["report"]["fallback_leaves"] == n_leaves
+    assert asked["report"]["streamed_leaves"] == n_leaves
+
+
+@pytest.mark.parametrize("tapped", [False, True],
+                         ids=["unset", "taps-asked"])
+def test_the_scatter_backward_holds_a_host_callback_only_where_asked(tapped):
+    """The program a mesh runs under unset: the reduce-scatter backward
+    with no host callback in it (so the persistent compile cache can
+    serve it) and no step tag among its arguments; the same builder
+    with taps planted holds one a tapped leaf."""
+    import jax
+    from jax.experimental import io_callback
+
+    from byteps_tpu.core.state import get_state
+    from byteps_tpu.jax import train
+    from byteps_tpu.models import mlp
+
+    cfg, params, batch = _setup()
+    shard_set = tuple(i for i, x in enumerate(jax.tree.leaves(params))
+                      if x.ndim == 2)
+    n_leaves = len(jax.tree.leaves(params))
+
+    def plant(i, step_tag, idx, g):
+        io_callback(lambda *a: None, None, step_tag, idx, g, ordered=False)
+
+    with _ps_env():
+        mesh = get_state().mesh
+        fn = train._scatter_backward(
+            train._loss_and_stats(lambda p, b: mlp.loss_fn(p, b, cfg)),
+            mesh, "dp", shard_set, n_leaves,
+            tapped=shard_set if tapped else (),
+            plant=plant if tapped else None)
+        args = (params, batch)
+        if tapped:
+            args = (np.int32(1),) + args
+        text = fn.lower(*args).as_text()
+        (_, _), grads = fn(*args)
+    assert ("callback" in text) == tapped
+    assert "reduce_scatter" in text
+    for i, g in enumerate(grads):
+        assert (len(g.sharding.spec) == 1) == (i in shard_set)
+
+
+@pytest.mark.parametrize("stream", [None, "1"], ids=["unset", "taps-asked"])
+def test_a_failed_shard_claim_abandons_leases_and_discards_handles(
+        stream, monkeypatch):
+    """One device's shard of the second weight cannot be submitted: the
+    step raises that error on either route, the shards already on the
+    wire leave no handle behind, and every staging slot of the round is
+    abandoned (dropped from the table, never recycled under a late
+    writer)."""
+    import time
+
+    from byteps_tpu.server import client as client_mod
+
+    cfg, params, batch = _setup()
+    real = client_mod.get_or_init_ctx
+    seen = []
+
+    def failing(state, name, flat):
+        if "@shard" in name:
+            seen.append(name)
+            if len(seen) == 11:  # the second shard leaf's third device
+                raise RuntimeError("shard submit refused for this test")
+        return real(state, name, flat)
+
+    env = {"BYTEPS_FUSION_BYTES": "0"}
+    if stream is not None:
+        env["BYTEPS_STREAM_EXPORT"] = stream
+    with _ps_env(env) as bps:
+        from byteps_tpu.core.state import get_state
+
+        monkeypatch.setattr(client_mod, "get_or_init_ctx", failing)
+        with pytest.raises(RuntimeError, match="shard submit refused"):
+            _run_steps(params, batch, cfg, steps=1)
+        monkeypatch.setattr(client_mod, "get_or_init_ctx", real)
+        assert len(seen) >= 11
+        state = get_state()
+        deadline = time.time() + 30
+        while time.time() < deadline and state.handles._handles:
+            time.sleep(0.05)
+        assert not state.handles._handles, \
+            f"leaked handles: {list(state.handles._handles)[:8]}"
+        with state.arena._mu:
+            busy = [k for k, sl in state.arena._slots.items() if sl.busy]
+        assert not busy, f"leaked busy arena slots: {busy[:8]}"
+        assert bps.get_arena_stats()["export_rounds"] == 0
 
 
 # --------------------------------------------------------------------- #

@@ -2,8 +2,8 @@
 (BYTEPS_STREAM_EXPORT / BYTEPS_SHARDED_APPLY, jax/train.py +
 jax/optim.py): numerics parity of stream-export on vs off vs the
 single-process baseline (dense, fused-bucket and compression-enabled
-configs), of the route that unset chooses against both, which leaves
-that route taps on a mesh and on one device, bitwise parity of the sharded apply against the fused optax
+configs), of the route that unset chooses against both, that it taps
+nothing on a mesh or on one device, bitwise parity of the sharded apply against the fused optax
 apply for adam/sgd, the non-separable fallback, export-stage telemetry
 (streamed-leaf counters + time-to-first-push), and production-order
 priority pinning end to end."""
@@ -232,25 +232,41 @@ def _export_plan_run(env, mesh_devices=None, steps=3):
     return out
 
 
-def test_unset_plan_on_the_mesh_taps_the_shard_leaves_only():
+def test_unset_plan_on_the_mesh_taps_nothing(monkeypatch):
     """Eight devices, nobody set BYTEPS_STREAM_EXPORT: the weights shard
-    and are tapped exactly as ``=1`` taps them (same per-device bytes,
-    exactly even; same shard keys at production-order priority; same
-    parameters), the biases ride whole-leaf keys and leave as outputs,
-    claimed on the train thread."""
+    exactly as ``=1`` shards them (same per-device bytes, exactly even;
+    same shard keys at their parent's production-order priority; same
+    parameters) and each device's shard leaves as a program output,
+    claimed on the train thread in flatten then mesh-device order
+    beside the biases' whole leaves; no ``io_callback`` is planted and
+    no export pool takes part (asked for with ``=1``, every leaf is
+    tapped)."""
+    import jax
+    import jax.experimental
+
+    planted = []
+    real = jax.experimental.io_callback
+
+    def spy(*a, **kw):
+        planted.append(1)
+        return real(*a, **kw)
+
+    monkeypatch.setattr(jax.experimental, "io_callback", spy)
     env = {"BYTEPS_FUSION_BYTES": "0", "BYTEPS_SHARD_MIN_BYTES": "1024"}
     unset = _export_plan_run(env)
+    assert planted == []
     asked = _export_plan_run({**env, "BYTEPS_STREAM_EXPORT": "1"})
-    train = threading.current_thread().name
-    import jax
+    train = threading.current_thread().name.rsplit("_", 1)[0]
     ndims = [x.ndim for x in jax.tree.leaves(_setup()[1])]
     weights = [i for i, n in enumerate(ndims) if n == 2]
     biases = [i for i, n in enumerate(ndims) if n == 1]
     assert len(weights) == len(biases) == 3
-    # shard leaves: the parent's program and bytes
-    assert unset["report"]["streamed_leaves"] == len(weights)
-    assert unset["report"]["fallback_leaves"] == len(biases)
+    assert len(planted) >= len(weights + biases)
+    # nothing streams; the shard plan and its bytes are ``=1``'s
+    assert unset["report"]["streamed_leaves"] == 0
+    assert unset["report"]["fallback_leaves"] == len(weights + biases)
     assert asked["report"]["streamed_leaves"] == len(weights + biases)
+    assert unset["arena"]["export_streamed_leaves"] == 0
     assert unset["arena"]["export_shard_leaves"] == \
         asked["arena"]["export_shard_leaves"] == 3 * len(weights)
     assert unset["shard_bytes"] == asked["shard_bytes"] > 0
@@ -258,19 +274,19 @@ def test_unset_plan_on_the_mesh_taps_the_shard_leaves_only():
     per_dev = [unset["device_bytes"][d] for d in range(1, 8)]
     assert len(set(per_dev)) == 1 and per_dev[0] * 8 == unset["shard_bytes"]
     assert unset["device_bytes"] == asked["device_bytes"]
-    # every device fires every tap; only the weights have one
-    assert sorted(set(unset["taps"])) == weights
+    assert unset["taps"] == []
     assert sorted(set(asked["taps"])) == sorted(weights + biases)
-    shard_ingests = [m for m in unset["ingests"] if m[0] in weights]
-    assert shard_ingests == [m for m in asked["ingests"] if m[0] in weights]
-    assert [m[:3] for m in shard_ingests] == [
+    # one ingest a (weight, device) and one a bias, all outputs, all on
+    # the thread that claims; the taps' ran on the devices' workers
+    assert unset["ingests"] == sorted(
+        [(w, d, "out", train) for w in weights for d in range(8)]
+        + [(b, -1, "out", train) for b in biases])
+    tapped = [m for m in asked["ingests"] if m[0] in weights]
+    assert [m[:3] for m in tapped] == [
         (w, d, "tap") for w in weights for d in range(8)]
-    assert all(m[3].startswith("bps-export-d") for m in shard_ingests)
-    # whole leaves: outputs, on the thread that claims
-    assert [m for m in unset["ingests"] if m[0] in biases] == [
-        (b, -1, "out", train.rsplit("_", 1)[0]) for b in biases]
-    # the shards' keys keep their measured production order; a whole
-    # leaf on the output route is not in that order any more
+    assert all(m[3].startswith("bps-export-d") for m in tapped)
+    # the shards' keys keep a production order (the claim's: flatten
+    # order); a whole leaf on the output route is in none
     assert len(asked["order"]) > len(unset["order"]) > 0
     assert set(unset["order"]) <= set(asked["order"])
     for a, b in zip(unset["leaves"], asked["leaves"]):
