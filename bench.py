@@ -73,14 +73,6 @@ Extra keys in the same line:
   plus the engaged-proof (``health_grad_norm`` non-null from the ON
   arm's last StepReport, ``health_infold_rounds`` nonzero from the
   server's stat slots). Acceptance bar: ``health_overhead_pct`` <= 2.
-- ``stream_on_step_ms`` / ``stream_off_step_ms`` and
-  ``stream_ttfp_on_ms`` / ``stream_ttfp_off_ms`` — the
-  COMPUTE/PUSH/UPDATE pipeline A/B (BYTEPS_STREAM_EXPORT +
-  BYTEPS_SHARDED_APPLY, jax/train.py): steady-state PS train step wall
-  and time-to-first-push with streamed gradient export + per-leaf
-  sharded optimizer apply on vs off; streaming must show a strictly
-  earlier first push (the tap fires mid-backward), with the export
-  counters proving the overlap engaged.
 
 The train phase A/Bs four variants per capture — remat, selective
 remat, chunked-vocab xent, and a hand-fused adam (one elementwise
@@ -1883,119 +1875,6 @@ def phase_shard_ab(steps: int = 6, reps: int = 3) -> dict:
             "shard_leaves_per_arm": int(d_on["_shard_leaves"])}
 
 
-def phase_stream_ab(steps: int = 6, reps: int = 4,
-                    throttle_mbps: float = 400.0) -> dict:
-    """A/B the COMPUTE/PUSH/UPDATE pipeline (BYTEPS_STREAM_EXPORT +
-    BYTEPS_SHARDED_APPLY, jax/train.py) on the PS train step: the same
-    model/batch trained through the loopback PS with both knobs on vs
-    both off, reporting best-of step wall AND time-to-first-push for
-    each arm. Streaming submits each large gradient leaf to the
-    scheduler the moment XLA produces it (the tap fires mid-backward),
-    so ``ttfp_on_ms`` must be strictly earlier than ``ttfp_off_ms``
-    (where the first submit waits for the whole backward + D2H); the
-    sharded apply then issues per-leaf updates from the
-    completion-ordered drain, removing the end-of-step barrier. The
-    export counters prove the overlap engaged rather than silently
-    falling back. Host-CPU only.
-
-    The server runs under BYTEPS_SERVER_THROTTLE_MBPS — the same
-    CORE-INDEPENDENT trick as phase_pushpull_throttled: on a loopback
-    host the "wire" is CPU work, so un-throttled COMPUTE/PUSH overlap
-    merely time-slices the same cores and the step wall cannot improve
-    (measured: concurrent comm stretched the backward 140→343ms).
-    The throttle's token bucket SLEEPS the serving thread, making wire
-    time a genuinely non-CPU resource like a bandwidth-bound DCN —
-    which is the deployment the pipeline exists for — so the A/B
-    measures overlap capacity, not core contention."""
-    import gc
-
-    def run(enabled: bool, shared: dict):
-        val = "1" if enabled else "0"
-        os.environ["BYTEPS_STREAM_EXPORT"] = val
-        os.environ["BYTEPS_SHARDED_APPLY"] = val
-        with _loopback_ps(1) as bps:
-            import jax.numpy as jnp
-            import numpy as np
-            import optax
-
-            from byteps_tpu.core.state import get_state
-            from byteps_tpu.jax.train import make_ps_train_step
-
-            rng = np.random.RandomState(0)
-            # large leaves on purpose: every w rides its own key above
-            # the fusion threshold, so streaming is eligible; biases
-            # keep the bucket path honest in the same round
-            params = {f"w{i}": _cpu_put(
-                rng.randn(1280, 1280).astype(np.float32))
-                for i in range(6)}
-            params.update({f"b{i}": _cpu_put(
-                rng.randn(1280).astype(np.float32)) for i in range(6)})
-            # batch sized so XLA SPREADS the weight-gradient matmuls
-            # across the backward schedule (measured: at this size the
-            # six dw matmuls produce at ~1/6 intervals, so the taps
-            # fire mid-backward; at much larger batches XLA parks all
-            # dw matmuls at the end of the thunk sequence and there is
-            # nothing to overlap — production order is the compiler's
-            # choice, which is exactly why the scheduler measures it)
-            batch = _cpu_put(rng.randn(32, 1280).astype(np.float32))
-
-            def loss_fn(p, b):
-                h = b
-                for i in range(6):
-                    h = jnp.tanh(h @ p[f"w{i}"] + p[f"b{i}"])
-                return jnp.mean(h * h)
-
-            tx = optax.adam(1e-3)
-            opt = tx.init(params)
-            step = make_ps_train_step(loss_fn, tx, get_state().mesh)
-            for _ in range(2):  # warmup: init-push, jit, slot allocs
-                params, opt, loss = step(params, opt, batch)
-            float(loss)
-            for _ in range(steps):
-                gc.collect()
-                t0 = time.perf_counter()
-                params, opt, loss = step(params, opt, batch)
-                float(loss)
-                shared["walls"].append(time.perf_counter() - t0)
-                s = bps.get_arena_stats()
-                if s.get("export_ttfp_ms") is not None:
-                    shared["ttfps"].append(s["export_ttfp_ms"])
-            shared["stats"] = bps.get_arena_stats()
-
-    saved = {k: os.environ.get(k) for k in ("BYTEPS_STREAM_EXPORT",
-                                            "BYTEPS_SHARDED_APPLY",
-                                            "BYTEPS_SERVER_THROTTLE_MBPS")}
-    os.environ["BYTEPS_SERVER_THROTTLE_MBPS"] = str(throttle_mbps)
-    # INTERLEAVED reps (the phase_scaling lesson): host-load drift on a
-    # shared box otherwise lands on one arm only and decides the A/B;
-    # best-of over all reps per arm is the capability number
-    on = {"walls": [], "ttfps": [], "stats": None}
-    off = {"walls": [], "ttfps": [], "stats": None}
-    try:
-        for _ in range(reps):
-            run(True, on)
-            run(False, off)
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-    on_ms = min(on["walls"]) * 1e3
-    off_ms = min(off["walls"]) * 1e3
-    ttfp_on = min(on["ttfps"]) if on["ttfps"] else None
-    ttfp_off = min(off["ttfps"]) if off["ttfps"] else None
-    stats = on["stats"]
-    return {"stream_on_step_ms": round(on_ms, 2),
-            "stream_off_step_ms": round(off_ms, 2),
-            "stream_ttfp_on_ms": round(ttfp_on, 2)
-            if ttfp_on is not None else None,
-            "stream_ttfp_off_ms": round(ttfp_off, 2)
-            if ttfp_off is not None else None,
-            "stream_streamed_leaves": stats["export_streamed_leaves"],
-            "stream_fallback_leaves": stats["export_fallback_leaves"]}
-
-
 def phase_barrier_ab(steps: int = 8, reps: int = 4,
                      slow_ms: int = 10) -> dict:
     """A/B cross-barrier bounded-staleness pipelining
@@ -2009,8 +1888,7 @@ def phase_barrier_ab(steps: int = 8, reps: int = 4,
     compute, so the end-of-step barrier no longer pays the straggling
     tail. Host-CPU only.
 
-    The server runs under BYTEPS_CHAOS_SLOW_SERVER — the same core-
-    independent trick as phase_stream_ab's throttle: the chaos knob
+    The server runs under BYTEPS_CHAOS_SLOW_SERVER: the chaos knob
     SLEEPS the serving thread per request, making wire+server time a
     genuinely non-CPU resource (the slow-straggler deployment the
     bounded-staleness window exists for), so the A/B measures barrier
@@ -2471,7 +2349,6 @@ _PHASES = {
     "trace_ab": phase_trace_ab,
     "ledger_ab": phase_ledger_ab,
     "health_ab": phase_health_ab,
-    "stream_ab": phase_stream_ab,
     "barrier_ab": phase_barrier_ab,
     "ts_ab": phase_ts_ab,
     "wire_ab": phase_wire_ab,
@@ -2645,10 +2522,6 @@ def main() -> int:
         "health_overhead_pct": None,
         "health_grad_norm": None,
         "health_infold_rounds": None,
-        "stream_on_step_ms": None,
-        "stream_off_step_ms": None,
-        "stream_ttfp_on_ms": None,
-        "stream_ttfp_off_ms": None,
         "barrier_on_step_ms": None,
         "barrier_off_step_ms": None,
         "barrier_speedup": None,
@@ -2821,10 +2694,6 @@ def main() -> int:
                             # engaged-proof (server trace records +
                             # rid flow links in the fused dump)
                             ("trace_ab", 240.0),
-                            # COMPUTE/PUSH/UPDATE pipeline A/B: stream
-                            # export + sharded apply on vs off, step
-                            # wall + time-to-first-push
-                            ("stream_ab", 240.0),
                             # cross-barrier bounded-staleness A/B:
                             # staleness 1 vs the sync barrier under the
                             # slow-server chaos knob, with the carried-

@@ -142,7 +142,7 @@ def get_metrics() -> dict:
       (per-stage per-key-class scheduler latencies, admission wait,
       per-leaf H2D+UPDATE drain spans) with count/sum/min/max/p50/p95/
       p99;
-    - ``arena`` — the staging-arena + streamed-export counters
+    - ``arena`` — the staging-arena + export stage counters
       (identical keys to ``get_arena_stats()``);
     - ``steps`` — the per-step pipeline profiler: ring-buffer window,
       the last ``StepReport`` and its stall diagnosis.
@@ -237,18 +237,14 @@ def get_step_reports() -> list:
 def get_arena_stats() -> dict:
     """Host staging arena counters (core/arena.py): slots live, bytes
     pinned, allocations avoided, checkout conflicts, fresh fallbacks —
-    plus the streamed-export stage counters (jax/train.py):
-    ``export_streamed_leaves`` / ``export_fallback_leaves`` (gradient
-    leaves that left the backward via io_callback taps vs the output
-    route: outputs of the backward, copied by the runtime and claimed
-    by the train thread), ``export_checkouts`` (arena leases serving
-    the tapped export), and ``export_ttfp_ms`` (the last round's
-    time-to-first-push). The steady-state PS train step should show
-    ``allocs_avoided`` growing and ``slot_allocs`` flat after warmup;
-    ``export_streamed_leaves`` stays 0 unless BYTEPS_STREAM_EXPORT=1
-    asks for taps (then it grows by every leaf above the fusion
-    threshold), and ``export_shard_leaves`` grows by the leaves the
-    plan shards on a mesh (none on one device) on either route.
+    plus the export stage counters (jax/train.py): ``export_leaves``
+    (gradient leaves that left the chip: outputs of the backward,
+    copied by the runtime and claimed by the train thread),
+    ``export_shard_leaves`` (those that left as per-device shards: the
+    leaves the plan shards on a mesh, none on one device) and
+    ``export_ttfp_ms`` (the last round's time-to-first-push). The
+    steady-state PS train step should show ``allocs_avoided`` growing
+    and ``slot_allocs`` flat after warmup.
 
     Deprecated alias: this is ``get_metrics()["arena"]`` — the unified
     registry snapshot is the maintained surface; the keys here are

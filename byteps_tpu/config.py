@@ -26,7 +26,7 @@ def _env_int(name: str, default: int) -> int:
     return int(v) if v not in (None, "") else default
 
 
-def _env_bool(name: str, default: Optional[bool] = False) -> Optional[bool]:
+def _env_bool(name: str, default: bool = False) -> bool:
     v = os.environ.get(name)
     if v is None or v == "":
         return default
@@ -106,21 +106,6 @@ class Config:
     # round (the pre-arena behavior; numerics identical). ---
     staging_arena: bool = True            # BYTEPS_STAGING_ARENA
 
-    # --- streamed gradient export (rebuild addition; the reference's
-    # COMPUTE/PUSH overlap: gradients of the last layers enter PUSH while
-    # earlier layers are still in backprop, core_loops.cc + the priority
-    # scheduler's "last layer first"). Three states (numerics identical
-    # in all). Unset (None): nothing is tapped — every leaf is an output
-    # of the backward that the runtime copies to the host and the claim
-    # loop submits, a locality-shard leaf of a mesh as one flat shard a
-    # device (on the v5e a callback operand reaches the host at 0.4-1.0
-    # GB/s, an output at 3.0-4.5: PERF.md, PRs 25 and 27). On: every
-    # eligible leaf, shard leaves too, is tapped inside the compiled
-    # backward with jax.experimental.io_callback, its PUSH submitted the
-    # moment XLA produces it at measured production-order priority.
-    # Off: no taps and no shard plan. ---
-    stream_export: Optional[bool] = None  # BYTEPS_STREAM_EXPORT
-
     # --- sharded optimizer apply (rebuild addition; PAPERS.md "Automatic
     # Cross-Replica Sharding of Weight Update": the weight update
     # decomposes per-shard). On: the PS train step's monolithic apply jit
@@ -139,8 +124,7 @@ class Config:
     # "Automatic Cross-Replica Sharding of Weight Update" (PAPERS.md)).
     # On: the PS train step reduce-SCATTERS eligible gradient leaves
     # instead of psum'ing them, each local device exports ONLY its own
-    # 1/local_size shard (a per-device program output; a tap and a
-    # per-device export worker under stream_export on), each shard
+    # 1/local_size shard (a per-device program output), each shard
     # rides its own PS key spread across servers, the drain imports
     # shard k back into the device that owns it, the optimizer update
     # runs on the shard alone, and a jitted all-gather rebuilds
@@ -148,7 +132,7 @@ class Config:
     # bytes by local_size. Leaves below shard_min_bytes, non-divisible
     # leaves past the pad threshold, rowsparse/compressed/bucket-fused
     # leaves and single-device meshes fall back to the whole-leaf path
-    # (numerics bitwise identical). Off with stream_export off. ---
+    # (numerics bitwise identical). ---
     local_shard_export: bool = True       # BYTEPS_LOCAL_SHARD_EXPORT
     shard_min_bytes: int = DEFAULT_SHARD_MIN_BYTES  # BYTEPS_SHARD_MIN_BYTES
 
@@ -347,7 +331,6 @@ class Config:
             min_compress_bytes=_env_int("BYTEPS_MIN_COMPRESS_BYTES",
                                         DEFAULT_MIN_COMPRESS_BYTES),
             staging_arena=_env_bool("BYTEPS_STAGING_ARENA", True),
-            stream_export=_env_bool("BYTEPS_STREAM_EXPORT", None),
             sharded_apply=_env_bool("BYTEPS_SHARDED_APPLY", True),
             local_shard_export=_env_bool("BYTEPS_LOCAL_SHARD_EXPORT", True),
             shard_min_bytes=_env_int("BYTEPS_SHARD_MIN_BYTES",

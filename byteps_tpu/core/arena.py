@@ -126,8 +126,8 @@ class StagingArena:
         self._conflicts = 0         # guarded-by: _mu
         self._fresh = 0             # guarded-by: _mu
         self._resizes = 0           # guarded-by: _mu
-        # per-stage checkout counters (tag="export": the streamed-export
-        # round's result-slot leases, jax/train.py) — proves which pipeline
+        # per-stage checkout counters (tag="shard": the per-shard
+        # result-slot leases, jax/train.py) — proves which pipeline
         # stage the staged bytes serve
         self._tag_checkouts: Dict[str, int] = {}  # guarded-by: _mu
 
@@ -138,8 +138,8 @@ class StagingArena:
         """Lease the persistent slot for ``key`` (allocating it on first
         use), or a fresh untracked buffer when the arena is disabled or
         the slot is still leased (conflict). ``tag`` attributes the
-        checkout to a pipeline stage in ``stats()`` (e.g. "export" for
-        the streamed-export round's result slots)."""
+        checkout to a pipeline stage in ``stats()`` (e.g. "shard" for
+        the per-shard result slots)."""
         nbytes = int(nbytes)
         if not self.enabled:
             with self._mu:
@@ -219,7 +219,6 @@ class StagingArena:
                 "checkout_conflicts": self._conflicts,
                 "fresh_allocs": self._fresh,
                 "resizes": self._resizes,
-                "export_checkouts": self._tag_checkouts.get("export", 0),
                 # per-shard result-slot leases (tag="shard"): the
                 # locality-sharded export path checks out one slot per
                 # (leaf, local device) instead of one whole-leaf slot —

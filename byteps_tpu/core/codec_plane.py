@@ -305,8 +305,7 @@ class CodecPlane:
         profiler ring and run the controller over them — the lazy round-
         boundary hook (resolve() runs at every round's submit). The
         ingest lock makes each report feed the controller EXACTLY once:
-        concurrent resolves (per-device export workers submit in
-        parallel) racing here would double-advance the hysteresis
+        concurrent resolves racing here would double-advance the hysteresis
         streaks and de-synchronize plans across workers."""
         if self._profiler is None:
             return

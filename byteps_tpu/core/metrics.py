@@ -304,6 +304,10 @@ class StepReport:
     drain_ms: float = 0.0
     tail_ms: float = 0.0
     ttfp_ms: Optional[float] = None
+    # the step's leaf count, under the names benchmark/run.py subscripts:
+    # every leaf leaves the chip as a program output, so streamed_leaves
+    # is a constant 0 and fallback_leaves every leaf (ROADMAP queue 3:
+    # a ``benchmark`` issue renames reader and field together)
     streamed_leaves: int = 0
     fallback_leaves: int = 0
     queue_depth_peak: int = 0
@@ -396,24 +400,16 @@ class StepReport:
     # The export path's own spans (utils/tracing.py span, reduced by
     # export_span_fields below): where compute_ms goes between the
     # backward's dispatch and the last leaf's submission. dispatch_ms =
-    # the backward jit's call on the train thread; export_tap_span_ms =
-    # first streamed tap's start to the last one's end on XLA's
-    # callback threads (how long the runtime took to hand the leaves
-    # over); export_router_busy_ms = the busiest ingesting thread's time
-    # inside ingests (the train thread where whole leaves leave as
-    # program outputs, the router where they are tapped, a per-device
-    # worker under BYTEPS_LOCAL_SHARD_EXPORT), of which
-    # export_materialize_ms is np.asarray and export_submit_ms the
-    # scheduler submission; export_router_wait_max_ms = the longest any
-    # tap's leaf sat queued before its ingest began. The two tap fields
-    # are None on a step with no tap, all six when no leaf or shard
-    # rode a key of its own — never a silent 0.
+    # the backward jit's call on the train thread;
+    # export_router_busy_ms = the train thread's time inside the claim
+    # loop's ingests (one a leaf or a device's shard on a key of its
+    # own), of which export_materialize_ms is np.asarray and
+    # export_submit_ms the scheduler submission. All four are None when
+    # no leaf or shard rode a key of its own — never a silent 0.
     dispatch_ms: Optional[float] = None
-    export_tap_span_ms: Optional[float] = None
     export_router_busy_ms: Optional[float] = None
     export_materialize_ms: Optional[float] = None
     export_submit_ms: Optional[float] = None
-    export_router_wait_max_ms: Optional[float] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -565,45 +561,22 @@ def export_span_fields(spans: List[tuple],
     """Reduce one step's spans — ``(stage, thread, start, end, args)``
     on perf_counter, as ``span`` appended them — to the StepReport's
     export fields. Only this round's leaves count: the spans whose
-    ``step`` is ``round_tag``, and of the taps those that caused an
-    ingest (a duplicate fire from another mesh device caused none). An
-    ingest is a leaf's or a shard's way to the scheduler under a key of
-    its own, on either route: a tap's, on an export thread, or an output
-    leaf's, on the train thread (``cause`` ``out:<leaf>``), which no tap
-    caused and nothing queued, so a step whose leaves all left as
-    outputs has neither ``export_tap_span_ms`` nor
-    ``export_router_wait_max_ms``. No ingest: ``{}``, so every field
-    stays None."""
+    ``step`` is ``round_tag``. An ingest is a leaf's or a shard's way
+    to the scheduler under a key of its own, on the train thread's
+    claim loop; materialize and submit are shares of it. No ingest:
+    ``{}``, so every field stays None."""
     mine = [sp for sp in spans if sp[4].get("step") == round_tag]
-    ingests = [sp for sp in mine if sp[0] == tracing.EXPORT_INGEST]
-    if not ingests:
+    if not any(sp[0] == tracing.EXPORT_INGEST for sp in mine):
         return {}
-    # busiest export thread: the router's routing of shard fires is its
-    # work too; materialize and submit are shares of an ingest
-    busy: Dict[str, float] = {}
-    for stage, thread, t0, t1, _ in mine:
-        if stage in (tracing.EXPORT_INGEST, tracing.EXPORT_ROUTE):
-            busy[thread] = busy.get(thread, 0.0) + (t1 - t0)
-    busiest = max(busy, key=busy.get)
 
-    def on_busiest(stage: str) -> float:
-        return sum(t1 - t0 for st, th, t0, t1, _ in mine
-                   if st == stage and th == busiest) * 1e3
+    def total_ms(stage: str) -> float:
+        return sum(sp[3] - sp[2] for sp in mine if sp[0] == stage) * 1e3
 
     out = {
-        "export_router_busy_ms": busy[busiest] * 1e3,
-        "export_materialize_ms": on_busiest(tracing.EXPORT_MATERIALIZE),
-        "export_submit_ms": on_busiest(tracing.EXPORT_SUBMIT),
+        "export_router_busy_ms": total_ms(tracing.EXPORT_INGEST),
+        "export_materialize_ms": total_ms(tracing.EXPORT_MATERIALIZE),
+        "export_submit_ms": total_ms(tracing.EXPORT_SUBMIT),
     }
-    queued = [sp[4]["queued_us"] for sp in ingests if "queued_us" in sp[4]]
-    if queued:
-        out["export_router_wait_max_ms"] = max(queued) / 1e3
-    causes = {sp[4].get("cause") for sp in ingests}
-    taps = [sp for sp in mine if sp[0] == tracing.EXPORT_TAP
-            and f"tap:{sp[4].get('seq')}" in causes]
-    if taps:
-        out["export_tap_span_ms"] = (max(sp[3] for sp in taps)
-                                     - min(sp[2] for sp in taps)) * 1e3
     for sp in mine:
         if sp[0] == tracing.STEP_DISPATCH:
             out["dispatch_ms"] = (sp[3] - sp[2]) * 1e3
@@ -655,7 +628,7 @@ class _StepBuilder:
         # (utils/tracing.py span): (stage, thread, start, end, args) on
         # perf_counter, from whichever thread ran it
         self.spans: List[tuple] = []                     # guarded-by: _mu
-        # the streamed-export round's tag (train thread, set by
+        # the PS round's tag (train thread, set by
         # jax/train.py before the backward is dispatched): the ``step``
         # argument of this step's spans
         self.round_tag: Optional[int] = None
@@ -837,7 +810,7 @@ class StepProfiler:
         }
 
     def end_step(self, b: Optional[_StepBuilder], ttfp_ms=None,
-                 streamed: int = 0, fallback: int = 0,
+                 leaves: int = 0,
                  health: Optional[dict] = None,
                  xb: Optional[dict] = None) -> Optional[StepReport]:
         if b is None:
@@ -891,8 +864,7 @@ class StepProfiler:
             tail_ms=wall - b.marks.get("drain_done", 0.0) * 1e3
             if "drain_done" in b.marks else 0.0,
             ttfp_ms=ttfp_ms,
-            streamed_leaves=streamed,
-            fallback_leaves=fallback,
+            fallback_leaves=leaves,
             queue_depth_peak=queue_peak,
             credit_stalls=stalls,
             push_p95_ms=_p95(samples.get("PUSH", [])),

@@ -619,12 +619,12 @@ class PipelineScheduler:
         to first cross the export boundary gets ordinal n and priority
         ``-n``, so the first gradient XLA actually produces is served
         first. The reference ASSUMES "last layer first" via the static
-        -declared_key convention (tensorflow/ops.cc:155-158); the
-        streamed-export tap calls this instead, so last-produced ≠
-        last-served whenever XLA's schedule disagrees with flatten
-        order. The assignment pins the key's priority (see
-        _pin_priority) — later submissions of the same key, streamed or
-        not, reuse it, keeping cross-round admission order stable.
+        -declared_key convention (tensorflow/ops.cc:155-158); the PS
+        train step's shard submission (jax/train.py submit_shard) calls
+        this instead, in the order its claim loop reaches the leaves.
+        The assignment pins the key's priority (see _pin_priority) —
+        later submissions of the same key reuse it, keeping cross-round
+        admission order stable.
 
         ``parent``: the logical tensor a shard subrange belongs to
         (locality-sharded export). All shard keys of one leaf are ONE
@@ -632,8 +632,7 @@ class PipelineScheduler:
         local device at the same collective — so they share the
         parent's ordinal; the queue's key-ascending tie-break then
         keeps a leaf's shards adjacent in admission order instead of
-        interleaving them with whichever leaf's shard fired next on a
-        racing export worker."""
+        interleaving them with another leaf's."""
         with self._prio_mu:
             pr = self._key_priority.get(ctx.declared_key)
             if pr is None:
@@ -669,7 +668,7 @@ class PipelineScheduler:
         positionally per worker per key, so the swap would silently sum
         round N+1's payload into round N across workers. The reference's
         priority is static per key by construction (-declared_key,
-        tensorflow/ops.cc:155-158) and the streamed-export path's is
+        tensorflow/ops.cc:155-158) and a shard key's is
         static by the production_priority pin above; an explicit
         per-call value sticks on first use, and later differing values
         warn ONCE then are silently ignored (same guard

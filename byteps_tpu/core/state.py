@@ -94,30 +94,25 @@ class _Telemetry:
         stats.update(self.export_stats())
         return stats
 
-    # --- streamed-export stage counters (jax/train.py) ---------------- #
+    # --- export stage counters (jax/train.py) ------------------------- #
 
-    def record_export(self, streamed: int, fallback: int,
-                      ttfp_s: Optional[float],
+    def record_export(self, leaves: int, ttfp_s: Optional[float],
                       shard_leaves: int = 0) -> None:
         """One PS train round's export accounting: how many gradient
-        leaves were streamed out of the backward by io_callback taps vs
-        left on the output route (outputs of the backward, claimed by
-        the train thread), and the round's time-to-first-push (first
-        submit entering the scheduler, measured from the backward's
-        dispatch). Cumulative counters + the last round's TTFP let
-        tests and the bench assert each route ran as often as the plan
-        says."""
+        leaves left the chip (outputs of the backward, claimed by the
+        train thread), how many of them as per-device shards, and the
+        round's time-to-first-push (first submit entering the
+        scheduler, measured from the backward's dispatch). Cumulative
+        counters + the last round's TTFP let tests and the bench assert
+        the plan ran as often as it says."""
         with self._lock:
-            self._export_streamed = \
-                getattr(self, "_export_streamed", 0) + int(streamed)
-            self._export_fallback = \
-                getattr(self, "_export_fallback", 0) + int(fallback)
+            self._export_leaves = \
+                getattr(self, "_export_leaves", 0) + int(leaves)
             self._export_rounds = getattr(self, "_export_rounds", 0) + 1
             # leaves that left the device as per-device reduce-scatter
-            # shards (BYTEPS_LOCAL_SHARD_EXPORT), as program outputs or
-            # (BYTEPS_STREAM_EXPORT=1: a subset of ``streamed``) as
-            # taps; the shard A/B asserts this engaged instead of
-            # silently riding the whole-leaf path
+            # shards (BYTEPS_LOCAL_SHARD_EXPORT); the shard A/B asserts
+            # this engaged instead of silently riding the whole-leaf
+            # path
             self._export_shard_leaves = \
                 getattr(self, "_export_shard_leaves", 0) + int(shard_leaves)
             if ttfp_s is not None:
@@ -126,10 +121,7 @@ class _Telemetry:
     def export_stats(self) -> dict:
         with self._lock:
             return {
-                "export_streamed_leaves": getattr(
-                    self, "_export_streamed", 0),
-                "export_fallback_leaves": getattr(
-                    self, "_export_fallback", 0),
+                "export_leaves": getattr(self, "_export_leaves", 0),
                 "export_rounds": getattr(self, "_export_rounds", 0),
                 "export_shard_leaves": getattr(
                     self, "_export_shard_leaves", 0),

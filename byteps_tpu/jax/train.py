@@ -19,18 +19,15 @@ Two flavors:
 
 from __future__ import annotations
 
-import functools
-import itertools
+import dataclasses
 import os
-import threading
-import time
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.push_pull import psum_tree, reduce_scatter_tree, all_gather_tree
 from ..parallel.mesh import DP_AXIS
@@ -231,11 +228,6 @@ def make_zero_train_step(
 
 
 _COMP_POOL = None
-_EXPORT_POOL = None
-# process-wide tap ordinal: a tap's identity on the trace (its ingest
-# names it as its cause) — the callback thread may not resolve the
-# step or device scalars it was handed (see _export_pool)
-_TAP_SEQ = itertools.count(1)
 _rowsparse_warned: set = set()  # names warned about dense fallback
 _chaos_nan_fired: set = set()   # BYTEPS_CHAOS_NAN_LEAF specs consumed
 
@@ -267,308 +259,20 @@ def _chaos_nan_poison(spec: str, name: str, flat, step_no: int):
     return poisoned
 
 
-def _export_pool():
-    """The stream-export ROUTER worker (BYTEPS_STREAM_EXPORT=1 only:
-    unset, nothing is tapped and no export pool is built). The
-    io_callback tap itself only
-    enqueues here: a callback arg is a lazy jax.Array whose
-    materialization needs the very executor running the tapped program
-    — touching it on the callback (= device) thread self-deadlocks the
-    step at the next collective. This thread materializes and submits
-    whole-leaf exports OFF the device threads (a single worker also
-    means whole-leaf ingests run in fire order, so production-order
-    priority assignment is measured from the real schedule); per-device
-    SHARD fires (BYTEPS_LOCAL_SHARD_EXPORT) are only routed here — the
-    router resolves the tiny step/device scalars and hands the heavy
-    shard materialization to that device's own worker
-    (``_shard_export_pool``), so the 1/N shards of different devices
-    materialize and submit in parallel."""
-    global _EXPORT_POOL
-    if _EXPORT_POOL is None:
-        import concurrent.futures
-        _EXPORT_POOL = concurrent.futures.ThreadPoolExecutor(
-            1, thread_name_prefix="bps-export")
-    return _EXPORT_POOL
-
-
-# per-LOCAL-DEVICE shard-export workers (BYTEPS_LOCAL_SHARD_EXPORT under
-# BYTEPS_STREAM_EXPORT=1 only: unset, a device's shard is a program
-# output the train thread claims): a TAPPED shard of device k is
-# materialized and submitted by worker k — one thread per device keeps
-# each device's fires in order (the per-shard analogue of the single
-# router's FIFO guarantee) while devices proceed independently,
-# parallelizing the D2H export across the local slice exactly as
-# BytePS's per-GPU copy threads do
-_SHARD_POOLS: Dict[int, Any] = {}
 _SHARD_INGESTS: Dict[int, int] = {}  # per-device ingest totals (gauges)
-
-
-def _shard_export_pool(dev: int):
-    pool = _SHARD_POOLS.get(dev)
-    if pool is None:
-        import concurrent.futures
-        pool = _SHARD_POOLS[dev] = concurrent.futures.ThreadPoolExecutor(
-            1, thread_name_prefix=f"bps-export-d{dev}")
-    return pool
-
-
 _RELEASE_POOL = None
 
 
 def _release_pool():
-    """Deferred arena-release worker, deliberately SEPARATE from the
-    export worker: its tasks block on import readiness, and queueing
-    them on the export FIFO would stall the next round's streamed
-    ingests (and the error path's quiesce sentinel) behind the previous
-    round's import tail."""
+    """Deferred arena-release worker: its tasks block on import
+    readiness, which the train thread does not wait for at a step's
+    end."""
     global _RELEASE_POOL
     if _RELEASE_POOL is None:
         import concurrent.futures
         _RELEASE_POOL = concurrent.futures.ThreadPoolExecutor(
             1, thread_name_prefix="bps-release")
     return _RELEASE_POOL
-
-
-class _StreamRound:
-    """One PS train step's tapped-export state, built only where
-    BYTEPS_STREAM_EXPORT=1 asked for taps (unset, every leaf, a mesh's
-    shard leaves included, is an output of the backward and nothing
-    comes here).
-
-    The io_callback taps planted on each tapped gradient leaf inside
-    the compiled backward fire while XLA is still producing later
-    gradients; each fire is enqueued (never executed — see
-    ``_export_pool``) to the export worker, whose ingest:
-
-    - drops stale fires from an earlier round via the step tag threaded
-      through the program, and dedups (shard_map fires the tap once per
-      mesh device — the post-psum value is identical on every device,
-      so the first fire wins);
-    - materializes the payload as a host view whose base keeps the
-      buffer alive through the asynchronous PUSH stage (no staging
-      copy), with the round's result slot leased from the arena under
-      the export tag;
-    - submits it straight into the PipelineScheduler at
-      production-order priority (scheduler.production_priority), so
-      "last layer first" is measured, not assumed;
-    - publishes the waiter for the step's completion-ordered drain.
-
-    The main thread ``claim``s each tapped leaf, which collects the
-    ingest's waiter. A tap that has not fired long after its gradient
-    was ready means the callback path is dead: that is an error, never
-    a quiet switch to the output route (the key set and the per-device
-    bytes are the plan's, on every worker).
-    """
-
-    def __init__(self, tag: int, names, submit_streamed, mark_first_push,
-                 shard_plan: Optional[dict] = None, submit_shard=None):
-        self.tag = tag
-        self._names = names
-        self._submit = submit_streamed  # (i, flat) -> (finish, notifier)
-        self._submit_shard = submit_shard  # (i, dev, flat) -> waiter
-        self._mark = mark_first_push
-        # leaf index -> num shards expected (BYTEPS_LOCAL_SHARD_EXPORT);
-        # a planned leaf fires once per local device with ITS shard
-        self._shard_plan: dict = shard_plan or {}
-        self._mu = threading.Lock()
-        self._events: Dict[int, threading.Event] = {}
-        self._waiters: Dict[int, tuple] = {}
-        self._shard_waiters: Dict[int, dict] = {}
-        self._shard_left: Dict[int, int] = {}
-        self._shard_started: set = set()
-        self._errors: Dict[int, BaseException] = {}
-        self._done: set = set()   # whole leaves done + (i, dev) shard fires
-        self.streamed = 0
-        self.shard_leaves = 0  # leaves exported as per-device shards
-        self.dead = False    # cancelled: late ingests must no-op
-
-    def expect(self, i: int) -> None:
-        self._events[i] = threading.Event()
-        n = self._shard_plan.get(i)
-        if n is not None:
-            self._shard_left[i] = n
-            self._shard_waiters[i] = {}
-
-    def on_fire(self, i: int, step_arr, dev_arr, arr, seq: int,
-                t_enq: float) -> None:
-        """One tap fire — runs on the export ROUTER. Whole leaves dedup
-        per leaf (every device fires the identical post-psum value;
-        first wins) and materialize inline. Shard leaves dedup per
-        (leaf, device) — every device's fire carries a DIFFERENT shard
-        — and hand the materialization to that device's own worker so
-        the shards export in parallel.
-
-        The fire is one program span on this thread, from here to the
-        leaf's submission (``bps.export.ingest``; ``bps.export.route``
-        for a shard fire, whose ingest runs on the device's worker): it
-        names the tap that caused it and how long the fire sat queued
-        behind the router's earlier work. A stale or duplicate fire is
-        nobody's work and drops out of the step's accounting."""
-        shard = i in self._shard_plan
-        sp = tracing.span(
-            tracing.EXPORT_ROUTE if shard else tracing.EXPORT_INGEST,
-            tid=self._names[i], step=self.tag, leaf=i,
-            bytes=arr.size * arr.dtype.itemsize, cause=f"tap:{seq}",
-            queued_us=round((time.perf_counter() - t_enq) * 1e6, 1)
-        ).start()
-        done_ev = None  # set AFTER the span closed: claim() may then
-        try:            # close the step, and the span must be in it
-            # int() materializes only the two scalars — the heavy
-            # payload is materialized below (whole leaves) or by the
-            # device's own worker (shards)
-            step_no, dev = int(step_arr), int(dev_arr)
-            sp.set(dev=dev)
-            ev = self._events.get(i)
-            mark = (i, dev) if shard else i
-            with self._mu:
-                # cancelled round / stale fire from an earlier round /
-                # another device's duplicate
-                fresh = not (self.dead or step_no != self.tag
-                             or ev is None or mark in self._done)
-                if fresh:
-                    self._done.add(mark)
-                    if shard:
-                        self._shard_started.add(i)
-            if not fresh:
-                sp.drop()
-                return
-            if shard:
-                _shard_export_pool(dev).submit(
-                    self._ingest_shard, i, dev, arr, seq, t_enq)
-                return
-            done_ev = ev
-            try:
-                with tracing.span(tracing.EXPORT_MATERIALIZE,
-                                  step=self.tag, leaf=i,
-                                  bytes=sp.args["bytes"]):
-                    # materialize off the device threads
-                    host = np.asarray(arr)
-                if self.dead:  # cancelled while materializing: no submit
-                    return
-                self._mark()
-                w = self._submit(i, host.reshape(-1))
-                with self._mu:
-                    self._waiters[i] = w
-                self.streamed += 1
-            except BaseException as e:  # noqa: BLE001 - surfaced via
-                self._errors[i] = e     # claim()
-        finally:
-            sp.stop()
-            if done_ev is not None:
-                done_ev.set()
-
-    def _ingest_shard(self, i: int, dev: int, arr, seq: int,
-                      t_enq: float) -> None:
-        """Device ``dev``'s shard of leaf ``i`` — runs on that device's
-        export worker; free to block on XLA, must never raise. The
-        leaf's event fires when its LAST shard submission lands, so
-        ``claim`` sees either the complete per-shard waiter set or an
-        error."""
-        ev = self._events.get(i)
-        nbytes = arr.size * arr.dtype.itemsize
-        sp = tracing.span(
-            tracing.EXPORT_INGEST, tid=self._names[i], step=self.tag,
-            leaf=i, dev=dev, bytes=nbytes, cause=f"tap:{seq}",
-            queued_us=round((time.perf_counter() - t_enq) * 1e6, 1)
-        ).start()
-        fire = False
-        try:
-            with tracing.span(tracing.EXPORT_MATERIALIZE, step=self.tag,
-                              leaf=i, bytes=nbytes):
-                host = np.asarray(arr)  # materialize this device's shard
-            if self.dead:  # cancelled while materializing: no submit
-                return
-            self._mark()
-            w = self._submit_shard(i, dev, host.reshape(-1))
-            with self._mu:
-                self._shard_waiters[i][dev] = w
-                self._shard_left[i] -= 1
-                fire = self._shard_left[i] == 0
-                if fire:
-                    # counters mutate under the lock: final shards of
-                    # two leaves can complete concurrently on different
-                    # per-device workers, and an unlocked += loses
-                    # increments the export telemetry (and the shard
-                    # A/B proof) reads
-                    self.streamed += 1
-                    self.shard_leaves += 1
-        except BaseException as e:  # noqa: BLE001 - surfaced via claim()
-            self._errors[i] = e
-            fire = True
-        finally:
-            sp.stop()  # before the event: the span is in claim()'s step
-            if fire and ev is not None:
-                ev.set()
-
-    def cancel(self) -> None:
-        """Error-path quiesce: mark the round dead (any ingest that
-        starts from now no-ops) and drain the export workers — the
-        router FIRST (it is the only dispatcher into the per-device
-        shard pools, so once its sentinel runs no new shard ingests can
-        appear), then every per-device pool — so an ingest already in
-        flight, which may be checking out an arena lease and allocating
-        a handle, finishes BEFORE the caller's abandon/discard cleanup
-        runs. Without this, a late submit after cleanup leaks a
-        permanently-busy slot and a gradient-sized handle entry."""
-        self.dead = True
-        pools = [_export_pool()]
-        pools.extend(_shard_export_pool(d) for d in sorted(_SHARD_POOLS))
-        for pool in pools:
-            try:
-                pool.submit(lambda: None).result(timeout=120)
-            except Exception:  # noqa: BLE001 - quiesce is best-effort
-                from ..utils.logging import log
-                log.warning(
-                    "stream-export worker did not quiesce in time; "
-                    "a late ingest may leak one staging slot")
-
-    def claim(self, i: int, timeout: float, final: bool):
-        """Collect leaf ``i``'s waiter — a ``(finish, notifier)`` tuple
-        for whole leaves, ``("shards", [(dev, waiter), ...])`` for
-        shard-planned leaves. ``final=False`` just peeks and returns
-        None when the ingest hasn't fired within ``timeout`` (the loop
-        then blocks on the leaf itself, surfacing a compute error
-        promptly instead of stalling here). ``final=True`` is called
-        once the leaf's gradient is READY, so its tap was issued: a tap
-        that still has not STARTED ``timeout`` seconds later raises —
-        the callback path is dead. A tap that did start (its ingest is
-        materializing or submitting; for shard leaves, some shard keys
-        are already on the wire) is waited for."""
-        ev = self._events[i]
-        if not ev.wait(timeout):
-            if not final:
-                return None
-            with self._mu:
-                started = (i in self._done
-                           or i in self._shard_started)
-            if not started:
-                raise RuntimeError(
-                    f"streamed gradient export: the tap of "
-                    f"{self._names[i]!r} did not fire within "
-                    f"{timeout:.0f}s of its gradient being ready — the "
-                    f"io_callback path is dead on this backend. Leaves "
-                    f"are tapped only under BYTEPS_STREAM_EXPORT=1: unset "
-                    f"it, and every leaf, a mesh's shard leaves included, "
-                    f"leaves the chip as a program output.")
-            ev.wait()  # ingest in flight; its submission completes
-        err = self._errors.get(i)
-        if err is not None:
-            raise err
-        if i in self._shard_plan:
-            with self._mu:
-                return ("shards",
-                        sorted(self._shard_waiters[i].items()))
-        return self._waiters[i]
-
-    def handles(self):
-        """Handles of every streamed submission, whole-leaf and
-        per-shard alike (error-path discard)."""
-        with self._mu:
-            hs = [n for _, n in self._waiters.values()
-                  if hasattr(n, "id")]
-            for ws in self._shard_waiters.values():
-                hs.extend(n for _, n in ws.values() if hasattr(n, "id"))
-            return hs
 
 
 def _comp_pool():
@@ -658,57 +362,144 @@ def _reduce_loss(pair, axis: str):
             jax.tree.map(lambda x: jax.lax.psum(x, axis), stats))
 
 
+def _psum_backward(loss_and_stats: Callable, mesh: Mesh, axis: str):
+    """The backward of a PS step whose export plan shards no leaf
+    (``make_ps_train_step``'s ``grad_fn``): every gradient leaf psum'd
+    over ``axis``, a replicated output."""
+
+    def local_grads(params, batch):
+        # ``loss`` is the pair (loss, stats) from here to the step's
+        # end, where the statistics are folded into the registry
+        loss, grads = jax.value_and_grad(
+            loss_and_stats, has_aux=True)(params, batch)
+        grads = psum_tree(grads, axis=axis, average=True)
+        return _reduce_loss(loss, axis), grads
+
+    return jax.jit(jax.shard_map(
+        local_grads, mesh=mesh, in_specs=(P(), P(axis)),
+        out_specs=(P(), P()), check_vma=False))
+
+
 def _scatter_backward(loss_and_stats: Callable, mesh: Mesh, axis: str,
-                      shard_set, n_leaves: int, tapped=(), plant=None):
-    """The backward of a PS step whose export plan shards leaves or
-    taps them: identical math to ``make_ps_train_step``'s ``grad_fn``,
-    with the leaves in ``shard_set`` (BYTEPS_LOCAL_SHARD_EXPORT) riding
+                      shard_set, n_leaves: int):
+    """The backward of a PS step whose export plan shards leaves:
+    identical math to ``_psum_backward``, with the leaves in
+    ``shard_set`` (BYTEPS_LOCAL_SHARD_EXPORT) riding
     ``reduce_scatter`` instead of the psum. The program returns those
     leaves as flat padded ``P(axis)``-sharded outputs, so each device
     holds only ITS 1/local_size shard and only that ever crosses
     device->host per device — BytePS's hierarchical "the intra-machine
     reduce puts 1/local_size on the wire". The remaining leaves keep
     the exact whole-leaf path (one psum over their subtree, replicated
-    output), so disabling sharding per leaf is bitwise-invisible.
-
-    ``tapped`` empty (what BYTEPS_STREAM_EXPORT unset means): the
+    output), so disabling sharding per leaf is bitwise-invisible. The
     program is ``fn(params, batch)`` and holds no host callback, so the
-    persistent compile cache can serve it. Otherwise ``plant(i,
-    step_tag, device_index, value)`` plants a tap on each leaf in
-    ``tapped`` INSIDE the shard_mapped body and the program is
-    ``fn(step_tag, params, batch)``."""
+    persistent compile cache can serve it."""
     from ..ops.push_pull import scatter_leaf
 
     shard_set = frozenset(shard_set)
-    tapped = frozenset(tapped)
 
-    def local(step_tag, params, batch):
+    def local(params, batch):
         loss, grads = jax.value_and_grad(
             loss_and_stats, has_aux=True)(params, batch)
         leaves = jax.tree.leaves(grads)
         # ONE psum over the whole-leaf subtree (identical reduction
-        # grouping to the untapped grad_fn's full-tree psum), RS
-        # per shard leaf
+        # grouping to _psum_backward's full-tree psum), RS per shard leaf
         whole_idx = [i for i in range(len(leaves)) if i not in shard_set]
         whole = psum_tree([leaves[i] for i in whole_idx],
                           axis=axis, average=True)
         whole_map = dict(zip(whole_idx, whole))
-        idx = jax.lax.axis_index(axis) if tapped else None
-        outs = []
-        for i in range(len(leaves)):
-            g = (scatter_leaf(leaves[i], axis=axis, average=True)
-                 if i in shard_set else whole_map[i])
-            if i in tapped:
-                plant(i, step_tag, idx, g)
-            outs.append(g)
-        return _reduce_loss(loss, axis), tuple(outs)
+        outs = tuple(scatter_leaf(leaves[i], axis=axis, average=True)
+                     if i in shard_set else whole_map[i]
+                     for i in range(len(leaves)))
+        return _reduce_loss(loss, axis), outs
 
     out_specs = (P(), tuple(P(axis) if i in shard_set else P()
                             for i in range(n_leaves)))
-    body, in_specs = (local, (P(), P(), P(axis))) if tapped else (
-        functools.partial(local, None), (P(), P(axis)))
-    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(P(), P(axis)),
                                  out_specs=out_specs, check_vma=False))
+
+
+@dataclasses.dataclass(frozen=True)
+class ExportPlan:
+    """How a PS step's gradient leaves leave the chip. Every leaf is an
+    output of the backward; the leaves in ``shard_set`` (flatten
+    indices, ascending) leave as ``n_shard`` flat per-device shards of
+    ``layouts[k] = (size, shard_len, dtype)`` under subrange keys of
+    their own, every other leaf as one replicated array (on a key of
+    its own, in a fusion bucket or row-sparse: the claim loop's
+    business, not the plan's)."""
+
+    shard_set: Tuple[int, ...] = ()
+    n_shard: int = 0
+    layouts: Tuple[Tuple[int, int, Any], ...] = ()
+
+
+def _export_plan(names, leaves, *, mesh: Mesh, axis: str, fusion_bytes: int,
+                 shard_min_bytes: int, local_shard: bool, rowsparse_params,
+                 host_codec: bool, scheduler_running: bool) -> ExportPlan:
+    """The rule that decides which leaves shard (BYTEPS_LOCAL_SHARD_EXPORT),
+    from configuration and topology alone: the set of PS keys a worker
+    pushes, shard subranges included, has to be the same pure function
+    on every worker, or the key sets would diverge and stall every
+    peer's aggregation. Nothing shards without a running scheduler,
+    under a host codec (the codec unit is the declared key: a per-shard
+    codec would reset EF/momentum state per device), on a mesh of more
+    than one axis or on an ``axis`` of one device (no locality axis to
+    shard over). Where leaves can shard, one does when it rides a dense
+    key of its own (not row-sparse by name, not empty, not a bucket
+    member under ``fusion_bytes``), is worth ``n_shard`` extra key
+    round trips (``shard_min_bytes``) and pads by at most 1/8 of its
+    size."""
+    if not (local_shard and scheduler_running and not host_codec
+            and len(mesh.axis_names) == 1):
+        return ExportPlan()
+    n_shard = int(mesh.shape.get(axis, 1))
+    if n_shard <= 1:
+        return ExportPlan()
+    from ..ops.push_pull import shard_layout
+
+    floor = max(fusion_bytes, shard_min_bytes)
+    shard_set, layouts = [], []
+    for i, (name, leaf) in enumerate(zip(names, leaves)):
+        if rowsparse_params and any(s in name for s in rowsparse_params):
+            continue
+        nbytes = getattr(leaf, "nbytes", 0)
+        if nbytes == 0 or nbytes < floor:
+            continue
+        size = int(np.prod(leaf.shape)) if leaf.shape else 1
+        shard_len, pad = shard_layout(size, n_shard)
+        if pad * 8 > size:
+            continue  # padding beyond 1/8: not worth the wire
+        shard_set.append(i)
+        layouts.append((size, shard_len, np.dtype(leaf.dtype)))
+    return ExportPlan(tuple(shard_set), n_shard if shard_set else 0,
+                      tuple(layouts))
+
+
+def _declare_shard_keys(registry, names, plan: ExportPlan, stale) -> dict:
+    """Realise a changed plan in the registry: declare each shard leaf's
+    subrange keys, in flatten order (every worker flattens the same
+    tree, so the declared keys agree across workers), and the parent
+    name as the production-order anchor all of a leaf's shards share;
+    then free the names in ``stale`` the plan no longer holds (a leaf
+    resized, the knob flipped, the mesh changed: dead keys must not
+    skew later least-loaded assignments). Returns leaf index ->
+    sizing, shard names and parent context."""
+    from ..core.types import DataType
+
+    info: Dict[int, dict] = {}
+    declared: set = set()
+    for i, (size, shard_len, dt) in zip(plan.shard_set, plan.layouts):
+        dtype = DataType.from_np(dt)
+        ctxs = registry.declare_shards(
+            names[i], shard_len * dt.itemsize, plan.n_shard, dtype)
+        info[i] = {"n": plan.n_shard, "shard_len": shard_len, "size": size,
+                   "dtype": dt, "names": [c.name for c in ctxs],
+                   "parent": registry.declare(names[i], dtype)}
+        declared.update(info[i]["names"])
+    for name in stale - declared:
+        registry.free(name)
+    return info
 
 
 def make_ps_train_step(
@@ -720,7 +511,6 @@ def make_ps_train_step(
     min_compress_bytes: Optional[int] = None,
     rowsparse_params: Optional[Tuple[str, ...]] = None,
     device_compress: Optional[bool] = None,
-    stream_export: Optional[bool] = None,
     sharded_apply: Optional[bool] = None,
     local_shard_export: Optional[bool] = None,
 ):
@@ -729,47 +519,28 @@ def make_ps_train_step(
     "General Workflow") with BOTH of its pipeline overlaps: the compiled
     program reduces gradients over the local slice (ICI psum == the NCCL
     ReduceScatter tier); gradients exit to host as outputs of that
-    program, copied by the runtime (or, asked for, through taps inside
-    the backward: below); the PS client push_pulls each declared
-    tensor across workers in priority order (the PUSH/PULL stages over
-    DCN); and the optimizer update is applied per leaf from the
-    completion-ordered drain, so UPDATE(k) overlaps PULL(k+1) (servers
-    only sum — the update stays on the worker).
+    program, copied by the runtime; the PS client push_pulls each
+    declared tensor across workers in priority order (the PUSH/PULL
+    stages over DCN); and the optimizer update is applied per leaf from
+    the completion-ordered drain, so UPDATE(k) overlaps PULL(k+1)
+    (servers only sum — the update stays on the worker).
 
-    ``stream_export`` (BYTEPS_STREAM_EXPORT; three states, needs a
-    running scheduler, numerics identical in all): the route by which a
-    gradient leaf leaves the chip.
-
-    - ``None``, nobody set it: every leaf is an OUTPUT of the backward,
-      on every topology, and no program holds a host callback. A leaf
-      on a whole-leaf key (dense or host-compressed), a bucket member
-      (sub-BYTEPS_FUSION_BYTES), a rowsparse or device-compressed leaf
-      is one replicated array; a shard leaf of the locality-shard plan
-      (``local_shard_export``, mesh axis > 1) is one flat 1/local_size
-      shard a device. ``copy_to_host_async()`` is issued on each right
-      after dispatch, in flatten order and, within a shard leaf, in
-      mesh-device order; the train thread's claim loop takes each with
-      ``np.asarray`` and submits it, a shard under its subrange key at
-      its parent's production-order priority. On a one-device mesh the
-      program that runs is ``grad_fn``; on a mesh with shard leaves,
-      the reduce-scatter backward (``_scatter_backward``) with no tap
-      in it. (On the v5e a callback operand reaches the host at
-      0.4-1.0 GB/s, a program output at 3.0-4.5: PERF.md section 6,
-      PRs 24, 25 and 27.)
-    - ``True``: every eligible leaf is TAPPED inside the compiled
-      backward with jax.experimental.io_callback, shard leaves too
-      (each device's shard handed to the scheduler by that device's
-      export worker while later gradients are still being produced;
-      time-to-first-push drops from "after the whole backward" to
-      "after the first gradient"), its key's priority pinned from its
-      measured first-export ordinal (scheduler.production_priority).
-      The arm the output route was measured against.
-    - ``False``: no taps and no shard plan; every leaf is a whole
-      output.
-
-    The split is decided by configuration and topology alone: a tapped
-    backward that fails to build or dispatch, or whose taps never fire,
-    raises; it is never swapped for the output route at run time.
+    The way off the chip is one: every gradient leaf is an OUTPUT of the
+    backward, on every topology, and no program holds a host callback
+    (so the persistent compile cache serves them). A leaf on a
+    whole-leaf key (dense or host-compressed), a bucket member
+    (sub-BYTEPS_FUSION_BYTES), a rowsparse or device-compressed leaf is
+    one replicated array; a shard leaf of the export plan
+    (``_export_plan``; ``local_shard_export`` below) is one flat
+    1/local_size shard a device. ``copy_to_host_async()`` is issued on
+    each right after dispatch, in flatten order and, within a shard
+    leaf, in mesh-device order; the train thread's claim loop takes
+    each with ``np.asarray`` and submits it, a shard under its subrange
+    key at its parent's production-order priority. With no shard leaf
+    the program that runs is ``grad_fn``, else the reduce-scatter
+    backward (``_scatter_backward``). (On the v5e a program output
+    reaches the host at 3.0-4.5 GB/s, a host callback's operand at
+    0.4-1.0: PERF.md section 6, PRs 24, 25 and 27.)
 
     ``sharded_apply`` (BYTEPS_SHARDED_APPLY, default on): split the
     monolithic apply jit into per-leaf donated partial updates
@@ -785,13 +556,11 @@ def make_ps_train_step(
     apply's mid-apply failure and restart from a checkpoint rather than
     retrying with the same trees.
 
-    ``local_shard_export`` (BYTEPS_LOCAL_SHARD_EXPORT, default on;
-    off with ``stream_export=False``): the hierarchical exchange —
-    reduce-scatter → push shard → update shard → all-gather. Eligible
-    leaves are reduce-SCATTERED instead of psum'd, so each local
-    device holds and exports only its own flat 1/local_size shard (a
-    per-device program output; under ``stream_export=True`` a tap and
-    a per-device export worker); each shard rides
+    ``local_shard_export`` (BYTEPS_LOCAL_SHARD_EXPORT, default on):
+    the hierarchical exchange — reduce-scatter → push shard → update
+    shard → all-gather. Eligible leaves are reduce-SCATTERED instead of
+    psum'd, so each local device holds and exports only its own flat
+    1/local_size shard (a per-device program output); each shard rides
     its own PS key, spread across servers by the registry's
     load-balanced assignment; the completion-ordered drain imports
     shard k back into the device that owns it (1/local_size H2D per
@@ -800,10 +569,8 @@ def make_ps_train_step(
     make_shard_apply; shard-separability verified by probe), and a
     jitted all-gather rebuilds the replicated params and state.
     Per-device D2H/H2D and per-key wire bytes divide by local_size.
-    Leaves below BYTEPS_SHARD_MIN_BYTES, leaves whose padding would
-    exceed 1/8 of their size, rowsparse/host-compressed/bucket-fused
-    leaves, multi-axis meshes and single-device meshes fall back to
-    the whole-leaf path — numerics bitwise identical either way.
+    Which leaves shard is ``_export_plan``'s rule; the others keep the
+    whole-leaf path — numerics bitwise identical either way.
 
     ``compression``: string-kwargs dict for the codec registry (e.g.
     ``{"compressor": "onebit", "ef": "vanilla"}``) — gradients then ride
@@ -845,16 +612,12 @@ def make_ps_train_step(
     # replaces state.ps_client, and a cached registry would then push on a
     # destroyed native handle with a stale worker count
     comp_state = {"registry": None, "client": None, "device": None}
-    # streamed-export machinery (one compiled tapped backward, rebuilt
-    # when the gradient tree or eligibility changes)
-    stream_state: dict = {"fn": None, "key": None,
-                          "tag": 0, "holder": {"round": None},
-                          # locality-shard plan (BYTEPS_LOCAL_SHARD_EXPORT):
-                          # leaf index -> sizing/names, the declared shard
-                          # subrange names (freed when the plan changes),
-                          # and the cached P(axis) sharding for imports
-                          "shard_info": {}, "shard_names": set(),
-                          "nsharding": None}
+    # the export plan as last realised ("key": the gradient tree and
+    # the plan): the backward it runs, leaf index -> sizing/names of the
+    # shard leaves (their declared subrange names are freed when the plan
+    # changes); "tag" counts this closure's PS rounds
+    plan_cache: dict = {"key": None, "backward": None, "tag": 0,
+                        "shard_info": {}}
     # sharded-apply build cache (keyed by params+opt_state structure;
     # sa None = transform not separable -> fused apply; ssa None =
     # not SHARD-separable -> gather gradients, full-leaf apply)
@@ -873,14 +636,9 @@ def make_ps_train_step(
     xb_state: dict = {"carry": None, "over": {}, "par": 0, "seq": 0}
 
     loss_and_stats = _loss_and_stats(loss_fn)
-
-    def local_grads(params, batch):
-        # ``loss`` is the pair (loss, stats) from here to the step's
-        # end, where the statistics are folded into the registry
-        loss, grads = jax.value_and_grad(
-            loss_and_stats, has_aux=True)(params, batch)
-        grads = psum_tree(grads, axis=axis, average=True)
-        return _reduce_loss(loss, axis), grads
+    grad_fn = _psum_backward(loss_and_stats, mesh, axis)
+    # what a shard leaf's imported shards assemble into
+    shard_sharding = NamedSharding(mesh, P(axis))
 
     def _finish(params, opt_state, pair):
         """The step's result: the statistics go to the registry (the
@@ -889,51 +647,6 @@ def make_ps_train_step(
         loss, stats = pair
         _fold_stats(stats)
         return params, opt_state, loss
-
-    grad_fn = jax.jit(jax.shard_map(
-        local_grads, mesh=mesh, in_specs=(P(), P(axis)),
-        out_specs=(P(), P()), check_vma=False))
-
-    def _tap_planter():
-        """What plants one io_callback tap (BYTEPS_STREAM_EXPORT=1
-        only): XLA schedules each tap right after its leaf's
-        collective, so the callback fires while later gradients are
-        still being produced (measured: first fire at ~1/3 of the
-        backward wall). The step tag rides through the program so a
-        late duplicate fire can never be mistaken for the next round's
-        export; a shard leaf's tap carries only ITS device's shard (the
-        device index rides alongside)."""
-        from jax.experimental import io_callback
-
-        holder = stream_state["holder"]
-
-        def _ingest(i, step_arr, dev_arr, arr, seq, t_enq):
-            # round resolved at INGEST time: a stale fire then fails
-            # the tag check instead of resurrecting a finished round
-            rnd = holder["round"]
-            if rnd is not None:
-                rnd.on_fire(i, step_arr, dev_arr, arr, seq, t_enq)
-
-        def _tap(i, step_arr, dev_arr, arr):
-            # device thread: enqueue ONLY (see _export_pool — touching
-            # the lazy callback args here would self-deadlock; shape
-            # and dtype are metadata). The span's start is the moment
-            # the runtime handed the leaf over; its ``step`` is the
-            # round open at that moment.
-            rnd = holder["round"]
-            seq = next(_TAP_SEQ)
-            with tracing.span(tracing.EXPORT_TAP,
-                              step=rnd.tag if rnd is not None else -1,
-                              leaf=i, seq=seq,
-                              bytes=arr.size * arr.dtype.itemsize):
-                _export_pool().submit(_ingest, i, step_arr, dev_arr, arr,
-                                      seq, time.perf_counter())
-
-        def plant(i, step_tag, idx, g):
-            io_callback(functools.partial(_tap, i), None,
-                        step_tag, idx, g, ordered=False)
-
-        return plant
 
     def apply_updates_fn(params, opt_state, grads):
         updates, opt_state = tx.update(grads, opt_state, params)
@@ -977,17 +690,15 @@ def make_ps_train_step(
         # closes it into the StepReport ring (+ stall diagnosis when
         # BYTEPS_STALL_DIAG=1). None when metrics are off.
         prof = state.profiler.begin_step()
-        # the round's tag: threaded through the tapped program so a
-        # late fire is never mistaken for the next round's, and the
-        # ``step`` argument of every span of this step
-        stream_state["tag"] += 1
-        tag = stream_state["tag"]
+        # the round's tag: the ``step`` argument of every span of this
+        # step
+        plan_cache["tag"] += 1
+        tag = plan_cache["tag"]
         if prof is not None:
             prof.round_tag = tag
         # names/shapes come from the params tree (value_and_grad gives
         # gradients the identical structure), so the whole export plan
-        # exists BEFORE the backward is dispatched — the streamed taps
-        # need somewhere to land
+        # exists BEFORE the backward is dispatched
         paths, treedef = jax.tree_util.tree_flatten_with_path(params)
         names, p_leaves = [], []
         for path, leaf in paths:
@@ -1011,10 +722,10 @@ def make_ps_train_step(
             # keyed on the LEDGER INSTANCE too: suspend/resume replaces
             # state.ledger, and a plan-key-only cache would leave the
             # fresh ledger with no cost model (post-resume MFU None)
-            if (stream_state.get("cost_key") != cost_key
-                    or stream_state.get("cost_ledger") is not ledger):
-                stream_state["cost_key"] = cost_key
-                stream_state["cost_ledger"] = ledger
+            if (plan_cache.get("cost_key") != cost_key
+                    or plan_cache.get("cost_ledger") is not ledger):
+                plan_cache["cost_key"] = cost_key
+                plan_cache["cost_ledger"] = ledger
                 from ..core import ledger as ledger_mod
                 flops = acc_bytes = None
                 for part in (ledger_mod.jit_cost(grad_fn, params, batch),
@@ -1052,7 +763,7 @@ def make_ps_train_step(
                 prof.mark("export_done")
                 prof.mark("drain_done")
             params, opt_state = apply_fn(params, opt_state, grads)
-            state.profiler.end_step(prof, fallback=len(names))
+            state.profiler.end_step(prof, leaves=len(names))
             return _finish(params, opt_state, loss)
         # ---- training-health collection (core/health.py,
         # BYTEPS_HEALTH): per-leaf gradient statistics accumulate off
@@ -1069,25 +780,24 @@ def make_ps_train_step(
         hc = hplane.begin_collect(len(names)) \
             if hplane is not None and prof is not None else None
         if hc is not None:
-            pnorm_key = stream_state.get("pnorm_key")
+            pnorm_key = plan_cache.get("pnorm_key")
             # identity-or-equality: PyTreeDef.__ne__ rejects None
             if pnorm_key is None or pnorm_key != treedef:
                 def _pnorms(leaves):
                     return jnp.sqrt(jnp.asarray(
                         [jnp.sum(jnp.square(x.astype(jnp.float32)))
                          for x in leaves]))
-                stream_state["pnorm_fn"] = jax.jit(_pnorms)
-                stream_state["pnorm_key"] = treedef
+                plan_cache["pnorm_fn"] = jax.jit(_pnorms)
+                plan_cache["pnorm_key"] = treedef
             try:
-                hc.param_norms_dev = stream_state["pnorm_fn"](
+                hc.param_norms_dev = plan_cache["pnorm_fn"](
                     list(p_leaves))
             except Exception:  # noqa: BLE001 - ratios degrade to None
                 hc.param_norms_dev = None
         # chaos harness: BYTEPS_CHAOS_NAN_LEAF poisons one matching
         # leaf's push mid-run (see _chaos_nan_poison)
         chaos_nan = os.environ.get("BYTEPS_CHAOS_NAN_LEAF") or None
-        # ---- host tier: dense D2H (streamed where possible), codecs
-        # in numpy ----
+        # ---- host tier: dense D2H, codecs in numpy ----
         reg = None
         mcb = min_compress_bytes
         if mcb is None:
@@ -1107,9 +817,9 @@ def make_ps_train_step(
         import byteps_tpu as bps
 
         # Persistent host staging (core/arena.py, the reference's
-        # cpubuff discipline): result slots, fused-bucket concat slots
-        # and streamed-export result slots check out of the arena instead
-        # of np.empty per step; every lease is released only after the
+        # cpubuff discipline): result slots and fused-bucket concat slots
+        # check out of the arena instead of np.empty per step; every
+        # lease is released only after the
         # imports below complete (or abandoned on error — correctness
         # never depends on a slot surviving).
         arena = state.arena
@@ -1131,23 +841,19 @@ def make_ps_train_step(
         exp_shard_ctr = metrics.counter("export/shard_bytes")
         exp_whole_ctr = metrics.counter("export/whole_bytes")
         exp_dev0_ctr = metrics.counter("export/device_bytes/0")
-        # export workers exist only where taps were asked for
-        metrics.gauge("export/shard_workers").set(len(_SHARD_POOLS))
         metrics.gauge("export/worker_ingests/0").set(
             _SHARD_INGESTS.get(0, 0))
         ag_hist = metrics.histogram("step/allgather_us")
 
         # time-to-first-push: wall from the backward's dispatch to the
-        # first submission entering the scheduler, whichever thread
-        # gets there first (telemetry: export_ttfp_ms)
+        # first submission entering the scheduler (telemetry:
+        # export_ttfp_ms)
         round_t0 = _time.perf_counter()
         first_push = [None]
-        fp_mu = threading.Lock()
 
         def mark_first_push():
-            with fp_mu:
-                if first_push[0] is None:
-                    first_push[0] = _time.perf_counter() - round_t0
+            if first_push[0] is None:
+                first_push[0] = _time.perf_counter() - round_t0
 
         def submit_sparse(name, h2d, out_dtype):
             from .. import _rowsparse_submit
@@ -1201,45 +907,15 @@ def make_ps_train_step(
                                 out=obuf)
             return (lambda: res), None
 
-        def submit_streamed(i, flat):
-            """Tap-side submit of leaf ``i`` (runs on the export
-            worker) at production-order priority. ``flat`` is the
-            materialized host view of the callback's array — its base
-            keeps the buffer alive through the PUSH stage, so no
-            staging copy is needed; the arena lease here is the EXPORT
-            round's result slot (tag="export" in the arena counters).
-            One program span, up to the scheduler's ``add_task``
-            returning for the last partition."""
-            from ..server.client import get_or_init_ctx
-            name = names[i]
-            with tracing.span(tracing.EXPORT_SUBMIT, tid=name, step=tag,
-                              leaf=i, bytes=flat.nbytes) as sp:
-                if reg is not None:
-                    # upcast BEFORE declaring: the compressed wire is
-                    # f32, and initializing the ctx from a non-f32 view
-                    # would re-partition it every round against the
-                    # registry's f32 sizing — recreating the
-                    # CompressedTensor and silently resetting its
-                    # EF/momentum codec state
-                    flat = flat.astype(np.float32, copy=False)
-                ctx = get_or_init_ctx(state, name, flat)
-                sp.set(key=ctx.declared_key,
-                       partitions=len(ctx.partitions))
-                pr = state.scheduler.production_priority(ctx)
-                exp_whole_ctr.inc(flat.nbytes)
-                exp_dev0_ctr.inc(flat.nbytes)
-                return submit(name, flat, priority=pr, tag="export")
-
         def submit_shard(i, dev, flat):
-            """Shard-side submit (on the train thread's claim loop; on
-            device ``dev``'s export worker where the shard was tapped):
+            """Shard-side submit (on the train thread's claim loop):
             device ``dev``'s 1/local_size shard of leaf ``i``
             rides its own subrange key at the PARENT leaf's
             production-order priority (all shards of one leaf are one
             production event), with its own per-shard arena result
             slot (tag="shard" in the arena counters)."""
             from ..server.client import get_or_init_ctx
-            info = stream_state["shard_info"][i]
+            info = plan_cache["shard_info"][i]
             name = info["names"][dev]
             with tracing.span(tracing.EXPORT_SUBMIT, tid=name, step=tag,
                               leaf=i, dev=dev, bytes=flat.nbytes) as sp:
@@ -1278,9 +954,8 @@ def make_ps_train_step(
         #   tensors the gate kept full-precision (biases, norms)
         #   are NOT quantized via the fused key (mcb == 0 means the
         #   user asked for everything compressed — buckets too);
-        # - sub-fusion leaves never stream: a bucket is a cross-leaf
-        #   artifact, and its members must all be on host before the
-        #   concat — exactly what the post-jit loop provides.
+        # - sub-fusion leaves never shard: a bucket is a cross-leaf
+        #   artifact of whole leaves, concatenated on the host.
         fusion = getattr(state.config, "fusion_bytes", 0)
         bucket_cap = min(4 << 20,
                          getattr(state.config, "partition_bytes",
@@ -1330,122 +1005,25 @@ def make_ps_train_step(
                 waiters.append((slots, finish, notifier))
             bucket, bucket_bytes = [], 0
 
-        # ---- streamed-export eligibility + tapped-backward build ----
-        # A leaf streams when it rides its own dense/host-compressed
-        # key: rowsparse routing needs the host 2D view, and
-        # sub-fusion leaves belong to a bucket (see above). The tapped
-        # jit is rebuilt only when the tree/eligibility changes.
-        stream_cfg = stream_export if stream_export is not None \
-            else getattr(state.config, "stream_export", None)
-        # a DETERMINISTIC gate (config + topology — identical on every
-        # worker): the set of PS keys a worker pushes, shard subranges
-        # included, has to be a pure function of deterministic inputs,
-        # or the key sets would diverge and stall every peer's
-        # aggregation. There is no runtime fallback behind it: a tapped
-        # backward that fails to build, dispatch or fire raises.
-        # ``stream_cfg`` False: no taps and no shard plan; None (nobody
-        # set it) and True differ only in which leaves are tapped, below.
-        stream_avail = (stream_cfg is not False
-                        and state.scheduler is not None)
-        eligible: tuple = ()
-        if stream_avail:
-            el = []
-            for i, (name, leaf) in enumerate(zip(names, p_leaves)):
-                if rowsparse_params and any(s in name
-                                            for s in rowsparse_params):
-                    continue
-                nb = getattr(leaf, "nbytes", 0)
-                if nb == 0 or nb < fusion:
-                    continue
-                el.append(i)
-            eligible = tuple(el)
-        # ---- locality-shard plan (BYTEPS_LOCAL_SHARD_EXPORT): which
-        # eligible leaves reduce-scatter so each local device exports
-        # only its own 1/local_size shard. Host-compressed rounds keep
-        # whole-leaf keys (the codec unit is the declared key — a
-        # per-shard codec would reset EF/momentum state per device),
-        # multi-axis and single-device meshes have no locality axis to
-        # shard over, and leaves below the size/pad thresholds are not
-        # worth local_size extra key round-trips. All of these gates
-        # are deterministic across workers.
-        shard_cfg = local_shard_export if local_shard_export is not None \
-            else getattr(state.config, "local_shard_export", True)
-        n_shard = 0
-        if (shard_cfg and stream_avail and reg is None
-                and len(mesh.axis_names) == 1):
-            n_shard = int(mesh.shape.get(axis, 1))
-        shard_set: tuple = ()
-        if n_shard > 1:
-            from ..ops.push_pull import shard_layout
-            smin = max(fusion, getattr(state.config, "shard_min_bytes",
-                                       65536))
-            ss = []
-            for i in eligible:
-                leaf = p_leaves[i]
-                if leaf.nbytes < smin:
-                    continue
-                size = int(np.prod(leaf.shape)) if leaf.shape else 1
-                _, pad = shard_layout(size, n_shard)
-                if pad * 8 > size:
-                    continue  # padding beyond 1/8: not worth the wire
-                ss.append(i)
-            shard_set = tuple(ss)
-        # ---- the route off the chip: every leaf is an OUTPUT of the
-        # backward, copied by the runtime and claimed below — a leaf on
-        # a whole-leaf key, a bucket member or a rowsparse leaf as one
-        # replicated array, a shard leaf as one flat shard a device, the
-        # devices' copies side by side — unless the caller asked for
-        # taps (stream_cfg True: every eligible leaf, shard leaves too).
-        # On the v5e a callback operand reaches the host at 0.4-1.0
-        # GB/s, a program output at 3.0-4.5 (PERF.md section 6, PRs 24,
-        # 25 and 27). With nothing tapped or sharded the program that
-        # runs is ``grad_fn``.
-        tapped = eligible if stream_cfg else ()
-        stream_on = stream_avail and bool(tapped)
-        plan_key = (treedef, tapped, shard_set, n_shard)
-        if stream_avail and stream_state["key"] != plan_key:
-            # declare the per-shard subrange keys FIRST, in flatten
-            # order — every worker flattens the same tree, so the
-            # shard declared_keys agree across workers (tap-order
-            # declaration would race per-device workers); the parent
-            # name is declared too, as the production-order anchor all
-            # of a leaf's shards share.
-            from ..core.types import DataType
-            from ..ops.push_pull import shard_layout
-            info: Dict[int, dict] = {}
-            declared: set = set()
-            for i in shard_set:
-                leaf = p_leaves[i]
-                size = int(np.prod(leaf.shape)) if leaf.shape else 1
-                slen, _ = shard_layout(size, n_shard)
-                dt = np.dtype(leaf.dtype)
-                ctxs = state.registry.declare_shards(
-                    names[i], slen * dt.itemsize, n_shard,
-                    DataType.from_np(dt))
-                info[i] = {
-                    "n": n_shard, "shard_len": slen, "size": size,
-                    "dtype": dt,
-                    "names": [c.name for c in ctxs],
-                    "parent": state.registry.declare(
-                        names[i], DataType.from_np(dt)),
-                }
-                declared.update(c.name for c in ctxs)
-            # shard-subrange free: retire stale keys' server-load
-            # accounting when the plan changes (leaf resized, knob
-            # flipped, mesh changed) — dead keys must not skew
-            # later least-loaded assignments
-            for stale in stream_state["shard_names"] - declared:
-                state.registry.free(stale)
-            stream_state["shard_info"] = info
-            stream_state["shard_names"] = declared
-            if shard_set:
-                from jax.sharding import NamedSharding
-                stream_state["nsharding"] = NamedSharding(mesh, P(axis))
-            stream_state["fn"] = _scatter_backward(
-                loss_and_stats, mesh, axis, shard_set, len(names), tapped,
-                _tap_planter() if tapped else None) \
-                if tapped or shard_set else None
-            stream_state["key"] = plan_key
+        # ---- the export plan (``_export_plan``): which leaves leave as
+        # per-device shards; realised when the tree or the plan changes
+        plan = _export_plan(
+            names, p_leaves, mesh=mesh, axis=axis, fusion_bytes=fusion,
+            shard_min_bytes=getattr(state.config, "shard_min_bytes", 65536),
+            local_shard=local_shard_export if local_shard_export is not None
+            else getattr(state.config, "local_shard_export", True),
+            rowsparse_params=rowsparse_params, host_codec=reg is not None,
+            scheduler_running=state.scheduler is not None)
+        shard_set, n_shard = plan.shard_set, plan.n_shard
+        if plan_cache["key"] != (treedef, plan):
+            stale = {n for info in plan_cache["shard_info"].values()
+                     for n in info["names"]}
+            plan_cache["shard_info"] = _declare_shard_keys(
+                state.registry, names, plan, stale)
+            plan_cache["backward"] = _scatter_backward(
+                loss_and_stats, mesh, axis, shard_set, len(names)) \
+                if shard_set else grad_fn
+            plan_cache["key"] = (treedef, plan)
 
         # ---- sharded-apply build (cached per tree structure) ----
         sharded_cfg = sharded_apply if sharded_apply is not None \
@@ -1516,52 +1094,20 @@ def make_ps_train_step(
         xb_carry_names = {names[i] for i in xb_carry_set}
         xb_par = xb_state["par"]
 
-        # ---- dispatch the backward (tapped when streaming) ----
-        round_obj = None
-        if stream_on:
-            round_obj = _StreamRound(
-                tag, names, submit_streamed,
-                mark_first_push,
-                shard_plan={i: n_shard for i in shard_set},
-                submit_shard=submit_shard)
-            for i in tapped:
-                round_obj.expect(i)
-            stream_state["holder"]["round"] = round_obj
-            try:
-                with tracing.span(tracing.STEP_DISPATCH, step=tag):
-                    loss, grads = stream_state["fn"](
-                        jnp.int32(tag), params, batch)
-            except BaseException:
-                # compile/dispatch failure of the tapped backward:
-                # quiesce the export worker, clean up whatever the
-                # partial round submitted, and surface the error — never
-                # retry on the untapped jit, which would hide a device
-                # or compile fault behind a slower step (and double-push
-                # any key the partial round already put on the wire)
-                stream_state["holder"]["round"] = None
-                round_obj.cancel()
-                for h in round_obj.handles():
-                    state.handles.discard(h.id)
-                for lease in leases:
-                    lease.abandon()
-                raise
-        else:
-            # untapped: the scatter backward where the plan shards
-            # leaves (no step tag: nothing in it fires), else ``grad_fn``
-            backward = stream_state["fn"] if shard_set else grad_fn
-            with tracing.span(tracing.STEP_DISPATCH, step=tag):
-                loss, grads = backward(params, batch)
+        # ---- dispatch the backward: the scatter backward where the plan
+        # shards leaves, else ``grad_fn``
+        with tracing.span(tracing.STEP_DISPATCH, step=tag):
+            loss, grads = plan_cache["backward"](params, batch)
         # the train thread's two phases as spans: ``claim`` from here to
         # the export_done mark, ``drain`` from there to drain_done
         phase = tracing.span(tracing.STEP_CLAIM, step=tag).start()
         g_leaves = jax.tree.leaves(grads)
-        streamed_set = set(tapped) if round_obj is not None else set()
         # per-leaf shard import state (BYTEPS_LOCAL_SHARD_EXPORT):
         # shard k of leaf i lands on the device that owns it the moment
         # its pull completes; when the last shard of a leaf lands, the
         # shards assemble into one P(axis)-sharded array and the
         # shard update + all-gather dispatch
-        active_shard = stream_state["shard_info"] if shard_set else {}
+        active_shard = plan_cache["shard_info"]
         shard_parts: Dict[int, list] = {}
         shard_left: Dict[int, int] = {}
         axis_devs = list(mesh.devices.flat)
@@ -1570,11 +1116,8 @@ def make_ps_train_step(
             return [by_dev[d] for d in axis_devs]
 
         def claim_shards(i, name, parts):
-            """A shard leaf on the output route: each device's flat
-            shard is claimed and submitted as its tap's ingest would
-            have done on that device's worker (same subrange key,
-            parent anchor and counters: ``submit_shard``), in
-            mesh-device order."""
+            """A shard leaf: each device's flat shard is claimed and
+            submitted (``submit_shard``), in mesh-device order."""
             shard_parts[i] = [None] * len(parts)
             shard_left[i] = len(parts)
             for dev, part in enumerate(parts):
@@ -1588,8 +1131,8 @@ def make_ps_train_step(
                     w = submit_shard(i, dev, h.reshape(-1))
                 waiters.append((("shard", i, dev), *w))
 
-        # start the D2H copies of the output-route leaves now, all of
-        # them, in flatten order, a shard leaf's per-device arrays in
+        # start the D2H copies of the leaves now, all of them, in
+        # flatten order, a shard leaf's per-device arrays in
         # mesh-device order (a pure function of the plan: every worker
         # issues and claims them alike); each np.asarray below then
         # only waits for ITS array. The TPU runtime works on the copies
@@ -1597,12 +1140,9 @@ def make_ps_train_step(
         # ones finish close together, late in the claim; a bounded
         # window of copies in flight does overlap the PUSH with the
         # transfers but slows the transfers by as much, on one host's
-        # cores (PERF.md section 6, PR 25). Tapped leaves cross in
-        # their tap.
+        # cores (PERF.md section 6, PR 25).
         out_shards: Dict[int, list] = {}
         for i, leaf in enumerate(g_leaves):
-            if i in streamed_set:
-                continue
             if i in active_shard:
                 out_shards[i] = device_parts(leaf)
                 for part in out_shards[i]:
@@ -1615,30 +1155,6 @@ def make_ps_train_step(
         apply_parts: list = [None] * len(names)
         try:
             for i, (name, leaf) in enumerate(zip(names, g_leaves)):
-                if i in streamed_set:
-                    # peek first; on a miss, block on the leaf ITSELF —
-                    # a compute error then surfaces immediately instead
-                    # of stalling a long claim — and give the ingest
-                    # one more beat (it fires by program end unless the
-                    # callback path is dead, which the final claim
-                    # raises)
-                    w = round_obj.claim(i, timeout=5.0, final=False)
-                    if w is None:
-                        # ready-or-raise WITHOUT materializing: a
-                        # D2H here would assemble the full (for shard
-                        # leaves: cross-device) value only to discard
-                        # it when the claim then succeeds
-                        jax.block_until_ready(leaf)
-                        w = round_obj.claim(i, timeout=30.0, final=True)
-                    if w[0] == "shards":
-                        shard_parts[i] = [None] * active_shard[i]["n"]
-                        shard_left[i] = active_shard[i]["n"]
-                        for dev, (fin, notif) in w[1]:
-                            waiters.append((("shard", i, dev),
-                                            fin, notif))
-                    else:
-                        waiters.append((i, *w))
-                    continue
                 if i in out_shards:
                     claim_shards(i, name, out_shards[i])
                     continue
@@ -1657,11 +1173,8 @@ def make_ps_train_step(
                     bucket.append((i, name, h))
                     bucket_bytes += nb
                     continue
-                # a leaf on a key of its own, on the output route: the
-                # same three spans its tap's ingest would be on the
-                # router, on this thread (no tap caused it and nothing
-                # queued it: ``cause`` names the output, no
-                # ``queued_us``)
+                # a leaf on a key of its own: ``cause`` names the
+                # program output it is
                 with tracing.span(tracing.EXPORT_INGEST, tid=name,
                                   step=tag, leaf=i, bytes=nb,
                                   cause=f"out:{i}"):
@@ -1672,8 +1185,8 @@ def make_ps_train_step(
                     if sparse:
                         flush_bucket()
                     # a whole-leaf key does not close the bucket: its
-                    # members, and so its digest, are the same whether
-                    # the whole leaves between them are tapped or not
+                    # members, and so its digest, depend on the tree
+                    # and the fusion size alone
                     with tracing.span(tracing.EXPORT_SUBMIT, tid=name,
                                       step=tag, leaf=i, bytes=nb) as sp:
                         if sparse:
@@ -1765,7 +1278,7 @@ def make_ps_train_step(
             # Completion-ordered drain — IMPORT + UPDATE: issue the
             # async H2D device_put for each leaf THE MOMENT its pull
             # lands (XLA overlaps the import of tensor k with the DCN
-            # PULL of tensor k+1 — the mirror of the streamed EXPORT
+            # PULL of tensor k+1 — the mirror of the export
             # above; reference: COPYH2D as its own pipeline stage,
             # core_loops.cc:620-648), and with the sharded apply, its
             # per-leaf optimizer update right behind it — UPDATE(k)
@@ -1836,7 +1349,7 @@ def make_ps_train_step(
                 # the all-gather that rebuilds the replicated leaves
                 garr = jax.make_array_from_single_device_arrays(
                     (info["n"] * info["shard_len"],),
-                    stream_state["nsharding"], parts)
+                    shard_sharding, parts)
                 imported[s] = garr
                 with tracing.span(tracing.APPLY_ALLGATHER, tid=names[s],
                                   step=tag, leaf=s) as sp:
@@ -1954,12 +1467,6 @@ def make_ps_train_step(
             # gradient-sized result buffers in the handle table for
             # the life of the process either (the same leak class
             # the TF graph tier discards against).
-            stream_state["holder"]["round"] = None
-            if round_obj is not None:
-                # quiesce BEFORE the abandon/discard loops: an ingest
-                # mid-flight on the export worker may still be checking
-                # out a lease / allocating a handle
-                round_obj.cancel()
             # a raised step voids the cross-barrier chain: overrides
             # reference buffers from the failed round, and a restarted
             # run must not apply them onto checkpoint-restored trees
@@ -1977,20 +1484,13 @@ def make_ps_train_step(
             for _, _, notifier in waiters:
                 if hasattr(notifier, "id"):
                     state.handles.discard(notifier.id)
-            if round_obj is not None:
-                for h in round_obj.handles():
-                    state.handles.discard(h.id)
             raise
-        stream_state["holder"]["round"] = None
-        n_streamed = round_obj.streamed if round_obj is not None else 0
-        state.telemetry.record_export(
-            n_streamed, len(names) - n_streamed, first_push[0],
-            shard_leaves=(round_obj.shard_leaves if round_obj is not None
-                          else len(out_shards)))
+        state.telemetry.record_export(len(names), first_push[0],
+                                      shard_leaves=len(out_shards))
         if sa is not None:
             # UPDATEs are already in flight; the end-of-step barrier is
             # gone. The leases release on whichever fires first: the
-            # export worker (as soon as the imports are ready — covers
+            # release worker (as soon as the imports are ready — covers
             # the LAST step of a run and a rebuilt step closure, which
             # would otherwise pin the slots forever and conflict a new
             # closure's checkouts into fresh allocations) or the next
@@ -2047,8 +1547,7 @@ def make_ps_train_step(
             prof,
             ttfp_ms=first_push[0] * 1e3 if first_push[0] is not None
             else None,
-            streamed=n_streamed, fallback=len(names) - n_streamed,
-            health=health_fields, xb=xb_fields)
+            leaves=len(names), health=health_fields, xb=xb_fields)
         if hplane is not None:
             hplane.raise_if_fatal()
         return _finish(params, opt_state, loss)

@@ -77,8 +77,6 @@ def estimate_clock_offset(
 STEP_DISPATCH = "bps.step.dispatch"
 STEP_CLAIM = "bps.step.claim"
 STEP_DRAIN = "bps.step.drain"
-EXPORT_TAP = "bps.export.tap"
-EXPORT_ROUTE = "bps.export.route"
 EXPORT_INGEST = "bps.export.ingest"
 EXPORT_MATERIALIZE = "bps.export.materialize"
 EXPORT_SUBMIT = "bps.export.submit"
@@ -117,13 +115,9 @@ class span:
       nothing with ``BYTEPS_METRICS=0`` (no builder);
     - a Chrome ``comm.json`` event (row ``tid``, default the thread's
       name) where a ``Tracer`` exists and its step window is open.
-
-    ``drop()`` keeps the span out of the builder and the Chrome trace
-    (a duplicate tap fire that turned out to be nobody's work); the
-    annotation, already entered, still closes and carries ``dropped=1``.
     """
 
-    __slots__ = ("stage", "tid", "args", "t0", "t1", "_ann", "_dropped")
+    __slots__ = ("stage", "tid", "args", "t0", "t1", "_ann")
 
     def __init__(self, stage: str, tid: Optional[str] = None, **args):
         self.stage = stage
@@ -132,7 +126,6 @@ class span:
         self.t0: Optional[float] = None
         self.t1: Optional[float] = None
         self._ann = None
-        self._dropped = False
 
     def __enter__(self) -> "span":
         if _trace_me.is_enabled():
@@ -148,19 +141,12 @@ class span:
         if self._ann is not None:
             self._ann.set_metadata(**args)
 
-    def drop(self) -> None:
-        self._dropped = True
-        if self._ann is not None:
-            self._ann.set_metadata(dropped=1)
-
     def __exit__(self, *exc) -> bool:
         if self.t0 is None or self.t1 is not None:
             return False
         self.t1 = t1 = time.perf_counter()
         if self._ann is not None:
             self._ann.__exit__(None, None, None)
-        if self._dropped:
-            return False
         global _get_state
         if _get_state is None:
             from ..core.state import get_state

@@ -98,11 +98,6 @@ def test_healthy_run_lands_everything(bench, monkeypatch, capsys):
                     "health_nonfinite_leaves": 0,
                     "health_infold_rounds": 48,
                     "health_verdict_named": True}, None
-        if name == "stream_ab":
-            return {"stream_on_step_ms": 4.0,
-                    "stream_off_step_ms": 4.8,
-                    "stream_ttfp_on_ms": 0.9,
-                    "stream_ttfp_off_ms": 3.1}, None
         if name == "barrier_ab":
             return {"barrier_on_step_ms": 3.4,
                     "barrier_off_step_ms": 4.6,
@@ -222,8 +217,6 @@ def test_healthy_run_lands_everything(bench, monkeypatch, capsys):
     assert out["trace_overhead_pct"] == 1.0
     assert out["trace_server_records"] == 96
     assert out["trace_rid_links"] == 24
-    assert out["stream_on_step_ms"] == 4.0
-    assert out["stream_ttfp_on_ms"] == 0.9
     assert out["barrier_on_step_ms"] == 3.4
     assert out["barrier_overlap_on_frac"] == 0.71
     assert out["barrier_carried_leaves"] == 96
@@ -336,7 +329,7 @@ def test_budget_gate_skips_everything_when_spent(bench, monkeypatch,
                             "scaleup_ab", "codec_adapt_ab", "stripe_ab",
                             "fold_ab", "ledger_ab", "health_ab",
                             "ts_ab", "arena_ab", "metrics_ab",
-                            "trace_ab", "stream_ab", "barrier_ab",
+                            "trace_ab", "barrier_ab",
                             "wire_ab", "shard_ab", "scaling"}
 
 

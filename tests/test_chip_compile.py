@@ -162,10 +162,10 @@ def test_kernel_compiles_for_v5e(v5e, lower):
 
 
 def test_untapped_scatter_backward_compiles_for_a_v5e_host(v5e_host):
-    """The program a four-chip mesh runs with BYTEPS_STREAM_EXPORT
-    unset: BERT-large's widths (two of its 24 layers: the full depth
-    compiles in 100 s here), batch 64 a chip, the plan's shard leaves
-    reduce-scattered and returned as flat ``P(dp)`` outputs. The TPU's
+    """The program a four-chip mesh runs: BERT-large's widths (two of
+    its 24 layers: the full depth compiles in 100 s here), batch 64 a
+    chip, the plan's shard leaves reduce-scattered and returned as flat
+    ``P(dp)`` outputs. The TPU's
     compiler takes it, no host callback or host transfer is in it (so
     the persistent cache can serve it), and a chip's outputs hold a
     quarter of every shard leaf."""

@@ -199,7 +199,7 @@ def test_profiler_ring_and_stall_counters():
         b.credit_stall()
         b.mark("export_done")
         b.mark("drain_done")
-        p.end_step(b, ttfp_ms=1.0, streamed=1, fallback=2)
+        p.end_step(b, ttfp_ms=1.0, leaves=3)
     reports = p.reports()
     assert len(reports) == 2, "ring must cap at the window"
     assert [r.step for r in reports] == [2, 3]
@@ -278,13 +278,10 @@ def _train_rounds(steps=3, **kw):
     return float(loss)
 
 
-@pytest.mark.parametrize("stream", [True, False])
-def test_step_report_assembly_real_step(stream):
-    # fusion off so leaves ride their own keys (streaming eligible);
-    # the stream=False arm proves the report shape is identical when
-    # every leaf exports through the post-jit fallback loop
+def test_step_report_assembly_real_step():
+    # fusion off so leaves ride their own keys
     with _ps_env({"BYTEPS_FUSION_BYTES": "0"}) as bps:
-        _train_rounds(steps=3, stream_export=stream)
+        _train_rounds(steps=3)
         m = bps.get_metrics()
         steps = m["steps"]
         assert steps["count"] == 3
@@ -294,12 +291,9 @@ def test_step_report_assembly_real_step(stream):
         assert last["compute_ms"] > 0
         assert last["drain_ms"] >= 0
         assert last["ttfp_ms"] is not None and last["ttfp_ms"] > 0
-        total = last["streamed_leaves"] + last["fallback_leaves"]
-        assert total == 6  # mlp: 3 layers x (w, b)
-        if stream:
-            assert last["streamed_leaves"] > 0
-        else:
-            assert last["streamed_leaves"] == 0
+        # mlp: 3 layers x (w, b), every one an output of the backward
+        assert last["streamed_leaves"] == 0
+        assert last["fallback_leaves"] == 6
         # the scheduler fed per-stage samples for this step
         assert last["pull_p95_ms"] is not None
         assert last["push_p95_ms"] is not None
@@ -344,8 +338,7 @@ def test_arena_stats_alias_matches_metrics_section():
 def test_compression_ratio_counters():
     with _ps_env() as bps:
         _train_rounds(steps=2, compression={"compressor": "onebit"},
-                      min_compress_bytes=1, device_compress=False,
-                      stream_export=False)
+                      min_compress_bytes=1, device_compress=False)
         m = bps.get_metrics()
         pre = m["counters"]["compress/bytes_pre"]
         post = m["counters"]["compress/bytes_post"]
@@ -404,7 +397,7 @@ def test_documented_schema_is_live():
     keys = _documented_schema()
     assert len(keys) > 30, "schema block suspiciously small"
     with _ps_env() as bps:
-        _train_rounds(steps=2, stream_export=False)
+        _train_rounds(steps=2)
         snap = bps.get_metrics()
         for path in keys:
             _resolve(snap, path)

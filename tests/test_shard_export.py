@@ -3,12 +3,13 @@ jax/train.py + jax/optim.py make_shard_apply + core/registry.py shard
 subranges): bitwise parity of shard-export on vs off vs the
 single-process baseline for dense, fused-bucket and
 compression-fallback configs; odd (non-divisible) shapes with padding;
-the pad-threshold and local_size==1 fallbacks; shard keys sharing the
-parent's production ordinal; the route BYTEPS_STREAM_EXPORT unset
-chooses on a mesh (each device's shard a program output: bitwise the
-tapped shards and the whole leaves, no host callback in the program,
-a failed claim cleaned up after); and a slow mixed-traffic churn
-asserting no arena-lease or handle leaks under per-shard checkouts.
+the export plan's rule, clause by clause, without a server
+(``jax/train.py _export_plan``); the pad-threshold and local_size==1
+fallbacks; shard keys sharing the parent's production ordinal; each
+device's shard a program output (bitwise the whole leaves, no host
+callback in any backward program, a failed claim cleaned up after);
+and a slow mixed-traffic churn asserting no arena-lease or handle
+leaks under per-shard checkouts.
 
 Bitwise parity relies on the conftest's
 ``--xla_cpu_enable_fast_math=false`` pin: XLA CPU fast-math
@@ -121,6 +122,64 @@ def _local_steps(params, batch, cfg, steps=3, tx=None):
 
 
 # --------------------------------------------------------------------- #
+# the export plan's rule, without a server
+# --------------------------------------------------------------------- #
+
+# names and host-side stand-ins of a gradient tree: two leaves large
+# enough to shard, one row-sparse by name, one that pads 1 of 7
+_PLAN_LEAVES = {"grad/w": (64, 48), "grad/embed": (64, 48),
+                "grad/small": (48,), "grad/frag": (7,)}
+_PLAN_DEFAULTS = dict(axis="dp", fusion_bytes=0, shard_min_bytes=1024,
+                      local_shard=True, rowsparse_params=None,
+                      host_codec=False, scheduler_running=True)
+
+
+@pytest.mark.parametrize("change,mesh_shape,want", [
+    ({}, (8,), ["grad/w", "grad/embed"]),
+    ({"shard_min_bytes": 16384}, (8,), []),
+    ({"shard_min_bytes": 0}, (8,), ["grad/w", "grad/embed", "grad/small"]),
+    ({"rowsparse_params": ("embed",)}, (8,), ["grad/w"]),
+    ({"host_codec": True}, (8,), []),
+    ({}, (2, 4), []),
+    ({}, (1,), []),
+    ({"shard_min_bytes": 0, "fusion_bytes": 16384}, (8,), []),
+    ({"scheduler_running": False}, (8,), []),
+    ({"local_shard": False}, (8,), []),
+], ids=["default", "below-shard-min-bytes", "padding-over-an-eighth",
+        "rowsparse-name", "host-codec", "two-mesh-axes", "axis-of-one",
+        "bucket-member", "no-scheduler", "knob-off"])
+def test_export_plan(change, mesh_shape, want):
+    """``_export_plan`` alone: which leaves shard, and why not. A leaf
+    shards when it rides a dense key of its own, is at least
+    ``max(fusion_bytes, shard_min_bytes)`` and pads by at most 1/8
+    (``grad/frag``: 1 of 7 elements on 8 shards, whatever the floor);
+    nothing shards under a host codec, without a scheduler, on two mesh
+    axes or on an axis of one device."""
+    import jax
+    from jax.sharding import Mesh
+
+    from byteps_tpu.jax.train import ExportPlan, _export_plan
+
+    n = int(np.prod(mesh_shape))
+    mesh = Mesh(np.array(jax.devices()[:n]).reshape(mesh_shape),
+                ("dp", "tp")[:len(mesh_shape)])
+    names = list(_PLAN_LEAVES)
+    leaves = [np.zeros(shape, np.float32) for shape in _PLAN_LEAVES.values()]
+    plan = _export_plan(names, leaves, mesh=mesh,
+                        **{**_PLAN_DEFAULTS, **change})
+    assert [names[i] for i in plan.shard_set] == want
+    if not want:
+        assert plan == ExportPlan()
+        return
+    assert plan.n_shard == 8
+    for i, (size, shard_len, dtype) in zip(plan.shard_set, plan.layouts):
+        assert size == leaves[i].size and dtype == np.float32
+        assert shard_len * 8 >= size > (shard_len - 1) * 8
+    assert hash(plan) == hash(_export_plan(
+        names, leaves, mesh=mesh, **{**_PLAN_DEFAULTS, **change}))
+
+
+# --------------------------------------------------------------------- #
 # parity: shard on vs off vs single-process baseline, per codec class
 # --------------------------------------------------------------------- #
 
@@ -208,11 +267,7 @@ def test_odd_shapes_pad_parity():
         np.testing.assert_array_equal(a, b)
 
 
-# unset, nothing is tapped: the shard leaf leaves as per-device outputs,
-# the fragment as a whole one; asked for, both are tapped
-@pytest.mark.parametrize("stream,streamed", [(None, 0), ("1", 4)],
-                         ids=["unset", "taps-asked"])
-def test_pad_threshold_falls_back(stream, streamed):
+def test_pad_threshold_falls_back():
     """A leaf whose padding would exceed 1/8 of its size keeps the
     whole-leaf path (with 8 shards that can only happen to sub-56-elem
     leaves, so the floor is dropped to expose the gate)."""
@@ -234,8 +289,6 @@ def test_pad_threshold_falls_back(stream, streamed):
 
     tx = optax.sgd(1e-2)
     env = {"BYTEPS_FUSION_BYTES": "0", "BYTEPS_SHARD_MIN_BYTES": "8"}
-    if stream is not None:
-        env["BYTEPS_STREAM_EXPORT"] = stream
     with _ps_env(env) as bps:
         p = jax.tree.map(jnp.array, params)
         opt = tx.init(p)
@@ -243,11 +296,9 @@ def test_pad_threshold_falls_back(stream, streamed):
         for _ in range(2):
             p, opt, _ = step(p, opt, batch)
         stats = bps.get_arena_stats()
-        # exactly ONE leaf per step sharded (big), on either route; frag
-        # exported whole; tapped only where taps were asked for
+        # exactly ONE leaf per step sharded (big); frag exported whole
         assert stats["export_shard_leaves"] == 2
-        assert stats["export_streamed_leaves"] == streamed
-        assert stats["export_fallback_leaves"] == 4 - streamed
+        assert stats["export_leaves"] == 4
 
 
 def test_local_size_one_degenerate_is_whole_leaf():
@@ -324,46 +375,19 @@ def test_shard_apply_unavailable_still_shards_wire():
         np.testing.assert_array_equal(a, b)
 
 
-def test_broken_taps_raise(monkeypatch):
-    """A tapped backward that cannot be built is an ERROR, never a quiet
-    switch to the post-jit export: a run-time fallback would hide a
-    device or compile fault behind a slower step, and a worker that
-    changed its export path alone could change its PS key set (whole
-    leaf vs shard subranges) and stall every peer's aggregation. The
-    export path is decided by configuration only."""
-    import jax
-    import jax.experimental
-
-    cfg, params, batch = _setup()
-
-    def _dead_tap(*a, **k):
-        raise RuntimeError("io_callback disabled for this test")
-
-    # taps are planted only where they are asked for
-    with _ps_env({"BYTEPS_FUSION_BYTES": "0",
-                  "BYTEPS_STREAM_EXPORT": "1"}) as bps:
-        monkeypatch.setattr(jax.experimental, "io_callback", _dead_tap)
-        with pytest.raises(RuntimeError, match="io_callback disabled"):
-            _run_steps(params, batch, cfg, local_shard_export=True)
-        # nothing left the worker, and no staging slot stayed leased
-        stats = bps.get_arena_stats()
-        assert stats["export_streamed_leaves"] == 0
-        assert bps.get_metrics()["counters"]["export/shard_bytes"] == 0
-
-
 # --------------------------------------------------------------------- #
-# the route unset chooses on a mesh: shard leaves as per-device outputs
+# on a mesh: shard leaves as per-device outputs
 # --------------------------------------------------------------------- #
 
 
-def _route_run(arm, steps=4):
-    """``steps`` PS steps on the 8-device mesh with BYTEPS_STREAM_EXPORT
-    unset (``None``), "1" or "0": parameters, every loss, the declared
-    keys and the export counters."""
+def _route_run(shard, steps=4):
+    """``steps`` PS steps on the 8-device mesh with
+    BYTEPS_LOCAL_SHARD_EXPORT unset or "0": parameters, every loss, the
+    declared keys and the export counters."""
     cfg, params, batch = _setup()
     env = {"BYTEPS_FUSION_BYTES": "0"}
-    if arm is not None:
-        env["BYTEPS_STREAM_EXPORT"] = arm
+    if not shard:
+        env["BYTEPS_LOCAL_SHARD_EXPORT"] = "0"
     with _ps_env(env) as bps:
         import jax
         import jax.numpy as jnp
@@ -395,84 +419,76 @@ def _route_run(arm, steps=4):
         }
 
 
-def test_unset_on_a_mesh_is_bitwise_the_taps_and_the_whole_leaves():
-    """Nobody set BYTEPS_STREAM_EXPORT, eight devices: the weights
-    reduce-scatter and each device's shard leaves as a program output.
-    Every loss and every parameter over four steps is bitwise what
-    ``=1`` (the same shards, tapped) and ``=0`` (no shard plan, whole
-    leaves) give; against ``=1`` the declared keys, the shard bytes and
-    each device's bytes are the same, and no leaf counts as streamed."""
-    unset, asked, off = _route_run(None), _route_run("1"), _route_run("0")
-    for other in (asked, off):
-        for a, b in zip(unset["leaves"], other["leaves"]):
-            np.testing.assert_array_equal(a, b)
-        for a, b in zip(unset["losses"], other["losses"]):
-            np.testing.assert_array_equal(a, b)
-    assert unset["keys"] == asked["keys"]
-    assert any("@shard" in k for k in unset["keys"])
-    assert not any("@shard" in k for k in off["keys"])
-    assert unset["shard_bytes"] == asked["shard_bytes"] > 0
-    assert off["shard_bytes"] == 0
-    assert unset["device_bytes"] == asked["device_bytes"]
-    assert sorted(unset["device_bytes"]) == [
+def test_shards_on_a_mesh_are_bitwise_the_whole_leaves():
+    """Eight devices: the weights reduce-scatter and each device's shard
+    leaves as a program output. Every loss and every parameter over
+    four steps is bitwise what ``BYTEPS_LOCAL_SHARD_EXPORT=0`` (no
+    shard plan, whole leaves) gives; the shard keys, the shard bytes
+    and each device's bytes are the plan's."""
+    plan, off = _route_run(True), _route_run(False)
+    for a, b in zip(plan["leaves"], off["leaves"]):
+        np.testing.assert_array_equal(a, b)
+    for a, b in zip(plan["losses"], off["losses"]):
+        np.testing.assert_array_equal(a, b)
+    shard_keys = [k for k in plan["keys"] if "@shard" in k]
+    assert len(shard_keys) == 3 * 8
+    assert [k for k in plan["keys"] if "@shard" not in k] == off["keys"]
+    assert plan["shard_bytes"] > 0 and off["shard_bytes"] == 0
+    assert sorted(plan["device_bytes"]) == [
         f"export/device_bytes/{d}" for d in range(8)]
-    n_leaves = len(unset["leaves"])
-    assert unset["arena"]["export_shard_leaves"] == \
-        asked["arena"]["export_shard_leaves"] == 4 * 3
-    assert unset["arena"]["export_streamed_leaves"] == 0
-    assert unset["report"]["streamed_leaves"] == 0
-    assert unset["report"]["fallback_leaves"] == n_leaves
-    assert asked["report"]["streamed_leaves"] == n_leaves
+    assert len({plan["device_bytes"][f"export/device_bytes/{d}"]
+                for d in range(1, 8)}) == 1
+    n_leaves = len(plan["leaves"])
+    assert plan["arena"]["export_shard_leaves"] == 4 * 3
+    assert plan["arena"]["export_leaves"] == 4 * n_leaves
+    assert plan["report"]["streamed_leaves"] == 0
+    assert plan["report"]["fallback_leaves"] == n_leaves
 
 
-@pytest.mark.parametrize("tapped", [False, True],
-                         ids=["unset", "taps-asked"])
-def test_the_scatter_backward_holds_a_host_callback_only_where_asked(tapped):
-    """The program a mesh runs under unset: the reduce-scatter backward
-    with no host callback in it (so the persistent compile cache can
-    serve it) and no step tag among its arguments; the same builder
-    with taps planted holds one a tapped leaf."""
+@pytest.mark.parametrize("devices,shard", [(1, False), (8, True), (8, False)],
+                         ids=["one-device", "mesh-sharded", "mesh-whole"])
+def test_no_backward_program_holds_a_host_callback(devices, shard):
+    """The backward programs a PS step runs, picked as ``step`` picks
+    them from the plan: ``grad_fn`` (``_psum_backward``) where nothing
+    shards, the reduce-scatter backward where leaves do. Neither holds
+    a host callback (so the persistent compile cache can serve it), and
+    only the shard leaves' outputs are sharded."""
     import jax
-    from jax.experimental import io_callback
+    from jax.sharding import Mesh
 
-    from byteps_tpu.core.state import get_state
     from byteps_tpu.jax import train
     from byteps_tpu.models import mlp
 
     cfg, params, batch = _setup()
-    shard_set = tuple(i for i, x in enumerate(jax.tree.leaves(params))
-                      if x.ndim == 2)
-    n_leaves = len(jax.tree.leaves(params))
-
-    def plant(i, step_tag, idx, g):
-        io_callback(lambda *a: None, None, step_tag, idx, g, ordered=False)
-
-    with _ps_env():
-        mesh = get_state().mesh
-        fn = train._scatter_backward(
-            train._loss_and_stats(lambda p, b: mlp.loss_fn(p, b, cfg)),
-            mesh, "dp", shard_set, n_leaves,
-            tapped=shard_set if tapped else (),
-            plant=plant if tapped else None)
-        args = (params, batch)
-        if tapped:
-            args = (np.int32(1),) + args
-        text = fn.lower(*args).as_text()
-        (_, _), grads = fn(*args)
-    assert ("callback" in text) == tapped
-    assert "reduce_scatter" in text
-    for i, g in enumerate(grads):
-        assert (len(g.sharding.spec) == 1) == (i in shard_set)
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    plan = train._export_plan(
+        [str(path) for path, _ in flat], [x for _, x in flat], mesh=mesh,
+        **{**_PLAN_DEFAULTS, "local_shard": shard or devices == 1})
+    assert bool(plan.shard_set) == shard
+    assert plan.shard_set == (tuple(
+        i for i, (_, x) in enumerate(flat) if x.ndim == 2) if shard else ())
+    loss = train._loss_and_stats(lambda p, b: mlp.loss_fn(p, b, cfg))
+    fn = train._scatter_backward(loss, mesh, "dp", plan.shard_set,
+                                 len(flat)) \
+        if plan.shard_set else train._psum_backward(loss, mesh, "dp")
+    text = fn.lower(params, batch).as_text()
+    assert "callback" not in text
+    assert ("reduce_scatter" in text) == shard
+    (_, _), grads = fn(params, batch)
+    for i, g in enumerate(jax.tree.leaves(grads)):
+        assert (len(g.sharding.spec) == 1) == (i in plan.shard_set)
 
 
-@pytest.mark.parametrize("stream", [None, "1"], ids=["unset", "taps-asked"])
-def test_a_failed_shard_claim_abandons_leases_and_discards_handles(
-        stream, monkeypatch):
-    """One device's shard of the second weight cannot be submitted: the
-    step raises that error on either route, the shards already on the
-    wire leave no handle behind, and every staging slot of the round is
-    abandoned (dropped from the table, never recycled under a late
-    writer)."""
+@pytest.mark.parametrize("shard", [True, False],
+                         ids=["shard", "whole-leaf"])
+def test_a_failed_claim_abandons_leases_and_discards_handles(
+        shard, monkeypatch):
+    """One device's shard of the second weight (or, with nothing
+    sharded, the fifth whole leaf) cannot be submitted: the step raises
+    that error, the leaves already on the wire leave no handle behind,
+    and every staging slot of the round is abandoned (dropped from the
+    table, never recycled under a late writer)."""
     import time
 
     from byteps_tpu.server import client as client_mod
@@ -481,24 +497,27 @@ def test_a_failed_shard_claim_abandons_leases_and_discards_handles(
     real = client_mod.get_or_init_ctx
     seen = []
 
+    # the second shard leaf's third device; the fifth whole leaf
+    nth = 11 if shard else 5
+
     def failing(state, name, flat):
-        if "@shard" in name:
+        if ("@shard" in name) == shard:
             seen.append(name)
-            if len(seen) == 11:  # the second shard leaf's third device
-                raise RuntimeError("shard submit refused for this test")
+            if len(seen) == nth:
+                raise RuntimeError("submit refused for this test")
         return real(state, name, flat)
 
     env = {"BYTEPS_FUSION_BYTES": "0"}
-    if stream is not None:
-        env["BYTEPS_STREAM_EXPORT"] = stream
+    if not shard:
+        env["BYTEPS_LOCAL_SHARD_EXPORT"] = "0"
     with _ps_env(env) as bps:
         from byteps_tpu.core.state import get_state
 
         monkeypatch.setattr(client_mod, "get_or_init_ctx", failing)
-        with pytest.raises(RuntimeError, match="shard submit refused"):
+        with pytest.raises(RuntimeError, match="submit refused"):
             _run_steps(params, batch, cfg, steps=1)
         monkeypatch.setattr(client_mod, "get_or_init_ctx", real)
-        assert len(seen) >= 11
+        assert len(seen) == nth
         state = get_state()
         deadline = time.time() + 30
         while time.time() < deadline and state.handles._handles:

@@ -1,12 +1,10 @@
-"""Streamed gradient export + sharded optimizer apply
-(BYTEPS_STREAM_EXPORT / BYTEPS_SHARDED_APPLY, jax/train.py +
-jax/optim.py): numerics parity of stream-export on vs off vs the
-single-process baseline (dense, fused-bucket and compression-enabled
-configs), of the route that unset chooses against both, that it taps
-nothing on a mesh or on one device, bitwise parity of the sharded apply against the fused optax
-apply for adam/sgd, the non-separable fallback, export-stage telemetry
-(streamed-leaf counters + time-to-first-push), and production-order
-priority pinning end to end."""
+"""Gradient export + sharded optimizer apply (jax/train.py +
+jax/optim.py; BYTEPS_SHARDED_APPLY): every leaf leaves the chip as an
+output of the backward (dense, fused-bucket and host-codec configs:
+keys, counters, the bucket's digest, the single-process baseline), no
+program of the package plants a host callback on a mesh or on one
+device, bitwise parity of the sharded apply against the fused optax
+apply for adam/sgd, and the non-separable fallback."""
 
 import contextlib
 import os
@@ -111,89 +109,63 @@ def _local_steps(params, batch, cfg, steps=3, tx=None):
 
 
 # --------------------------------------------------------------------- #
-# parity: stream on vs off vs single-process baseline
+# the one route: every leaf an output of the backward
 # --------------------------------------------------------------------- #
 
 
-# fusion 0 = every leaf rides its own key -> all stream ("dense");
-# fusion 4096 = weights stream, biases ride the fused bucket
+# fusion 0 = every leaf rides its own key ("dense"); fusion 4096 = the
+# weights ride keys of their own, the biases the fused bucket
 # ("fused-bucket"); the compression config exercises the host codec
-# tier under streaming
+# tier
 @pytest.mark.parametrize("fusion,kw", [
     ("0", {}),
     ("4096", {}),
     ("0", dict(compression={"compressor": "onebit", "ef": "vanilla"},
                min_compress_bytes=0, device_compress=False)),
 ], ids=["dense", "fused-bucket", "onebit"])
-def test_stream_on_off_parity(fusion, kw):
-    """Stream-export on and off produce IDENTICAL params after 3 steps
-    (the tap changes WHEN bytes leave the device, never what is
-    computed), and both track the single-process baseline."""
+def test_output_route_parity(fusion, kw):
+    """Every leaf leaves as an output of the backward (these leaves are
+    too small to shard): each is counted once a step, its bytes as a
+    whole-leaf export; the declared keys are the leaves' own and, under
+    a fusion size, one bucket whose digest is its members' names and
+    sizes alone (a whole leaf between two members does not close it);
+    the lossless transports track the single-process baseline."""
+    import hashlib
+
+    import jax
+
     cfg, params, batch = _setup()
-    with _ps_env({"BYTEPS_STREAM_EXPORT": "1",
-                  "BYTEPS_FUSION_BYTES": fusion}) as bps:
-        on, _ = _run_steps(params, batch, cfg, **kw)
+    with _ps_env({"BYTEPS_FUSION_BYTES": fusion}) as bps:
+        from byteps_tpu.core.state import get_state
+
+        losses = []
+        leaves, _ = _run_steps(params, batch, cfg, losses=losses, **kw)
         stats = bps.get_arena_stats()
-        assert stats["export_streamed_leaves"] > 0, \
-            "streaming never engaged — the on-arm is vacuous"
-        assert stats["export_checkouts"] > 0
-    with _ps_env({"BYTEPS_STREAM_EXPORT": "0",
-                  "BYTEPS_FUSION_BYTES": fusion}) as bps:
-        off, _ = _run_steps(params, batch, cfg, **kw)
-        assert bps.get_arena_stats()["export_streamed_leaves"] == 0
-    for a, b in zip(on, off):
-        np.testing.assert_array_equal(a, b)
+        whole_bytes = bps.get_metrics()["counters"]["export/whole_bytes"]
+        keys = sorted(c.name for c in
+                      get_state().registry.contexts_in_order())
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    names = ["grad/" + "/".join(str(getattr(k, "key", getattr(k, "idx", k)))
+                                for k in path) for path, _ in flat]
+    sizes = [int(np.prod(x.shape)) for _, x in flat]
+    assert stats["export_leaves"] == 3 * len(leaves)
+    assert stats["export_rounds"] == 3 and stats["export_ttfp_ms"] > 0
+    assert stats["export_shard_leaves"] == 0
+    assert whole_bytes == 3 * sum(x.nbytes for x in leaves)
+    members = [(n, z) for n, z in zip(names, sizes) if z * 4 < int(fusion)]
+    want = [n for n, z in zip(names, sizes) if (n, z) not in members]
+    if members:
+        # two whole leaves (w0, w1) lie between the bucket's members
+        assert [n for n, _ in members] == [
+            "grad/b0", "grad/b1", "grad/b2", "grad/w2"]
+        want.append("fused/" + hashlib.sha1(";".join(
+            f"{n}:{z}" for n, z in members).encode()).hexdigest()[:12])
+    assert keys == sorted(want)
+    assert all(np.isfinite(x) for x in losses) and losses[-1] < losses[0]
     if not kw:  # lossless transports also track the local baseline
         base = _local_steps(params, batch, cfg)
-        for a, b in zip(on, base):
+        for a, b in zip(leaves, base):
             np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
-
-
-@pytest.mark.parametrize("fusion,kw", [
-    ("0", {}),
-    ("4096", {}),
-    ("0", dict(compression={"compressor": "onebit", "ef": "vanilla"},
-               min_compress_bytes=0, device_compress=False)),
-], ids=["dense", "fused-bucket", "onebit"])
-def test_unset_route_parity_with_taps_and_without(fusion, kw):
-    """Nobody set BYTEPS_STREAM_EXPORT: whole leaves leave as outputs of
-    the untapped backward. Every loss and every parameter is bitwise
-    what ``=1`` (whole leaves tapped) and ``=0`` give; no leaf streams
-    (these leaves are too small to shard), the same bytes are counted
-    as whole-leaf exports, and the same keys are declared: a whole leaf
-    between two bucket members closes the bucket on no route, so the
-    bucket's digest is one."""
-    cfg, params, batch = _setup()
-    got = {}
-    for arm in (None, "1", "0"):
-        env = {"BYTEPS_FUSION_BYTES": fusion}
-        if arm is not None:
-            env["BYTEPS_STREAM_EXPORT"] = arm
-        with _ps_env(env) as bps:
-            from byteps_tpu.core.state import get_state
-
-            assert get_state().config.stream_export is {
-                None: None, "1": True, "0": False}[arm]
-            losses = []
-            leaves, _ = _run_steps(params, batch, cfg, losses=losses, **kw)
-            stats = bps.get_arena_stats()
-            got[arm] = (leaves, losses, stats["export_streamed_leaves"],
-                        stats["export_fallback_leaves"],
-                        bps.get_metrics()["counters"]["export/whole_bytes"],
-                        sorted(c.name for c in
-                               get_state().registry.contexts_in_order()))
-    n_leaves = len(got[None][0])
-    assert got[None][2] == 0 and got["0"][2] == 0 and got["1"][2] > 0
-    assert got[None][3] == 3 * n_leaves
-    assert got[None][4] == got["1"][4] == got["0"][4] > 0
-    assert got[None][5] == got["1"][5] == got["0"][5]
-    assert any(n.startswith("fused/") for n in got[None][5]) == (
-        fusion != "0")
-    for arm in ("1", "0"):
-        for a, b in zip(got[None][0], got[arm][0]):
-            np.testing.assert_array_equal(a, b)
-        for a, b in zip(got[None][1], got[arm][1]):
-            np.testing.assert_array_equal(a, b)
 
 
 def _export_plan_run(env, mesh_devices=None, steps=3):
@@ -221,8 +193,6 @@ def _export_plan_run(env, mesh_devices=None, steps=3):
             "device_bytes": {int(k.rsplit("/", 1)[1]): v
                              for k, v in ctr.items()
                              if k.startswith("export/device_bytes/")},
-            "taps": sorted(sp[4]["leaf"] for sp in spans
-                           if sp[0] == tracing.EXPORT_TAP),
             "ingests": sorted(
                 (sp[4]["leaf"], sp[4].get("dev", -1),
                  sp[4]["cause"].split(":")[0], sp[1].rsplit("_", 1)[0])
@@ -232,16 +202,7 @@ def _export_plan_run(env, mesh_devices=None, steps=3):
     return out
 
 
-def test_unset_plan_on_the_mesh_taps_nothing(monkeypatch):
-    """Eight devices, nobody set BYTEPS_STREAM_EXPORT: the weights shard
-    exactly as ``=1`` shards them (same per-device bytes, exactly even;
-    same shard keys at their parent's production-order priority; same
-    parameters) and each device's shard leaves as a program output,
-    claimed on the train thread in flatten then mesh-device order
-    beside the biases' whole leaves; no ``io_callback`` is planted and
-    no export pool takes part (asked for with ``=1``, every leaf is
-    tapped)."""
-    import jax
+def _spy_on_io_callback(monkeypatch):
     import jax.experimental
 
     planted = []
@@ -252,74 +213,71 @@ def test_unset_plan_on_the_mesh_taps_nothing(monkeypatch):
         return real(*a, **kw)
 
     monkeypatch.setattr(jax.experimental, "io_callback", spy)
+    return planted
+
+
+def test_the_plan_on_the_mesh_plants_no_host_callback(monkeypatch):
+    """Eight devices: the weights shard (per-device bytes exactly even,
+    shard keys at their parent's production-order priority) and each
+    device's shard leaves as a program output, claimed on the train
+    thread in flatten then mesh-device order beside the biases' whole
+    leaves; no ``io_callback`` is planted by any program of the
+    package; the parameters are bitwise what
+    ``BYTEPS_LOCAL_SHARD_EXPORT=0`` (every leaf whole) gives."""
+    import jax
+
+    planted = _spy_on_io_callback(monkeypatch)
     env = {"BYTEPS_FUSION_BYTES": "0", "BYTEPS_SHARD_MIN_BYTES": "1024"}
-    unset = _export_plan_run(env)
+    plan = _export_plan_run(env)
+    whole = _export_plan_run({**env, "BYTEPS_LOCAL_SHARD_EXPORT": "0"})
     assert planted == []
-    asked = _export_plan_run({**env, "BYTEPS_STREAM_EXPORT": "1"})
     train = threading.current_thread().name.rsplit("_", 1)[0]
     ndims = [x.ndim for x in jax.tree.leaves(_setup()[1])]
     weights = [i for i, n in enumerate(ndims) if n == 2]
     biases = [i for i, n in enumerate(ndims) if n == 1]
     assert len(weights) == len(biases) == 3
-    assert len(planted) >= len(weights + biases)
-    # nothing streams; the shard plan and its bytes are ``=1``'s
-    assert unset["report"]["streamed_leaves"] == 0
-    assert unset["report"]["fallback_leaves"] == len(weights + biases)
-    assert asked["report"]["streamed_leaves"] == len(weights + biases)
-    assert unset["arena"]["export_streamed_leaves"] == 0
-    assert unset["arena"]["export_shard_leaves"] == \
-        asked["arena"]["export_shard_leaves"] == 3 * len(weights)
-    assert unset["shard_bytes"] == asked["shard_bytes"] > 0
-    assert unset["whole_bytes"] == asked["whole_bytes"] > 0
-    per_dev = [unset["device_bytes"][d] for d in range(1, 8)]
-    assert len(set(per_dev)) == 1 and per_dev[0] * 8 == unset["shard_bytes"]
-    assert unset["device_bytes"] == asked["device_bytes"]
-    assert unset["taps"] == []
-    assert sorted(set(asked["taps"])) == sorted(weights + biases)
+    assert plan["report"]["streamed_leaves"] == 0
+    assert plan["report"]["fallback_leaves"] == len(weights + biases)
+    assert plan["arena"]["export_shard_leaves"] == 3 * len(weights)
+    assert whole["arena"]["export_shard_leaves"] == 0
+    assert plan["shard_bytes"] > 0 and whole["shard_bytes"] == 0
+    assert plan["shard_bytes"] + plan["whole_bytes"] == whole["whole_bytes"]
+    per_dev = [plan["device_bytes"][d] for d in range(1, 8)]
+    assert len(set(per_dev)) == 1 and per_dev[0] * 8 == plan["shard_bytes"]
     # one ingest a (weight, device) and one a bias, all outputs, all on
-    # the thread that claims; the taps' ran on the devices' workers
-    assert unset["ingests"] == sorted(
+    # the thread that claims
+    assert plan["ingests"] == sorted(
         [(w, d, "out", train) for w in weights for d in range(8)]
         + [(b, -1, "out", train) for b in biases])
-    tapped = [m for m in asked["ingests"] if m[0] in weights]
-    assert [m[:3] for m in tapped] == [
-        (w, d, "tap") for w in weights for d in range(8)]
-    assert all(m[3].startswith("bps-export-d") for m in tapped)
+    assert whole["ingests"] == sorted(
+        (i, -1, "out", train) for i in weights + biases)
     # the shards' keys keep a production order (the claim's: flatten
-    # order); a whole leaf on the output route is in none
-    assert len(asked["order"]) > len(unset["order"]) > 0
-    assert set(unset["order"]) <= set(asked["order"])
-    for a, b in zip(unset["leaves"], asked["leaves"]):
+    # order), one ordinal a leaf; a whole leaf is in none
+    assert sorted(set(plan["order"].values())) == list(range(len(weights)))
+    assert len(plan["order"]) == len(weights) * (8 + 1)
+    assert whole["order"] == {}
+    for a, b in zip(plan["leaves"], whole["leaves"]):
         np.testing.assert_array_equal(a, b)
 
 
-def test_unset_on_one_device_builds_no_tapped_program(monkeypatch):
-    """A one-device mesh has nothing to shard, so nothing is tapped:
-    the program that runs is ``grad_fn``, and no ``io_callback`` is ever
-    planted (asked for with ``=1``, it is)."""
-    import jax.experimental
-
-    planted = []
-    real = jax.experimental.io_callback
-
-    def spy(*a, **kw):
-        planted.append(1)
-        return real(*a, **kw)
-
-    monkeypatch.setattr(jax.experimental, "io_callback", spy)
+def test_one_device_plants_no_host_callback(monkeypatch):
+    """A one-device mesh has nothing to shard: the program that runs is
+    ``grad_fn``, no ``io_callback`` is ever planted, and
+    ``BYTEPS_LOCAL_SHARD_EXPORT=0`` changes nothing."""
+    planted = _spy_on_io_callback(monkeypatch)
     env = {"BYTEPS_FUSION_BYTES": "0", "BYTEPS_SHARD_MIN_BYTES": "1024"}
-    unset = _export_plan_run(env, mesh_devices=1)
-    assert planted == []
-    assert unset["taps"] == [] and unset["shard_bytes"] == 0
-    assert unset["report"]["streamed_leaves"] == 0
-    assert unset["report"]["fallback_leaves"] == 6
-    assert [m[2] for m in unset["ingests"]] == ["out"] * 6
-    assert unset["order"] == {}
-    asked = _export_plan_run({**env, "BYTEPS_STREAM_EXPORT": "1"},
+    plan = _export_plan_run(env, mesh_devices=1)
+    whole = _export_plan_run({**env, "BYTEPS_LOCAL_SHARD_EXPORT": "0"},
                              mesh_devices=1)
-    assert len(planted) == 6 and asked["report"]["streamed_leaves"] == 6
-    assert unset["whole_bytes"] == asked["whole_bytes"] > 0
-    for a, b in zip(unset["leaves"], asked["leaves"]):
+    assert planted == []
+    assert plan["shard_bytes"] == 0
+    assert plan["report"]["streamed_leaves"] == 0
+    assert plan["report"]["fallback_leaves"] == 6
+    assert [m[2] for m in plan["ingests"]] == ["out"] * 6
+    assert plan["order"] == {}
+    assert plan["whole_bytes"] == whole["whole_bytes"] > 0
+    assert plan["ingests"] == whole["ingests"]
+    for a, b in zip(plan["leaves"], whole["leaves"]):
         np.testing.assert_array_equal(a, b)
 
 
@@ -412,65 +370,3 @@ def test_sharded_apply_rejects_non_separable():
     base = _local_steps(params, batch, cfg, tx=tx)
     for a, b in zip(got, base):
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)
-
-
-# --------------------------------------------------------------------- #
-# telemetry + production-order priority
-# --------------------------------------------------------------------- #
-
-
-def test_export_telemetry_and_production_priority():
-    """The export-stage counters prove the overlap engaged (streamed
-    leaves counted, TTFP recorded, arena export leases tagged), and
-    the scheduler's pinned priorities come from measured first-export
-    ordinals for every streamed key."""
-    cfg, params, batch = _setup()
-    with _ps_env({"BYTEPS_STREAM_EXPORT": "1",
-                  "BYTEPS_FUSION_BYTES": "0"}) as bps:
-        from byteps_tpu.core.state import get_state
-
-        _run_steps(params, batch, cfg, steps=3)
-        stats = bps.get_arena_stats()
-        n_leaves = 6  # 3 layers x (w, b)
-        assert stats["export_rounds"] == 3
-        # every leaf streams every round (fusion off, no rowsparse)
-        assert stats["export_streamed_leaves"] == 3 * n_leaves
-        assert stats["export_fallback_leaves"] == 0
-        assert stats["export_checkouts"] == 3 * n_leaves
-        assert stats["export_ttfp_ms"] is not None
-        assert stats["export_ttfp_ms"] > 0
-        sched = get_state().scheduler
-        order = sched.export_order()
-        assert len(order) == n_leaves
-        assert sorted(order.values()) == list(range(n_leaves))
-        # the pinned priority of every streamed key IS -ordinal
-        for key, o in order.items():
-            assert sched._key_priority[key] == -o
-    # stream off: counters stay flat, TTFP still measured (the loop's
-    # first submit), so the bench can A/B both arms
-    with _ps_env({"BYTEPS_STREAM_EXPORT": "0",
-                  "BYTEPS_FUSION_BYTES": "0"}) as bps:
-        _run_steps(params, batch, cfg, steps=2)
-        stats = bps.get_arena_stats()
-        assert stats["export_streamed_leaves"] == 0
-        assert stats["export_fallback_leaves"] > 0
-        assert stats["export_ttfp_ms"] is not None
-
-
-def test_stream_rowsparse_leaves_fall_back():
-    """rowsparse-routed leaves are excluded from streaming (the host
-    row-sparse path needs the dense host rows) but the round's other
-    leaves still stream — and numerics match the non-streamed run."""
-    cfg, params, batch = _setup()
-    kw = dict(rowsparse_params=("w0",))
-    with _ps_env({"BYTEPS_STREAM_EXPORT": "1",
-                  "BYTEPS_FUSION_BYTES": "0"}) as bps:
-        on, _ = _run_steps(params, batch, cfg, **kw)
-        stats = bps.get_arena_stats()
-        assert stats["export_streamed_leaves"] > 0
-        assert stats["export_fallback_leaves"] > 0  # the rowsparse leaf
-    with _ps_env({"BYTEPS_STREAM_EXPORT": "0",
-                  "BYTEPS_FUSION_BYTES": "0"}):
-        off, _ = _run_steps(params, batch, cfg, **kw)
-    for a, b in zip(on, off):
-        np.testing.assert_array_equal(a, b)

@@ -79,10 +79,10 @@ def test_span_with_no_session_costs_no_annotation(fake_annotation,
                                                   open_step):
     _, builder = open_step
     fake_annotation.enabled = False
-    with tracing.span(tracing.EXPORT_TAP, leaf=1):
+    with tracing.span(tracing.EXPORT_INGEST, leaf=1):
         pass
     assert fake_annotation.instances == []
-    assert [sp[0] for sp in builder.spans] == ["bps.export.tap"]
+    assert [sp[0] for sp in builder.spans] == ["bps.export.ingest"]
 
 
 def test_span_closes_its_annotation_when_the_body_raises_and_once(
@@ -99,16 +99,6 @@ def test_span_closes_its_annotation_when_the_body_raises_and_once(
     assert (outer.entered, outer.exited) == (1, 1)
     assert [s[0] for s in builder.spans] == ["bps.export.submit",
                                              "bps.step.claim"]
-
-
-def test_a_dropped_span_is_in_no_step_but_closes_its_annotation(
-        fake_annotation, open_step):
-    _, builder = open_step
-    with tracing.span(tracing.EXPORT_INGEST, step=1) as sp:
-        sp.drop()
-    (ann,) = fake_annotation.instances
-    assert ann.exited == 1 and ann.kw["dropped"] == 1
-    assert builder.spans == []
 
 
 def test_spans_reach_the_chrome_trace_inside_the_step_window(
