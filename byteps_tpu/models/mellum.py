@@ -184,7 +184,7 @@ def _rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
 
 def _block(x, p, ropes, cfg: MellumConfig, kind: str, ep_axis):
     """One decoder block of layer kind ``kind``; p: one layer's params.
-    Returns (x, (load [n_held], dropped))."""
+    Returns (x, the layer's additive statistics by counter name)."""
     B, S, d = x.shape
     nh, nkv, hd, dt = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.dtype
     cos, sin = ropes[kind]
@@ -201,7 +201,10 @@ def _block(x, p, ropes, cfg: MellumConfig, kind: str, ep_axis):
     ffn, st = moe.moe_layer(h, p, cfg.top_k, dt, first=cfg.first_expert,
                             ep_axis=ep_axis, chunk=EXPERT_SLICE,
                             router_dtype=cfg.router_dtype)
-    return x + ffn, (st["load"], st["dropped"])
+    return x + ffn, {"moe/expert_load": st["load"],
+                     "moe/dropped_pairs": st["dropped"],
+                     "moe/compact_slices": st["compact_slices"],
+                     "moe/full_slices": st["full_slices"]}
 
 
 def _period(kinds: Tuple[str, ...]) -> int:
@@ -217,8 +220,9 @@ def _period(kinds: Tuple[str, ...]) -> int:
 
 def forward_hidden(params: Dict[str, Any], tokens: jnp.ndarray,
                    cfg: MellumConfig, ep_axis: Optional[str] = None):
-    """tokens [B, S] -> (final normed hidden [B, S, d], loads [layers,
-    n_held], pairs dropped over all layers: 0)."""
+    """tokens [B, S] -> (final normed hidden [B, S, d], the step's
+    statistics: the load [layers, n_held], the other counts summed over
+    the layers)."""
     B, S = tokens.shape
     kinds = tuple(cfg.layer_types[:cfg.n_layers])
     period = _period(kinds)
@@ -238,21 +242,25 @@ def forward_hidden(params: Dict[str, Any], tokens: jnp.ndarray,
     stacked = jax.tree.map(
         lambda a: a.reshape(cfg.n_layers // period, period, *a.shape[1:]),
         params["blocks"])
-    x, (loads, dropped) = jax.lax.scan(body, x, stacked)
+    x, stats = jax.lax.scan(body, x, stacked)
     x = L._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return x, loads.reshape(cfg.n_layers, -1), jnp.sum(dropped)
+    # the scan stacks [periods, period, ...]: a vector a layer (the
+    # load) keeps its layers, a scalar a layer is summed over them
+    return x, {name: v.reshape(cfg.n_layers, -1) if v.ndim == 3
+               else jnp.sum(v) for name, v in stats.items()}
 
 
 def loss_fn(params: Dict[str, Any], batch: Dict[str, jnp.ndarray],
             cfg: MellumConfig, ep_axis: Optional[str] = None):
     """(next-token cross-entropy over the vocabulary held, the step's
     statistics: ``moe/expert_load`` [layers, n_held], the pairs each
-    held expert computed, and ``moe/dropped_pairs``; both are counts,
-    so they add up across data shards as the step makers need).
+    held expert computed, ``moe/dropped_pairs``, and the expert slices
+    by the sorted buffer they ran on, ``moe/compact_slices`` and
+    ``moe/full_slices``; all are counts, so they add up across data
+    shards as the step makers need).
     batch: ``{"tokens"}`` (shifted here) or pre-shifted ``{"inputs",
     "targets"}``."""
     inputs, targets = L.split_batch(batch)
-    x, loads, dropped = forward_hidden(params, inputs, cfg, ep_axis)
+    x, stats = forward_hidden(params, inputs, cfg, ep_axis)
     logits = x @ params["lm_head"].astype(cfg.dtype)
-    return L.next_token_xent(logits, targets), {
-        "moe/expert_load": loads, "moe/dropped_pairs": dropped}
+    return L.next_token_xent(logits, targets), stats

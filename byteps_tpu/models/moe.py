@@ -13,14 +13,24 @@ this is green-field TPU design:
   whatever the imbalance. What absent experts would add is left out of
   the sum, and nothing stands in for it;
 - the held experts' three products are ONE grouped matrix product each
-  (``jax.lax.ragged_dot``) over the pairs sorted by expert — work
-  follows the pairs routed here, not tokens x experts. XLA:TPU lowers
-  ``ragged_dot`` to its own Mosaic kernel that walks only the row tiles
-  inside a group, which is why it is used and not a Pallas kernel of
-  this package; the same call runs on the CPU mesh. Shapes stay static:
-  the sorted pair buffer has room for every pair (the worst routing),
-  rows past the routed ones are masked, and ``chunk`` bounds the
-  buffer by walking the tokens in slices;
+  (``jax.lax.ragged_dot``) over the pairs sorted by expert. XLA:TPU
+  lowers ``ragged_dot`` to its own Mosaic kernel that walks only the
+  row tiles inside a group, which is why it is used and not a Pallas
+  kernel of this package; the same call runs on the CPU mesh;
+- work follows the pairs routed here, not tokens x experts: shapes
+  stay static, so the sorted pair buffer has a static number of rows,
+  and that number is sized for the pairs this device can be expected
+  to hold (``compact_rows``: twice what an even router sends to
+  ``n_held`` of ``E`` experts). The held pairs sort first, so the
+  buffer is the head of the sort: token rows gathered, the products,
+  SwiGLU, the gate weighting and the sum back into the tokens all run
+  on its rows, and rows past the held pairs are masked. A layer one of
+  whose slices holds more pairs than that walks its slices through a
+  buffer with a row for EVERY pair (the worst routing) instead, chosen
+  by ``lax.cond`` on the router's own count: the bound costs speed
+  there, never a pair. Where half the experts or more are held the two
+  buffers are the same size and only the full-size path is traced.
+  ``chunk`` bounds either buffer by walking the tokens in slices;
 - expert parallelism = the experts dim sharded over ``ep``: the same
   grouped product sits between two ``lax.all_to_all`` calls (pairs to
   their expert's owner, results back); on one device it runs without
@@ -179,10 +189,60 @@ def _rows_in_order_bwd(k, res, g):
 _rows_in_order.defvjp(_rows_in_order_fwd, _rows_in_order_bwd)
 
 
+def _sorted_pairs(key: jnp.ndarray, n_held: int):
+    """The pairs of ``key`` ([N] int32, ``n_held`` where a pair's expert
+    is not held here) sorted by held expert. Returns (order, inv, load):
+    ``order[r]`` is the pair in sorted row ``r`` (the sort is stable and
+    ``n_held`` sorts last, so the first ``sum(load)`` rows are exactly
+    the held pairs, grouped by expert), ``inv`` its inverse, ``load``
+    [n_held] int32 the pairs per held expert."""
+    N = key.shape[0]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.zeros((N,), jnp.int32).at[order].set(
+        jnp.arange(N, dtype=jnp.int32))
+    load = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                   axis=0, dtype=jnp.int32)
+    return order, inv, load
+
+
+def _dropped_pairs(key, inv, load, rows):
+    """The router's choice against the product's groups: a held pair is
+    computed where its sorted row lies among the rows the grouped
+    product is told to give that pair's expert, inside the ``rows`` the
+    buffer that ran had. Counts the held pairs of which that is not
+    true."""
+    n_held = load.shape[0]
+    end = jnp.cumsum(load)
+    group = jnp.sum(inv[:, None] >= end[None, :], axis=1)
+    held = key < n_held
+    return jnp.sum(held & ((group != key) | (inv >= rows)), dtype=jnp.int32)
+
+
+def _full_rows(x, order, inv, load, k, w_gate, w_up, w_down, dtype):
+    """The products over a buffer with a row for EVERY pair (the worst
+    routing). Returns y [N, d] in pair order, zero rows where the
+    pair's expert is not held."""
+    N = order.shape[0]
+    # rows past the routed pairs belong to no group: the grouped
+    # product may leave them unwritten, so they are masked on the
+    # way in, between the products and on the way out (the
+    # cotangents with them)
+    routed = (jnp.arange(N) < jnp.sum(load))[:, None]
+    xs = jnp.where(routed, _rows_in_order(x, order, inv, k), 0)
+    gate = jax.lax.ragged_dot(xs, w_gate.astype(dtype), load)
+    up = jax.lax.ragged_dot(xs, w_up.astype(dtype), load)
+    h = jnp.where(routed, jax.nn.silu(gate) * up, 0)
+    ys = jnp.where(routed,
+                   jax.lax.ragged_dot(h, w_down.astype(dtype), load), 0)
+    # back to pair order: again a permutation, k = 1
+    return _rows_in_order(ys, inv, order, 1)
+
+
 def grouped_ffn(x: jnp.ndarray, key: jnp.ndarray, k: int, w_gate, w_up,
                 w_down, dtype
                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """SwiGLU of the held experts over the pairs routed to them.
+    """SwiGLU of the held experts over the pairs routed to them, on the
+    full-size buffer.
 
     x [T, d]; ``key`` [T * k] int32, token-major: the held expert (0 ..
     n_held - 1) pair ``t * k + c`` is routed to, or ``n_held`` where
@@ -191,51 +251,144 @@ def grouped_ffn(x: jnp.ndarray, key: jnp.ndarray, k: int, w_gate, w_up,
     pairs per held expert; dropped: the held pairs whose sorted row
     lies outside their expert's group). Nothing is dropped: the sorted
     buffer has a row for every pair."""
-    n_held = w_gate.shape[0]
-    N = key.shape[0]
     with jax.named_scope("bps.moe.experts"):
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
-        inv = jnp.zeros((N,), jnp.int32).at[order].set(
-            jnp.arange(N, dtype=jnp.int32))
-        load = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
-                       axis=0, dtype=jnp.int32)
-        # rows past the routed pairs belong to no group: the grouped
-        # product may leave them unwritten, so they are masked on the
-        # way in, between the products and on the way out (the
-        # cotangents with them)
-        routed = (jnp.arange(N) < jnp.sum(load))[:, None]
-        xs = jnp.where(routed, _rows_in_order(x, order, inv, k), 0)
-        gate = jax.lax.ragged_dot(xs, w_gate.astype(dtype), load)
-        up = jax.lax.ragged_dot(xs, w_up.astype(dtype), load)
-        h = jnp.where(routed, jax.nn.silu(gate) * up, 0)
-        ys = jnp.where(routed,
-                       jax.lax.ragged_dot(h, w_down.astype(dtype), load), 0)
-        # back to pair order: again a permutation, k = 1
-        y = _rows_in_order(ys, inv, order, 1)
-        # the router's choice against the product's groups: a pair is
-        # computed where its sorted row lies among the rows the grouped
-        # product is told to give that pair's expert
-        end = jnp.cumsum(load)
-        group = jnp.sum(inv[:, None] >= end[None, :], axis=1)
-        held = key < n_held
-        dropped = jnp.sum(held & (group != key), dtype=jnp.int32)
+        order, inv, load = _sorted_pairs(key, w_gate.shape[0])
+        y = _full_rows(x, order, inv, load, k, w_gate, w_up, w_down, dtype)
+        dropped = _dropped_pairs(key, inv, load, key.shape[0])
     return y, load, dropped
 
 
-def _held_chunk(x, gates, idx, first, w_gate, w_up, w_down, dtype):
-    """The held experts' part of the layer's output for one slice of
-    tokens: x [T, d], gates/idx [T, k]. Returns (y [T, d], load,
-    dropped)."""
-    T, k = idx.shape
-    n_held = w_gate.shape[0]
-    local = idx - first
-    here = (local >= 0) & (local < n_held)
-    key = jnp.where(here, local, n_held).reshape(-1)
-    y, load, dropped = grouped_ffn(x, key, k, w_gate, w_up, w_down, dtype)
-    w = jnp.where(here, gates, 0.0)
+# The compact buffer has rows for this many times the pairs an even
+# router sends here: an ordinary slice fits it with room to spare, at a
+# quarter of the full buffer's rows where an eighth of the experts is
+# held. A layer with a slice that holds more takes the full-size
+# buffer: the bound costs speed there, never a pair. One compact size,
+# not a ladder of them: every size is one more copy of the grouped
+# products' kernels in the step program (PERF.md section 6, PR 29).
+_COMPACT_OVER_EVEN = 2
+_ROW_TILE = 16          # rows of one packed bfloat16 tile
+
+
+def compact_rows(n_pairs: int, n_held: int, n_experts: int) -> int:
+    """Rows of the compact sorted buffer for a slice of ``n_pairs``
+    pairs on a device that holds ``n_held`` of ``n_experts``: a static
+    function of what the layer is told. ``n_pairs`` (no compact
+    buffer) where half the experts or more are held."""
+    even = -(-n_pairs * n_held // n_experts)
+    rows = -(-_COMPACT_OVER_EVEN * even // _ROW_TILE) * _ROW_TILE
+    return min(n_pairs, rows)
+
+
+def _full_out(x, w, order, inv, load, w_gate, w_up, w_down, dtype):
+    """One slice's output through the full-size buffer: x [T, d], w
+    [T, k] (the gates, 0 where the expert is not held) -> [T, d]."""
+    T, k = w.shape
+    y = _full_rows(x, order, inv, load, k, w_gate, w_up, w_down, dtype)
     out = jnp.einsum("tkd,tk->td", y.reshape(T, k, -1), w.astype(dtype),
                      preferred_element_type=jnp.float32)
-    return out.astype(dtype), load, dropped
+    return out.astype(dtype)
+
+
+def _compact_out(x, w, head, load, w_gate, w_up, w_down, dtype):
+    """The same output through the compact buffer, for a slice that
+    holds no more pairs than it has rows: ``head`` [C] is the head of
+    the sort, the held pairs first and by expert. Token rows gathered,
+    the three products, the gate weighting and the sum back into the
+    tokens all run on ``C`` rows; only index vectors are ``T * k``
+    long."""
+    T, k = w.shape
+    tok = head // k
+    held = (jnp.arange(head.shape[0]) < jnp.sum(load))[:, None]
+    # masked as on the full-size path: rows past the held pairs are in
+    # no group
+    xs = jnp.where(held, x[tok], 0)
+    gate = jax.lax.ragged_dot(xs, w_gate.astype(dtype), load)
+    up = jax.lax.ragged_dot(xs, w_up.astype(dtype), load)
+    h = jnp.where(held, jax.nn.silu(gate) * up, 0)
+    ys = jnp.where(held,
+                   jax.lax.ragged_dot(h, w_down.astype(dtype), load), 0)
+    # the gates' product and sum in float32, as the full path's einsum
+    gate_of = w.reshape(-1)[head].astype(dtype).astype(jnp.float32)
+    out = jax.ops.segment_sum(ys.astype(jnp.float32) * gate_of[:, None],
+                              tok, num_segments=T)
+    return out.astype(dtype)
+
+
+def _held_here(idx, first, n_held):
+    """[T, k] bool: the router's choices that fall on the ``n_held``
+    experts from ``first``, and their index among those."""
+    local = idx - first
+    return (local >= 0) & (local < n_held), local
+
+
+def _held_chunk(x, gates, idx, first, w_gate, w_up, w_down, dtype, rows):
+    """The held experts' part of the layer's output for one slice of
+    tokens, through a sorted buffer of ``rows`` rows (every pair's, or
+    ``compact_rows``): x [T, d], gates/idx [T, k]. Returns (y [T, d],
+    load, dropped)."""
+    T, k = idx.shape
+    n_held = w_gate.shape[0]
+    here, local = _held_here(idx, first, n_held)
+    key = jnp.where(here, local, n_held).reshape(-1)
+    w = jnp.where(here, gates, 0.0)
+    leaves = (w_gate, w_up, w_down, dtype)
+    with jax.named_scope("bps.moe.experts"):
+        order, inv, load = _sorted_pairs(key, n_held)
+        out = _full_out(x, w, order, inv, load, *leaves) if rows == T * k \
+            else _compact_out(x, w, order[:rows], load, *leaves)
+        dropped = _dropped_pairs(key, inv, load, rows)
+    return out, load, dropped
+
+
+def _held_slices(x, gates, idx, w_gate, w_up, w_down, *, first, dtype, n,
+                 rows):
+    """The held experts' part of the layer for all its tokens, walked
+    in ``n`` slices, each through a sorted buffer of ``rows`` rows: x
+    [T, d], gates/idx [T, k]. Returns (y [T, d], load, dropped)."""
+    T, d = x.shape
+    held = (first, w_gate, w_up, w_down, dtype, rows)
+    if n == 1:
+        return _held_chunk(x, gates, idx, *held)
+
+    @jax.checkpoint
+    def one(args):
+        return _held_chunk(*args, *held)
+
+    out, loads, drops = jax.lax.map(one, (
+        x.reshape(n, T // n, d), gates.reshape(n, T // n, -1),
+        idx.reshape(n, T // n, -1)))
+    return out.reshape(T, d), jnp.sum(loads, axis=0), jnp.sum(drops)
+
+
+def _held(x, gates, idx, first, n_experts, w_gate, w_up, w_down, dtype, n):
+    """``_held_slices`` through the buffer the routing allows. The
+    sorted buffer is sized for the pairs this device can be expected to
+    hold (``compact_rows``); a layer with a slice that holds more walks
+    its slices through the full-size one, chosen by the count of held
+    pairs in the router's choice. Returns (y, load, dropped, the
+    slices that went through the compact buffer: ``n`` or 0).
+
+    The conditional sits around the walk, not inside it: the expert
+    leaves' cotangents then leave a branch as the walk's finished sums;
+    a conditional a slice returns them a slice, 0.2 GB of float32
+    beside the sums they are added to. Each branch is a
+    ``jax.checkpoint`` of its own: a differentiated ``cond`` returns
+    both branches' residuals and zero-fills the untaken one's; this
+    way the residuals are the operands."""
+    T, k = idx.shape
+    n_held = w_gate.shape[0]
+    N = T // n * k
+    C = compact_rows(N, n_held, n_experts)
+    walk = functools.partial(_held_slices, first=first, dtype=dtype, n=n)
+    operands = (x, gates, idx, w_gate, w_up, w_down)
+    if C == N:                              # one path, at trace time
+        return *walk(*operands, rows=N), jnp.zeros((), jnp.int32)
+    pairs = jnp.sum(_held_here(idx, first, n_held)[0].reshape(n, -1), axis=1)
+    fits = jnp.all(pairs <= C)
+    return *jax.lax.cond(
+        fits, jax.checkpoint(functools.partial(walk, rows=C)),
+        jax.checkpoint(functools.partial(walk, rows=N)), *operands), \
+        n * fits.astype(jnp.int32)
 
 
 def _exchanged(x, gates, idx, w_gate, w_up, w_down, dtype, ep_axis):
@@ -300,7 +453,11 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
     router sent to a held expert whose sorted row lies outside that
     expert's group of the product: 0, there is no capacity; it checks
     the sort's bookkeeping, the arithmetic is ``correct``'s to check),
-    ``aux`` (the Switch balancing loss, for models that use it).
+    ``compact_slices`` and ``full_slices`` (the layer's slices, all on
+    one counter or the other: walked through the compact sorted buffer,
+    ``compact_rows``, or through the full-size one, as every slice is
+    where half the experts or more are held), ``aux`` (the Switch
+    balancing loss, for models that use it).
     """
     B, S, d = x.shape
     T = B * S
@@ -308,27 +465,23 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
     gates, idx, probs = route(x_flat, p["router"], top_k, router_dtype)
     w = (p["w_gate"], p["w_up"], p["w_down"])
     if ep_axis is not None:
+        n = 1
         out, load, dropped = _exchanged(x_flat, gates, idx, *w, dtype,
                                         ep_axis)
-    elif chunk is None or chunk >= T:
-        out, load, dropped = _held_chunk(x_flat, gates, idx, first, *w,
-                                         dtype)
-    elif T % chunk:
-        raise ValueError(f"{T} tokens do not divide into slices of {chunk}")
+        compact = jnp.zeros((), jnp.int32)
     else:
-        n = T // chunk
-
-        @jax.checkpoint
-        def one(args):
-            return _held_chunk(*args, first, *w, dtype)
-
-        out, loads, drops = jax.lax.map(one, (
-            x_flat.reshape(n, chunk, d), gates.reshape(n, chunk, top_k),
-            idx.reshape(n, chunk, top_k)))
-        out, load, dropped = (out.reshape(T, d), jnp.sum(loads, axis=0),
-                              jnp.sum(drops))
-    return out.reshape(B, S, d), {"load": load, "dropped": dropped,
-                                  "aux": switch_aux_loss(probs, idx)}
+        if chunk is None or chunk >= T:
+            n = 1
+        elif T % chunk:
+            raise ValueError(
+                f"{T} tokens do not divide into slices of {chunk}")
+        else:
+            n = T // chunk
+        out, load, dropped, compact = _held(
+            x_flat, gates, idx, first, p["router"].shape[-1], *w, dtype, n)
+    return out.reshape(B, S, d), {
+        "load": load, "dropped": dropped, "compact_slices": compact,
+        "full_slices": n - compact, "aux": switch_aux_loss(probs, idx)}
 
 
 # --------------------------------------------------------------------- #

@@ -212,3 +212,97 @@ def test_untapped_scatter_backward_compiles_for_a_v5e_host(v5e_host):
     out = compiled.memory_analysis().output_size_in_bytes
     want = sum(b // n if i in shard_set else b for i, b in enumerate(nbytes))
     assert want <= out <= want + (1 << 20)
+
+
+def _computations(text):
+    """{name: body lines} of a compiled module's HLO text."""
+    out, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            name = head.group(1)
+            out[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            out[name].append(line)
+    return out
+
+
+def _reached(comps, root):
+    """The text of ``root`` and of every computation it calls."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in comps:
+            continue
+        seen.add(name)
+        body = "\n".join(comps[name])
+        todo += re.findall(
+            r"(?:calls|to_apply|body|condition|true_computation|"
+            r"false_computation)=%?([\w.\-]+)", body)
+        for group in re.findall(r"branch_computations=\{([^}]*)\}", body):
+            todo += [n.strip().lstrip("%") for n in group.split(",")]
+    return "\n".join("\n".join(comps[n]) for n in seen)
+
+
+def test_the_sparse_decoders_block_walks_a_compact_buffer_on_a_v5e(
+        v5e, monkeypatch):
+    """One block of the benchmark's sparse decoder, forward and backward
+    under its remat, at published widths (4 rows of 8192 tokens, 8 of 64
+    experts held, 8 a token): the expert layer is a conditional of two
+    walks, one whose grouped products see the compact buffer's 16,384
+    rows a slice and which holds no array of a slice's 65,536 pairs by
+    the hidden or the expert width, one that is the full-size path."""
+    import dataclasses
+
+    from byteps_tpu.models import mellum, moe
+
+    # the attention kernels, as on the chip (the process's backend is
+    # the CPU here)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(mellum.MellumConfig(), n_experts_held=8,
+                              n_layers=1, layer_types=(mellum.SLIDING,))
+    rows, seq = 4, 8192
+    pairs = mellum.EXPERT_SLICE * cfg.top_k
+    compact = moe.compact_rows(pairs, 8, cfg.n_experts)
+    assert (pairs, compact) == (65536, 16384)
+    layer = jax.tree.map(
+        lambda v: _sds(v.shape[1:], v.dtype, v5e),
+        jax.eval_shape(lambda: mellum.init_params(
+            jax.random.PRNGKey(0), cfg))["blocks"])
+    block = jax.checkpoint(mellum._block, static_argnums=(3, 4, 5))
+
+    def loss(x, p):
+        out, stats = block(x, p, mellum.rope_tables(cfg, seq), cfg,
+                           mellum.SLIDING, None)
+        return jnp.sum(out.astype(jnp.float32)), stats
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+                   ).lower(_sds((rows, seq, cfg.dim), jnp.bfloat16, v5e),
+                           layer).compile().as_text()
+    comps = _computations(text)
+    full_rows = re.compile(r"\[%d,(?:%d|%d)\]" % (
+        pairs, cfg.dim, cfg.expert_hidden))
+    compact_rows = re.compile(r"\[%d,(?:%d|%d)\]" % (
+        compact, cfg.dim, cfg.expert_hidden))
+    walks = []
+    for lines in comps.values():
+        for line in lines:
+            if " conditional(" not in line:
+                continue
+            names = re.findall(r"(?:true|false)_computation=%?([\w.\-]+)",
+                               line) or [
+                n.strip().lstrip("%") for n in re.search(
+                    r"branch_computations=\{([^}]*)\}", line).group(1)
+                .split(",")]
+            branches = [_reached(comps, n) for n in names]
+            if any("ragged-dot" in b for b in branches):
+                walks.append(branches)
+    # the forward's conditional and the backward's
+    assert len(walks) >= 2
+    for branches in walks:
+        kinds = sorted((bool(full_rows.search(b)),
+                        bool(compact_rows.search(b))) for b in branches)
+        assert kinds == [(False, True), (True, False)], kinds
+    assert "tpu_custom_call" in text

@@ -56,8 +56,11 @@ def _reference_loss(cfg):
     return loss
 
 
-def test_loss_and_every_leafs_gradient_match_the_reference():
-    cfg = _config()
+@pytest.mark.parametrize("held, compact", [(4, False), (2, True)])
+def test_loss_and_every_leafs_gradient_match_the_reference(held, compact):
+    """Half the experts held: the full-size sorted buffer is the only
+    one; a quarter: every layer's one slice fits the compact buffer."""
+    cfg = _config(num_experts_held=held)
     params, batch = _state(cfg)
     with jax.default_matmul_precision("highest"):
         (loss, stats), grads = jax.value_and_grad(
@@ -69,10 +72,13 @@ def test_loss_and_every_leafs_gradient_match_the_reference():
         np.testing.assert_allclose(
             np.asarray(got[path]), np.asarray(w), rtol=2e-3,
             atol=1e-6 + 1e-4 * float(jnp.abs(w).max()), err_msg=str(path))
-    # 2 rows x 64 tokens x 4 layers x top-2, about half of them held
+    # 2 rows x 64 tokens x 4 layers x top-2, about held / 8 of them held
     load = np.asarray(stats["moe/expert_load"])
-    assert load.shape == (4, 4) and 0 < load.sum() < 2 * 64 * 4 * 2
+    assert load.shape == (4, held) and 0 < load.sum() < 2 * 64 * 4 * 2
     assert int(stats["moe/dropped_pairs"]) == 0
+    # one slice a layer, four layers
+    assert (int(stats["moe/compact_slices"]), int(stats["moe/full_slices"])) \
+        == ((4, 0) if compact else (0, 4))
 
 
 def test_window_layers_differ_from_full_ones():
@@ -193,12 +199,17 @@ def _one_device_mesh():
     return Mesh(np.array(jax.devices()[:1]), ("dp",))
 
 
-def test_ps_step_matches_the_reference_and_folds_the_statistics():
+@pytest.mark.parametrize("held", [4, 2])
+def test_ps_step_matches_the_reference_and_folds_the_statistics(
+        held, monkeypatch):
     """Loss and the first applied gradient through ``bps.init()`` ->
     ``make_ps_train_step`` -> a loopback server, against the reference;
-    the ``moe/*`` counters are in the registry when the step returns and
-    no host callback is in the step program."""
-    cfg = _config()
+    the ``moe/*`` counters are in the registry when the step returns:
+    the load, and the slices by the sorted buffer they went through
+    (two slices a layer; the full-size one where half the experts are
+    held, the compact one where a quarter are)."""
+    monkeypatch.setattr(mellum, "EXPERT_SLICE", 64)
+    cfg = _config(num_experts_held=held)
     params, batch = _state(cfg)
     want, want_grads = jax.value_and_grad(_reference_loss(cfg))(params, batch)
     loss_fn = family.program_loss(cfg)
@@ -225,6 +236,11 @@ def test_ps_step_matches_the_reference_and_folds_the_statistics():
         name = f"moe/expert_load/{layer}/{expert}"
         assert after[name] - before.get(name, 0) == pairs, name
     assert after["moe/dropped_pairs"] - before.get("moe/dropped_pairs", 0) == 0
+    slices = {name: after[f"moe/{name}_slices"]
+              - before.get(f"moe/{name}_slices", 0)
+              for name in ("compact", "full")}
+    assert slices == ({"compact": 0, "full": 8} if held == 4
+                      else {"compact": 8, "full": 0})
 
 
 def test_no_host_callback_enters_the_step_programs():
@@ -243,7 +259,7 @@ def test_fused_step_folds_the_statistics_one_step_late():
     it has just queued; ``fold_stats`` folds the last."""
     from byteps_tpu.core.state import get_state
 
-    cfg = _config()
+    cfg = _config(num_experts_held=2)
     params, batch = _state(cfg)
     loss_fn = family.program_loss(cfg)
     tx = optax.sgd(0.0)
@@ -257,6 +273,9 @@ def test_fused_step_folds_the_statistics_one_step_late():
     assert pairs > 0
     routed = get_state().metrics.counter("moe/expert_load/0/0")
     start = routed.value
+    by_buffer = [get_state().metrics.counter(f"moe/{name}_slices")
+                 for name in ("compact", "full")]
+    slices = sum(c.value for c in by_buffer)
     opt = tx.init(params)
     _, _, loss = step(params, opt, batch)
     np.testing.assert_allclose(float(loss), float(want), rtol=1e-6)
@@ -265,6 +284,8 @@ def test_fused_step_folds_the_statistics_one_step_late():
     assert routed.value - start == pairs
     step.fold_stats()
     assert routed.value - start == 2 * pairs
+    # a slice a layer, four layers, two steps: each on one buffer
+    assert sum(c.value for c in by_buffer) - slices == 2 * 4
 
 
 @pytest.mark.parametrize("maker", ["fused", "ps"])

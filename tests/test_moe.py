@@ -301,3 +301,286 @@ def test_uneven_routing_through_the_exchange(devices):
     np.testing.assert_allclose(
         np.asarray(out).reshape(-1, cfg.dim),
         _oracle(x, p, cfg.top_k, list(range(8))), rtol=1e-4, atol=1e-5)
+
+
+# --------------------------------------------------------------------- #
+# the compact sorted buffer and its full-size fallback
+# --------------------------------------------------------------------- #
+
+def _parent_grouped_ffn(x, key, k, w_gate, w_up, w_down, dtype):
+    """``grouped_ffn`` as it stood before the compact buffer (PR 28),
+    copied: what the full-size path is held to, bit for bit."""
+    n_held = w_gate.shape[0]
+    N = key.shape[0]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    inv = jnp.zeros((N,), jnp.int32).at[order].set(
+        jnp.arange(N, dtype=jnp.int32))
+    load = jnp.sum(key[:, None] == jnp.arange(n_held)[None, :],
+                   axis=0, dtype=jnp.int32)
+    routed = (jnp.arange(N) < jnp.sum(load))[:, None]
+    xs = jnp.where(routed, moe._rows_in_order(x, order, inv, k), 0)
+    gate = jax.lax.ragged_dot(xs, w_gate.astype(dtype), load)
+    up = jax.lax.ragged_dot(xs, w_up.astype(dtype), load)
+    h = jnp.where(routed, jax.nn.silu(gate) * up, 0)
+    ys = jnp.where(routed,
+                   jax.lax.ragged_dot(h, w_down.astype(dtype), load), 0)
+    y = moe._rows_in_order(ys, inv, order, 1)
+    end = jnp.cumsum(load)
+    group = jnp.sum(inv[:, None] >= end[None, :], axis=1)
+    held = key < n_held
+    dropped = jnp.sum(held & (group != key), dtype=jnp.int32)
+    return y, load, dropped
+
+
+def _parent_held_chunk(x, gates, idx, first, w_gate, w_up, w_down, dtype):
+    """``_held_chunk`` of PR 28, copied."""
+    T, k = idx.shape
+    n_held = w_gate.shape[0]
+    local = idx - first
+    here = (local >= 0) & (local < n_held)
+    key = jnp.where(here, local, n_held).reshape(-1)
+    y, load, dropped = _parent_grouped_ffn(x, key, k, w_gate, w_up, w_down,
+                                           dtype)
+    w = jnp.where(here, gates, 0.0)
+    out = jnp.einsum("tkd,tk->td", y.reshape(T, k, -1), w.astype(dtype),
+                     preferred_element_type=jnp.float32)
+    return out.astype(dtype), load, dropped
+
+
+def _slice(top_k, held_pairs=None, tokens=32, n_experts=16, n_held=2,
+           seed=11):
+    """One slice's inputs on a device that holds ``n_held`` of
+    ``n_experts``: x [T, d], gates/idx [T, k] and the expert leaves.
+    The router's choice is random, or hand-made so that exactly
+    ``held_pairs`` pairs go to held experts (the first tokens' first
+    choices, as many a token as there are held experts)."""
+    cfg = _cfg(n_experts=n_experts, top_k=top_k)
+    p = _share(_layer0(moe.init_params(jax.random.PRNGKey(0), cfg)),
+               0, n_held)
+    key = jax.random.PRNGKey(seed)
+    x = jax.random.normal(key, (tokens, cfg.dim), jnp.float32)
+    if held_pairs is None:
+        gates, idx, _ = moe.route(x, p["router"], top_k)
+    else:
+        a_token = min(top_k, n_held)
+        idx = np.tile(np.arange(n_held, n_held + top_k), (tokens, 1))
+        for pair in range(held_pairs):
+            idx[pair // a_token, pair % a_token] = pair % a_token
+        assert (idx < n_held).sum() == held_pairs
+        idx = jnp.asarray(idx, jnp.int32)
+        gates = jax.nn.softmax(jax.random.normal(
+            jax.random.fold_in(key, 1), (tokens, top_k)), axis=-1)
+    return x, gates, idx, [p[name] for name in moe.EXPERT_LEAVES]
+
+
+def _value_and_grads(fn, x, gates, leaves):
+    """(output, load, dropped) and the gradients of a fixed projection
+    of the output with respect to x, the gates and the expert leaves."""
+    cot = jax.random.normal(jax.random.PRNGKey(5), x.shape, x.dtype)
+
+    def f(x, gates, leaves):
+        out, load, dropped = fn(x, gates, *leaves)
+        return jnp.sum(out * cot), (out, load, dropped)
+
+    (_, aux), grads = jax.value_and_grad(f, argnums=(0, 1, 2),
+                                         has_aux=True)(x, gates, leaves)
+    return aux, jax.tree.leaves(grads)
+
+
+@pytest.mark.parametrize("top_k", [2, 4])
+def test_compact_buffer_against_the_full_one(top_k):
+    """One slice under the bound, through both buffers: output, load,
+    dropped and the gradients with respect to x, the gates and the
+    three expert leaves. The products see the same rows in the same
+    groups, so the leaves' gradients agree bit for bit; a token's terms
+    are summed in sorted order on one path and in pair order on the
+    other, and a gate's gradient is a sum over the width by another
+    operation: float32's rounding apart."""
+    x, gates, idx, leaves = _slice(top_k)
+    N = idx.size
+    C = moe.compact_rows(N, 2, 16)
+    held = int((np.asarray(idx) < 2).sum())
+    assert 0 < held <= C == N // 4
+
+    def through(rows):
+        return _value_and_grads(
+            lambda x, g, *w: moe._held_chunk(x, g, idx, 0, *w, jnp.float32,
+                                             rows), x, gates, leaves)
+
+    (out_c, load_c, drop_c), grads_c = through(C)
+    (out_f, load_f, drop_f), grads_f = through(N)
+    np.testing.assert_array_equal(np.asarray(load_c), np.asarray(load_f))
+    assert int(drop_c) == int(drop_f) == 0 and int(load_c.sum()) == held
+    np.testing.assert_allclose(np.asarray(out_c), np.asarray(out_f),
+                               rtol=1e-6, atol=1e-7)
+    for a, b in zip(grads_c[:2], grads_f[:2]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+    for a, b in zip(grads_c[2:], grads_f[2:]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # and both are the layer: the float64 oracle of the held terms
+    want = np.zeros(x.shape, np.float64)
+    xf = np.asarray(x, np.float64)
+    for t in range(x.shape[0]):
+        for g, e in zip(np.asarray(gates)[t], np.asarray(idx)[t]):
+            if e < 2:
+                gate = xf[t] @ np.asarray(leaves[0][e], np.float64)
+                up = xf[t] @ np.asarray(leaves[1][e], np.float64)
+                want[t] += g * ((gate / (1 + np.exp(-gate)) * up)
+                                @ np.asarray(leaves[2][e], np.float64))
+    np.testing.assert_allclose(np.asarray(out_c), want, rtol=1e-4, atol=1e-5)
+
+
+def test_the_full_buffer_is_the_parents_arithmetic():
+    """The full-size path is PR 28's ``grouped_ffn`` and ``_held_chunk``
+    unchanged: same outputs and same gradients, bit for bit, for the
+    layer's own path and for ``grouped_ffn`` (the exchange's)."""
+    x, gates, idx, leaves = _slice(4)
+    N = idx.size
+    got, got_grads = _value_and_grads(
+        lambda x, g, *w: moe._held_chunk(x, g, idx, 0, *w, jnp.float32, N),
+        x, gates, leaves)
+    want, want_grads = _value_and_grads(
+        lambda x, g, *w: _parent_held_chunk(x, g, idx, 0, *w, jnp.float32),
+        x, gates, leaves)
+    for a, b in zip(jax.tree.leaves((got, got_grads)),
+                    jax.tree.leaves((want, want_grads))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    key = jnp.where(idx < 2, idx, 2).reshape(-1)
+    for a, b in zip(moe.grouped_ffn(x, key, 4, *leaves, jnp.float32),
+                    _parent_grouped_ffn(x, key, 4, *leaves, jnp.float32)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _held_layer(x, gates, idx, leaves, n=1):
+    """``moe._held`` on a device holding experts 0, 1 of 16: the walk of
+    ``n`` slices behind the conditional."""
+    return moe._held(x, gates, idx, 0, 16, *leaves, jnp.float32, n)
+
+
+@pytest.mark.parametrize("held_pairs", [16, 17, 64])
+def test_a_slice_at_the_bound_and_one_pair_over(held_pairs):
+    """Exactly ``compact_rows`` held pairs go through the compact
+    buffer, one more (and every pair) through the full-size one; both
+    compute every pair (a buffer too small would show as pairs
+    dropped) and give the parent's result."""
+    C = moe.compact_rows(32 * 2, 2, 16)
+    assert C == 16
+    x, gates, idx, leaves = _slice(2, held_pairs=held_pairs)
+    out, load, dropped, compact = _held_layer(x, gates, idx, leaves)
+    assert int(compact) == (held_pairs <= C)
+    assert int(load.sum()) == held_pairs and int(dropped) == 0
+    want, want_load, _ = _parent_held_chunk(x, gates, idx, 0, *leaves,
+                                            jnp.float32)
+    np.testing.assert_array_equal(np.asarray(load), np.asarray(want_load))
+    if held_pairs > C:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    else:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=1e-6, atol=1e-7)
+
+
+def test_every_pair_to_held_experts_takes_the_full_buffer():
+    """A router that sends every token's every choice to the two held
+    experts: twice the compact buffer's rows, so the layer walks its
+    slices through the full-size one, drops nothing and returns the
+    parent's result, gradients included (to float32's rounding: the
+    walk is a loop here and unrolled there; bit for bit is
+    ``test_the_full_buffer_is_the_parents_arithmetic``)."""
+    cfg = _cfg(n_experts=16, top_k=2)
+    p = _share(_layer0(moe.init_params(jax.random.PRNGKey(0), cfg)), 0, 2)
+    r = np.zeros((cfg.dim, 16), np.float32)
+    r[0, :2] = 50.0
+    p["router"] = p["router"] * 0.01 + jnp.asarray(r)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(4), (2, 16, cfg.dim),
+                                  jnp.float32)) + 0.1
+
+    def layer(x, p):
+        out, st = moe.moe_layer(x, p, 2, jnp.float32, chunk=16)
+        return jnp.sum(out ** 2), (out, st)
+
+    (_, (out, st)), grads = jax.value_and_grad(layer, argnums=(0, 1),
+                                               has_aux=True)(x, p)
+    assert moe.compact_rows(16 * 2, 2, 16) == 16
+    assert int(st["compact_slices"]) == 0 and int(st["full_slices"]) == 2
+    assert int(st["load"].sum()) == 2 * 16 * 2 and int(st["dropped"]) == 0
+
+    def parent(x, p):
+        xf = x.reshape(-1, cfg.dim)
+        gates, idx, _ = moe.route(xf, p["router"], 2)
+        outs = [_parent_held_chunk(xf[s:s + 16], gates[s:s + 16],
+                                   idx[s:s + 16], 0, p["w_gate"], p["w_up"],
+                                   p["w_down"], jnp.float32)[0]
+                for s in range(0, 32, 16)]
+        out = jnp.concatenate(outs).reshape(x.shape)
+        return jnp.sum(out ** 2), out
+
+    (_, want), want_grads = jax.value_and_grad(parent, argnums=(0, 1),
+                                               has_aux=True)(x, p)
+    for a, b in zip(jax.tree.leaves((out, grads)),
+                    jax.tree.leaves((want, want_grads))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(out).reshape(-1, cfg.dim),
+        _oracle(x, p, 2, [0, 1]), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("n_held, conditional", [
+    (16, False), (8, False), (4, True), (2, True)])
+def test_the_conditional_is_there_only_where_a_compact_buffer_is(
+        n_held, conditional):
+    """Where every expert is held (the Mixtral model on one device), or
+    half of them, the compact buffer would be the full-size one: the
+    choice is made at trace time and the program holds no
+    conditional."""
+    cfg = _cfg(n_experts=16, top_k=2)
+    p = _share(_layer0(moe.init_params(jax.random.PRNGKey(0), cfg)),
+               0, n_held)
+    x = jnp.ones((2, 16, cfg.dim), jnp.float32)
+    assert (moe.compact_rows(32, n_held, 16) < 32) == conditional
+
+    def layer(x, p):
+        return moe.moe_layer(x, p, 2, jnp.float32, chunk=16)[0]
+
+    text = jax.jit(jax.grad(lambda x, p: jnp.sum(layer(x, p)))).lower(
+        x, p).as_text()
+    assert ("stablehlo.case" in text or "stablehlo.if" in text) \
+        == conditional
+    _, st = moe.moe_layer(x, p, 2, jnp.float32, chunk=16)
+    if not conditional:
+        assert int(st["compact_slices"]) == 0 and int(st["full_slices"]) == 2
+
+
+@pytest.mark.parametrize("chunk, favourite", [
+    (None, None), (16, None), (32, None), (16, 0), (None, 0)])
+def test_every_slice_is_counted_on_one_buffer_or_the_other(chunk, favourite):
+    """``compact_slices + full_slices`` is the number of slices, under
+    an ordinary routing (compact) and under one that overfills a slice
+    (full)."""
+    cfg = _cfg(n_experts=16, top_k=2)
+    p = _share(_layer0(moe.init_params(jax.random.PRNGKey(0), cfg)), 0, 2)
+    x = jax.random.normal(jax.random.PRNGKey(9), (2, 32, cfg.dim),
+                          jnp.float32)
+    if favourite is not None:
+        r = np.zeros((cfg.dim, 16), np.float32)
+        r[0, :2] = 50.0
+        p["router"] = p["router"] * 0.01 + jnp.asarray(r)
+        x = jnp.abs(x) + 0.1
+    _, st = moe.moe_layer(x, p, 2, jnp.float32, chunk=chunk)
+    slices = 1 if chunk is None else 64 // chunk
+    compact, full = int(st["compact_slices"]), int(st["full_slices"])
+    assert compact + full == slices
+    assert (compact, full) == ((0, slices) if favourite is not None
+                               else (slices, 0))
+    assert int(st["dropped"]) == 0
+
+
+def test_dropped_sees_a_compact_buffer_cut_short():
+    """On the compact path the counter also holds the sort against the
+    buffer's rows: a bound that lets more pairs in than it has rows
+    for shows as pairs dropped."""
+    x, gates, idx, leaves = _slice(2, held_pairs=20)
+    _, load, dropped = moe._held_chunk(x, gates, idx, 0, *leaves,
+                                       jnp.float32, 16)
+    assert int(load.sum()) == 20 and int(dropped) == 4
