@@ -144,19 +144,52 @@ def param_count(params: Dict[str, Any]) -> int:
 # --------------------------------------------------------------------- #
 
 def route(x_flat: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
-          router_dtype: Any = jnp.float32):
+          router_dtype: Any = jnp.float32, score: str = "softmax",
+          select_bias: Optional[jnp.ndarray] = None,
+          norm_eps: float = 0.0, scale: float = 1.0):
     """Top-k routing over ALL experts. x_flat [T, d], router_w [d, E].
     Returns (gates [T, k] f32, renormalised to sum to one; idx [T, k]
-    int32; probs [T, E] f32). ``router_dtype`` is the matmul's and the
-    softmax's type: float32, always, outside a precision control."""
+    int32; probs [T, E] f32: every expert's score). ``router_dtype`` is
+    the matmul's and the score function's type: float32, always,
+    outside a precision control.
+
+    ``score``: ``"softmax"`` over the experts, or an independent
+    ``"sigmoid"`` an expert. ``select_bias`` [E] is added to the scores
+    for the SELECTION only: the k experts are the largest of ``score +
+    select_bias``, their gates are the scores without it (a balancing
+    buffer, no parameter: it gets no gradient). The gates are divided
+    by their sum plus ``norm_eps`` and multiplied by ``scale``. The
+    defaults are softmax top-k renormalised, operation for operation
+    what this function was before it had these arguments."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"score {score!r}: 'softmax' or 'sigmoid'")
     with jax.named_scope("bps.moe.route"):
         logits = jnp.matmul(x_flat.astype(router_dtype),
                             router_w.astype(router_dtype),
                             preferred_element_type=router_dtype)
-        probs = jax.nn.softmax(logits, axis=-1).astype(jnp.float32)
-        gates, idx = jax.lax.top_k(probs, top_k)
-        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        probs = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+                 else jax.nn.sigmoid(logits)).astype(jnp.float32)
+        if select_bias is None:
+            gates, idx = jax.lax.top_k(probs, top_k)
+        else:
+            _, idx = jax.lax.top_k(
+                probs + jax.lax.stop_gradient(
+                    select_bias.astype(jnp.float32)), top_k)
+            gates = jnp.take_along_axis(probs, idx, axis=-1)
+        total = jnp.sum(gates, axis=-1, keepdims=True)
+        gates = gates / (total + norm_eps if norm_eps else total)
+        if scale != 1.0:
+            gates = gates * scale
     return gates, idx.astype(jnp.int32), probs
+
+
+def bias_moved_pairs(probs: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """The selection bias at work: the (token, slot) pairs of ``idx``
+    [T, k] whose expert is not among the token's ``k`` largest of
+    ``probs`` [T, E] alone. int32 scalar; 0 where no bias was added."""
+    _, plain = jax.lax.top_k(probs, idx.shape[-1])
+    kept = jnp.any(idx[:, :, None] == plain[:, None, :], axis=-1)
+    return jnp.sum(~kept, dtype=jnp.int32)
 
 
 def switch_aux_loss(probs: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
@@ -433,7 +466,7 @@ def _exchanged(x, gates, idx, w_gate, w_up, w_down, dtype, ep_axis):
 def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
               dtype: Any, first: int = 0, ep_axis: Optional[str] = None,
               chunk: Optional[int] = None,
-              router_dtype: Any = jnp.float32
+              router_dtype: Any = jnp.float32, **routing
               ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """The MoE FFN of the experts this device holds.
 
@@ -447,6 +480,8 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
     ``all_to_all``. ``chunk``: tokens per slice of the grouped product
     (bounds the sorted pair buffer at ``chunk * top_k`` rows); it must
     divide the tokens where there are more of them than a slice.
+    ``routing``: ``route``'s ``score``, ``select_bias``, ``norm_eps``
+    and ``scale``; none is softmax top-k renormalised.
 
     Returns (output [B, S, d], stats): ``load`` [n_held] int32 (pairs
     per held expert in the grouped product), ``dropped`` (pairs the
@@ -457,12 +492,14 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
     one counter or the other: walked through the compact sorted buffer,
     ``compact_rows``, or through the full-size one, as every slice is
     where half the experts or more are held), ``aux`` (the Switch
-    balancing loss, for models that use it).
+    balancing loss, for models that use it; softmax routing only), and
+    with a ``select_bias`` ``bias_moved`` (``bias_moved_pairs``).
     """
     B, S, d = x.shape
     T = B * S
     x_flat = x.reshape(T, d)
-    gates, idx, probs = route(x_flat, p["router"], top_k, router_dtype)
+    gates, idx, probs = route(x_flat, p["router"], top_k, router_dtype,
+                              **routing)
     w = (p["w_gate"], p["w_up"], p["w_down"])
     if ep_axis is not None:
         n = 1
@@ -479,9 +516,13 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
             n = T // chunk
         out, load, dropped, compact = _held(
             x_flat, gates, idx, first, p["router"].shape[-1], *w, dtype, n)
-    return out.reshape(B, S, d), {
-        "load": load, "dropped": dropped, "compact_slices": compact,
-        "full_slices": n - compact, "aux": switch_aux_loss(probs, idx)}
+    stats = {"load": load, "dropped": dropped, "compact_slices": compact,
+             "full_slices": n - compact}
+    if routing.get("score", "softmax") == "softmax":
+        stats["aux"] = switch_aux_loss(probs, idx)
+    if routing.get("select_bias") is not None:
+        stats["bias_moved"] = bias_moved_pairs(probs, idx)
+    return out.reshape(B, S, d), stats
 
 
 # --------------------------------------------------------------------- #

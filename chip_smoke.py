@@ -279,36 +279,42 @@ def check_kernels(rehearse: bool) -> None:
             raise AssertionError(
                 f"flash_fwd hd={D}: max |err| {err} vs blockwise")
         log(f"  flash_fwd[hd={D}] max |err| vs blockwise = {err:.3e}")
-    # sliding-window forward and the two backward kernels (dK/dV, dQ),
-    # grouped heads, against the blockwise path and its vjp
-    B, S, H, D, hkv, win = (1, 128, 4, 128, 2, 48) if rehearse \
-        else (2, 2048, 8, 128, 2, 640)
-    blk = min(512, S) if not rehearse else 32
-    kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(SEED + 7), 4)
-    q = jax.random.normal(kq, (B, S, H, D), jnp.bfloat16)
-    kx = jax.random.normal(kk, (B, S, hkv, D), jnp.bfloat16)
-    vx = jax.random.normal(kv, (B, S, hkv, D), jnp.bfloat16)
-    g = jax.random.normal(kg, (B, S, H, D), jnp.bfloat16)
-    out, lse = timed(f"flash_fwd[window={win}]", lambda: fa._flash_fwd(
-        q, kx, vx, True, blk, blk, interpret=interp, window=win,
-        with_lse=True))
-    grads = timed(f"flash_bwd[window={win}]", lambda: fa._flash_bwd(
-        q, kx, vx, out, lse, g, True, blk, blk, win, interpret=interp))
-    want_o, vjp = jax.vjp(lambda a, b, c: fa.blockwise_attention(
-        a, b, c, causal=True, block_k=blk, window=win, block_q=blk),
-        q, kx, vx)
-    for name, got, want in zip(("out", "dq", "dk", "dv"),
-                               (out,) + tuple(grads), (want_o,) + vjp(g)):
-        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
-        # bf16 results of f32 sums; dk and dv sum a group's heads
-        err = float(jnp.max(jnp.abs(got - want))
-                    / jnp.maximum(jnp.max(jnp.abs(want)), 1e-6))
-        if not (np.isfinite(err) and err <= 3e-2):
-            raise AssertionError(
-                f"flash window {name}: max |err| {err} of the largest "
-                f"entry vs blockwise")
-        log(f"  flash[window={win}] {name}: max |err| = {err:.3e} of the "
-            f"largest entry")
+    # forward with the row logsumexp and the two backward kernels (dK/dV,
+    # dQ), grouped heads, against the blockwise path and its vjp: a
+    # sliding window at head size 128 (the sparse decoder's), and the
+    # causal band at head size 64 with four query heads a key head (the
+    # sparse hybrid decoder's)
+    cases = [(1, 128, 4, 128, 2, 48), (1, 128, 8, 64, 2, None)] if rehearse \
+        else [(2, 2048, 8, 128, 2, 640), (2, 2048, 32, 64, 8, None)]
+    for B, S, H, D, hkv, win in cases:
+        blk = min(512, S) if not rehearse else 32
+        kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(SEED + 7 + D), 4)
+        q = jax.random.normal(kq, (B, S, H, D), jnp.bfloat16)
+        kx = jax.random.normal(kk, (B, S, hkv, D), jnp.bfloat16)
+        vx = jax.random.normal(kv, (B, S, hkv, D), jnp.bfloat16)
+        g = jax.random.normal(kg, (B, S, H, D), jnp.bfloat16)
+        tag = f"hd={D},window={win}"
+        out, lse = timed(f"flash_fwd[{tag}]", lambda: fa._flash_fwd(
+            q, kx, vx, True, blk, blk, interpret=interp, window=win,
+            with_lse=True))
+        grads = timed(f"flash_bwd[{tag}]", lambda: fa._flash_bwd(
+            q, kx, vx, out, lse, g, True, blk, blk, win, interpret=interp))
+        want_o, vjp = jax.vjp(lambda a, b, c: fa.blockwise_attention(
+            a, b, c, causal=True, block_k=blk, window=win, block_q=blk),
+            q, kx, vx)
+        for name, got, want in zip(("out", "dq", "dk", "dv"),
+                                   (out,) + tuple(grads),
+                                   (want_o,) + vjp(g)):
+            got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+            # bf16 results of f32 sums; dk and dv sum a group's heads
+            err = float(jnp.max(jnp.abs(got - want))
+                        / jnp.maximum(jnp.max(jnp.abs(want)), 1e-6))
+            if not (np.isfinite(err) and err <= 3e-2):
+                raise AssertionError(
+                    f"flash[{tag}] {name}: max |err| {err} of the largest "
+                    f"entry vs blockwise")
+            log(f"  flash[{tag}] {name}: max |err| = {err:.3e} of the "
+                f"largest entry")
     if not rehearse:
         # the public entry must engage the kernel on this platform
         txt = jax.jit(lambda a: fa.flash_attention(a, a, a)).lower(

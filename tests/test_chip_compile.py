@@ -124,12 +124,12 @@ def _flash_bwd(shape, hkv, window=None):
     return lower
 
 
-def _grouped_products(sh):
-    """The held experts' products at the benchmark's sparse decoder's
-    widths: 8 experts of 2304 x 896 over one slice's sorted pair buffer
-    (8192 tokens x 8 pairs)."""
+def _grouped_products(sh, k=8, d=2304, h=896):
+    """The held experts' products at a benchmark's sparse decoder's
+    widths (Mellum's by default): 8 experts of ``d`` x ``h`` over one
+    slice's sorted pair buffer (8192 tokens x ``k`` pairs)."""
     from byteps_tpu.models import moe
-    T, k, d, h, H = 8192, 8, 2304, 896, 8
+    T, H = 8192, 8
     return jax.jit(
         lambda x, key, wg, wu, wd: moe.grouped_ffn(
             x, key, k, wg, wu, wd, jnp.bfloat16)
@@ -154,6 +154,13 @@ def _grouped_products(sh):
     pytest.param(_flash_bwd((1, 8192, 32, 128), 4),
                  id="flash_bwd-full_8k_gqa32x4"),
     pytest.param(_grouped_products, id="grouped_ffn-8x2304x896"),
+    # LFM2-8B-A1B's widths: heads of 64, four query heads a key head;
+    # 8 experts of 2048 x 1792 at 4 pairs a token
+    pytest.param(_flash((1, 8192, 32, 64), 8), id="flash_fwd-full_8k_gqa32x8_hd64"),
+    pytest.param(_flash_bwd((1, 8192, 32, 64), 8),
+                 id="flash_bwd-full_8k_gqa32x8_hd64"),
+    pytest.param(lambda sh: _grouped_products(sh, k=4, d=2048, h=1792),
+                 id="grouped_ffn-8x2048x1792"),
 ])
 def test_kernel_compiles_for_v5e(v5e, lower):
     compiled = lower(v5e).compile()
@@ -306,3 +313,52 @@ def test_the_sparse_decoders_block_walks_a_compact_buffer_on_a_v5e(
                         bool(compact_rows.search(b))) for b in branches)
         assert kinds == [(False, True), (True, False)], kinds
     assert "tpu_custom_call" in text
+
+
+def test_the_hybrid_decoders_blocks_compile_for_a_v5e(v5e, monkeypatch):
+    """The three kinds of block of the benchmark's sparse hybrid decoder
+    (``models/lfm2.py`` at LFM2-8B-A1B's published widths, 2 rows of 8192
+    tokens, 8 of 32 experts held, 4 a token), forward and backward under
+    their remat: the gated short convolution is fusions (no convolution
+    custom call, no kernel of this package), the attention block holds
+    the Pallas kernels at head size 64, the sparse FFN the grouped
+    products over a compact buffer of 16,384 rows a slice of 32,768
+    pairs."""
+    import dataclasses
+
+    from byteps_tpu.models import lfm2, moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        lfm2.LFM2Config(), vocab_size=16384, n_experts_held=8,
+        layer_types=(lfm2.CONV, lfm2.FULL, lfm2.CONV), n_dense_layers=1)
+    assert cfg.runs() == [((lfm2.CONV, lfm2.DENSE), 1),
+                          ((lfm2.FULL, lfm2.SPARSE), 1),
+                          ((lfm2.CONV, lfm2.SPARSE), 1)]
+    rows, seq = 2, 8192
+    pairs = lfm2.EXPERT_SLICE * cfg.top_k
+    assert (pairs, moe.compact_rows(pairs, 8, cfg.n_experts)) == (32768, 16384)
+    runs = jax.tree.map(
+        lambda v: _sds(v.shape[1:], v.dtype, v5e),
+        jax.eval_shape(lambda: lfm2.init_params(
+            jax.random.PRNGKey(0), cfg))["runs"])
+    block = jax.checkpoint(lfm2._block, static_argnums=(4, 5, 6))
+    x = _sds((rows, seq, cfg.dim), jnp.bfloat16, v5e)
+    bias = _sds((cfg.n_experts,), jnp.float32, v5e)
+    texts = {}
+    for (kind, _), p in zip(cfg.runs(), runs):
+        def loss(x_, p_, b_, kind=kind):
+            out, stats = block(x_, p_, b_ if kind[1] == lfm2.SPARSE else None,
+                               lfm2.L.rope_cache(cfg, seq), cfg, kind, None)
+            return jnp.sum(out.astype(jnp.float32)), stats
+
+        texts[kind] = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True)).lower(
+                x, p, bias).compile().as_text()
+    dense, attn, sparse = (texts[kind] for kind, _ in cfg.runs())
+    # the convolution block with the dense FFN: XLA's own programs only
+    assert "tpu_custom_call" not in dense and "ragged-dot" not in dense
+    assert "bps.attn.full" in attn and "tpu_custom_call" in attn
+    for text in (attn, sparse):
+        assert "ragged-dot" in text and " conditional(" in text
+        assert re.search(r"\[16384,(?:2048|1792)\]", text)

@@ -584,3 +584,150 @@ def test_dropped_sees_a_compact_buffer_cut_short():
     _, load, dropped = moe._held_chunk(x, gates, idx, 0, *leaves,
                                        jnp.float32, 16)
     assert int(load.sum()) == 20 and int(dropped) == 4
+
+
+# ------------------------------------------------------------------ #
+# sigmoid routing under a selection bias (models/lfm2.py's router), and
+# the guard that softmax top-k is what it was
+# ------------------------------------------------------------------ #
+
+def _router_inputs(T=384, d=64, E=32, scale=0.2, seed=5):
+    key = jax.random.PRNGKey(seed)
+    x = jax.random.normal(key, (T, d), jnp.float32).astype(jnp.bfloat16)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (d, E),
+                          jnp.float32) * scale
+    bias = jax.random.uniform(jax.random.fold_in(key, 2), (E,),
+                              minval=-0.1, maxval=0.1)
+    return x, w, bias
+
+
+@pytest.mark.parametrize("scale", [1.0, 2.5])
+def test_sigmoid_router_selects_with_the_bias_and_weighs_without_it(scale):
+    """Against a float64 loop over the tokens: the k experts are the
+    largest of ``sigmoid + bias``, the weights are the sigmoids alone
+    over their sum plus 1e-6, times the scale."""
+    k = 4
+    x, w, bias = _router_inputs()
+    with jax.default_matmul_precision("highest"):
+        gates, idx, scores = moe.route(
+            x, w, k, score="sigmoid", select_bias=bias, norm_eps=1e-6,
+            scale=scale)
+    s = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                              @ np.asarray(w, np.float64))))
+    b = np.asarray(bias, np.float64)
+    np.testing.assert_allclose(np.asarray(scores), s, rtol=0, atol=1e-6)
+    moved = 0
+    for t in range(x.shape[0]):
+        sel = np.argsort(-(s[t] + b), kind="stable")[:k]
+        assert sorted(sel) == sorted(np.asarray(idx[t]).tolist()), t
+        chosen = s[t][np.asarray(idx[t])]
+        np.testing.assert_allclose(
+            np.asarray(gates[t]), chosen / (chosen.sum() + 1e-6) * scale,
+            rtol=1e-5)
+        moved += len(set(sel) - set(np.argsort(-s[t], kind="stable")[:k]))
+    # the weights are NOT those of a router that weighs with the bias
+    biased = np.take_along_axis(s + b, np.asarray(idx), -1)
+    assert np.abs(np.asarray(gates) - biased / biased.sum(-1, keepdims=True)
+                  * scale).max() > 1e-3
+    assert 0 < moved < x.shape[0] * k
+    assert int(moe.bias_moved_pairs(scores, idx)) == moved
+    # the sum is short of the scale by the 1e-6 in the divisor
+    total = np.asarray(gates, np.float64).sum(-1)
+    assert np.all(total < scale) and np.all(total > scale * (1 - 1e-5))
+
+
+def test_a_zero_bias_moves_no_pair_and_the_bias_gets_no_gradient():
+    x, w, bias = _router_inputs()
+    gates, idx, scores = moe.route(x, w, 4, score="sigmoid",
+                                   select_bias=jnp.zeros_like(bias),
+                                   norm_eps=1e-6)
+    plain = moe.route(x, w, 4, score="sigmoid", norm_eps=1e-6)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(plain[1]))
+    np.testing.assert_array_equal(np.asarray(gates), np.asarray(plain[0]))
+    assert int(moe.bias_moved_pairs(scores, idx)) == 0
+
+    def loss(b, w_):
+        g, _, _ = moe.route(x, w_, 4, score="sigmoid", select_bias=b,
+                            norm_eps=1e-6)
+        return jnp.sum(g * jnp.arange(1.0, 5.0))
+
+    g_bias, g_w = jax.grad(loss, (0, 1))(bias, w)
+    assert not np.any(np.asarray(g_bias)) and np.any(np.asarray(g_w))
+    with pytest.raises(ValueError, match="softmax"):
+        moe.route(x, w, 4, score="tanh")
+
+
+@pytest.mark.parametrize("router_dtype, within", [
+    (jnp.float32, True), (jnp.bfloat16, False)])
+def test_the_sigmoid_router_computes_in_float32(router_dtype, within):
+    """As ``test_the_router_computes_in_float32`` holds the softmax
+    router: the scores of bfloat16 activations against float32 weights
+    to float32's rounding against a float64 oracle, the selection the
+    oracle's; a bfloat16 router is far off and flips choices."""
+    x, w, bias = _router_inputs(T=512, d=64, E=32)
+    with jax.default_matmul_precision("highest"):
+        _, idx, scores = moe.route(x, w, 4, router_dtype, score="sigmoid",
+                                   select_bias=bias, norm_eps=1e-6)
+    want = 1.0 / (1.0 + np.exp(-(np.asarray(x, np.float64)
+                                 @ np.asarray(w, np.float64))))
+    gap = np.abs(np.asarray(scores, np.float64) - want).max()
+    same = np.array_equal(
+        np.sort(np.asarray(idx), -1),
+        np.sort(np.argsort(-(want + np.asarray(bias, np.float64)),
+                           -1)[:, :4], -1))
+    assert (gap < 1e-6) == within and same == within
+
+
+def _route_as_pr26_wrote_it(x_flat, router_w, top_k,
+                            router_dtype=jnp.float32):
+    """``moe.route`` before it had a score function or a bias (PRs 26 to
+    29), kept here as the yardstick of the guard below."""
+    with jax.named_scope("bps.moe.route"):
+        logits = jnp.matmul(x_flat.astype(router_dtype),
+                            router_w.astype(router_dtype),
+                            preferred_element_type=router_dtype)
+        probs = jax.nn.softmax(logits, axis=-1).astype(jnp.float32)
+        gates, idx = jax.lax.top_k(probs, top_k)
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates, idx.astype(jnp.int32), probs
+
+
+@pytest.mark.parametrize("what", ["route", "layer_and_gradient"])
+def test_softmax_routing_is_operation_for_operation_what_it_was(
+        what, monkeypatch):
+    """The Mellum guard: ``route`` with its defaults, and ``moe_layer``
+    as ``models/mellum.py`` calls it (a held share, slices, the float32
+    router) with its gradient, trace to the same jaxpr as with the
+    router PR 26 wrote: the new arguments cost the sparse decoder's
+    program no operation."""
+    x, w, _ = _router_inputs()
+    if what == "route":
+        def new():
+            return jax.make_jaxpr(
+                lambda a, b: moe.route(a, b, 8, jnp.float32))(x, w)
+
+        old = jax.make_jaxpr(
+            lambda a, b: _route_as_pr26_wrote_it(a, b, 8, jnp.float32))(x, w)
+        assert str(new()) == str(old)
+        return
+    cfg = _cfg(n_experts=8, top_k=2)
+    p = _layer0(moe.init_params(jax.random.PRNGKey(0), cfg))
+    p = {k: (v[2:6] if k in moe.EXPERT_LEAVES else v) for k, v in p.items()}
+    xs = jax.random.normal(jax.random.PRNGKey(1), (2, 16, cfg.dim),
+                           jnp.float32)
+
+    def as_mellum_calls_it(p_, x_):
+        out, st = moe.moe_layer(x_, p_, cfg.top_k, jnp.float32, first=2,
+                                ep_axis=None, chunk=16,
+                                router_dtype=jnp.float32)
+        return jnp.sum(out), (st["load"], st["dropped"],
+                              st["compact_slices"], st["full_slices"])
+
+    def trace():
+        return str(jax.make_jaxpr(jax.value_and_grad(
+            as_mellum_calls_it, argnums=(0, 1), has_aux=True))(p, xs))
+
+    new = trace()
+    monkeypatch.setattr(moe, "route",
+                        lambda *a, **kw: _route_as_pr26_wrote_it(*a))
+    assert new == trace()

@@ -145,3 +145,35 @@ def test_a_window_needs_causal_and_binds_as_attn_impl():
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(_explicit(q, k, v, 16)),
                                    rtol=1e-5, atol=1e-5)
+
+
+# head size 64, half a lane tile, four query heads a key head (the
+# LFM2 configuration's attention), beside the 128 the kernels met first
+@pytest.mark.parametrize("D, H, Hkv, window", [
+    (64, 8, 2, None), (64, 8, 2, 96), (128, 8, 2, None)])
+def test_kernels_match_blockwise_attention_at_the_head_size(D, H, Hkv,
+                                                            window):
+    """The Pallas forward, dK/dV and dQ kernels (interpret mode) against
+    ``blockwise_attention`` and its vjp, forward and backward."""
+    q, k, v = _qkv(S=256, H=H, Hkv=Hkv, D=D, seed=4)
+    do = jax.random.normal(jax.random.PRNGKey(5), q.shape)
+    with jax.default_matmul_precision("highest"):
+        out, lse = _flash_fwd(q, k, v, True, 64, 64, interpret=True,
+                              window=window, with_lse=True)
+        got = _flash_bwd(q, k, v, out, lse, do, True, 64, 64, window,
+                         interpret=True)
+        want_out, vjp = jax.vjp(
+            lambda q, k, v: blockwise_attention(
+                q, k, v, causal=True, block_k=64, window=window,
+                block_q=64), q, k, v)
+        want = vjp(do)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
+                               rtol=1e-5, atol=1e-5)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
+    # the scale is the head's own: 1 / 8 at 64
+    flat = _explicit(q, k, v, window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(flat),
+                               rtol=1e-5, atol=1e-5)
