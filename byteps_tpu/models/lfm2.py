@@ -239,6 +239,8 @@ def _block(x, p, bias, rope, cfg: LFM2Config, kind, ep_axis):
                      "moe/dropped_pairs": st["dropped"],
                      "moe/compact_slices": st["compact_slices"],
                      "moe/full_slices": st["full_slices"],
+                     "moe/kernel_slices": st["kernel_slices"],
+                     "moe/kernel_tile_rows": st["kernel_tile_rows"],
                      "moe/bias_moved_pairs": st["bias_moved"]}
 
 

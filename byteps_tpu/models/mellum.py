@@ -204,7 +204,9 @@ def _block(x, p, ropes, cfg: MellumConfig, kind: str, ep_axis):
     return x + ffn, {"moe/expert_load": st["load"],
                      "moe/dropped_pairs": st["dropped"],
                      "moe/compact_slices": st["compact_slices"],
-                     "moe/full_slices": st["full_slices"]}
+                     "moe/full_slices": st["full_slices"],
+                     "moe/kernel_slices": st["kernel_slices"],
+                     "moe/kernel_tile_rows": st["kernel_tile_rows"]}
 
 
 def _period(kinds: Tuple[str, ...]) -> int:

@@ -13,10 +13,14 @@ this is green-field TPU design:
   whatever the imbalance. What absent experts would add is left out of
   the sum, and nothing stands in for it;
 - the held experts' three products are ONE grouped matrix product each
-  (``jax.lax.ragged_dot``) over the pairs sorted by expert. XLA:TPU
-  lowers ``ragged_dot`` to its own Mosaic kernel that walks only the
-  row tiles inside a group, which is why it is used and not a Pallas
-  kernel of this package; the same call runs on the CPU mesh;
+  (``ops/grouped_matmul.py grouped_matmul``) over the pairs sorted by
+  expert. On the TPU that is a Pallas kernel family of this package
+  (product, its transpose by the weights, its transpose by the rows),
+  tiled for groups of some hundreds of rows against an expert's whole
+  weights in VMEM: XLA:TPU's own ``ragged_dot`` kernel took a
+  millisecond a call on a tenth of a millisecond of work at such
+  groups (PERF.md section 6, PR 32). Off the TPU (tier-1, the CPU
+  mesh) the same call is ``jax.lax.ragged_dot``;
 - work follows the pairs routed here, not tokens x experts: shapes
   stay static, so the sorted pair buffer has a static number of rows,
   and that number is sized for the pairs this device can be expected
@@ -50,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.grouped_matmul import grouped_matmul, row_tile, visited_rows
 from . import llama as L
 
 
@@ -262,11 +267,11 @@ def _full_rows(x, order, inv, load, k, w_gate, w_up, w_down, dtype):
     # cotangents with them)
     routed = (jnp.arange(N) < jnp.sum(load))[:, None]
     xs = jnp.where(routed, _rows_in_order(x, order, inv, k), 0)
-    gate = jax.lax.ragged_dot(xs, w_gate.astype(dtype), load)
-    up = jax.lax.ragged_dot(xs, w_up.astype(dtype), load)
+    gate = grouped_matmul(xs, w_gate.astype(dtype), load)
+    up = grouped_matmul(xs, w_up.astype(dtype), load)
     h = jnp.where(routed, jax.nn.silu(gate) * up, 0)
     ys = jnp.where(routed,
-                   jax.lax.ragged_dot(h, w_down.astype(dtype), load), 0)
+                   grouped_matmul(h, w_down.astype(dtype), load), 0)
     # back to pair order: again a permutation, k = 1
     return _rows_in_order(ys, inv, order, 1)
 
@@ -335,11 +340,11 @@ def _compact_out(x, w, head, load, w_gate, w_up, w_down, dtype):
     # masked as on the full-size path: rows past the held pairs are in
     # no group
     xs = jnp.where(held, x[tok], 0)
-    gate = jax.lax.ragged_dot(xs, w_gate.astype(dtype), load)
-    up = jax.lax.ragged_dot(xs, w_up.astype(dtype), load)
+    gate = grouped_matmul(xs, w_gate.astype(dtype), load)
+    up = grouped_matmul(xs, w_up.astype(dtype), load)
     h = jnp.where(held, jax.nn.silu(gate) * up, 0)
     ys = jnp.where(held,
-                   jax.lax.ragged_dot(h, w_down.astype(dtype), load), 0)
+                   grouped_matmul(h, w_down.astype(dtype), load), 0)
     # the gates' product and sum in float32, as the full path's einsum
     gate_of = w.reshape(-1)[head].astype(dtype).astype(jnp.float32)
     out = jax.ops.segment_sum(ys.astype(jnp.float32) * gate_of[:, None],
@@ -424,6 +429,19 @@ def _held(x, gates, idx, first, n_experts, w_gate, w_up, w_down, dtype, n):
         n * fits.astype(jnp.int32)
 
 
+def _kernel_stats(loads, rows, d, h):
+    """The grouped products' kernel at work on slices whose held pairs
+    are ``loads`` [slices, n_held], each through a sorted buffer of
+    ``rows`` rows: (the slices whose products ran in the package's
+    kernel: all or, where ``row_tile`` leaves the shape to
+    ``ragged_dot``, none; the rows of the row tiles it visited)."""
+    tile = row_tile(rows, d, h)
+    if tile is None:
+        return jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32)
+    visited = jax.vmap(lambda load: visited_rows(load, rows, tile))
+    return jnp.int32(loads.shape[0]), jnp.sum(visited(loads))
+
+
 def _exchanged(x, gates, idx, w_gate, w_up, w_down, dtype, ep_axis):
     """The same grouped product between two ``all_to_all`` calls: the
     experts dim is sharded over ``ep_axis`` (this device holds experts
@@ -491,7 +509,11 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
     ``compact_slices`` and ``full_slices`` (the layer's slices, all on
     one counter or the other: walked through the compact sorted buffer,
     ``compact_rows``, or through the full-size one, as every slice is
-    where half the experts or more are held), ``aux`` (the Switch
+    where half the experts or more are held), ``kernel_slices`` (the
+    slices whose grouped products ran in the package's kernel: all of
+    them on the TPU, none off it) and ``kernel_tile_rows`` (the rows of
+    the row tiles it visited: beside the loads' sum, the tiles'
+    occupancy), ``aux`` (the Switch
     balancing loss, for models that use it; softmax routing only), and
     with a ``select_bias`` ``bias_moved`` (``bias_moved_pairs``).
     """
@@ -501,11 +523,16 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
     gates, idx, probs = route(x_flat, p["router"], top_k, router_dtype,
                               **routing)
     w = (p["w_gate"], p["w_up"], p["w_down"])
+    n_held, _, hidden = w[0].shape
     if ep_axis is not None:
         n = 1
         out, load, dropped = _exchanged(x_flat, gates, idx, *w, dtype,
                                         ep_axis)
         compact = jnp.zeros((), jnp.int32)
+        # the exchange's buffer is one slice
+        kernel = _kernel_stats(
+            load[None], jax.lax.axis_size(ep_axis) * T * min(top_k, n_held),
+            d, hidden)
     else:
         if chunk is None or chunk >= T:
             n = 1
@@ -514,10 +541,23 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
                 f"{T} tokens do not divide into slices of {chunk}")
         else:
             n = T // chunk
+        n_experts = p["router"].shape[-1]
         out, load, dropped, compact = _held(
-            x_flat, gates, idx, first, p["router"].shape[-1], *w, dtype, n)
+            x_flat, gates, idx, first, n_experts, *w, dtype, n)
+        # the tiles the kernel visited in the buffer the layer walked,
+        # from each slice's own loads
+        here, local = _held_here(idx, first, n_held)
+        loads = jnp.sum(jnp.where(here, local, n_held).reshape(n, -1, 1)
+                        == jnp.arange(n_held), axis=1, dtype=jnp.int32)
+        N = T // n * top_k
+        kernel = jax.tree.map(
+            functools.partial(jnp.where, compact > 0),
+            _kernel_stats(loads, compact_rows(N, n_held, n_experts), d,
+                          hidden),
+            _kernel_stats(loads, N, d, hidden))
     stats = {"load": load, "dropped": dropped, "compact_slices": compact,
-             "full_slices": n - compact}
+             "full_slices": n - compact, "kernel_slices": kernel[0],
+             "kernel_tile_rows": kernel[1]}
     if routing.get("score", "softmax") == "softmax":
         stats["aux"] = switch_aux_loss(probs, idx)
     if routing.get("select_bias") is not None:

@@ -138,6 +138,23 @@ def _grouped_products(sh, k=8, d=2304, h=896):
             _sds((H, h, d), jnp.float32, sh))
 
 
+def _grouped_kernels(sh, rows=16384, d=2304, h=896):
+    """The package's grouped product with both transposes of its
+    backward (``ops/grouped_matmul.py``: the kernels themselves, which
+    the dispatch reaches on the TPU only) at a sparse decoder's widths:
+    a slice's compact buffer against 8 held experts."""
+    from byteps_tpu.ops import grouped_matmul as gm
+
+    def both_ways(lhs, rhs, sizes, g):
+        out, vjp = jax.vjp(
+            lambda l, r: gm._product(l, r, sizes, 256, False), lhs, rhs)
+        return out, vjp(g)
+
+    return jax.jit(both_ways).lower(
+        _sds((rows, d), jnp.bfloat16, sh), _sds((8, d, h), jnp.bfloat16, sh),
+        _sds((8,), jnp.int32, sh), _sds((rows, h), jnp.bfloat16, sh))
+
+
 @pytest.mark.parametrize("lower", [
     pytest.param(_onebit_pack, id="onebit_pack-bert_leaf"),
     pytest.param(_onebit_unpack, id="onebit_unpack-bert_leaf"),
@@ -161,6 +178,13 @@ def _grouped_products(sh, k=8, d=2304, h=896):
                  id="flash_bwd-full_8k_gqa32x8_hd64"),
     pytest.param(lambda sh: _grouped_products(sh, k=4, d=2048, h=1792),
                  id="grouped_ffn-8x2048x1792"),
+    pytest.param(_grouped_kernels, id="grouped_kernels-16384x8x2304x896"),
+    pytest.param(lambda sh: _grouped_kernels(sh, rows=65536),
+                 id="grouped_kernels-65536x8x2304x896"),
+    pytest.param(lambda sh: _grouped_kernels(sh, d=2048, h=1792),
+                 id="grouped_kernels-16384x8x2048x1792"),
+    pytest.param(lambda sh: _grouped_kernels(sh, rows=32768, d=2048, h=1792),
+                 id="grouped_kernels-32768x8x2048x1792"),
 ])
 def test_kernel_compiles_for_v5e(v5e, lower):
     compiled = lower(v5e).compile()
@@ -313,6 +337,11 @@ def test_the_sparse_decoders_block_walks_a_compact_buffer_on_a_v5e(
                         bool(compact_rows.search(b))) for b in branches)
         assert kinds == [(False, True), (True, False)], kinds
     assert "tpu_custom_call" in text
+    # every grouped product is the package's kernel, none XLA's own: a
+    # trace that held both would divide all the needed FLOPs by part of
+    # the time (``benchmark/layers/moe.py`` sums the ``ragged-dot``
+    # family)
+    assert "ragged-dot.bps" in text and "ragged-dot-none" not in text
 
 
 def test_the_hybrid_decoders_blocks_compile_for_a_v5e(v5e, monkeypatch):
@@ -362,3 +391,4 @@ def test_the_hybrid_decoders_blocks_compile_for_a_v5e(v5e, monkeypatch):
     for text in (attn, sparse):
         assert "ragged-dot" in text and " conditional(" in text
         assert re.search(r"\[16384,(?:2048|1792)\]", text)
+        assert "ragged-dot.bps" in text and "ragged-dot-none" not in text
