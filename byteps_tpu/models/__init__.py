@@ -6,4 +6,5 @@ vehicles natively: MLP/MNIST (config 1), ResNet-50 (config 2), BERT-large
 (config 3), Llama-3 (config 4).
 """
 
-from . import bert, lfm2, llama, mellum, mlp, moe, resnet, vgg  # noqa: F401
+from . import (bert, lfm2, llama, mellum, mlp, moe, resnet, sdar,  # noqa: F401
+               vgg)

@@ -197,9 +197,17 @@ def _block(x, p, ropes, cfg: MellumConfig, kind: str, ep_axis):
     attn = flash_attention(q, k, v, True, ATTN_BLOCK, ATTN_BLOCK,
                            cfg.sliding_window if kind == SLIDING else None)
     x = x + attn.reshape(B, S, nh * hd) @ p["wo"].astype(dt)
+    return moe_sublayer(x, p, cfg, ep_axis)
+
+
+def moe_sublayer(x, p, cfg, ep_axis):
+    """``x + MoE(RMSNorm(x))`` of one layer's params ``p``, and the
+    layer's additive statistics by counter name (``models/sdar.py``'s
+    block ends in it too)."""
     h = L._rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
-    ffn, st = moe.moe_layer(h, p, cfg.top_k, dt, first=cfg.first_expert,
-                            ep_axis=ep_axis, chunk=EXPERT_SLICE,
+    ffn, st = moe.moe_layer(h, p, cfg.top_k, cfg.dtype,
+                            first=cfg.first_expert, ep_axis=ep_axis,
+                            chunk=EXPERT_SLICE,
                             router_dtype=cfg.router_dtype)
     return x + ffn, {"moe/expert_load": st["load"],
                      "moe/dropped_pairs": st["dropped"],

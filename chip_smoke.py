@@ -283,25 +283,30 @@ def check_kernels(rehearse: bool) -> None:
     # dQ), grouped heads, against the blockwise path and its vjp: a
     # sliding window at head size 128 (the sparse decoder's), and the
     # causal band at head size 64 with four query heads a key head (the
-    # sparse hybrid decoder's)
-    cases = [(1, 128, 4, 128, 2, 48), (1, 128, 8, 64, 2, None)] if rehearse \
-        else [(2, 2048, 8, 128, 2, 640), (2, 2048, 32, 64, 8, None)]
-    for B, S, H, D, hkv, win in cases:
+    # sparse hybrid decoder's); and the block-diffusion mask over a
+    # noised and a clean copy of a row at head size 128, blocks of 4
+    # (the block-diffusion decoder's)
+    cases = [(1, 128, 4, 128, 2, 48, None), (1, 128, 8, 64, 2, None, None),
+             (1, 128, 4, 128, 2, None, 4)] if rehearse \
+        else [(2, 2048, 8, 128, 2, 640, None), (2, 2048, 32, 64, 8, None, None),
+              (2, 4096, 8, 128, 1, None, 4)]
+    for B, S, H, D, hkv, win, blk_diff in cases:
         blk = min(512, S) if not rehearse else 32
         kq, kk, kv, kg = jax.random.split(jax.random.PRNGKey(SEED + 7 + D), 4)
         q = jax.random.normal(kq, (B, S, H, D), jnp.bfloat16)
         kx = jax.random.normal(kk, (B, S, hkv, D), jnp.bfloat16)
         vx = jax.random.normal(kv, (B, S, hkv, D), jnp.bfloat16)
         g = jax.random.normal(kg, (B, S, H, D), jnp.bfloat16)
-        tag = f"hd={D},window={win}"
+        tag = f"hd={D},window={win},diffusion_block={blk_diff}"
         out, lse = timed(f"flash_fwd[{tag}]", lambda: fa._flash_fwd(
             q, kx, vx, True, blk, blk, interpret=interp, window=win,
-            with_lse=True))
+            with_lse=True, diffusion_block=blk_diff))
         grads = timed(f"flash_bwd[{tag}]", lambda: fa._flash_bwd(
-            q, kx, vx, out, lse, g, True, blk, blk, win, interpret=interp))
+            q, kx, vx, out, lse, g, True, blk, blk, win, interpret=interp,
+            diffusion_block=blk_diff))
         want_o, vjp = jax.vjp(lambda a, b, c: fa.blockwise_attention(
-            a, b, c, causal=True, block_k=blk, window=win, block_q=blk),
-            q, kx, vx)
+            a, b, c, causal=True, block_k=blk, window=win, block_q=blk,
+            diffusion_block=blk_diff), q, kx, vx)
         for name, got, want in zip(("out", "dq", "dk", "dv"),
                                    (out,) + tuple(grads),
                                    (want_o,) + vjp(g)):

@@ -97,7 +97,7 @@ def _randomk(sh):
                                  _sds((), jnp.int32, sh), LEAF // 100, False)
 
 
-def _flash(shape, hkv, window=None):
+def _flash(shape, hkv, window=None, diffusion_block=None):
     def lower(sh):
         from byteps_tpu.ops.flash_attention import _flash_fwd
         B, S, H, D = shape
@@ -105,12 +105,13 @@ def _flash(shape, hkv, window=None):
         kv = _sds((B, S, hkv, D), jnp.bfloat16, sh)
         return jax.jit(
             lambda q_, k_, v_: _flash_fwd(q_, k_, v_, True, 512, 512,
-                                          window=window)
+                                          window=window,
+                                          diffusion_block=diffusion_block)
         ).lower(q, kv, kv)
     return lower
 
 
-def _flash_bwd(shape, hkv, window=None):
+def _flash_bwd(shape, hkv, window=None, diffusion_block=None):
     def lower(sh):
         from byteps_tpu.ops.flash_attention import _flash_bwd as bwd
         B, S, H, D = shape
@@ -118,8 +119,9 @@ def _flash_bwd(shape, hkv, window=None):
         kv = _sds((B, S, hkv, D), jnp.bfloat16, sh)
         lse = _sds((B, H, S, 128), jnp.float32, sh)
         return jax.jit(
-            lambda q_, k_, v_, o_, l_, g_: bwd(q_, k_, v_, o_, l_, g_, True,
-                                               512, 512, window)
+            lambda q_, k_, v_, o_, l_, g_: bwd(
+                q_, k_, v_, o_, l_, g_, True, 512, 512, window,
+                diffusion_block=diffusion_block)
         ).lower(q, kv, kv, q, lse, q)
     return lower
 
@@ -185,6 +187,13 @@ def _grouped_kernels(sh, rows=16384, d=2304, h=896):
                  id="grouped_kernels-16384x8x2048x1792"),
     pytest.param(lambda sh: _grouped_kernels(sh, rows=32768, d=2048, h=1792),
                  id="grouped_kernels-32768x8x2048x1792"),
+    # SDAR-30B-A3B's cell: 2 rows of 8192 tokens, each a noised and a
+    # clean copy (16,384 positions) under the block-diffusion mask at
+    # block length 4, 32 query and 4 key heads of 128
+    pytest.param(_flash((2, 16384, 32, 128), 4, diffusion_block=4),
+                 id="flash_fwd-blockdiff4_2x16k_gqa32x4"),
+    pytest.param(_flash_bwd((2, 16384, 32, 128), 4, diffusion_block=4),
+                 id="flash_bwd-blockdiff4_2x16k_gqa32x4"),
 ])
 def test_kernel_compiles_for_v5e(v5e, lower):
     compiled = lower(v5e).compile()
@@ -392,3 +401,49 @@ def test_the_hybrid_decoders_blocks_compile_for_a_v5e(v5e, monkeypatch):
         assert "ragged-dot" in text and " conditional(" in text
         assert re.search(r"\[16384,(?:2048|1792)\]", text)
         assert "ragged-dot.bps" in text and "ragged-dot-none" not in text
+
+
+def test_the_block_diffusion_decoders_block_compiles_for_a_v5e(
+        v5e, monkeypatch):
+    """One block of the benchmark's block-diffusion decoder
+    (``models/sdar.py`` at SDAR-30B-A3B's published widths, 2 rows of
+    8192 tokens as 16,384 positions each, 16 of 128 experts held, 8 a
+    token), forward and backward under its remat: the attention is the
+    Pallas kernels under ``bps.attn.blockdiff`` and under no other
+    attention scope, the sparse FFN the package's grouped products over
+    a compact buffer of 16,384 rows a slice of 65,536 pairs."""
+    import dataclasses
+
+    from byteps_tpu.models import moe, sdar
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(sdar.SDARConfig(), vocab_size=18992,
+                              n_experts_held=16, n_layers=1)
+    rows, seq = 2, 8192
+    pairs = sdar.mellum.EXPERT_SLICE * cfg.top_k
+    assert (pairs, moe.compact_rows(pairs, 16, cfg.n_experts)) \
+        == (65536, 16384)
+    layer = jax.tree.map(
+        lambda v: _sds(v.shape[1:], v.dtype, v5e),
+        jax.eval_shape(lambda: sdar.init_params(
+            jax.random.PRNGKey(0), cfg))["blocks"])
+    block = jax.checkpoint(sdar._block, static_argnums=(3, 4))
+    rope = tuple(_sds((2 * seq, cfg.head_dim // 2), jnp.float32, v5e)
+                 for _ in range(2))
+
+    def loss(x, p, rope):
+        out, stats = block(x, p, rope, cfg, None)
+        return jnp.sum(out.astype(jnp.float32)), stats
+
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+                   ).lower(_sds((rows, 2 * seq, cfg.dim), jnp.bfloat16, v5e),
+                           layer, rope).compile().as_text()
+    assert "tpu_custom_call" in text
+    # the forward (with the row logsumexp; once more where the remat's
+    # copy is not merged with it), dK/dV and dQ, each instruction named
+    # by the scope alone
+    assert len(set(re.findall(r"%(bps\.attn\.blockdiff[\w.]*) = ", text))) \
+        >= 3
+    assert "bps.attn.full" not in text and "bps.attn.window" not in text
+    assert "ragged-dot.bps" in text and "ragged-dot-none" not in text
+    assert re.search(r"\[16384,(?:2048|768)\]", text)
