@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.push_pull import psum_tree, reduce_scatter_tree, all_gather_tree
@@ -419,6 +420,59 @@ def _scatter_backward(loss_and_stats: Callable, mesh: Mesh, axis: str,
                                  out_specs=out_specs, check_vma=False))
 
 
+def _row_major(fmt) -> bool:
+    """Whether a ``Format`` (or none at all: a host array) orders the
+    dimensions major-to-minor, the order the wire carries."""
+    order = getattr(getattr(fmt, "layout", None), "major_to_minor", None)
+    return order is None or tuple(order) == tuple(range(len(order)))
+
+
+def _row_major_outputs(backward, args, own, mesh: Mesh):
+    """``backward`` (the jit of ``_psum_backward`` or
+    ``_scatter_backward``) with the layout the wire needs on the
+    gradient outputs in ``own`` (flatten indices: the whole leaves on a
+    key of their own). ``np.asarray`` of an output has the strides of
+    the DEVICE's dimension order, and the runtime's default order is
+    not major-to-minor where another packs the tiles better (on the
+    v5e a float32 ``[2048, 18992]`` is minor in its FIRST dimension):
+    such a leaf reaches the claim loop as a view that is not
+    C-contiguous, and one host core transposes it before a partition is
+    enqueued. An output pinned to major-to-minor is re-laid by a copy
+    on the chip, at HBM speed, inside the program.
+
+    Which outputs: a gradient has its parameter's shape and type, so
+    the default layout of both is the one the parameter lives in, and
+    the first program built is already the one that runs (a default
+    program compiled only to be looked at would stay loaded beside it:
+    0.23 GiB of the chip on SDAR's cell). The compiled program's output
+    layouts are then read, and an output of ``own`` that is out of
+    order all the same (a parameter that came from the host, or in a
+    layout of the caller's) is pinned in a second build. Returns the
+    jit to run, compiled by this look (the call that follows compiles
+    nothing again), and the number of outputs pinned; with none the jit
+    is ``backward`` itself and its program text is what it was. A
+    pinned jit wraps the same traced function: nothing is traced
+    twice."""
+    leaves = jax.tree.leaves(args[0])
+    # outputs are ((loss, stats), gradients)
+    gradients = jax.tree.structure(backward.trace(*args).out_info[1])
+    whole = NamedSharding(mesh, P())
+    pins = {i for i in own
+            if not _row_major(getattr(leaves[i], "format", None))}
+    while True:
+        fn = backward if not pins else jax.jit(
+            backward.__wrapped__,
+            out_shardings=(None, gradients.unflatten(
+                Format(Layout(major_to_minor=tuple(range(leaf.ndim))), whole)
+                if i in pins else None for i, leaf in enumerate(leaves))))
+        formats = jax.tree.leaves(
+            fn.lower(*args).compile().output_formats[1])
+        missed = {i for i in own if not _row_major(formats[i])} - pins
+        if not missed:
+            return fn, len(pins)
+        pins |= missed
+
+
 @dataclasses.dataclass(frozen=True)
 class ExportPlan:
     """How a PS step's gradient leaves leave the chip. Every leaf is an
@@ -613,11 +667,12 @@ def make_ps_train_step(
     # destroyed native handle with a stale worker count
     comp_state = {"registry": None, "client": None, "device": None}
     # the export plan as last realised ("key": the gradient tree and
-    # the plan): the backward it runs, leaf index -> sizing/names of the
-    # shard leaves (their declared subrange names are freed when the plan
-    # changes); "tag" counts this closure's PS rounds
-    plan_cache: dict = {"key": None, "backward": None, "tag": 0,
-                        "shard_info": {}}
+    # the plan): the backward it runs and how many of its outputs it pins
+    # row-major (``_row_major_outputs``), leaf index -> sizing/names of
+    # the shard leaves (their declared subrange names are freed when the
+    # plan changes); "tag" counts this closure's PS rounds
+    plan_cache: dict = {"key": None, "backward": None, "pinned": 0,
+                        "tag": 0, "shard_info": {}}
     # sharded-apply build cache (keyed by params+opt_state structure;
     # sa None = transform not separable -> fused apply; ssa None =
     # not SHARD-separable -> gather gradients, full-leaf apply)
@@ -841,6 +896,11 @@ def make_ps_train_step(
         exp_shard_ctr = metrics.counter("export/shard_bytes")
         exp_whole_ctr = metrics.counter("export/whole_bytes")
         exp_dev0_ctr = metrics.counter("export/device_bytes/0")
+        # outputs this step's backward hands over under a pinned layout,
+        # and the bytes the train thread re-laid all the same (a claimed
+        # array that was not C-contiguous: expected 0)
+        exp_pinned_ctr = metrics.counter("export/pinned_layout_leaves")
+        exp_relayout_ctr = metrics.counter("export/host_relayout_bytes")
         metrics.gauge("export/worker_ingests/0").set(
             _SHARD_INGESTS.get(0, 0))
         ag_hist = metrics.histogram("step/allgather_us")
@@ -918,7 +978,8 @@ def make_ps_train_step(
             info = plan_cache["shard_info"][i]
             name = info["names"][dev]
             with tracing.span(tracing.EXPORT_SUBMIT, tid=name, step=tag,
-                              leaf=i, dev=dev, bytes=flat.nbytes) as sp:
+                              leaf=i, dev=dev, bytes=flat.nbytes,
+                              contiguous=flat.flags.c_contiguous) as sp:
                 ctx = get_or_init_ctx(state, name, flat)
                 sp.set(key=ctx.declared_key,
                        partitions=len(ctx.partitions))
@@ -1020,9 +1081,17 @@ def make_ps_train_step(
                      for n in info["names"]}
             plan_cache["shard_info"] = _declare_shard_keys(
                 state.registry, names, plan, stale)
-            plan_cache["backward"] = _scatter_backward(
+            backward = _scatter_backward(
                 loss_and_stats, mesh, axis, shard_set, len(names)) \
                 if shard_set else grad_fn
+            # the whole leaves on a key of their own leave the chip in
+            # the wire's order (bucket members are copied into their
+            # bucket's slot anyway, a shard is flat)
+            plan_cache["backward"], plan_cache["pinned"] = \
+                _row_major_outputs(backward, (params, batch), [
+                    i for i, pl in enumerate(p_leaves)
+                    if i not in shard_set
+                    and getattr(pl, "nbytes", 0) >= fusion], mesh)
             plan_cache["key"] = (treedef, plan)
 
         # ---- sharded-apply build (cached per tree structure) ----
@@ -1134,13 +1203,19 @@ def make_ps_train_step(
         # start the D2H copies of the leaves now, all of them, in
         # flatten order, a shard leaf's per-device arrays in
         # mesh-device order (a pure function of the plan: every worker
-        # issues and claims them alike); each np.asarray below then
-        # only waits for ITS array. The TPU runtime works on the copies
-        # side by side (it de-tiles each on host threads) and the large
-        # ones finish close together, late in the claim; a bounded
-        # window of copies in flight does overlap the PUSH with the
-        # transfers but slows the transfers by as much, on one host's
-        # cores (PERF.md section 6, PR 25).
+        # issues and claims them alike). What an np.asarray of an
+        # output below can cost is the wait for ITS transfer and no
+        # more: it returns a view of the buffer the runtime filled, in
+        # the output's own dimension order, and that order is the
+        # wire's, because the plan pinned every output that would have
+        # come otherwise (``_row_major_outputs``; a shard is flat). It
+        # never costs a copy: the train thread moves no leaf's bytes.
+        # The TPU runtime works on the copies side by side (it de-tiles
+        # each on host threads) and the large ones finish close
+        # together, late in the claim; a bounded window of copies in
+        # flight does overlap the PUSH with the transfers but slows
+        # the transfers by as much, on one host's cores (PERF.md
+        # section 6, PR 25).
         out_shards: Dict[int, list] = {}
         for i, leaf in enumerate(g_leaves):
             if i in active_shard:
@@ -1149,6 +1224,8 @@ def make_ps_train_step(
                     part.copy_to_host_async()
             elif hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
+
+        exp_pinned_ctr.inc(plan_cache["pinned"])
 
         imported: list = [None] * len(names)
         new_params: list = [None] * len(names)
@@ -1188,7 +1265,14 @@ def make_ps_train_step(
                     # members, and so its digest, depend on the tree
                     # and the fusion size alone
                     with tracing.span(tracing.EXPORT_SUBMIT, tid=name,
-                                      step=tag, leaf=i, bytes=nb) as sp:
+                                      step=tag, leaf=i, bytes=nb,
+                                      contiguous=h.flags.c_contiguous) as sp:
+                        if not h.flags.c_contiguous:
+                            # the guard: a backend that ignored the
+                            # plan's pin. The copy is the train
+                            # thread's, one core's, and is counted
+                            exp_relayout_ctr.inc(nb)
+                            h = np.ascontiguousarray(h)
                         if sparse:
                             # non-f32 grads upcast for the wire, cast
                             # back

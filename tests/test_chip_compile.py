@@ -254,6 +254,48 @@ def test_untapped_scatter_backward_compiles_for_a_v5e_host(v5e_host):
     assert want <= out <= want + (1 << 20)
 
 
+def test_the_plan_pins_the_output_a_v5e_would_hand_over_out_of_order(v5e_host):
+    """The v5e keeps a float32 ``[2048, 18992]`` (SDAR's head: a minor
+    dimension that is no multiple of a lane tile) minor in its FIRST
+    dimension, and a program output's default layout is the device's:
+    ``np.asarray`` of that gradient is a transposed view and one host
+    core copies 155 MB into order (``PERF.md`` 6, PR 38).
+    ``_row_major_outputs`` sees it in the compiled backward and pins
+    that output alone; the well-shaped leaf beside it keeps its
+    default, and a tree of such leaves keeps its program."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from byteps_tpu.jax import train
+
+    mesh = Mesh(np.array(v5e_host[:1]), ("dp",))
+    rep, dp = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    params = {"head": _sds((2048, 18992), jnp.float32, rep),
+              "proj": _sds((2048, 1024), jnp.float32, rep)}
+    batch = _sds((64, 2048), jnp.float32, dp)
+
+    def loss(p, x):
+        return (jnp.mean((x @ p["head"]) ** 2)
+                + jnp.mean(jnp.tanh(x @ p["proj"])))
+
+    backward = train._psum_backward(train._loss_and_stats(loss), mesh, "dp")
+
+    def orders(fn):
+        formats = fn.lower(params, batch).compile().output_formats[1]
+        return {k: tuple(f.layout.major_to_minor)
+                for k, f in formats.items()}
+
+    assert orders(backward) == {"head": (1, 0), "proj": (0, 1)}
+    pinned, n = train._row_major_outputs(
+        backward, (params, batch), [0, 1], mesh)
+    assert n == 1 and pinned is not backward
+    assert orders(pinned) == {"head": (0, 1), "proj": (0, 1)}
+    # nothing to pin: the backward itself, compiled by the look
+    same, n = train._row_major_outputs(
+        backward, (params, batch), [1], mesh)
+    assert n == 0 and same is backward
+
+
 def _computations(text):
     """{name: body lines} of a compiled module's HLO text."""
     out, name = {}, None
