@@ -410,6 +410,35 @@ class StepReport:
     export_router_busy_ms: Optional[float] = None
     export_materialize_ms: Optional[float] = None
     export_submit_ms: Optional[float] = None
+    # What the claim and the drain waited for (step_path_fields below;
+    # docs/observability.md has each field's definition and the
+    # identities a test holds). backward_wait_ms = the claim's first
+    # act, the wait for the backward PROGRAM to end on the device;
+    # export_behind_backward_ms = from there to the last leaf's
+    # submission, the chip idle behind its own backward;
+    # export_bucket_member_ms = np.asarray of the leaves under the
+    # fusion size; drain_land_ms = the train thread inside land /
+    # land_shard (imports, updates, a shard leaf's assembly and
+    # all-gather); drain_finish_ms = the train thread collecting the
+    # landed waiters' results (finish()); wire_tail_after_claim_ms =
+    # the round's last wire completion - export_done, not below 0;
+    # claim_thread_cpu_ms = the train thread's own CPU from
+    # backward_done to export_done (a clock of 10 ms ticks on some
+    # kernels: one step's reading is a multiple of the tick, the mean
+    # over steps is the figure). All None on a monolithic round (the
+    # device-compressed tier) - never a silent 0.
+    backward_wait_ms: Optional[float] = None
+    export_behind_backward_ms: Optional[float] = None
+    export_bucket_member_ms: Optional[float] = None
+    drain_land_ms: Optional[float] = None
+    drain_finish_ms: Optional[float] = None
+    wire_tail_after_claim_ms: Optional[float] = None
+    claim_thread_cpu_ms: Optional[float] = None
+    # CPU of ALL the process's threads (time.process_time) over the
+    # step's wall, begin_step to end_step: over wall_ms it is the cores
+    # the worker kept busy. Whole steps only: a kernel that books a
+    # thread's CPU late smears any reading taken inside a step
+    step_cpu_ms: Optional[float] = None
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -583,6 +612,45 @@ def export_span_fields(spans: List[tuple],
     return out
 
 
+# the train thread inside land / land_shard
+_LAND_STAGES = (tracing.APPLY_H2D_UPDATE, tracing.APPLY_ASSEMBLE,
+                tracing.APPLY_ALLGATHER)
+
+
+def step_path_fields(spans: List[tuple], round_tag: Optional[int],
+                     marks: Dict[str, float],
+                     thread_cpu_marks: Dict[str, float],
+                     wire_spans: List[tuple]) -> dict:
+    """Reduce one step's spans and marks to the StepReport's fields on
+    what the claim and the drain waited for. ``marks`` are seconds from
+    the step's start (as ``wire_spans``), ``thread_cpu_marks`` the train
+    thread's CPU seconds at the same marks. A round that never marked
+    the backward's end (the monolithic one) gives ``{}``, so every field
+    stays None."""
+    if "backward_done" not in marks or "export_done" not in marks:
+        return {}
+    out = {"export_behind_backward_ms":
+           (marks["export_done"] - marks["backward_done"]) * 1e3}
+    if "export_done" in thread_cpu_marks:
+        out["claim_thread_cpu_ms"] = (
+            thread_cpu_marks["export_done"]
+            - thread_cpu_marks["backward_done"]) * 1e3
+    if wire_spans:
+        out["wire_tail_after_claim_ms"] = max(
+            0.0, max(e for _, e in wire_spans) - marks["export_done"]) * 1e3
+    ms: Dict[str, float] = {}  # this round's spans, summed by name
+    for stage, _thread, start, end, args in spans:
+        if args.get("step") == round_tag:
+            ms[stage] = ms.get(stage, 0.0) + (end - start) * 1e3
+    out["drain_land_ms"] = sum(ms.get(stage, 0.0) for stage in _LAND_STAGES)
+    out["drain_finish_ms"] = ms.get(tracing.APPLY_FINISH, 0.0)
+    if tracing.EXPORT_BUCKET_MEMBER in ms:
+        out["export_bucket_member_ms"] = ms[tracing.EXPORT_BUCKET_MEMBER]
+    if tracing.STEP_BACKWARD_WAIT in ms:
+        out["backward_wait_ms"] = ms[tracing.STEP_BACKWARD_WAIT]
+    return out
+
+
 class _StepBuilder:
     """Mutable collection state for one in-flight step. Scheduler pool
     threads append stage samples concurrently with the train thread's
@@ -592,11 +660,13 @@ class _StepBuilder:
     __slots__ = ("step", "t0", "_mu", "stage_samples", "queue_peak",
                  "credit_stalls", "marks", "pull_wait_s", "fleet_base",
                  "wire_spans", "wire_base", "monolithic", "lane_base",
-                 "spans", "round_tag")
+                 "spans", "round_tag", "cpu0", "thread_cpu_marks")
 
     def __init__(self, step: int):
         self.step = step
         self.t0 = time.perf_counter()
+        # the CPU all the process's threads had used by the step's start
+        self.cpu0 = time.process_time()
         # fleet per-stage counter snapshot at step start (train-thread
         # only, set by StepProfiler.begin_step); None = no probe
         self.fleet_base: Optional[Dict[str, int]] = None
@@ -633,6 +703,8 @@ class _StepBuilder:
         # argument of this step's spans
         self.round_tag: Optional[int] = None
         self.marks: Dict[str, float] = {}
+        # the train thread's own CPU seconds at the claim's two marks
+        self.thread_cpu_marks: Dict[str, float] = {}
         self.pull_wait_s = 0.0
 
     def stage_sample(self, stage: str, seconds: float) -> None:
@@ -659,9 +731,12 @@ class _StepBuilder:
         with self._mu:
             self.credit_stalls += 1
 
-    def mark(self, name: str) -> None:
-        """Phase boundary relative to step start (train-thread only)."""
+    def mark(self, name: str, thread_cpu: bool = False) -> None:
+        """Phase boundary relative to step start (train-thread only),
+        with the calling thread's own CPU where asked."""
         self.marks[name] = time.perf_counter() - self.t0
+        if thread_cpu:
+            self.thread_cpu_marks[name] = time.thread_time()
 
     def add_pull_wait(self, seconds: float) -> None:
         self.pull_wait_s += seconds
@@ -816,11 +891,16 @@ class StepProfiler:
         if b is None:
             return None
         wall = (time.perf_counter() - b.t0) * 1e3
+        step_cpu = (time.process_time() - b.cpu0) * 1e3
         with b._mu:
             samples = {k: list(v) for k, v in b.stage_samples.items()}
             queue_peak, stalls = b.queue_peak, b.credit_stalls
             prog_spans = list(b.spans)
+            wire_spans = list(b.wire_spans)
         exp = export_span_fields(prog_spans, b.round_tag)
+        exp.update(step_path_fields(
+            prog_spans, b.round_tag, b.marks, b.thread_cpu_marks,
+            wire_spans))
         # server attribution: delta the fleet's per-stage counters over
         # the step (ns -> ms); pull_total is the comparable worker-side
         # sum (each PULL sample is one partition's submit→completion)
@@ -846,8 +926,7 @@ class StepProfiler:
         # cost model + this step's wire spans and wire byte delta
         eff: dict = {}
         if self._ledger is not None:
-            with b._mu:
-                spans = [] if b.monolithic else list(b.wire_spans)
+            spans = [] if b.monolithic else wire_spans
             try:
                 eff = self._ledger.step_efficiency(
                     wall_s=wall / 1e3,
@@ -858,6 +937,7 @@ class StepProfiler:
         r = StepReport(
             step=b.step,
             wall_ms=wall,
+            step_cpu_ms=step_cpu,
             compute_ms=b.marks.get("export_done", 0.0) * 1e3,
             drain_ms=(b.marks.get("drain_done", 0.0)
                       - b.marks.get("export_done", 0.0)) * 1e3,
@@ -901,7 +981,7 @@ class StepProfiler:
             carry_drain_ms=(xb or {}).get("carry_drain_ms"),
             staleness_lag=(xb or {}).get("staleness_lag"),
             window_depth=(xb or {}).get("window_depth"),
-            **exp,  # dispatch_ms and the export_* fields, or none
+            **exp,  # dispatch_ms, the export_* and step-path fields, or none
         )
         with self._mu:
             self._reports.append(r)

@@ -260,7 +260,6 @@ def _chaos_nan_poison(spec: str, name: str, flat, step_no: int):
     return poisoned
 
 
-_SHARD_INGESTS: Dict[int, int] = {}  # per-device ingest totals (gauges)
 _RELEASE_POOL = None
 
 
@@ -740,6 +739,12 @@ def make_ps_train_step(
             loss, grads = grad_fn(params, batch)
             params, opt_state = apply_fn(params, opt_state, grads)
             return _finish(params, opt_state, loss)
+        # who burned the CPU, by thread, in the BYTEPS_TRACE_ON window
+        # only (a reading takes milliseconds): one reading here and one
+        # after end_step, both outside the report's compute_ms and
+        # drain_ms, and one table over the whole step between them
+        by_thread = state.tracer is not None and state.tracer.active()
+        cpu_at = tracing.thread_cpu_ms() if by_thread else None
         # per-step pipeline profile (core/metrics.py): the scheduler's
         # stage threads feed samples into this builder; end_step below
         # closes it into the StepReport ring (+ stall diagnosis when
@@ -901,8 +906,6 @@ def make_ps_train_step(
         # array that was not C-contiguous: expected 0)
         exp_pinned_ctr = metrics.counter("export/pinned_layout_leaves")
         exp_relayout_ctr = metrics.counter("export/host_relayout_bytes")
-        metrics.gauge("export/worker_ingests/0").set(
-            _SHARD_INGESTS.get(0, 0))
         ag_hist = metrics.histogram("step/allgather_us")
 
         # time-to-first-push: wall from the backward's dispatch to the
@@ -988,9 +991,6 @@ def make_ps_train_step(
                 exp_shard_ctr.inc(flat.nbytes)
                 metrics.counter(f"export/device_bytes/{dev}").inc(
                     flat.nbytes)
-                _SHARD_INGESTS[dev] = _SHARD_INGESTS.get(dev, 0) + 1
-                metrics.gauge(f"export/worker_ingests/{dev}").set(
-                    _SHARD_INGESTS[dev])
                 return submit(name, flat, priority=pr, tag="shard")
 
         # Bucket fusion (BYTEPS_FUSION_BYTES; the group-push cure):
@@ -1231,6 +1231,15 @@ def make_ps_train_step(
         new_params: list = [None] * len(names)
         apply_parts: list = [None] * len(names)
         try:
+            # the claim starts by waiting for the backward PROGRAM to
+            # end on the device (the loss is an output of it), where the
+            # first np.asarray below would have waited as long: from
+            # here on a materialize is a wait for a transfer and nothing
+            # else
+            with tracing.span(tracing.STEP_BACKWARD_WAIT, step=tag):
+                loss[0].block_until_ready()
+            if prof is not None:
+                prof.mark("backward_done", thread_cpu=True)
             for i, (name, leaf) in enumerate(zip(names, g_leaves)):
                 if i in out_shards:
                     claim_shards(i, name, out_shards[i])
@@ -1243,7 +1252,9 @@ def make_ps_train_step(
                 if not sparse and nb < fusion:
                     # bucket member: a cross-leaf artifact, submitted
                     # by whichever later leaf flushes the bucket
-                    h = np.asarray(leaf)
+                    with tracing.span(tracing.EXPORT_BUCKET_MEMBER,
+                                      step=tag, leaf=i, bytes=nb):
+                        h = np.asarray(leaf)
                     if bucket and (bucket[0][2].dtype != h.dtype
                                    or bucket_bytes + nb > bucket_cap):
                         flush_bucket()
@@ -1287,12 +1298,15 @@ def make_ps_train_step(
                                        partitions=len(ctx.partitions))
                     waiters.append((i, *w))
             flush_bucket()
-            phase.stop()
             if prof is not None:
                 # every leaf is now off the device and submitted (each
                 # np.asarray above blocked on ITS leaf): the compute +
                 # export wall of this step's report
-                prof.mark("export_done")
+                prof.mark("export_done", thread_cpu=True)
+                phase.set(behind_backward_ms=(
+                    prof.marks["export_done"]
+                    - prof.marks["backward_done"]) * 1e3)
+            phase.stop()
             phase = tracing.span(tracing.STEP_DRAIN, step=tag).start()
             # ---- carried drain (BYTEPS_CROSS_BARRIER): the PREVIOUS
             # step's tail rounds land here, AFTER this step's backward
@@ -1371,14 +1385,16 @@ def make_ps_train_step(
             import queue as _queue
 
             ready: "_queue.Queue" = _queue.Queue()
-            for wi, (_, _, notifier) in enumerate(waiters):
-                if notifier is None:
-                    ready.put(wi)
-                else:
-                    notifier.add_done_callback(
-                        lambda *_a, wi=wi: ready.put(wi))
+            with tracing.span(tracing.APPLY_BEGIN, step=tag,
+                              waiters=len(waiters)):
+                for wi, (_, _, notifier) in enumerate(waiters):
+                    if notifier is None:
+                        ready.put(wi)
+                    else:
+                        notifier.add_done_callback(
+                            lambda *_a, wi=wi: ready.put(wi))
 
-            sa_round = sa.begin(opt_state) if sa is not None else None
+                sa_round = sa.begin(opt_state) if sa is not None else None
             # per-leaf PULL→H2D→UPDATE drain spans (the ISSUE's
             # measurement of the import half of the pipeline): each
             # land() is one leaf's H2D issue + sharded-update dispatch
@@ -1431,9 +1447,11 @@ def make_ps_train_step(
                 # last shard landed: assemble the P(axis)-sharded
                 # gradient, run the update on the shards, and dispatch
                 # the all-gather that rebuilds the replicated leaves
-                garr = jax.make_array_from_single_device_arrays(
-                    (info["n"] * info["shard_len"],),
-                    shard_sharding, parts)
+                with tracing.span(tracing.APPLY_ASSEMBLE, tid=names[s],
+                                  step=tag, leaf=s):
+                    garr = jax.make_array_from_single_device_arrays(
+                        (info["n"] * info["shard_len"],),
+                        shard_sharding, parts)
                 imported[s] = garr
                 with tracing.span(tracing.APPLY_ALLGATHER, tid=names[s],
                                   step=tag, leaf=s) as sp:
@@ -1464,13 +1482,18 @@ def make_ps_train_step(
 
             def _dispatch(wi):
                 slot, finish, _ = waiters[wi]
+                # the landed waiter's result: a leaf, a device's shard
+                # or a bucket split back into its members
+                with tracing.span(tracing.APPLY_FINISH, step=tag,
+                                  waiter=wi):
+                    got = finish()
                 if isinstance(slot, list):
-                    for s, piece in zip(slot, finish()):
+                    for s, piece in zip(slot, got):
                         land(s, piece)
                 elif isinstance(slot, tuple):
-                    land_shard(slot[1], slot[2], finish())
+                    land_shard(slot[1], slot[2], got)
                 else:
-                    land(slot, finish())
+                    land(slot, got)
 
             # cross-barrier release condition: every NON-carryable
             # waiter must land this step (front-of-model leaves,
@@ -1539,9 +1562,10 @@ def make_ps_train_step(
                 # idle before release
                 jax.block_until_ready([x for x in imported
                                        if x is not None])
-            phase.stop()
             if prof is not None:
                 prof.mark("drain_done")
+                phase.set(pull_wait_ms=prof.pull_wait_s * 1e3)
+            phase.stop()
         except BaseException:
             phase.stop()
             # a failed round (submission OR drain) may leave pulls
@@ -1632,6 +1656,12 @@ def make_ps_train_step(
             ttfp_ms=first_push[0] * 1e3 if first_push[0] is not None
             else None,
             leaves=len(names), health=health_fields, xb=xb_fields)
+        if by_thread:
+            # the step's report is closed: the second reading (the span
+            # is its cost) and the table over everything between the two
+            with tracing.span(tracing.STEP_HOST_CPU, step=tag) as sp:
+                sp.set(cpu_ms_by_thread=tracing.cpu_ms_by_thread(
+                    cpu_at, tracing.thread_cpu_ms()))
         if hplane is not None:
             hplane.raise_if_fatal()
         return _finish(params, opt_state, loss)

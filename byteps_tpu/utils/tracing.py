@@ -21,9 +21,10 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import threading
 import time
-from typing import Callable, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from jax.profiler import TraceAnnotation as _trace_me
 
@@ -76,10 +77,13 @@ def estimate_clock_offset(
 # ARGUMENTS, never part of its name.
 STEP_DISPATCH = "bps.step.dispatch"
 STEP_CLAIM = "bps.step.claim"
+STEP_BACKWARD_WAIT = "bps.step.backward_wait"
 STEP_DRAIN = "bps.step.drain"
+STEP_HOST_CPU = "bps.step.host_cpu"
 EXPORT_INGEST = "bps.export.ingest"
 EXPORT_MATERIALIZE = "bps.export.materialize"
 EXPORT_SUBMIT = "bps.export.submit"
+EXPORT_BUCKET_MEMBER = "bps.export.bucket_member"
 WIRE_SEND = "bps.wire.send"
 WIRE_DONE = "bps.wire.done"
 WIRE_PUSH = "bps.wire.push"
@@ -88,6 +92,9 @@ CODEC_COMPRESS = "bps.codec.compress"
 CODEC_DECOMPRESS = "bps.codec.decompress"
 APPLY_H2D_UPDATE = "bps.apply.h2d_update"
 APPLY_ALLGATHER = "bps.apply.allgather"
+APPLY_BEGIN = "bps.apply.begin"
+APPLY_FINISH = "bps.apply.finish"
+APPLY_ASSEMBLE = "bps.apply.assemble"
 
 _get_state = None  # core.state.get_state, imported on first use (cycle)
 
@@ -167,6 +174,61 @@ class span:
         self.__exit__(None, None, None)
 
 
+# --------------------------------------------------------------------- #
+# who burned the CPU: the process's threads, by name
+# --------------------------------------------------------------------- #
+
+_TRAILING_NUMBER = re.compile(r"[-_/:. ]*\d+$")
+
+
+def thread_cpu_ms(root: str = "/proc/self/task") -> Dict[str, float]:
+    """CPU milliseconds (user + system, since each started) of every
+    thread of this process, summed by thread name with its trailing
+    number cut (``bps-push_3`` -> ``bps-push``): the kernel's
+    ``<root>/<tid>/stat``, in clock ticks of 10 ms. A thread Python
+    started goes by its Python name (this interpreter does not hand it
+    to the kernel); every other by the name its maker gave it (the
+    runtime's pools), or the process's where it gave none (the native
+    client's). ``{}`` where there is no such tree (not Linux)."""
+    python_names = {t.native_id: t.name for t in threading.enumerate()}
+    ms_per_tick = 1e3 / os.sysconf("SC_CLK_TCK")
+    out: Dict[str, float] = {}
+    try:
+        tids = os.listdir(root)
+    except OSError:
+        return out
+    for tid in tids:
+        # three system calls a thread: on the chip machines, whose /proc
+        # is slow, ``open()``'s six and a glob's one more tripled a
+        # reading
+        try:
+            fd = os.open(f"{root}/{tid}/stat", os.O_RDONLY)
+        except OSError:
+            continue  # the thread ended between the listing and the read
+        try:
+            stat = os.read(fd, 1024).decode()
+        finally:
+            os.close(fd)
+        # "<tid> (<name, which may hold spaces and brackets>) <state> ..."
+        # : utime and stime are the 12th and 13th fields after the name
+        name = stat[stat.index("(") + 1:stat.rindex(")")]
+        fields = stat[stat.rindex(")") + 2:].split()
+        name = _TRAILING_NUMBER.sub("", python_names.get(int(tid), name)) \
+            or name
+        out[name] = out.get(name, 0.0) \
+            + (int(fields[11]) + int(fields[12])) * ms_per_tick
+    return out
+
+
+def cpu_ms_by_thread(before: Dict[str, float], after: Dict[str, float],
+                     top: int = 8) -> Dict[str, float]:
+    """The ``top`` thread names that used most CPU between two
+    ``thread_cpu_ms`` readings, with their milliseconds."""
+    used = {name: ms - before.get(name, 0.0) for name, ms in after.items()}
+    return dict(sorted(((n, ms) for n, ms in used.items() if ms > 0),
+                       key=lambda kv: -kv[1])[:top])
+
+
 class Tracer:
     """The Chrome-trace half: ``comm.json`` events of the spans that end
     inside the step window (``BYTEPS_TRACE_START_STEP``..``END_STEP``),
@@ -185,7 +247,8 @@ class Tracer:
         # "offset_ns": o, "err_ns": e, "records": [TraceRec dicts]}]
         self._server_collector: Optional[Callable[[], list]] = None
 
-    def _active(self) -> bool:
+    def active(self) -> bool:
+        """Whether the step window is open."""
         return (self._config.trace_on and
                 self._config.trace_start_step <= self._step <= self._config.trace_end_step)
 
@@ -204,7 +267,7 @@ class Tracer:
         "X"``) event on row ``tid`` when the step window is open
         (reference: core_loops.cc:69-91). The wire stage's ``rid``
         argument is what the fused dump flow-links on."""
-        if not self._active():
+        if not self.active():
             return
         ev = {
             "name": stage, "cat": "comm", "ph": "X",
