@@ -56,7 +56,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import flash_attention, publish_walk_sizes
 from . import llama as L
 from . import moe
 
@@ -206,6 +206,7 @@ def _attn_op(u, p, rope, cfg: LFM2Config):
     q = _head_norm_rope(q, p["q_norm"], cos, sin, cfg.norm_eps).astype(dt)
     k = _head_norm_rope(k, p["k_norm"], cos, sin, cfg.norm_eps).astype(dt)
     # the kernels sit under ``bps.attn.full`` (ops/flash_attention.py)
+    publish_walk_sizes(S, nh // nkv, ATTN_BLOCK, ATTN_BLOCK)
     attn = flash_attention(q, k, v, True, ATTN_BLOCK, ATTN_BLOCK, None)
     return attn.reshape(B, S, nh * hd) @ p["wo"].astype(dt)
 

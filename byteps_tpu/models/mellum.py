@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import flash_attention, publish_walk_sizes
 from . import llama as L
 from . import moe
 
@@ -194,8 +194,9 @@ def _block(x, p, ropes, cfg: MellumConfig, kind: str, ep_axis):
     v = (h @ p["wv"].astype(dt)).reshape(B, S, nkv, hd)
     # the kernels sit under ``bps.attn.window`` / ``bps.attn.full``
     # (ops/flash_attention.py ``_scope``)
-    attn = flash_attention(q, k, v, True, ATTN_BLOCK, ATTN_BLOCK,
-                           cfg.sliding_window if kind == SLIDING else None)
+    window = cfg.sliding_window if kind == SLIDING else None
+    publish_walk_sizes(S, nh // nkv, ATTN_BLOCK, ATTN_BLOCK, window)
+    attn = flash_attention(q, k, v, True, ATTN_BLOCK, ATTN_BLOCK, window)
     x = x + attn.reshape(B, S, nh * hd) @ p["wo"].astype(dt)
     return moe_sublayer(x, p, cfg, ep_axis)
 

@@ -51,7 +51,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import flash_attention, publish_walk_sizes
 from . import llama as L
 from . import mellum
 from .lfm2 import _head_norm_rope
@@ -131,6 +131,8 @@ def _block(x, p, rope, cfg: SDARConfig, ep_axis):
 
     q, k = head_norm_rope(q, p["q_norm"]), head_norm_rope(k, p["k_norm"])
     # the kernels sit under ``bps.attn.blockdiff`` (ops/flash_attention.py)
+    publish_walk_sizes(S, nh // nkv, ATTN_BLOCK, ATTN_BLOCK,
+                       diffusion_block=cfg.block_length)
     attn = flash_attention(q, k, v, True, ATTN_BLOCK, ATTN_BLOCK, None,
                            cfg.block_length)
     x = x + attn.reshape(B, S, nh * hd) @ p["wo"].astype(dt)

@@ -13,12 +13,12 @@ The quadratic HBM term wins at longer S, so long-context runs get:
   O(S * block_k)), runs on any backend — the portable reference
   semantics and the autodiff path.
 - ``flash_attention`` — Pallas TPU forward kernel (one [block_q, hd]
-  output tile per grid step, online softmax across the K grid, causal
-  blocks skipped) with a ``jax.custom_vjp`` whose backward is two more
-  Pallas kernels (``_flash_bwd``: dK/dV per key tile, dQ per query
-  tile; scores recomputed from the saved row logsumexp, never in HBM).
-  The blockwise backward's f32 score tiles went through HBM a dozen
-  times a fold: 2.4 s of a 3.0 s step at 8k tokens on the v5e (PERF.md,
+  output tile held while its key tiles pass, online softmax across
+  them) with a ``jax.custom_vjp`` whose backward is two more Pallas
+  kernels (``_flash_bwd``: dK/dV per key tile, dQ per query tile;
+  scores recomputed from the saved row logsumexp, never in HBM). The
+  blockwise backward's f32 score tiles went through HBM a dozen times
+  a fold: 2.4 s of a 3.0 s step at 8k tokens on the v5e (PERF.md,
   PR 26). Off-TPU both directions run ``blockwise_attention``.
 
 Green-field component (the reference has no attention kernels at all —
@@ -28,8 +28,8 @@ q [B,S,H,D], k/v [B,S,Hkv,D] (GQA), causal, scale 1/sqrt(D).
 
 ``window=W`` (causal only) is sliding-window attention: query ``i`` sees
 keys ``j`` with ``0 <= i - j < W``. Both paths then visit only the key
-blocks that touch the band: the kernel's inner grid axis is as long as
-the band is wide, not as long as the sequence, and the blockwise path
+blocks that touch the band: the kernels' work lists hold the band's
+tiles, not the sequence's, and the blockwise path
 (``block_q`` given, which the kernel's backward does) walks query
 blocks and folds, per query block, the key blocks between the band's
 first and last, skipping the dead ones.
@@ -44,16 +44,31 @@ its own; nothing sees a noised key of another block. That is no band:
 a noised query tile visits its own tile and the clean tiles up to it.
 
 One description of a mask on a tile grid, ``_Tiles``, says which key
-tiles a query tile visits, which query tiles a key tile, and which
-pairs inside a tile are seen; the forward, dK/dV and dQ kernels and the
-blockwise walk all take their walk from it, whatever the mask.
+tiles a query tile visits, which query tiles a key tile, which pairs
+inside a tile are seen and which tiles are seen whole; the forward,
+dK/dV and dQ kernels and the blockwise walk all take their walk from
+it, whatever the mask.
+
+The kernels' grid is (rows, heads, WORK ITEMS): the masks are static,
+so the live tiles are listed when the call is traced (``_Walk``: by
+query tile for the forward and dQ, by key tile and head of the group
+for dK/dV), handed to the kernel as scalar-prefetched int32 columns,
+and the index maps read the tile indices from them. No grid step is
+dead (a rectangular grid as long as the longest walk spends half the
+causal mask's steps and more of the block-diffusion mask's past the
+walks' ends), an item's flags say where an output tile's run begins and
+ends, and a tile the mask lets through whole takes a body without the
+in-tile test. Neither moves a bit: a plain fold that tests every tile
+gives the same output, logsumexp and gradients
+(tests/test_window_attention.py; on the v5e against the rectangular
+grid's kernels, PERF.md, PR 40).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -75,7 +90,9 @@ class _Tiles:
     ``last < first``; the index may be traced (a program id) or an
     array of indices. ``seen(i, j)``: the ``[block_q, block_k]`` pairs
     of tile ``(i, j)`` the mask lets through, or None where it lets
-    all through."""
+    all through; ``whole(i, j)``: whether that is every pair of the
+    tile. ``query_walk`` / ``key_walk(groups)``: the live tiles as the
+    kernels' work lists (``_Walk``)."""
 
     S: int
     block_q: int
@@ -163,15 +180,58 @@ class _Tiles:
         clean = hq + b_lo * B // bq
         return [(lo, hi), (clean, jnp.where(noised, clean - 1, 2 * hq - 1))]
 
-    @functools.cached_property
+    # ---- the walks, as lists of work items --------------------------- #
+
+    @property
+    def query_walk(self) -> "_Walk":
+        """The live tiles by query tile, a query tile's by key tile
+        ascending: the forward and the dQ kernel's grid axis."""
+        return _walk(self, 1, True)
+
+    def key_walk(self, groups: int) -> "_Walk":
+        """The live tiles by key tile, a key tile's once for each of
+        the ``groups`` query heads that share its key head, a head's by
+        query tile ascending: the dK/dV kernel's grid axis."""
+        return _walk(self, groups, False)
+
+    @property
     def key_steps(self) -> int:
         """The longest walk of a query tile over its key tiles."""
-        return _longest(self.key_tiles, self.nq)
+        return int(np.bincount(self.query_walk.q_tile).max())
 
-    @functools.cached_property
+    @property
     def query_steps(self) -> int:
         """The longest walk of a key tile over its query tiles."""
-        return _longest(self.query_tiles, self.nk)
+        return int(np.bincount(self.query_walk.k_tile).max())
+
+    def whole(self, i, j):
+        """Whether the mask lets every pair of tile ``(i, j)`` through
+        (``seen`` is then all true and the kernels leave it out);
+        indices or arrays of them."""
+        bq, bk, B = self.block_q, self.block_k, self.diffusion_block
+        i, j = np.asarray(i), np.asarray(j)
+        if B is None:
+            if not self.causal:
+                return np.ones(np.broadcast(i, j).shape, bool)
+            # the tile's first query against its last key, and under a
+            # window its last query against its first key
+            whole = i * bq >= j * bk + bk - 1
+            if self.window is not None:
+                whole &= i * bq + bq - 1 - j * bk < self.window
+            return whole
+        hq, hk = self.nq // 2, self.nk // 2
+        q_noised, k_noised = i < hq, j < hk
+        q_first, k_first = (i - hq * ~q_noised) * bq, (j - hk * ~k_noised) * bk
+        q_lo, q_hi = q_first // B, (q_first + bq - 1) // B     # its blocks
+        k_lo, k_hi = k_first // B, (k_first + bk - 1) // B
+        # noised under noised: one block both; a clean key under a
+        # noised query: every block of the keys before the queries';
+        # clean under clean: up to them. A noised key under a clean
+        # query is never seen
+        return np.where(
+            k_noised, q_noised & (q_lo == q_hi) & (k_lo == k_hi)
+            & (q_lo == k_lo),
+            np.where(q_noised, k_hi < q_lo, k_hi <= q_lo))
 
     # ---- which pairs of a tile --------------------------------------- #
 
@@ -212,18 +272,65 @@ class _Tiles:
         return (k1 <= q1) & (k2 >= q2)
 
 
-def _longest(tiles_of, n: int) -> int:
-    """The most tiles any of ``n`` walks visits: a size of the grid, so
-    it is computed now, whatever trace this is called under."""
+class _Walk(NamedTuple):
+    """A kernel's inner grid axis: one work item a live tile (under
+    the key walk, a live tile and query head of the group), as int32
+    columns the kernel gets by scalar prefetch. ``first`` / ``last``:
+    the item opens / closes the run of its output tile (the query tile
+    under the query walk, the key tile under the key walk); ``tested``:
+    the tile holds a pair the mask bars, so its scores take the
+    in-tile test. No item is dead."""
+
+    q_tile: np.ndarray
+    k_tile: np.ndarray
+    first: np.ndarray
+    last: np.ndarray
+    tested: np.ndarray
+    head: np.ndarray        # within the group; 0 under the query walk
+
+
+_COLUMNS = len(_Walk._fields)   # a kernel's first arguments: the walk
+
+
+@functools.lru_cache(maxsize=None)
+def _walk(tiles: _Tiles, heads: int, by_query: bool) -> _Walk:
+    """The work items of the output tiles (query tiles if ``by_query``,
+    else key tiles), each tile's walk once for each of ``heads``. The
+    masks are static, so the lists are made now, whatever trace this is
+    called under, and once a process for a mask and shape (a ``_Tiles``
+    hashes by its fields); the columns are shared, so read-only."""
+    tiles_of, n = (tiles.key_tiles, tiles.nq) if by_query \
+        else (tiles.query_tiles, tiles.nk)
     with jax.ensure_compile_time_eval():
-        return int(jnp.max(sum(jnp.maximum(hi - lo + 1, 0)
-                               for lo, hi in tiles_of(jnp.arange(n)))))
+        intervals = [(np.broadcast_to(np.asarray(lo), (n,)),
+                      np.broadcast_to(np.asarray(hi), (n,)))
+                     for lo, hi in tiles_of(jnp.arange(n))]
+    held, walked, head = [], [], []
+    for a in range(n):
+        run = np.concatenate([np.arange(lo[a], hi[a] + 1)
+                              for lo, hi in intervals])
+        walked.append(np.tile(run, heads))
+        head.append(np.repeat(np.arange(heads), run.size))
+        held.append(np.full(run.size * heads, a))
+    held, walked, head = (np.concatenate(c).astype(np.int32)
+                          for c in (held, walked, head))
+    q_tile, k_tile = (held, walked) if by_query else (walked, held)
+    edge = np.flatnonzero(np.diff(held)) + 1      # where the runs change
+    first = np.zeros(held.size, np.int32)
+    last = np.zeros(held.size, np.int32)
+    first[np.r_[0, edge]] = 1
+    last[np.r_[edge - 1, held.size - 1]] = 1
+    walk = _Walk(q_tile, k_tile, first, last,
+                 (~tiles.whole(q_tile, k_tile)).astype(np.int32), head)
+    for column in walk:
+        column.flags.writeable = False
+    return walk
 
 
 def _step(intervals, t):
     """(tile, live) at step ``t`` of a walk through ``intervals`` in
-    order: past the walk's last tile the index stays there, so a dead
-    step fetches nothing new, and ``live`` is false."""
+    order, for the blockwise path's scan of ``key_steps`` steps: past
+    the walk's last tile the index stays there and ``live`` is false."""
     (lo, hi), *more = intervals
     if not more:
         return jnp.minimum(lo + t, hi), lo + t <= hi
@@ -360,84 +467,118 @@ def _tiles(S: int, block_q: int, block_k: int, causal: bool,
                   diffusion_block)
 
 
-def _scores(q, kb, i, j, *, tiles: _Tiles, scale: float):
+def _scores(q, kb, i, j, *, tiles: _Tiles, scale: float, tested: bool):
     """[bq, bk] f32 scores of query tile ``i`` against key tile ``j``,
-    masked as ``tiles`` says. Operands go to the MXU in the type they
-    came in (bf16 stays bf16); the scores are f32."""
+    masked as ``tiles`` says where the tile is ``tested``, as they are
+    where the mask lets the whole tile through. Operands go to the MXU
+    in the type they came in (bf16 stays bf16); the scores are f32."""
     s = jax.lax.dot_general(
         q, kb, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    seen = tiles.seen(i, j)
+    seen = tiles.seen(i, j) if tested else None
     return s if seen is None else jnp.where(seen, s, _NEG_INF)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, tiles: _Tiles,
-                      scale: float, with_lse: bool):
-    """Grid (B, H, nq, key_steps) — innermost sequential ("arbitrary"):
-    scratch carries the online softmax state across one query tile's
-    key tiles for one [block_q, D] output tile. Step ``t`` of tile ``i``
-    holds the ``t``-th key tile of its walk; ``key_steps`` is the
-    longest walk (all ``nk`` tiles under the causal mask)."""
+def _item(pl, walk):
+    """What a kernel reads of its work item, the grid's last axis:
+    (the ``_Walk`` columns' entries, ``on_tile``). ``on_tile(body)``
+    runs ``body(tested)`` for the item's tile: with the in-tile test
+    where the tile holds a barred pair, without it where it does not."""
+    t = pl.program_id(2)
+    item = _Walk(*(column[t] for column in walk))
+
+    def on_tile(body):
+        pl.when(item.tested == 1)(lambda: body(True))
+        pl.when(item.tested == 0)(lambda: body(False))
+
+    return item, on_tile
+
+
+def _flash_fwd_kernel(*refs, tiles: _Tiles, scale: float, with_lse: bool):
+    """Grid (B, H, items of the query walk) — innermost sequential
+    ("arbitrary"): scratch carries the online softmax state across one
+    query tile's key tiles for one [block_q, D] output tile, from the
+    tile's first item to its last."""
     import jax.experimental.pallas as pl
 
+    walk, (q_ref, k_ref, v_ref, o_ref, *rest) = refs[:_COLUMNS], refs[_COLUMNS:]
     # with the row logsumexp asked for (the backward's residual), it is
     # one more output before the scratch
     lse_ref = rest[0] if with_lse else None
     acc_ref, m_ref, l_ref = rest[-3:]
-    i = pl.program_id(2)
-    t = pl.program_id(3)
-    j, live = _step(tiles.key_tiles(i), t)
+    item, on_tile = _item(pl, walk)
 
-    @pl.when(t == 0)
+    @pl.when(item.first == 1)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
 
-    # a step past the walk's last tile holds that tile again and
-    # contributes nothing
-    @pl.when(live)
-    def _compute():
+    @on_tile
+    def _compute(tested):
         # softmax state and the accumulator are f32
         vb = v_ref[0, 0]
-        s = _scores(q_ref[0, 0], k_ref[0, 0], i, j, tiles=tiles,
-                    scale=scale)                  # [bq, bk]
-        m_prev = m_ref[:, :1]                     # [bq, 1]
+        s = _scores(q_ref[0, 0], k_ref[0, 0], item.q_tile, item.k_tile,
+                    tiles=tiles, scale=scale, tested=tested)  # [bq, bk]
+        m_prev = m_ref[:]                         # [bq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)            # [bq, 1]
-        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
             p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_ref[:] = m_new
+        l_ref[:] = l_ref[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
 
-    @pl.when(t == tiles.key_steps - 1)
+    @pl.when(item.last == 1)
     def _finish():
-        l = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[0, 0] = (acc_ref[:] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[:] / jnp.maximum(l_ref[:], 1e-30)
+                       ).astype(o_ref.dtype)
         if lse_ref is not None:
-            # every lane holds its row's value, as the scratch does
-            lse_ref[0, 0] = m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30))
+            # every lane gets its row's value: spread here, once a query
+            # tile, and not with the state once an item
+            lse_ref[0, 0] = jnp.broadcast_to(
+                m_ref[:] + jnp.log(jnp.maximum(l_ref[:], 1e-30)),
+                lse_ref.shape[2:])
 
 
 _LANES = 128    # a row statistic is kept once a lane, [rows, 128]
 
 
+def _walk_call(kernel, walk: _Walk, operands, grid, in_specs, out_specs,
+               out_shape, scratch_shapes, tiles: _Tiles, interpret: bool):
+    """The ``pallas_call`` of all three kernels, made under the mask's
+    scope: the walk's columns as scalar prefetch, a grid (rows, heads,
+    work items) with the items in order (``ops/grouped_matmul.py``
+    hands its work items over the same way)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    with jax.named_scope(tiles.scope):
+        return pl.pallas_call(
+            kernel, out_shape=out_shape,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(walk),
+                grid=(*grid, len(walk.q_tile)), in_specs=in_specs,
+                out_specs=out_specs, scratch_shapes=scratch_shapes),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=(
+                "parallel", "parallel", "arbitrary")),
+            interpret=interpret)(*map(jnp.asarray, walk), *operands)
+
+
 def _query_walk_specs(pl, tiles: _Tiles, D: int, groups: int):
-    """Block specs of a grid (B, H, nq, key_steps) that holds one query
-    tile and walks its key tiles (the forward kernel and the dQ kernel):
-    ``q_tile(width)`` for a query-side operand, ``kv_tile`` for k and v
-    of the head's group."""
+    """Block specs of a grid (B, H, items of the query walk) that holds
+    a query tile while its items last and fetches each item's key tile
+    (the forward kernel and the dQ kernel): ``q_tile(width)`` for a
+    query-side operand, ``kv_tile`` for k and v of the head's group."""
     def q_tile(width):
-        return pl.BlockSpec((1, 1, tiles.block_q, width),
-                            lambda b, h, i, t: (b, h, i, 0))
+        return pl.BlockSpec(
+            (1, 1, tiles.block_q, width),
+            lambda b, h, t, *walk: (b, h, _Walk(*walk).q_tile[t], 0))
 
-    def kv_block(b, h, i, t):
-        return b, h // groups, _step(tiles.key_tiles(i), t)[0], 0
-
-    return q_tile, pl.BlockSpec((1, 1, tiles.block_k, D), kv_block)
+    return q_tile, pl.BlockSpec(
+        (1, 1, tiles.block_k, D),
+        lambda b, h, t, *walk: (b, h // groups, _Walk(*walk).k_tile[t], 0))
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
@@ -467,23 +608,13 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
         out_specs.append(q_tile(_LANES))
         out_shape.append(jax.ShapeDtypeStruct((B, H, S, _LANES),
                                               jnp.float32))
-    with jax.named_scope(tiles.scope):
-        out = pl.pallas_call(
-            kernel,
-            grid=(B, H, tiles.nq, tiles.key_steps),
-            in_specs=[q_tile(D), kv_tile, kv_tile],
-            out_specs=out_specs,
-            out_shape=out_shape,
-            scratch_shapes=[
-                pltpu.VMEM((tiles.block_q, D), jnp.float32),       # acc
-                pltpu.VMEM((tiles.block_q, _LANES), jnp.float32),  # max
-                pltpu.VMEM((tiles.block_q, _LANES), jnp.float32),  # denom
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "parallel",
-                                     "arbitrary")),
-            interpret=interpret,
-        )(qt, kt, vt)
+    out = _walk_call(
+        kernel, tiles.query_walk, (qt, kt, vt), (B, H),
+        [q_tile(D), kv_tile, kv_tile], out_specs, out_shape,
+        [pltpu.VMEM((tiles.block_q, D), jnp.float32),       # acc
+         pltpu.VMEM((tiles.block_q, 1), jnp.float32),       # max
+         pltpu.VMEM((tiles.block_q, 1), jnp.float32)],      # denom
+        tiles, interpret)
     o = out[0].transpose(0, 2, 1, 3)  # back to [B,S,H,D]
     return (o, out[1]) if with_lse else o
 
@@ -493,12 +624,13 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
 # --------------------------------------------------------------------- #
 
 
-def _softmax_grad(q, kb, vb, o, do, lse, i, j, *, tiles: _Tiles,
-                  scale: float):
+def _softmax_grad(q, kb, vb, o, do, lse, item: _Walk, tested: bool, *,
+                  tiles: _Tiles, scale: float):
     """(p, ds), both [bq, bk] f32: the tile's probabilities recomputed
     from the saved row logsumexp, and the scores' cotangent times the
     scale, ``p * (do v^T - rowsum(o * do)) * scale``."""
-    s = _scores(q, kb, i, j, tiles=tiles, scale=scale)
+    s = _scores(q, kb, item.q_tile, item.k_tile, tiles=tiles, scale=scale,
+                tested=tested)
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
         do, vb, (((1,), (1,)), ((), ())),
@@ -508,29 +640,27 @@ def _softmax_grad(q, kb, vb, o, do, lse, i, j, *, tiles: _Tiles,
     return p, p * (dp - delta) * scale
 
 
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                      dk_ref, dv_ref, dk_acc, dv_acc, *, groups: int,
-                      tiles: _Tiles, scale: float):
-    """Grid (B, Hkv, nk, groups * query_steps) — innermost sequential:
-    one key tile's dK and dV, summed over the query heads that share the
-    key head and over the query tiles that see it."""
+def _flash_dkv_kernel(*refs, tiles: _Tiles, scale: float):
+    """Grid (B, Hkv, items of the key walk) — innermost sequential: one
+    key tile's dK and dV, summed over the query heads that share the
+    key head and, a head, over the query tiles that see it."""
     import jax.experimental.pallas as pl
 
-    j = pl.program_id(2)
-    t = pl.program_id(3)
-    i, live = _step(tiles.query_tiles(j), t % tiles.query_steps)
+    walk, (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
+           dk_acc, dv_acc) = refs[:_COLUMNS], refs[_COLUMNS:]
+    item, on_tile = _item(pl, walk)
 
-    @pl.when(t == 0)
+    @pl.when(item.first == 1)
     def _init():
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    @pl.when(live)
-    def _compute():
+    @on_tile
+    def _compute(tested):
         q, do = q_ref[0, 0], do_ref[0, 0]
         p, ds = _softmax_grad(q, k_ref[0, 0], v_ref[0, 0], o_ref[0, 0], do,
-                              lse_ref[0, 0][:, :1], i, j, tiles=tiles,
-                              scale=scale)
+                              lse_ref[0, 0][:, :1], item, tested,
+                              tiles=tiles, scale=scale)
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -538,37 +668,36 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(t == groups * tiles.query_steps - 1)
+    @pl.when(item.last == 1)
     def _finish():
         dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
         dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
-                     dq_acc, *, tiles: _Tiles, scale: float):
-    """Grid (B, H, nq, key_steps) — the forward's walk: one query tile's
-    dQ, summed over the key tiles it sees."""
+def _flash_dq_kernel(*refs, tiles: _Tiles, scale: float):
+    """Grid (B, H, items of the query walk) — the forward's: one query
+    tile's dQ, summed over the key tiles it sees."""
     import jax.experimental.pallas as pl
 
-    i = pl.program_id(2)
-    t = pl.program_id(3)
-    j, live = _step(tiles.key_tiles(i), t)
+    walk, (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
+           dq_acc) = refs[:_COLUMNS], refs[_COLUMNS:]
+    item, on_tile = _item(pl, walk)
 
-    @pl.when(t == 0)
+    @pl.when(item.first == 1)
     def _init():
         dq_acc[:] = jnp.zeros_like(dq_acc)
 
-    @pl.when(live)
-    def _compute():
+    @on_tile
+    def _compute(tested):
         kb = k_ref[0, 0]
         _, ds = _softmax_grad(q_ref[0, 0], kb, v_ref[0, 0], o_ref[0, 0],
-                              do_ref[0, 0], lse_ref[0, 0][:, :1], i, j,
-                              tiles=tiles, scale=scale)
+                              do_ref[0, 0], lse_ref[0, 0][:, :1], item,
+                              tested, tiles=tiles, scale=scale)
         dq_acc[:] += jax.lax.dot_general(
             ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(t == tiles.key_steps - 1)
+    @pl.when(item.last == 1)
     def _finish():
         dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
 
@@ -592,57 +721,88 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, block_q: int,
     tiles = _tiles(S, block_q, block_k, causal, window, diffusion_block)
     block_q, block_k = tiles.block_q, tiles.block_k
     mask = dict(tiles=tiles, scale=1.0 / np.sqrt(D))
-    qt, kt, vt, ot, dot_ = (a.transpose(0, 2, 1, 3) for a in (q, k, v, o, do))
-    sequential = pltpu.CompilerParams(dimension_semantics=(
-        "parallel", "parallel", "parallel", "arbitrary"))
+    operands = tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v, o, do)) \
+        + (lse,)
 
-    # ---- dK, dV: key tile j of key head g; step t walks the group's
-    # query heads and, within one, the query tiles that see the tile --- #
-    n_q = tiles.query_steps
-
+    # ---- dK, dV: key head g; an item is a key tile, a query head of
+    # the group and a query tile that sees the key tile ----------------- #
     def q_side(width):
-        def index(b, g, j, t):
-            return (b, g * groups + t // n_q,
-                    _step(tiles.query_tiles(j), t % n_q)[0], 0)
+        def index(b, g, t, *walk):
+            walk = _Walk(*walk)
+            return b, g * groups + walk.head[t], walk.q_tile[t], 0
         return pl.BlockSpec((1, 1, block_q, width), index)
 
-    k_side = pl.BlockSpec((1, 1, block_k, D), lambda b, g, j, t: (b, g, j, 0))
-    with jax.named_scope(tiles.scope):
-        dk, dv = pl.pallas_call(
-            functools.partial(_flash_dkv_kernel, groups=groups, **mask),
-            grid=(B, Hkv, tiles.nk, groups * n_q),
-            in_specs=[q_side(D), k_side, k_side, q_side(D), q_side(D),
-                      q_side(_LANES)],
-            out_specs=[k_side, k_side],
-            out_shape=[jax.ShapeDtypeStruct((B, Hkv, S, D), k.dtype),
-                       jax.ShapeDtypeStruct((B, Hkv, S, D), v.dtype)],
-            scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32),
-                            pltpu.VMEM((block_k, D), jnp.float32)],
-            compiler_params=sequential, interpret=interpret,
-        )(qt, kt, vt, ot, dot_, lse)
+    k_side = pl.BlockSpec(
+        (1, 1, block_k, D),
+        lambda b, g, t, *walk: (b, g, _Walk(*walk).k_tile[t], 0))
+    dk, dv = _walk_call(
+        functools.partial(_flash_dkv_kernel, **mask), tiles.key_walk(groups),
+        operands, (B, Hkv),
+        [q_side(D), k_side, k_side, q_side(D), q_side(D), q_side(_LANES)],
+        [k_side, k_side],
+        [jax.ShapeDtypeStruct((B, Hkv, S, D), k.dtype),
+         jax.ShapeDtypeStruct((B, Hkv, S, D), v.dtype)],
+        [pltpu.VMEM((block_k, D), jnp.float32),
+         pltpu.VMEM((block_k, D), jnp.float32)], tiles, interpret)
 
     # ---- dQ: the forward's walk -------------------------------------- #
     q_tile, kv_tile = _query_walk_specs(pl, tiles, D, groups)
-    with jax.named_scope(tiles.scope):
-        dq = pl.pallas_call(
-            functools.partial(_flash_dq_kernel, **mask),
-            grid=(B, H, tiles.nq, tiles.key_steps),
-            in_specs=[q_tile(D), kv_tile, kv_tile, q_tile(D), q_tile(D),
-                      q_tile(_LANES)],
-            out_specs=q_tile(D),
-            out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-            compiler_params=sequential, interpret=interpret,
-        )(qt, kt, vt, ot, dot_, lse)
+    dq = _walk_call(
+        functools.partial(_flash_dq_kernel, **mask), tiles.query_walk,
+        operands, (B, H),
+        [q_tile(D), kv_tile, kv_tile, q_tile(D), q_tile(D), q_tile(_LANES)],
+        q_tile(D), jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        [pltpu.VMEM((block_q, D), jnp.float32)], tiles, interpret)
     return tuple(a.transpose(0, 2, 1, 3) for a in (dq, dk, dv))
+
+
+def walk_sizes(S: int, groups: int, block_q: int, block_k: int,
+               window: Optional[int] = None,
+               diffusion_block: Optional[int] = None) -> dict:
+    """What the kernels' work lists of this causal mask and shape hold,
+    by gauge name:
+    ``attention/<scope>/{items,tested_items,rect_steps}/{query,key}_walk``
+    (the walk's work items a row and head, a row and key head under
+    the key walk whose ``groups`` query heads are in its list; those
+    that take the in-tile test; the steps a rectangular grid as long as
+    the longest walk would make)."""
+    tiles = _tiles(S, block_q, block_k, True, window, diffusion_block)
+    sizes = {}
+    for walk, name, rect in (
+            (tiles.query_walk, "query_walk", tiles.nq * tiles.key_steps),
+            (tiles.key_walk(groups), "key_walk",
+             tiles.nk * groups * tiles.query_steps)):
+        for kind, value in (("items", walk.tested.size),
+                            ("tested_items", int(walk.tested.sum())),
+                            ("rect_steps", rect)):
+            # a joined name, as ``jax/train.py _fold_stats`` makes the
+            # step's statistics': a model's own instrument, none of
+            # docs/observability.md's schema of a dense step
+            sizes["/".join(("attention", tiles.scope, kind, name))] = value
+    return sizes
+
+
+def publish_walk_sizes(S: int, groups: int, block_q: int, block_k: int,
+                       window: Optional[int] = None,
+                       diffusion_block: Optional[int] = None) -> None:
+    """Set ``walk_sizes`` of this mask and shape as gauges in the
+    process's metrics registry. Properties of the mask alone: a model
+    calls this where it is traced, once a program."""
+    from ..core.state import get_state
+
+    registry = get_state().metrics
+    for name, value in walk_sizes(S, groups, block_q, block_k, window,
+                                  diffusion_block).items():
+        registry.gauge(name).set(value)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = True, block_q: int = 512,
                     block_k: int = 512, window: Optional[int] = None,
                     diffusion_block: Optional[int] = None):
-    """Pallas flash attention forward (TPU), blockwise-recompute
-    backward. Off-TPU (tests, CPU mesh) the forward also runs the
+    """Pallas flash attention (TPU): the forward kernel, and a backward
+    of two more (dK/dV and dQ, scores recomputed from the saved row
+    logsumexp). Off-TPU (tests, CPU mesh) both directions run the
     portable blockwise path, so behavior is uniform. ``window``,
     ``diffusion_block``: see the module's head."""
     if jax.default_backend() == "tpu":

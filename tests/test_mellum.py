@@ -91,6 +91,33 @@ def test_window_layers_differ_from_full_ones():
     assert abs(float(wide(params, batch)[0]) - float(loss)) > 1e-6
 
 
+def test_tracing_the_program_publishes_the_attention_walks():
+    """Where the model is traced it sets the sizes of its attention
+    kernels' work lists as gauges (``ops/flash_attention.py
+    publish_walk_sizes``), once a program, no output of the step: the
+    six of each scope, for the mask and grouping the model runs
+    (``walk_sizes`` itself is held to the dense mask in
+    tests/test_window_attention.py)."""
+    from byteps_tpu.core.state import get_state
+    from byteps_tpu.ops.flash_attention import walk_sizes
+
+    cfg = _config()
+    params, batch = _state(cfg)
+    registry = get_state().metrics
+    groups = cfg["num_attention_heads"] // cfg["num_key_value_heads"]
+    want = {**walk_sizes(cfg["seq_len"], groups, mellum.ATTN_BLOCK,
+                         mellum.ATTN_BLOCK),
+            **walk_sizes(cfg["seq_len"], groups, mellum.ATTN_BLOCK,
+                         mellum.ATTN_BLOCK, cfg["sliding_window"])}
+    assert len(want) == 12
+    assert sum(name.startswith("attention/bps.attn.window/")
+               for name in want) == 6
+    for name in want:
+        registry.gauge(name).set(-1)
+    jax.eval_shape(family.program_loss(cfg), params, batch)
+    gauges = registry.instruments()[1]
+    assert {name: gauges[name].value for name in want} == want
+
 def test_remat_and_chunks_change_nothing(monkeypatch):
     cfg = _config()
     params, batch = _state(cfg)

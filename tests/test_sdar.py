@@ -181,6 +181,30 @@ def test_loss_and_every_leafs_gradient_match_the_reference(held, compact):
         == int(batch["noise_mask"].sum())
 
 
+def test_tracing_the_program_publishes_the_attention_walks():
+    """Where the model is traced it sets the sizes of its attention
+    kernels' work lists as gauges (``ops/flash_attention.py
+    publish_walk_sizes``), once a program, no output of the step: the
+    six of each scope, for the mask and grouping the model runs
+    (``walk_sizes`` itself is held to the dense mask in
+    tests/test_window_attention.py)."""
+    from byteps_tpu.core.state import get_state
+    from byteps_tpu.ops.flash_attention import walk_sizes
+
+    cfg = _config()
+    params, batch = _state(cfg)
+    registry = get_state().metrics
+    groups = cfg["num_attention_heads"] // cfg["num_key_value_heads"]
+    want = walk_sizes(2 * cfg["seq_len"], groups, sdar.ATTN_BLOCK,
+                      sdar.ATTN_BLOCK, diffusion_block=cfg["block_length"])
+    assert len(want) == 6
+    assert want["attention/bps.attn.blockdiff/items/query_walk"] == 3
+    for name in want:
+        registry.gauge(name).set(-1)
+    jax.eval_shape(family.program_loss(cfg), params, batch)
+    gauges = registry.instruments()[1]
+    assert {name: gauges[name].value for name in want} == want
+
 def test_remat_and_tiles_change_nothing(monkeypatch):
     cfg = _config()
     params, batch = _state(cfg)

@@ -1,8 +1,11 @@
 """Masked attention (ops/flash_attention.py ``window=``,
 ``diffusion_block=``): the Pallas kernels (interpret mode) and the
 blockwise path against an explicit ``[S, S]`` mask at lengths above the
-window, forward and gradient; and the tiles the mask touches are the
-only ones visited."""
+window, forward and gradient; the tiles the mask touches are the only
+ones visited; and the kernels' work lists hold those tiles, each once
+and in order, and change no bit of a plain fold's result."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +14,8 @@ import pytest
 
 from byteps_tpu.ops.flash_attention import (_flash_bwd, _flash_fwd, _step,
                                             _tiles, blockwise_attention,
-                                            flash_attention, make_flash_attn)
+                                            flash_attention, make_flash_attn,
+                                            publish_walk_sizes, walk_sizes)
 
 
 def _qkv(S=256, H=4, Hkv=2, D=32, B=2, seed=0):
@@ -21,9 +25,11 @@ def _qkv(S=256, H=4, Hkv=2, D=32, B=2, seed=0):
             jax.random.normal(ks[2], (B, S, Hkv, D)))
 
 
-def _dense_mask(S, window=None, diffusion_block=None):
-    """[S, S] bool, written out pair by pair."""
-    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+def _dense_mask(S, window=None, diffusion_block=None, rows=None):
+    """[S, S] bool, written out pair by pair (``rows``: those query
+    positions only)."""
+    i = (np.arange(S) if rows is None else rows)[:, None]
+    j = np.arange(S)[None, :]
     if diffusion_block is None:
         mask = i >= j
         return mask if window is None else mask & (i - j < window)
@@ -138,6 +144,152 @@ def test_band_is_as_wide_as_the_window_not_the_sequence():
     assert (tiles.key_steps, tiles.query_steps) == (17, 32)
 
 
+# (S, diffusion block, (block_q, block_k)): one tile a half and several;
+# block lengths 1, 4 and the tile's own; unequal tiles; a block longer
+# than a tile and one no tile is a multiple of
+DIFFUSION = [(128, 4, (64, 64)), (256, 1, (32, 32)), (256, 4, (32, 32)),
+             (256, 32, (32, 32)), (256, 4, (64, 32)), (256, 4, (32, 64)),
+             (256, 64, (32, 32)), (192, 12, (32, 32)), (128, 64, (64, 64))]
+
+
+def _dense_tiles(S, window, block, bq, bk):
+    """([nq, nk] bool, the same): the tiles of the dense mask that hold
+    a pair it lets through, and those it lets through whole; a row of
+    tiles at a time (the cells' masks are 2 ** 28 pairs)."""
+    rows = [_dense_mask(S, window, block, np.arange(i, i + bq))
+            .reshape(bq, S // bk, bk) for i in range(0, S, bq)]
+    return (np.stack([r.any((0, 2)) for r in rows]),
+            np.stack([r.all((0, 2)) for r in rows]))
+
+
+# (S, window, diffusion block, (block_q, block_k), query heads a key
+# head): the shapes the walks' test has, and the three cells' (SDAR's
+# two copies of 8,192 positions, Mellum's causal and window layers,
+# LFM2's four heads a group), tile 512
+LISTS = [(256, None, None, (64, 64), 2), (256, 96, None, (32, 64), 2),
+         (256, 64, None, (64, 32), 1), (256, 200, None, (64, 64), 4)] \
+    + [(S, None, b, bl, 2) for S, b, bl in DIFFUSION] \
+    + [(16384, None, 4, (512, 512), 8), (8192, None, None, (512, 512), 8),
+       (8192, 1024, None, (512, 512), 8), (8192, None, None, (512, 512), 4)]
+
+
+def _runs(held):
+    """(first, last) flags of the runs of equal entries of ``held``."""
+    edge = held[1:] != held[:-1]
+    return np.r_[True, edge], np.r_[edge, True]
+
+
+@pytest.mark.parametrize("S, window, block, blocks, groups", LISTS)
+def test_the_work_lists_hold_exactly_the_live_tiles(S, window, block,
+                                                    blocks, groups):
+    """The kernels' grid axes: the query walk holds exactly the tiles
+    the dense mask touches, each once, by query tile and then key tile
+    ascending; the key walk the same tiles by key tile, head of the
+    group and query tile; ``first`` / ``last`` bracket each output
+    tile's run; ``tested`` is set where the dense tile holds a barred
+    pair and only there; no item is dead."""
+    bq, bk = blocks
+    tiles = _tiles(S, bq, bk, True, window, block)
+    touched, whole = _dense_tiles(S, window, block, bq, bk)
+    grid = np.indices(touched.shape)
+    np.testing.assert_array_equal(tiles.whole(*grid), whole)
+
+    walk = tiles.query_walk
+    want_q, want_k = np.nonzero(touched)         # row-major: by i, then j
+    np.testing.assert_array_equal(walk.q_tile, want_q)
+    np.testing.assert_array_equal(walk.k_tile, want_k)
+    first, last = _runs(want_q)
+    np.testing.assert_array_equal(walk.first, first)
+    np.testing.assert_array_equal(walk.last, last)
+    np.testing.assert_array_equal(walk.tested, ~whole[want_q, want_k])
+    assert not walk.head.any()
+    assert first.sum() == last.sum() == tiles.nq    # no tile without a run
+
+    # the key walk is the query walk transposed, once a head
+    key = tiles.key_walk(groups)
+    t_k, t_q = np.nonzero(touched.T)             # by j, then i
+    order = np.lexsort((np.tile(t_q, groups), np.repeat(np.arange(groups),
+                                                        t_k.size),
+                        np.tile(t_k, groups)))
+    np.testing.assert_array_equal(key.k_tile, np.tile(t_k, groups)[order])
+    np.testing.assert_array_equal(key.q_tile, np.tile(t_q, groups)[order])
+    np.testing.assert_array_equal(
+        key.head, np.repeat(np.arange(groups), t_k.size)[order])
+    first, last = _runs(key.k_tile)
+    np.testing.assert_array_equal(key.first, first)
+    np.testing.assert_array_equal(key.last, last)
+    np.testing.assert_array_equal(key.tested, ~whole[key.q_tile, key.k_tile])
+    assert first.sum() == tiles.nk
+    assert all(c.dtype == np.int32 for c in (*walk, *key))
+    # what a rectangular grid would have walked
+    assert tiles.key_steps == touched.sum(1).max()
+    assert tiles.query_steps == touched.sum(0).max()
+
+
+# (scope, S, window, diffusion block, query heads a key head): the
+# kernels' calls in the three cells, tile 512
+CELLS = [("bps.attn.blockdiff", 16384, None, 4, 8),     # sdar-30b-a3b
+         ("bps.attn.full", 8192, None, None, 8),        # mellum2-12b
+         ("bps.attn.window", 8192, 1024, None, 8),
+         ("bps.attn.full", 8192, None, None, 4)]        # lfm2-8b-a1b
+
+
+@pytest.mark.parametrize("scope, S, window, block, groups", CELLS)
+def test_the_gauges_count_the_dense_masks_tiles(scope, S, window, block,
+                                                groups):
+    """``walk_sizes`` at a cell's shape, and the gauges
+    ``publish_walk_sizes`` sets from it: work items, those that take the
+    in-tile test and the steps of a rectangular grid as long as the
+    longest walk, a row and head under the query walk and a row and key
+    head under the key walk, each counted here on the DENSE mask's tiles
+    (SDAR: 288 items where the grid had 544 steps, 2,304 where it had
+    8,192)."""
+    from byteps_tpu.core.state import get_state
+
+    touched, whole = _dense_tiles(S, window, block, 512, 512)
+    live, tested = int(touched.sum()), int((touched & ~whole).sum())
+    assert 0 < tested < live < touched.size
+    want = {"items/query_walk": live, "tested_items/query_walk": tested,
+            "rect_steps/query_walk":
+            touched.shape[0] * int(touched.sum(1).max()),
+            "items/key_walk": groups * live,
+            "tested_items/key_walk": groups * tested,
+            "rect_steps/key_walk":
+            touched.shape[1] * groups * int(touched.sum(0).max())}
+    assert want["items/query_walk"] < want["rect_steps/query_walk"]
+    want = {f"attention/{scope}/{name}": n for name, n in want.items()}
+    assert walk_sizes(S, groups, 512, 512, window, block) == want
+    registry = get_state().metrics
+    for name in want:
+        registry.gauge(name).set(-1)
+    publish_walk_sizes(S, groups, 512, 512, window, block)
+    gauges = registry.instruments()[1]
+    assert {name: gauges[name].value for name in want} == want
+
+
+def test_each_kernels_grid_is_as_long_as_its_list():
+    """No dead step: the three kernels' grids end at their lists' last
+    items."""
+    q, k, v = _qkv(S=256, B=1)                    # 4 heads on 2
+    tiles = _tiles(256, 32, 32, True, None, 4)
+
+    def grids(fn, *args):
+        return [eqn.params["grid_mapping"].grid
+                for eqn in jax.make_jaxpr(fn)(*args).eqns
+                if eqn.primitive.name == "pallas_call"]
+
+    fwd = functools.partial(_flash_fwd, causal=True, block_q=32, block_k=32,
+                            interpret=True, with_lse=True, diffusion_block=4)
+    assert grids(fwd, q, k, v) == [(1, 4, tiles.query_walk.first.size)]
+    out, lse = fwd(q, k, v)
+    assert grids(
+        lambda *a: _flash_bwd(*a, True, 32, 32, interpret=True,
+                              diffusion_block=4), q, k, v, out, lse, q) \
+        == [(1, 2, tiles.key_walk(2).first.size),
+            (1, 4, tiles.query_walk.first.size)]
+    assert tiles.query_walk.first.size < tiles.nq * tiles.key_steps
+
+
 def test_key_blocks_outside_the_band_are_not_read():
     """Keys outside every query's window may hold anything, NaN even:
     a path that multiplied them by zero would spread it."""
@@ -152,14 +304,6 @@ def test_key_blocks_outside_the_band_are_not_read():
             blockwise_attention(q, k, v, True, block_k=64, window=window,
                                 block_q=64)):
         assert np.all(np.isfinite(np.asarray(out[:, 128:])))
-
-
-# (S, diffusion block, (block_q, block_k)): one tile a half and several;
-# block lengths 1, 4 and the tile's own; unequal tiles; a block longer
-# than a tile and one no tile is a multiple of
-DIFFUSION = [(128, 4, (64, 64)), (256, 1, (32, 32)), (256, 4, (32, 32)),
-             (256, 32, (32, 32)), (256, 4, (64, 32)), (256, 4, (32, 64)),
-             (256, 64, (32, 32)), (192, 12, (32, 32)), (128, 64, (64, 64))]
 
 
 def _walked(tiles):
@@ -317,3 +461,128 @@ def test_kernels_match_blockwise_attention_at_the_head_size(D, H, Hkv,
     flat = _explicit(q, k, v, window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(flat),
                                rtol=1e-5, atol=1e-5)
+
+
+# --------------------------------------------------------------------- #
+# the work lists and the body without the in-tile test change no bit
+# --------------------------------------------------------------------- #
+
+_dot = functools.partial(jax.lax.dot_general,
+                         preferred_element_type=jnp.float32)
+_ROWS, _COLS, _BOTH_ROWS = (((1,), (0,)), ((), ())), \
+    (((1,), (1,)), ((), ())), (((0,), (0,)), ((), ()))
+
+
+@jax.jit
+def _fold_tile(q, k, v, seen, m, l, acc, scale):
+    """One tile of the plain forward fold, the in-tile test applied."""
+    s = jnp.where(seen, _dot(q, k, _COLS) * scale, -1e30)
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m - m_new)
+    return (m_new, l * corr + jnp.sum(p, axis=-1, keepdims=True),
+            acc * corr + _dot(p.astype(v.dtype), v, _ROWS))
+
+
+@jax.jit
+def _grad_tile(q, k, v, o, do, lse, seen, scale):
+    """(p, ds) of one tile of the plain backward, the test applied."""
+    s = jnp.where(seen, _dot(q, k, _COLS) * scale, -1e30)
+    p = jnp.exp(s - lse)
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32),
+                    axis=-1, keepdims=True)
+    return p, p * (_dot(do, v, _COLS) - delta) * scale
+
+
+def _plain_fold(q, k, v, do, bq, bk, window, block):
+    """(out, row logsumexp, dq, dk, dv) by loops over rows, heads and
+    the tiles the DENSE mask touches, ascending, ``seen`` (the dense
+    mask's tile) applied on every one: float32 state, the kernels'
+    arithmetic written out a tile at a time, nothing of ``_Tiles``."""
+    B, S, H, D = q.shape
+    g = H // k.shape[2]
+    scale = 1.0 / np.sqrt(D)
+    dense = _dense_mask(S, window, block)
+    nq, nk = S // bq, S // bk
+    live = dense.reshape(nq, bq, nk, bk).any((1, 3))
+
+    def rows(i, n):
+        return slice(i * n, (i + 1) * n)
+
+    def seen(i, j):
+        return jnp.asarray(dense[rows(i, bq), rows(j, bk)])
+
+    out = np.zeros(q.shape, np.float32)
+    lse = np.zeros((B, H, S, 1), np.float32)
+    for b, h, i in np.ndindex(B, H, nq):
+        m, l, acc = jnp.full((bq, 1), -1e30), jnp.zeros((bq, 1)), \
+            jnp.zeros((bq, D))
+        for j in np.flatnonzero(live[i]):
+            m, l, acc = _fold_tile(q[b, rows(i, bq), h],
+                                   k[b, rows(j, bk), h // g],
+                                   v[b, rows(j, bk), h // g], seen(i, j),
+                                   m, l, acc, scale)
+        out[b, rows(i, bq), h] = acc / jnp.maximum(l, 1e-30)
+        lse[b, h, rows(i, bq)] = m + jnp.log(jnp.maximum(l, 1e-30))
+    out = jnp.asarray(out).astype(q.dtype)
+
+    def grads(b, h, i, j):
+        return _grad_tile(q[b, rows(i, bq), h], k[b, rows(j, bk), h // g],
+                          v[b, rows(j, bk), h // g], out[b, rows(i, bq), h],
+                          do[b, rows(i, bq), h],
+                          jnp.asarray(lse[b, h, rows(i, bq)]), seen(i, j),
+                          scale)
+
+    dq = np.zeros(q.shape, np.float32)
+    dk, dv = np.zeros(k.shape, np.float32), np.zeros(v.shape, np.float32)
+    for b, h, i in np.ndindex(B, H, nq):
+        acc = jnp.zeros((bq, D))
+        for j in np.flatnonzero(live[i]):
+            kb = k[b, rows(j, bk), h // g]
+            acc = acc + _dot(grads(b, h, i, j)[1].astype(kb.dtype), kb, _ROWS)
+        dq[b, rows(i, bq), h] = acc
+    # a key tile: the group's heads in order, a head's query tiles in order
+    for b, kvh, j in np.ndindex(B, k.shape[2], nk):
+        dk_acc, dv_acc = jnp.zeros((bk, D)), jnp.zeros((bk, D))
+        for h in range(kvh * g, kvh * g + g):
+            for i in np.flatnonzero(live[:, j]):
+                p, ds = grads(b, h, i, j)
+                qt, dot_ = q[b, rows(i, bq), h], do[b, rows(i, bq), h]
+                dv_acc = dv_acc + _dot(p.astype(dot_.dtype), dot_, _BOTH_ROWS)
+                dk_acc = dk_acc + _dot(ds.astype(qt.dtype), qt, _BOTH_ROWS)
+        dk[b, rows(j, bk), kvh], dv[b, rows(j, bk), kvh] = dk_acc, dv_acc
+    return (out, jnp.asarray(lse[..., 0]), jnp.asarray(dq).astype(q.dtype),
+            jnp.asarray(dk).astype(k.dtype), jnp.asarray(dv).astype(v.dtype))
+
+
+@pytest.mark.parametrize("window, block, blocks", [
+    (None, 4, (32, 32)), (96, None, (32, 64)), (None, None, (64, 64))])
+def test_the_lists_and_the_untested_body_change_no_bit(window, block, blocks):
+    """Output, row logsumexp and the three gradients of the kernels
+    (interpret mode; two query heads a key head) are BITWISE those of a
+    plain fold over the dense mask's live tiles that applies the
+    in-tile test on every tile: leaving the test out where the mask
+    lets a tile through whole, and walking a list, move nothing.
+
+    At head size 16, whose scale 1/4 is a power of two. XLA's CPU
+    backend contracts ``dot * scale - max`` into one fused multiply-add
+    where no select stands between the two, a rounding the chip's
+    vector unit does not make (on the v5e the kernels equal the
+    rectangular-grid ones bit for bit at head sizes 64 and 128: PERF.md,
+    PR 40); with an exact product the contraction moves nothing and the
+    comparison holds the algorithm alone."""
+    bq, bk = blocks
+    q, k, v = _qkv(S=256, B=1, H=4, Hkv=2, D=16, seed=6)
+    do = jax.random.normal(jax.random.PRNGKey(7), q.shape)
+    out, lse = _flash_fwd(q, k, v, True, bq, bk, interpret=True,
+                          window=window, with_lse=True,
+                          diffusion_block=block)
+    got = (out, lse[..., 0]) + _flash_bwd(
+        q, k, v, out, lse, do, True, bq, bk, window, interpret=True,
+        diffusion_block=block)
+    tiles = _tiles(256, bq, bk, True, window, block)
+    assert 0 < tiles.query_walk.tested.sum() < tiles.query_walk.tested.size
+    for name, g, w in zip(("out", "lse", "dq", "dk", "dv"), got,
+                          _plain_fold(q, k, v, do, bq, bk, window, block)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        assert bool(jnp.array_equal(g, w)), name
