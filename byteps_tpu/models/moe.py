@@ -39,6 +39,10 @@ this is green-field TPU design:
   grouped product sits between two ``lax.all_to_all`` calls (pairs to
   their expert's owner, results back); on one device it runs without
   the exchange;
+- a layer may carry a SHARED expert (leaves ``shared_gate`` /
+  ``shared_up`` / ``shared_down``): a dense SwiGLU every token passes,
+  added to the held experts' sum. It is replicated, not a share: when
+  the shares of a deployment are added up it counts once;
 - attention/embedding reuse the Llama building blocks (models/llama.py).
 
 The Mixtral model below adds the Switch load-balancing auxiliary loss.
@@ -481,6 +485,19 @@ def _exchanged(x, gates, idx, w_gate, w_up, w_down, dtype, ep_axis):
     return out.astype(dtype), load, dropped
 
 
+def shared_expert(x_flat: jnp.ndarray, p: Dict[str, jnp.ndarray],
+                  dtype: Any) -> jnp.ndarray:
+    """The shared expert of a layer: ``W_down(silu(W_gate x) * W_up x)``
+    on every token, x_flat [T, d] -> [T, d]. Three dense products
+    (fusions under ``bps.moe.shared``: the HLO ``op_name`` carries the
+    scope), no routing, no statistic."""
+    with jax.named_scope("bps.moe.shared"):
+        x = x_flat.astype(dtype)
+        h = jax.nn.silu(x @ p["shared_gate"].astype(dtype)) \
+            * (x @ p["shared_up"].astype(dtype))
+        return h @ p["shared_down"].astype(dtype)
+
+
 def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
               dtype: Any, first: int = 0, ep_axis: Optional[str] = None,
               chunk: Optional[int] = None,
@@ -499,7 +516,11 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
     (bounds the sorted pair buffer at ``chunk * top_k`` rows); it must
     divide the tokens where there are more of them than a slice.
     ``routing``: ``route``'s ``score``, ``select_bias``, ``norm_eps``
-    and ``scale``; none is softmax top-k renormalised.
+    and ``scale``; none is softmax top-k renormalised. Where ``p`` has
+    ``shared_gate`` / ``shared_up`` / ``shared_down`` ([d, h], [d, h],
+    [h, d]) the shared expert's SwiGLU of every token is added to the
+    output (the same on every device of a deployment: a caller that
+    adds shares up hands it to one of them).
 
     Returns (output [B, S, d], stats): ``load`` [n_held] int32 (pairs
     per held expert in the grouped product), ``dropped`` (pairs the
@@ -555,6 +576,8 @@ def moe_layer(x: jnp.ndarray, p: Dict[str, jnp.ndarray], top_k: int,
             _kernel_stats(loads, compact_rows(N, n_held, n_experts), d,
                           hidden),
             _kernel_stats(loads, N, d, hidden))
+    if "shared_gate" in p:
+        out = out + shared_expert(x_flat, p, dtype)
     stats = {"load": load, "dropped": dropped, "compact_slices": compact,
              "full_slices": n - compact, "kernel_slices": kernel[0],
              "kernel_tile_rows": kernel[1]}

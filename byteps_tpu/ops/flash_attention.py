@@ -43,6 +43,17 @@ blocks before it; a clean query sees the clean keys of the blocks up to
 its own; nothing sees a noised key of another block. That is no band:
 a noised query tile visits its own tile and the clean tiles up to it.
 
+``latent_attention`` is the causal mask over a score in two parts
+(multi-head latent attention, not absorbed): each query head's own
+``D``-wide product with its key head plus a ``Dr``-wide product against
+ONE rotary key head shared by every query head of the row, scaled ``1 /
+sqrt(D + Dr)``, mixing values of a width of their own. The shared key
+is one ``[B, S, 1, Dr]`` operand of every kernel (its block index has
+no head in it) and is never repeated a head in HBM; its gradient leaves
+the dK/dV kernel a key head and is summed over them once. The same
+kernels, walks and lists as the causal mask's, under
+``bps.attn.mla``.
+
 One description of a mask on a tile grid, ``_Tiles``, says which key
 tiles a query tile visits, which query tiles a key tile, which pairs
 inside a tile are seen and which tiles are seen whole; the forward,
@@ -100,9 +111,15 @@ class _Tiles:
     causal: bool = True
     window: Optional[int] = None
     diffusion_block: Optional[int] = None
+    # the score has a second part against one key head shared by all
+    # (``latent_attention``): changes no tile and no list, names the scope
+    latent: bool = False
 
     def __post_init__(self):
         S, B = self.S, self.diffusion_block
+        if self.latent and (B is not None or self.window is not None
+                            or not self.causal):
+            raise ValueError("latent attention runs under the causal mask")
         if self.window is not None and not self.causal:
             raise ValueError("a window needs causal=True")
         if B is not None and (self.window is not None or not self.causal):
@@ -135,6 +152,8 @@ class _Tiles:
         (docs/timeline.md "Device scopes")."""
         if self.diffusion_block is not None:
             return "bps.attn.blockdiff"
+        if self.latent:
+            return "bps.attn.mla"
         return "bps.attn.window" if self.window is not None \
             else "bps.attn.full"
 
@@ -341,14 +360,20 @@ def _step(intervals, t):
     return jnp.where(at < n1, lo + at, lo2 + at - n1), t < n
 
 
-def _tiled_attention(q, k, v, tiles: _Tiles, remat: bool):
+def _tiled_attention(q, k, v, tiles: _Tiles, remat: bool, shared=None):
     """Query blocks outside, key blocks inside: a query block folds only
     the key blocks the mask gives it (``lax.cond`` skips the steps past
     its walk's last), so a causal layer does half the score work of the
     all-pairs scan, a window layer ``(W + block_q) / S`` of it and a
-    block-diffusion layer a quarter. Same fold, same result."""
-    B, S, H, D = q.shape
-    Hkv = k.shape[2]
+    block-diffusion layer a quarter. Same fold, same result.
+    ``shared``: ``(q_r [B,S,H,Dr], k_r [B,S,1,Dr])``, the score's second
+    part (``latent_attention``): the queries' columns are joined to
+    ``q`` once, the one shared key head to each key TILE as it is
+    folded, so the transpose sums its gradient over the heads."""
+    if shared is not None:
+        q = jnp.concatenate([q, shared[0]], axis=-1)
+    B, S, H, D = q.shape                              # D: the score's width
+    Hkv, Dv = k.shape[2], v.shape[-1]
     groups = H // Hkv
     block_q, block_k = tiles.block_q, tiles.block_k
     scale = 1.0 / np.sqrt(D)
@@ -366,6 +391,11 @@ def _tiled_attention(q, k, v, tiles: _Tiles, remat: bool):
                 vb = jax.lax.dynamic_slice_in_dim(v, start, block_k, 1)
                 kb = kb.astype(jnp.float32)
                 vb = vb.astype(jnp.float32)
+                if shared is not None:
+                    kr = jax.lax.dynamic_slice_in_dim(
+                        shared[1], start, block_k, 1).astype(jnp.float32)
+                    kb = jnp.concatenate([kb, jnp.broadcast_to(
+                        kr, (*kb.shape[:3], kr.shape[-1]))], axis=-1)
                 if groups > 1:
                     kb = jnp.repeat(kb, groups, axis=2)
                     vb = jnp.repeat(vb, groups, axis=2)
@@ -375,7 +405,7 @@ def _tiled_attention(q, k, v, tiles: _Tiles, remat: bool):
 
         m0 = jnp.full((B, H, block_q), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, H, block_q), jnp.float32)
-        o0 = jnp.zeros((B, block_q, H, D), jnp.float32)
+        o0 = jnp.zeros((B, block_q, H, Dv), jnp.float32)
         (m, l, o), _ = jax.lax.scan(
             jax.checkpoint(fold) if remat else fold, (m0, l0, o0),
             jnp.arange(tiles.key_steps))
@@ -385,26 +415,29 @@ def _tiled_attention(q, k, v, tiles: _Tiles, remat: bool):
     fn = jax.checkpoint(one_q_block) if remat else one_q_block
     qs = q.reshape(B, tiles.nq, block_q, H, D).transpose(1, 0, 2, 3, 4)
     out = jax.lax.map(lambda a: fn(*a), (jnp.arange(tiles.nq), qs))
-    return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, D)
+    return out.transpose(1, 0, 2, 3, 4).reshape(B, S, H, Dv)
 
 
 def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         causal: bool = True, block_k: int = 512,
                         remat: bool = True, window: Optional[int] = None,
                         block_q: Optional[int] = None,
-                        diffusion_block: Optional[int] = None
-                        ) -> jnp.ndarray:
+                        diffusion_block: Optional[int] = None,
+                        shared=None) -> jnp.ndarray:
     """Exact attention streaming over KV blocks: peak residency
     O(S * block_k) instead of O(S^2). q [B,S,H,D], k/v [B,S,Hkv,D].
     ``window``, ``diffusion_block`` (see the module's head) or
     ``block_q`` selects the walk over query blocks that leaves out the
-    key blocks the mask does not touch."""
+    key blocks the mask does not touch; so does ``shared`` (``(q_r,
+    k_r)``: ``latent_attention``'s score in two parts, v of a width of
+    its own)."""
     B, S, H, D = q.shape
     if window is not None or block_q is not None \
-            or diffusion_block is not None:
+            or diffusion_block is not None or shared is not None:
         return _tiled_attention(
             q, k, v, _tiles(S, block_q or block_k, block_k, causal, window,
-                            diffusion_block), remat)
+                            diffusion_block, shared is not None), remat,
+            shared)
     Hkv = k.shape[2]
     groups = H // Hkv
     block_k = min(block_k, S)
@@ -458,25 +491,44 @@ def blockwise_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 def _tiles(S: int, block_q: int, block_k: int, causal: bool,
-           window: Optional[int], diffusion_block: Optional[int]) -> _Tiles:
+           window: Optional[int], diffusion_block: Optional[int],
+           latent: bool = False) -> _Tiles:
     """The mask on the tile grid the callers ask for, a tile never
     longer than what it tiles (the sequence; a half of it under the
     block-diffusion mask)."""
     most = S if diffusion_block is None else max(S // 2, 1)
     return _Tiles(S, min(block_q, most), min(block_k, most), causal, window,
-                  diffusion_block)
+                  diffusion_block, latent)
 
 
-def _scores(q, kb, i, j, *, tiles: _Tiles, scale: float, tested: bool):
+def _scores(q, kb, i, j, *, tiles: _Tiles, scale: float, tested: bool,
+            shared=None):
     """[bq, bk] f32 scores of query tile ``i`` against key tile ``j``,
     masked as ``tiles`` says where the tile is ``tested``, as they are
     where the mask lets the whole tile through. Operands go to the MXU
-    in the type they came in (bf16 stays bf16); the scores are f32."""
+    in the type they came in (bf16 stays bf16); the scores are f32.
+    ``shared``: the tiles ``(q_r, k_r)`` of the score's second part."""
     s = jax.lax.dot_general(
         q, kb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
+        preferred_element_type=jnp.float32)
+    if shared is not None:
+        s = s + jax.lax.dot_general(
+            *shared, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    s = s * scale
     seen = tiles.seen(i, j) if tested else None
     return s if seen is None else jnp.where(seen, s, _NEG_INF)
+
+
+def _shared_refs(refs, tiles: _Tiles):
+    """(the refs ``(q_r, k_r)`` of the score's second part or None, the
+    refs after them): under ``tiles.latent`` they follow a kernel's
+    other inputs."""
+    return (tuple(refs[:2]), refs[2:]) if tiles.latent else (None, refs)
+
+
+def _tile_of(shared):
+    return None if shared is None else tuple(r[0, 0] for r in shared)
 
 
 def _item(pl, walk):
@@ -501,7 +553,8 @@ def _flash_fwd_kernel(*refs, tiles: _Tiles, scale: float, with_lse: bool):
     tile's first item to its last."""
     import jax.experimental.pallas as pl
 
-    walk, (q_ref, k_ref, v_ref, o_ref, *rest) = refs[:_COLUMNS], refs[_COLUMNS:]
+    walk, (q_ref, k_ref, v_ref, *rest) = refs[:_COLUMNS], refs[_COLUMNS:]
+    shared, (o_ref, *rest) = _shared_refs(rest, tiles)
     # with the row logsumexp asked for (the backward's residual), it is
     # one more output before the scratch
     lse_ref = rest[0] if with_lse else None
@@ -519,7 +572,8 @@ def _flash_fwd_kernel(*refs, tiles: _Tiles, scale: float, with_lse: bool):
         # softmax state and the accumulator are f32
         vb = v_ref[0, 0]
         s = _scores(q_ref[0, 0], k_ref[0, 0], item.q_tile, item.k_tile,
-                    tiles=tiles, scale=scale, tested=tested)  # [bq, bk]
+                    tiles=tiles, scale=scale, tested=tested,
+                    shared=_tile_of(shared))                  # [bq, bk]
         m_prev = m_ref[:]                         # [bq, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -566,56 +620,68 @@ def _walk_call(kernel, walk: _Walk, operands, grid, in_specs, out_specs,
             interpret=interpret)(*map(jnp.asarray, walk), *operands)
 
 
-def _query_walk_specs(pl, tiles: _Tiles, D: int, groups: int):
+def _query_walk_specs(pl, tiles: _Tiles, groups: int):
     """Block specs of a grid (B, H, items of the query walk) that holds
     a query tile while its items last and fetches each item's key tile
     (the forward kernel and the dQ kernel): ``q_tile(width)`` for a
-    query-side operand, ``kv_tile`` for k and v of the head's group."""
+    query-side operand, ``kv_tile(width)`` for k and v of the head's
+    group; ``kv_tile(width, shared=True)`` for the one key head every
+    query head shares."""
     def q_tile(width):
         return pl.BlockSpec(
             (1, 1, tiles.block_q, width),
             lambda b, h, t, *walk: (b, h, _Walk(*walk).q_tile[t], 0))
 
-    return q_tile, pl.BlockSpec(
-        (1, 1, tiles.block_k, D),
-        lambda b, h, t, *walk: (b, h // groups, _Walk(*walk).k_tile[t], 0))
+    def kv_tile(width, shared=False):
+        return pl.BlockSpec(
+            (1, 1, tiles.block_k, width),
+            lambda b, h, t, *walk: (b, 0 if shared else h // groups,
+                                    _Walk(*walk).k_tile[t], 0))
+
+    return q_tile, kv_tile
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
                interpret: bool = False, window: Optional[int] = None,
                with_lse: bool = False,
-               diffusion_block: Optional[int] = None):
+               diffusion_block: Optional[int] = None, shared=None):
+    """``shared``: ``(q_r [B,S,H,Dr], k_r [B,S,1,Dr])``, the score's
+    second part (``latent_attention``); v may then be of another width
+    than q and k, and the output is of v's."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, H, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[-1]
     groups = H // Hkv
-    tiles = _tiles(S, block_q, block_k, causal, window, diffusion_block)
-    scale = 1.0 / np.sqrt(D)
+    tiles = _tiles(S, block_q, block_k, causal, window, diffusion_block,
+                   shared is not None)
+    Dr = shared[0].shape[-1] if tiles.latent else 0
+    scale = 1.0 / np.sqrt(D + Dr)
 
     # [B,H,S,D] layout: one (b, h, tile) per grid step
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
+    operands = tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v, *(shared or ())))
 
     kernel = functools.partial(_flash_fwd_kernel, tiles=tiles, scale=scale,
                                with_lse=with_lse)
-    q_tile, kv_tile = _query_walk_specs(pl, tiles, D, groups)
-    out_specs = [q_tile(D)]
-    out_shape = [jax.ShapeDtypeStruct((B, H, S, D), q.dtype)]
+    q_tile, kv_tile = _query_walk_specs(pl, tiles, groups)
+    in_specs = [q_tile(D), kv_tile(D), kv_tile(Dv)]
+    if tiles.latent:
+        in_specs += [q_tile(Dr), kv_tile(Dr, shared=True)]
+    out_specs = [q_tile(Dv)]
+    out_shape = [jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype)]
     if with_lse:
         out_specs.append(q_tile(_LANES))
         out_shape.append(jax.ShapeDtypeStruct((B, H, S, _LANES),
                                               jnp.float32))
     out = _walk_call(
-        kernel, tiles.query_walk, (qt, kt, vt), (B, H),
-        [q_tile(D), kv_tile, kv_tile], out_specs, out_shape,
-        [pltpu.VMEM((tiles.block_q, D), jnp.float32),       # acc
+        kernel, tiles.query_walk, operands, (B, H), in_specs, out_specs,
+        out_shape,
+        [pltpu.VMEM((tiles.block_q, Dv), jnp.float32),      # acc
          pltpu.VMEM((tiles.block_q, 1), jnp.float32),       # max
          pltpu.VMEM((tiles.block_q, 1), jnp.float32)],      # denom
         tiles, interpret)
-    o = out[0].transpose(0, 2, 1, 3)  # back to [B,S,H,D]
+    o = out[0].transpose(0, 2, 1, 3)  # back to [B,S,H,Dv]
     return (o, out[1]) if with_lse else o
 
 
@@ -625,12 +691,12 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int,
 
 
 def _softmax_grad(q, kb, vb, o, do, lse, item: _Walk, tested: bool, *,
-                  tiles: _Tiles, scale: float):
+                  tiles: _Tiles, scale: float, shared=None):
     """(p, ds), both [bq, bk] f32: the tile's probabilities recomputed
     from the saved row logsumexp, and the scores' cotangent times the
     scale, ``p * (do v^T - rowsum(o * do)) * scale``."""
     s = _scores(q, kb, item.q_tile, item.k_tile, tiles=tiles, scale=scale,
-                tested=tested)
+                tested=tested, shared=shared)
     p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(
         do, vb, (((1,), (1,)), ((), ())),
@@ -646,32 +712,43 @@ def _flash_dkv_kernel(*refs, tiles: _Tiles, scale: float):
     key head and, a head, over the query tiles that see it."""
     import jax.experimental.pallas as pl
 
-    walk, (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dk_ref, dv_ref,
-           dk_acc, dv_acc) = refs[:_COLUMNS], refs[_COLUMNS:]
+    walk, (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest) = \
+        refs[:_COLUMNS], refs[_COLUMNS:]
+    shared, rest = _shared_refs(rest, tiles)
+    # outputs, then as many accumulators: dK, dV and, of the score's
+    # second part, this key head's share of the shared key's gradient
+    outs, accs = rest[:len(rest) // 2], rest[len(rest) // 2:]
+    dk_acc, dv_acc = accs[:2]
     item, on_tile = _item(pl, walk)
 
     @pl.when(item.first == 1)
     def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
+        for acc in accs:
+            acc[:] = jnp.zeros_like(acc)
 
     @on_tile
     def _compute(tested):
         q, do = q_ref[0, 0], do_ref[0, 0]
         p, ds = _softmax_grad(q, k_ref[0, 0], v_ref[0, 0], o_ref[0, 0], do,
                               lse_ref[0, 0][:, :1], item, tested,
-                              tiles=tiles, scale=scale)
+                              tiles=tiles, scale=scale,
+                              shared=_tile_of(shared))
         dv_acc[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dk_acc[:] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if shared is not None:
+            qr = shared[0][0, 0]
+            accs[2][:] += jax.lax.dot_general(
+                ds.astype(qr.dtype), qr, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(item.last == 1)
     def _finish():
-        dk_ref[0, 0] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, 0] = dv_acc[:].astype(dv_ref.dtype)
+        for out, acc in zip(outs, accs):
+            out[0, 0] = acc[:].astype(out.dtype)
 
 
 def _flash_dq_kernel(*refs, tiles: _Tiles, scale: float):
@@ -679,50 +756,68 @@ def _flash_dq_kernel(*refs, tiles: _Tiles, scale: float):
     tile's dQ, summed over the key tiles it sees."""
     import jax.experimental.pallas as pl
 
-    walk, (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
-           dq_acc) = refs[:_COLUMNS], refs[_COLUMNS:]
+    walk, (q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, *rest) = \
+        refs[:_COLUMNS], refs[_COLUMNS:]
+    shared, rest = _shared_refs(rest, tiles)
+    # dQ and, of the score's second part, dQ_r; then as many accumulators
+    outs, accs = rest[:len(rest) // 2], rest[len(rest) // 2:]
     item, on_tile = _item(pl, walk)
 
     @pl.when(item.first == 1)
     def _init():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
+        for acc in accs:
+            acc[:] = jnp.zeros_like(acc)
 
     @on_tile
     def _compute(tested):
         kb = k_ref[0, 0]
         _, ds = _softmax_grad(q_ref[0, 0], kb, v_ref[0, 0], o_ref[0, 0],
                               do_ref[0, 0], lse_ref[0, 0][:, :1], item,
-                              tested, tiles=tiles, scale=scale)
-        dq_acc[:] += jax.lax.dot_general(
+                              tested, tiles=tiles, scale=scale,
+                              shared=_tile_of(shared))
+        accs[0][:] += jax.lax.dot_general(
             ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if shared is not None:
+            kr = shared[1][0, 0]
+            accs[1][:] += jax.lax.dot_general(
+                ds.astype(kr.dtype), kr, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     @pl.when(item.last == 1)
     def _finish():
-        dq_ref[0, 0] = dq_acc[:].astype(dq_ref.dtype)
+        for out, acc in zip(outs, accs):
+            out[0, 0] = acc[:].astype(out.dtype)
 
 
 def _flash_bwd(q, k, v, o, lse, do, causal: bool, block_q: int,
                block_k: int, window: Optional[int] = None,
                interpret: bool = False,
-               diffusion_block: Optional[int] = None):
+               diffusion_block: Optional[int] = None, shared=None):
     """(dq, dk, dv) of ``_flash_fwd`` from its output, its row
     logsumexp ([B,H,S,128], every lane the row's value) and the
     output's cotangent. Two kernels: dK/dV per key tile (the query
     heads of a group folded into the walk, so the sums are complete and
     [B,Hkv,S,D]) and dQ per query tile; both walk only the tiles the
-    mask touches."""
+    mask touches. With ``shared`` (``_flash_fwd``'s) also (dq_r, dk_r):
+    the dK/dV kernel gives each key head's float32 share of the shared
+    key's gradient, [B,Hkv,S,Dr], summed over the heads here, once."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, S, H, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[-1]
     groups = H // Hkv
-    tiles = _tiles(S, block_q, block_k, causal, window, diffusion_block)
+    tiles = _tiles(S, block_q, block_k, causal, window, diffusion_block,
+                   shared is not None)
     block_q, block_k = tiles.block_q, tiles.block_k
-    mask = dict(tiles=tiles, scale=1.0 / np.sqrt(D))
+    Dr = shared[0].shape[-1] if tiles.latent else 0
+    mask = dict(tiles=tiles, scale=1.0 / np.sqrt(D + Dr))
     operands = tuple(a.transpose(0, 2, 1, 3) for a in (q, k, v, o, do)) \
-        + (lse,)
+        + (lse,) + tuple(a.transpose(0, 2, 1, 3) for a in (shared or ()))
+
+    def struct(heads, width, dtype):
+        return jax.ShapeDtypeStruct((B, heads, S, width), dtype)
 
     # ---- dK, dV: key head g; an item is a key tile, a query head of
     # the group and a query tile that sees the key tile ----------------- #
@@ -732,33 +827,52 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, block_q: int,
             return b, g * groups + walk.head[t], walk.q_tile[t], 0
         return pl.BlockSpec((1, 1, block_q, width), index)
 
-    k_side = pl.BlockSpec(
-        (1, 1, block_k, D),
-        lambda b, g, t, *walk: (b, g, _Walk(*walk).k_tile[t], 0))
-    dk, dv = _walk_call(
+    def k_side(width, shared=False):
+        return pl.BlockSpec(
+            (1, 1, block_k, width),
+            lambda b, g, t, *walk: (b, 0 if shared else g,
+                                    _Walk(*walk).k_tile[t], 0))
+
+    in_specs = [q_side(D), k_side(D), k_side(Dv), q_side(Dv), q_side(Dv),
+                q_side(_LANES)]
+    out_specs = [k_side(D), k_side(Dv)]
+    out_shape = [struct(Hkv, D, k.dtype), struct(Hkv, Dv, v.dtype)]
+    scratch = [pltpu.VMEM((block_k, D), jnp.float32),
+               pltpu.VMEM((block_k, Dv), jnp.float32)]
+    if tiles.latent:
+        in_specs += [q_side(Dr), k_side(Dr, shared=True)]
+        out_specs.append(k_side(Dr))
+        out_shape.append(struct(Hkv, Dr, jnp.float32))
+        scratch.append(pltpu.VMEM((block_k, Dr), jnp.float32))
+    dk, dv, *dkr = _walk_call(
         functools.partial(_flash_dkv_kernel, **mask), tiles.key_walk(groups),
-        operands, (B, Hkv),
-        [q_side(D), k_side, k_side, q_side(D), q_side(D), q_side(_LANES)],
-        [k_side, k_side],
-        [jax.ShapeDtypeStruct((B, Hkv, S, D), k.dtype),
-         jax.ShapeDtypeStruct((B, Hkv, S, D), v.dtype)],
-        [pltpu.VMEM((block_k, D), jnp.float32),
-         pltpu.VMEM((block_k, D), jnp.float32)], tiles, interpret)
+        operands, (B, Hkv), in_specs, out_specs, out_shape, scratch, tiles,
+        interpret)
+    if tiles.latent:
+        dkr = [jnp.sum(dkr[0], axis=1, keepdims=True).astype(shared[1].dtype)]
 
     # ---- dQ: the forward's walk -------------------------------------- #
-    q_tile, kv_tile = _query_walk_specs(pl, tiles, D, groups)
-    dq = _walk_call(
+    q_tile, kv_tile = _query_walk_specs(pl, tiles, groups)
+    in_specs = [q_tile(D), kv_tile(D), kv_tile(Dv), q_tile(Dv), q_tile(Dv),
+                q_tile(_LANES)]
+    out_specs, out_shape = [q_tile(D)], [struct(H, D, q.dtype)]
+    scratch = [pltpu.VMEM((block_q, D), jnp.float32)]
+    if tiles.latent:
+        in_specs += [q_tile(Dr), kv_tile(Dr, shared=True)]
+        out_specs.append(q_tile(Dr))
+        out_shape.append(struct(H, Dr, shared[0].dtype))
+        scratch.append(pltpu.VMEM((block_q, Dr), jnp.float32))
+    dq, *dqr = _walk_call(
         functools.partial(_flash_dq_kernel, **mask), tiles.query_walk,
-        operands, (B, H),
-        [q_tile(D), kv_tile, kv_tile, q_tile(D), q_tile(D), q_tile(_LANES)],
-        q_tile(D), jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
-        [pltpu.VMEM((block_q, D), jnp.float32)], tiles, interpret)
-    return tuple(a.transpose(0, 2, 1, 3) for a in (dq, dk, dv))
+        operands, (B, H), in_specs, out_specs, out_shape, scratch, tiles,
+        interpret)
+    return tuple(a.transpose(0, 2, 1, 3) for a in (dq, dk, dv, *dqr, *dkr))
 
 
 def walk_sizes(S: int, groups: int, block_q: int, block_k: int,
                window: Optional[int] = None,
-               diffusion_block: Optional[int] = None) -> dict:
+               diffusion_block: Optional[int] = None,
+               latent: bool = False) -> dict:
     """What the kernels' work lists of this causal mask and shape hold,
     by gauge name:
     ``attention/<scope>/{items,tested_items,rect_steps}/{query,key}_walk``
@@ -766,7 +880,7 @@ def walk_sizes(S: int, groups: int, block_q: int, block_k: int,
     the key walk whose ``groups`` query heads are in its list; those
     that take the in-tile test; the steps a rectangular grid as long as
     the longest walk would make)."""
-    tiles = _tiles(S, block_q, block_k, True, window, diffusion_block)
+    tiles = _tiles(S, block_q, block_k, True, window, diffusion_block, latent)
     sizes = {}
     for walk, name, rect in (
             (tiles.query_walk, "query_walk", tiles.nq * tiles.key_steps),
@@ -784,7 +898,8 @@ def walk_sizes(S: int, groups: int, block_q: int, block_k: int,
 
 def publish_walk_sizes(S: int, groups: int, block_q: int, block_k: int,
                        window: Optional[int] = None,
-                       diffusion_block: Optional[int] = None) -> None:
+                       diffusion_block: Optional[int] = None,
+                       latent: bool = False) -> None:
     """Set ``walk_sizes`` of this mask and shape as gauges in the
     process's metrics registry. Properties of the mask alone: a model
     calls this where it is traced, once a program."""
@@ -792,7 +907,7 @@ def publish_walk_sizes(S: int, groups: int, block_q: int, block_k: int,
 
     registry = get_state().metrics
     for name, value in walk_sizes(S, groups, block_q, block_k, window,
-                                  diffusion_block).items():
+                                  diffusion_block, latent).items():
         registry.gauge(name).set(value)
 
 
@@ -843,6 +958,52 @@ def _flash_vjp_bwd(causal, block_q, block_k, window, diffusion_block, res,
 
 
 flash_attention.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def latent_attention(q, q_r, k, k_r, v, block_q: int = 512,
+                     block_k: int = 512):
+    """Causal attention over a score in two parts (multi-head latent
+    attention as it is trained, the projections not absorbed): ``s =
+    (q . k + q_r . k_r) / sqrt(D + Dr)``, ``o = softmax(s) v``. q, k
+    ``[B,S,H,D]`` a head its own; q_r ``[B,S,H,Dr]``; k_r ``[B,S,1,Dr]``,
+    ONE (rotary) key head under every query head; v ``[B,S,H,Dv]``, and
+    the output is ``[B,S,H,Dv]``. The kernels and the blockwise walk
+    are ``flash_attention``'s, on the causal mask's lists, under
+    ``bps.attn.mla``; k_r's gradient is the sum over the heads."""
+    shared = (q_r, k_r)
+    if jax.default_backend() == "tpu":
+        return _flash_fwd(q, k, v, True, block_q, block_k, shared=shared)
+    return blockwise_attention(q, k, v, block_k=block_k, block_q=block_q,
+                               shared=shared)
+
+
+def _latent_vjp_fwd(q, q_r, k, k_r, v, block_q, block_k):
+    if jax.default_backend() == "tpu":
+        out, lse = _flash_fwd(q, k, v, True, block_q, block_k,
+                              with_lse=True, shared=(q_r, k_r))
+        return out, (q, q_r, k, k_r, v, out, lse)
+    out = latent_attention(q, q_r, k, k_r, v, block_q, block_k)
+    return out, (q, q_r, k, k_r, v, None, None)
+
+
+def _latent_vjp_bwd(block_q, block_k, res, g):
+    q, q_r, k, k_r, v, out, lse = res
+    if lse is not None:
+        dq, dk, dv, dqr, dkr = _flash_bwd(
+            q, k, v, out, lse, g, True, block_q, block_k,
+            shared=(q_r, k_r))
+        return dq, dqr, dk, dkr, dv
+    # off-TPU: through the differentiable blockwise path, as
+    # ``_flash_vjp_bwd``
+    _, vjp = jax.vjp(
+        lambda q_, qr_, k_, kr_, v_: blockwise_attention(
+            q_, k_, v_, block_k=block_k, block_q=block_q,
+            shared=(qr_, kr_)), q, q_r, k, k_r, v)
+    return vjp(g)
+
+
+latent_attention.defvjp(_latent_vjp_fwd, _latent_vjp_bwd)
 
 
 def make_flash_attn(causal: bool = True, block_q: int = 512,

@@ -320,6 +320,37 @@ def check_kernels(rehearse: bool) -> None:
                     f"entry vs blockwise")
             log(f"  flash[{tag}] {name}: max |err| = {err:.3e} of the "
                 f"largest entry")
+    # latent attention (the latent-attention decoder's): a score of 128
+    # a head plus 64 against ONE rotary key head shared by all, over
+    # values of 128; the shared key's gradient is the sum over the heads
+    B, S, H, dn, dr, dv = (1, 128, 4, 128, 64, 128) if rehearse \
+        else (2, 2048, 32, 128, 64, 128)
+    blk = 32 if rehearse else 512
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 41), 6)
+    q, q_r, kx, k_r, vx, g = (
+        jax.random.normal(key, shape, jnp.bfloat16) for key, shape in zip(
+            keys, ((B, S, H, dn), (B, S, H, dr), (B, S, H, dn), (B, S, 1, dr),
+                   (B, S, H, dv), (B, S, H, dv))))
+    out, lse = timed("flash_fwd[mla]", lambda: fa._flash_fwd(
+        q, kx, vx, True, blk, blk, interpret=interp, with_lse=True,
+        shared=(q_r, k_r)))
+    grads = timed("flash_bwd[mla]", lambda: fa._flash_bwd(
+        q, kx, vx, out, lse, g, True, blk, blk, interpret=interp,
+        shared=(q_r, k_r)))
+    want_o, vjp = jax.vjp(lambda a, b, c, d, e: fa.blockwise_attention(
+        a, b, c, block_k=blk, block_q=blk, shared=(d, e)),
+        q, kx, vx, q_r, k_r)
+    for name, got, want in zip(("out", "dq", "dk", "dv", "dq_r", "dk_r"),
+                               (out,) + tuple(grads), (want_o,) + vjp(g)):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        err = float(jnp.max(jnp.abs(got - want))
+                    / jnp.maximum(jnp.max(jnp.abs(want)), 1e-6))
+        if got.shape != want.shape or not (np.isfinite(err) and err <= 3e-2):
+            raise AssertionError(
+                f"flash[mla] {name}: max |err| {err} of the largest entry "
+                f"vs blockwise")
+        log(f"  flash[mla] {name}: max |err| = {err:.3e} of the largest "
+            f"entry")
     if not rehearse:
         # the public entry must engage the kernel on this platform
         txt = jax.jit(lambda a: fa.flash_attention(a, a, a)).lower(

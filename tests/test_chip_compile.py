@@ -126,6 +126,29 @@ def _flash_bwd(shape, hkv, window=None, diffusion_block=None):
     return lower
 
 
+def _latent(backward):
+    """The latent-attention shape of JoyAI-LLM-Flash's cell: 2 rows of
+    8192, 32 heads, a score of 128 a head plus 64 against ONE shared
+    rotary key head, values of 128."""
+    def lower(sh):
+        from byteps_tpu.ops import flash_attention as fa
+        B, S, H = 2, 8192, 32
+        head = _sds((B, S, H, 128), jnp.bfloat16, sh)
+        q_r = _sds((B, S, H, 64), jnp.bfloat16, sh)
+        k_r = _sds((B, S, 1, 64), jnp.bfloat16, sh)
+        if not backward:
+            return jax.jit(
+                lambda q, qr, k, kr, v: fa._flash_fwd(
+                    q, k, v, True, 512, 512, shared=(qr, kr))
+            ).lower(head, q_r, head, k_r, head)
+        lse = _sds((B, H, S, 128), jnp.float32, sh)
+        return jax.jit(
+            lambda q, qr, k, kr, v, o, l, g: fa._flash_bwd(
+                q, k, v, o, l, g, True, 512, 512, shared=(qr, kr))
+        ).lower(head, q_r, head, k_r, head, head, lse, head)
+    return lower
+
+
 def _grouped_products(sh, k=8, d=2304, h=896):
     """The held experts' products at a benchmark's sparse decoder's
     widths (Mellum's by default): 8 experts of ``d`` x ``h`` over one
@@ -194,6 +217,10 @@ def _grouped_kernels(sh, rows=16384, d=2304, h=896):
                  id="flash_fwd-blockdiff4_2x16k_gqa32x4"),
     pytest.param(_flash_bwd((2, 16384, 32, 128), 4, diffusion_block=4),
                  id="flash_bwd-blockdiff4_2x16k_gqa32x4"),
+    # JoyAI-LLM-Flash's cell: latent attention, 192-wide scores in two
+    # parts over 128-wide values, one shared rotary key head of 64
+    pytest.param(_latent(False), id="flash_fwd-mla_2x8k_32x128+64_shared"),
+    pytest.param(_latent(True), id="flash_bwd-mla_2x8k_32x128+64_shared"),
 ])
 def test_kernel_compiles_for_v5e(v5e, lower):
     compiled = lower(v5e).compile()

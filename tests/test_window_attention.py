@@ -14,7 +14,8 @@ import pytest
 
 from byteps_tpu.ops.flash_attention import (_flash_bwd, _flash_fwd, _step,
                                             _tiles, blockwise_attention,
-                                            flash_attention, make_flash_attn,
+                                            flash_attention, latent_attention,
+                                            make_flash_attn,
                                             publish_walk_sizes, walk_sizes)
 
 
@@ -41,11 +42,17 @@ def _dense_mask(S, window=None, diffusion_block=None, rows=None):
             | (~q_noised & ~k_noised & (kb <= qb)))  # clean, causal by block
 
 
-def _explicit(q, k, v, window, diffusion_block=None):
+def _explicit(q, k, v, window, diffusion_block=None, shared=None):
+    """``shared``: ``(q_r [B,S,H,Dr], k_r [B,S,1,Dr])``, a second part of
+    the score against ONE key head under every query head."""
     B, S, H, D = q.shape
     g = H // k.shape[2]
     k, v = jnp.repeat(k, g, 2), jnp.repeat(v, g, 2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(D)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    if shared is not None:
+        s = s + jnp.einsum("bqhd,bkd->bhqk", shared[0], shared[1][:, :, 0])
+        D += shared[0].shape[-1]
+    s = s / np.sqrt(D)
     mask = jnp.asarray(_dense_mask(S, window, diffusion_block))
     p = jax.nn.softmax(jnp.where(mask, s, -1e30), -1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
@@ -224,6 +231,14 @@ def test_the_work_lists_hold_exactly_the_live_tiles(S, window, block,
     # what a rectangular grid would have walked
     assert tiles.key_steps == touched.sum(1).max()
     assert tiles.query_steps == touched.sum(0).max()
+    if window is None and block is None:
+        # latent attention walks the causal mask's lists, entry for entry
+        latent = _tiles(S, bq, bk, True, None, None, True)
+        assert latent.scope == "bps.attn.mla" != tiles.scope
+        for mine, its in ((walk, latent.query_walk),
+                          (key, latent.key_walk(groups))):
+            for a, b in zip(mine, its):
+                np.testing.assert_array_equal(a, b)
 
 
 # (scope, S, window, diffusion block, query heads a key head): the
@@ -231,7 +246,8 @@ def test_the_work_lists_hold_exactly_the_live_tiles(S, window, block,
 CELLS = [("bps.attn.blockdiff", 16384, None, 4, 8),     # sdar-30b-a3b
          ("bps.attn.full", 8192, None, None, 8),        # mellum2-12b
          ("bps.attn.window", 8192, 1024, None, 8),
-         ("bps.attn.full", 8192, None, None, 4)]        # lfm2-8b-a1b
+         ("bps.attn.full", 8192, None, None, 4),        # lfm2-8b-a1b
+         ("bps.attn.mla", 8192, None, None, 1)]         # joyai-llm-flash
 
 
 @pytest.mark.parametrize("scope, S, window, block, groups", CELLS)
@@ -258,11 +274,13 @@ def test_the_gauges_count_the_dense_masks_tiles(scope, S, window, block,
             touched.shape[1] * groups * int(touched.sum(0).max())}
     assert want["items/query_walk"] < want["rect_steps/query_walk"]
     want = {f"attention/{scope}/{name}": n for name, n in want.items()}
-    assert walk_sizes(S, groups, 512, 512, window, block) == want
+    # latent attention: the causal mask's lists under a scope of its own
+    latent = scope == "bps.attn.mla"
+    assert walk_sizes(S, groups, 512, 512, window, block, latent) == want
     registry = get_state().metrics
     for name in want:
         registry.gauge(name).set(-1)
-    publish_walk_sizes(S, groups, 512, 512, window, block)
+    publish_walk_sizes(S, groups, 512, 512, window, block, latent)
     gauges = registry.instruments()[1]
     assert {name: gauges[name].value for name in want} == want
 
@@ -432,35 +450,79 @@ def test_a_window_needs_causal_and_binds_as_attn_impl():
 
 
 # head size 64, half a lane tile, four query heads a key head (the
-# LFM2 configuration's attention), beside the 128 the kernels met first
-@pytest.mark.parametrize("D, H, Hkv, window", [
-    (64, 8, 2, None), (64, 8, 2, 96), (128, 8, 2, None)])
-def test_kernels_match_blockwise_attention_at_the_head_size(D, H, Hkv,
-                                                            window):
+# LFM2 configuration's attention), beside the 128 the kernels met first;
+# and latent attention's shape (JoyAI-LLM-Flash's at a quarter and at
+# an eighth): a score in two parts, ``D`` a head its own and ``Dr``
+# against ONE key head shared by all, over values of ``Dv``, a width
+# that is neither ``D`` nor ``D + Dr``; unequal tiles
+@pytest.mark.parametrize("D, H, Hkv, window, latent, blocks", [
+    (64, 8, 2, None, None, (64, 64)), (64, 8, 2, 96, None, (64, 64)),
+    (128, 8, 2, None, None, (64, 64)),
+    (32, 8, 8, None, (16, 32), (64, 64)), (16, 4, 4, None, (8, 24), (32, 64)),
+    (16, 4, 4, None, (8, 24), (128, 32)), (32, 4, 2, None, (16, 8), (64, 64))])
+def test_kernels_match_blockwise_attention_at_the_head_size(
+        D, H, Hkv, window, latent, blocks):
     """The Pallas forward, dK/dV and dQ kernels (interpret mode) against
-    ``blockwise_attention`` and its vjp, forward and backward."""
+    ``blockwise_attention`` and its vjp, forward and backward, and
+    against the explicit mask; ``latent`` ``(Dr, Dv)``: the score's
+    shared part and the values' width (the shared key's gradient is then
+    the sum over the heads: one ``[B, S, 1, Dr]`` cotangent)."""
+    bq, bk = blocks
     q, k, v = _qkv(S=256, H=H, Hkv=Hkv, D=D, seed=4)
-    do = jax.random.normal(jax.random.PRNGKey(5), q.shape)
+    shared = None
+    if latent is not None:
+        Dr, Dv = latent
+        ks = jax.random.split(jax.random.PRNGKey(6), 3)
+        v = jax.random.normal(ks[0], (*k.shape[:3], Dv))
+        shared = (jax.random.normal(ks[1], (*q.shape[:3], Dr)),
+                  jax.random.normal(ks[2], (q.shape[0], 256, 1, Dr)))
+    do = jax.random.normal(jax.random.PRNGKey(5), (*q.shape[:3], v.shape[-1]))
+
+    def blockwise(q, k, v, *shared):
+        return blockwise_attention(
+            q, k, v, causal=True, block_k=bk, window=window, block_q=bq,
+            shared=shared or None)
+
     with jax.default_matmul_precision("highest"):
-        out, lse = _flash_fwd(q, k, v, True, 64, 64, interpret=True,
-                              window=window, with_lse=True)
-        got = _flash_bwd(q, k, v, out, lse, do, True, 64, 64, window,
-                         interpret=True)
-        want_out, vjp = jax.vjp(
-            lambda q, k, v: blockwise_attention(
-                q, k, v, causal=True, block_k=64, window=window,
-                block_q=64), q, k, v)
+        out, lse = _flash_fwd(q, k, v, True, bq, bk, interpret=True,
+                              window=window, with_lse=True, shared=shared)
+        got = _flash_bwd(q, k, v, out, lse, do, True, bq, bk, window,
+                         interpret=True, shared=shared)
+        want_out, vjp = jax.vjp(blockwise, q, k, v, *(shared or ()))
         want = vjp(do)
+        flat, flat_vjp = jax.vjp(
+            lambda q, k, v, *shared: _explicit(q, k, v, window,
+                                               shared=shared or None),
+            q, k, v, *(shared or ()))
+        flat_grads = flat_vjp(do)
+    assert out.shape == do.shape and len(got) == (3 if shared is None else 5)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want_out),
                                rtol=1e-5, atol=1e-5)
-    for g, w in zip(got, want):
-        assert g.shape == w.shape
+    for g, w, f in zip(got, want, flat_grads):
+        assert g.shape == w.shape == f.shape
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-4, atol=1e-5)
-    # the scale is the head's own: 1 / 8 at 64
-    flat = _explicit(q, k, v, window)
+        np.testing.assert_allclose(np.asarray(g), np.asarray(f),
+                                   rtol=1e-4, atol=2e-5)
+    # the scale is the score's own: 1 / 8 at 64, 1 / sqrt(D + Dr)
     np.testing.assert_allclose(np.asarray(out), np.asarray(flat),
                                rtol=1e-5, atol=1e-5)
+    if shared is None:
+        return
+    # the shared key is ONE head's array, and so is its gradient
+    assert got[4].shape == (q.shape[0], 256, 1, latent[0])
+    # ``latent_attention`` off the TPU: the blockwise walk both ways
+    with jax.default_matmul_precision("highest"):
+        again, vjp = jax.vjp(
+            lambda q, q_r, k, k_r, v: latent_attention(q, q_r, k, k_r, v,
+                                                       bq, bk),
+            q, shared[0], k, shared[1], v)
+        grads = vjp(do)
+    np.testing.assert_allclose(np.asarray(again), np.asarray(flat),
+                               rtol=1e-5, atol=1e-5)
+    for g, f in zip(grads, (flat_grads[i] for i in (0, 3, 1, 4, 2))):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(f),
+                                   rtol=1e-4, atol=2e-5)
 
 
 # --------------------------------------------------------------------- #
