@@ -422,8 +422,10 @@ class StepReport:
     # all-gather); drain_finish_ms = the train thread collecting the
     # landed waiters' results (finish()); wire_tail_after_claim_ms =
     # the round's last wire completion - export_done, not below 0;
-    # claim_thread_cpu_ms = the train thread's own CPU from
-    # backward_done to export_done (a clock of 10 ms ticks on some
+    # claim_thread_cpu_ms = the train thread's own CPU in claiming, the
+    # claim's start to export_done less its waits for the backward:
+    # behind one program backward_done to export_done, on a cut step
+    # the claims under the backward too (a clock of 10 ms ticks on some
     # kernels: one step's reading is a multiple of the tick, the mean
     # over steps is the figure). All None on a monolithic round (the
     # device-compressed tier) - never a silent 0.
@@ -704,6 +706,8 @@ class _StepBuilder:
         self.round_tag: Optional[int] = None
         self.marks: Dict[str, float] = {}
         # the train thread's own CPU seconds at the claim's two marks
+        # (backward_done: the claim's start and the waits for the
+        # backward, ``jax/train.py backward_ended``)
         self.thread_cpu_marks: Dict[str, float] = {}
         self.pull_wait_s = 0.0
 

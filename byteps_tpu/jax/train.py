@@ -30,6 +30,7 @@ import optax
 from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops import chain as chain_mod
 from ..ops.push_pull import psum_tree, reduce_scatter_tree, all_gather_tree
 from ..parallel.mesh import DP_AXIS
 from ..utils import tracing
@@ -419,6 +420,176 @@ def _scatter_backward(loss_and_stats: Callable, mesh: Mesh, axis: str,
                                  out_specs=out_specs, check_vma=False))
 
 
+def _shapes(tree):
+    """What a jit keys its traces on: the tree's structure and each
+    leaf's shape and type."""
+    leaves, treedef = jax.tree.flatten(tree)
+    return treedef, tuple((np.shape(a), str(getattr(a, "dtype", type(a))))
+                          for a in leaves)
+
+
+@dataclasses.dataclass
+class _CutBackward:
+    """The programs of a backward cut at a chain's links
+    (``_cut_backward``), each a jit over the mesh, and which gradient
+    leaves each hands over: ``leaves[k]`` are link ``k``'s flatten
+    indices in the parameter tree, in the order of its program's
+    gradient outputs."""
+
+    chain: Any
+    forward: Callable
+    last: Callable
+    pulls: Dict[int, Callable]
+    leaves: Dict[int, Tuple[int, ...]]
+    pinned: Dict[int, int] = dataclasses.field(default_factory=dict)
+
+    @property
+    def outputs_pinned(self) -> int:
+        """Gradient outputs a step's programs hand over under a pinned
+        layout: a layer's program runs once a layer."""
+        return sum(n * getattr(self.chain.links[k], "depth", 1)
+                   for k, n in self.pinned.items())
+
+    @property
+    def programs(self) -> int:
+        """Programs a step runs: the forward, the last link's, one a
+        layer of a run and one a link before."""
+        return 2 + sum(getattr(ln, "depth", 1)
+                       for ln in self.chain.links[:-1])
+
+
+def _chain_leaves(ch, params, paths=None
+                  ) -> Optional[Dict[int, Tuple[int, ...]]]:
+    """Link index -> the flatten indices in ``params`` of the leaves the
+    link picks, in the order it picks them; None where ``ch`` cannot be
+    cut over this tree (``chain.Chain.cuts``) or a link's leaves are not
+    the tree's own under its keys. ``paths``: ``params`` flattened with
+    paths, where the caller has it."""
+    if not ch.cuts(params):
+        return None
+    if paths is None:
+        paths = jax.tree_util.tree_flatten_with_path(params)[0]
+    leaves = {k: tuple(i for i, (path, _) in enumerate(paths)
+                       if getattr(path[0], "key", None) in ln.keys)
+              for k, ln in enumerate(ch.links)}
+    for k, ln in enumerate(ch.links):
+        picked = jax.tree.leaves(ln.pick(params))
+        if len(picked) != len(leaves[k]) or any(
+                a is not paths[i][1] for a, i in zip(picked, leaves[k])):
+            return None
+    return leaves
+
+
+def _cut_backward(ch, mesh: Mesh, axis: str, leaves) -> _CutBackward:
+    """The backward of a PS step whose loss is the chain ``ch``, as one
+    program a link (``ops/chain.py``): ``forward(params, batch) ->
+    (kept, stats)``, ``last(p, kept[-1], batch) -> (((loss, stats),
+    cotangent), gradients)`` and, for each link ``k`` before the last,
+    ``pulls[k](p, [layer,] kept[k], batch, cotangent) -> (cotangent,
+    gradients)``: ``_psum_backward``'s mathematics link by link, every
+    gradient psum'd over ``axis`` where it is computed. A carry is a
+    data shard's own value: between programs it is a ``P(axis)`` array
+    with the device as its leading dimension. Every program that has
+    gradients returns them LAST and takes their parameters FIRST, which
+    is what ``_row_major_outputs`` looks at. ``leaves``:
+    ``_chain_leaves``."""
+    rep, loc = P(), P(axis)
+
+    def lift(tree):
+        return jax.tree.map(lambda a: a[None], tree)
+
+    def drop(tree):
+        return jax.tree.map(lambda a: a[0], tree)
+
+    def mean(grads):
+        return psum_tree(grads, axis=axis, average=True)
+
+    def program(fn, in_specs, out_specs):
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
+
+    def forward(params, batch):
+        kept, stats = ch.forward(params, batch)
+        return lift(kept), jax.tree.map(
+            lambda x: jax.lax.psum(x, axis), stats)
+
+    def last(p, carry, batch):
+        loss, stats, g_carry, g_p = ch.last(p, drop(carry), batch)
+        return (_reduce_loss((loss, stats), axis), lift(g_carry)), mean(g_p)
+
+    def pull(k):
+        if isinstance(ch.links[k], chain_mod.Run):
+            def layer(p, j, inputs, batch, ct):
+                g_x, g_p = ch.pull_layer(k, p, j, drop(inputs), batch,
+                                         drop(ct))
+                return lift(g_x), mean(g_p)
+            return program(layer, (rep, rep, loc, loc, loc), (loc, rep))
+
+        def whole(p, carry, batch, ct):
+            g_carry, g_p = ch.pull_link(k, p, drop(carry), batch, drop(ct))
+            return lift(g_carry), mean(g_p)
+        return program(whole, (rep, loc, loc, loc), (loc, rep))
+
+    return _CutBackward(
+        ch, program(forward, (rep, loc), (loc, rep)),
+        program(last, (rep, loc, loc), ((rep, loc), rep)),
+        {k: pull(k) for k in range(len(ch.links) - 1)}, leaves)
+
+
+def _dispatch_cut(cut: _CutBackward, params, batch):
+    """Dispatch every program of a cut backward, all at once and
+    asynchronously, in the order they run -> ((loss, stats), programs):
+    one ``(links, layer, ready, outputs)`` a program in the order the
+    programs END, a pure function of the chain: the links it runs (a
+    label), the layer of a run it is (or None), an output of it to wait
+    on, and its gradient outputs by flatten index."""
+    links = cut.chain.links
+    last = len(links) - 1
+    kept, stats = cut.forward(params, batch)
+    programs = [(f"0-{last - 1}", None, jax.tree.leaves(kept)[-1], {})]
+
+    def ran(k, layer, ct, grads):
+        grads = jax.tree.leaves(grads)
+        programs.append((str(k), layer, (grads or jax.tree.leaves(ct))[0],
+                         dict(zip(cut.leaves[k], grads))))
+
+    ((loss, last_stats), ct), grads = cut.last(
+        links[last].pick(params), kept[last], batch)
+    ran(last, None, ct, grads)
+    for k in reversed(range(last)):
+        p = links[k].pick(params)
+        if isinstance(links[k], chain_mod.Run):
+            for j in reversed(range(links[k].depth)):
+                ct, grads = cut.pulls[k](p, np.int32(j), kept[k], batch, ct)
+                ran(k, j, ct, grads)
+        else:
+            ct, grads = cut.pulls[k](p, kept[k], batch, ct)
+            ran(k, None, ct, grads)
+    return (loss, {**stats, **last_stats}), programs
+
+
+class _Layers:
+    """A stacked leaf that a cut backward hands over a layer at a time
+    (``parts[j]``: layer ``j``'s ``[1, ...]`` output) and the plan keeps
+    whole: what the claim loop takes for the leaf. ``np.asarray`` puts
+    it together on the host, where the loop would have waited for the
+    leaf's transfer."""
+
+    def __init__(self, parts: list):
+        self.parts = parts
+
+    @property
+    def ndim(self) -> int:
+        return self.parts[0].ndim
+
+    @property
+    def nbytes(self) -> int:
+        return sum(part.nbytes for part in self.parts)
+
+    def __array__(self, dtype=None, copy=None):
+        return np.concatenate([np.asarray(part) for part in self.parts])
+
+
 def _row_major(fmt) -> bool:
     """Whether a ``Format`` (or none at all: a host array) orders the
     dimensions major-to-minor, the order the wire carries."""
@@ -472,72 +643,122 @@ def _row_major_outputs(backward, args, own, mesh: Mesh):
         pins |= missed
 
 
+def _pin_cut_outputs(cut: _CutBackward, params, batch, own, mesh: Mesh,
+                     axis: str) -> None:
+    """``_row_major_outputs`` for each program of ``cut`` that has
+    gradients, last link first, as the step will run them: the outputs
+    in ``own`` (flatten indices of ``params``; a piece is its leaf's
+    layer) are pinned major-to-minor. Nothing runs: a program's carries
+    and cotangent are described by the shapes the program before it
+    returns, each a ``P(axis)`` array."""
+    local = NamedSharding(mesh, P(axis))
+    links = cut.chain.links
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=local), tree)
+
+    def pin(k, program, *args):
+        p = links[k].pick(params)
+        fn, cut.pinned[k] = _row_major_outputs(
+            program, (p, *args),
+            [n for n, i in enumerate(cut.leaves[k]) if i in own], mesh)
+        return fn, fn.trace(p, *args).out_info[0]
+
+    kept = described(cut.forward.trace(params, batch).out_info[0])
+    cut.last, (_, ct) = pin(len(links) - 1, cut.last, kept[-1], batch)
+    for k in reversed(range(len(links) - 1)):
+        layer = (np.int32(0),) if isinstance(links[k], chain_mod.Run) else ()
+        cut.pulls[k], ct = pin(k, cut.pulls[k], *layer, kept[k], batch,
+                               described(ct))
+
+
 @dataclasses.dataclass(frozen=True)
 class ExportPlan:
     """How a PS step's gradient leaves leave the chip. Every leaf is an
     output of the backward; the leaves in ``shard_set`` (flatten
     indices, ascending) leave as ``n_shard`` flat per-device shards of
     ``layouts[k] = (size, shard_len, dtype)`` under subrange keys of
-    their own, every other leaf as one replicated array (on a key of
-    its own, in a fusion bucket or row-sparse: the claim loop's
-    business, not the plan's)."""
+    their own; the leaves in ``pieces`` (``(flatten index, depth)``: the
+    stacked leaves of a chain's runs, where the backward is cut at the
+    chain's links) leave as ``depth`` pieces, layer ``j``'s ``[1, ...]``
+    slice an output of layer ``j``'s program, under subrange keys of
+    their own; every other leaf as one replicated array (on a key of its
+    own, in a fusion bucket or row-sparse: the claim loop's business,
+    not the plan's). A plan holds shards or pieces, never both: the
+    backward is cut only where ``pieces`` is not empty."""
 
     shard_set: Tuple[int, ...] = ()
     n_shard: int = 0
     layouts: Tuple[Tuple[int, int, Any], ...] = ()
+    pieces: Tuple[Tuple[int, int], ...] = ()
 
 
 def _export_plan(names, leaves, *, mesh: Mesh, axis: str, fusion_bytes: int,
                  shard_min_bytes: int, local_shard: bool, rowsparse_params,
-                 host_codec: bool, scheduler_running: bool) -> ExportPlan:
-    """The rule that decides which leaves shard (BYTEPS_LOCAL_SHARD_EXPORT),
-    from configuration and topology alone: the set of PS keys a worker
-    pushes, shard subranges included, has to be the same pure function
-    on every worker, or the key sets would diverge and stall every
-    peer's aggregation. Nothing shards without a running scheduler,
-    under a host codec (the codec unit is the declared key: a per-shard
-    codec would reset EF/momentum state per device), on a mesh of more
-    than one axis or on an ``axis`` of one device (no locality axis to
-    shard over). Where leaves can shard, one does when it rides a dense
-    key of its own (not row-sparse by name, not empty, not a bucket
-    member under ``fusion_bytes``), is worth ``n_shard`` extra key
-    round trips (``shard_min_bytes``) and pads by at most 1/8 of its
-    size."""
-    if not (local_shard and scheduler_running and not host_codec
-            and len(mesh.axis_names) == 1):
+                 host_codec: bool, scheduler_running: bool,
+                 stacked: Optional[Dict[int, int]] = None) -> ExportPlan:
+    """The rule that decides which leaves shard (BYTEPS_LOCAL_SHARD_EXPORT)
+    and which leave as pieces, from configuration, topology and the
+    loss's chain alone: the set of PS keys a worker pushes, subranges
+    included, has to be the same pure function on every worker, or the
+    key sets would diverge and stall every peer's aggregation. Nothing
+    shards and nothing is cut without a running scheduler or under a
+    host codec (the codec unit is the declared key: a per-shard codec
+    would reset EF/momentum state per device). Leaves shard on a mesh of
+    one axis of more than one device, where ``local_shard``: one does
+    when it rides a dense key of its own (not row-sparse by name, not
+    empty, not a bucket member under ``fusion_bytes``), is worth
+    ``n_shard`` extra key round trips (``shard_min_bytes``) and pads by
+    at most 1/8 of its size. ``stacked`` (flatten index -> depth) are
+    the stacked leaves of the runs of a chain that can be cut
+    (``chain.Chain.cuts``): where no leaf shards, each of them that the
+    same rules would let ride keys of its own leaves as ``depth``
+    pieces; the others stay whole (a norm of kilobytes is put together
+    on the host and rides its bucket)."""
+    if not scheduler_running or host_codec:
         return ExportPlan()
-    n_shard = int(mesh.shape.get(axis, 1))
-    if n_shard <= 1:
-        return ExportPlan()
-    from ..ops.push_pull import shard_layout
 
     floor = max(fusion_bytes, shard_min_bytes)
-    shard_set, layouts = [], []
-    for i, (name, leaf) in enumerate(zip(names, leaves)):
+
+    def own_key(name, leaf):
         if rowsparse_params and any(s in name for s in rowsparse_params):
-            continue
+            return False
         nbytes = getattr(leaf, "nbytes", 0)
-        if nbytes == 0 or nbytes < floor:
-            continue
-        size = int(np.prod(leaf.shape)) if leaf.shape else 1
-        shard_len, pad = shard_layout(size, n_shard)
-        if pad * 8 > size:
-            continue  # padding beyond 1/8: not worth the wire
-        shard_set.append(i)
-        layouts.append((size, shard_len, np.dtype(leaf.dtype)))
-    return ExportPlan(tuple(shard_set), n_shard if shard_set else 0,
-                      tuple(layouts))
+        return nbytes != 0 and nbytes >= floor
+
+    n_shard = int(mesh.shape.get(axis, 1)) \
+        if local_shard and len(mesh.axis_names) == 1 else 1
+    shard_set, layouts = [], []
+    if n_shard > 1:
+        from ..ops.push_pull import shard_layout
+
+        for i, (name, leaf) in enumerate(zip(names, leaves)):
+            if not own_key(name, leaf):
+                continue
+            size = int(np.prod(leaf.shape)) if leaf.shape else 1
+            shard_len, pad = shard_layout(size, n_shard)
+            if pad * 8 > size:
+                continue  # padding beyond 1/8: not worth the wire
+            shard_set.append(i)
+            layouts.append((size, shard_len, np.dtype(leaf.dtype)))
+    if shard_set:
+        return ExportPlan(tuple(shard_set), n_shard, tuple(layouts))
+    return ExportPlan(pieces=tuple(
+        (i, depth) for i, depth in sorted((stacked or {}).items())
+        if depth > 1 and own_key(names[i], leaves[i])))
 
 
-def _declare_shard_keys(registry, names, plan: ExportPlan, stale) -> dict:
-    """Realise a changed plan in the registry: declare each shard leaf's
-    subrange keys, in flatten order (every worker flattens the same
-    tree, so the declared keys agree across workers), and the parent
-    name as the production-order anchor all of a leaf's shards share;
-    then free the names in ``stale`` the plan no longer holds (a leaf
-    resized, the knob flipped, the mesh changed: dead keys must not
-    skew later least-loaded assignments). Returns leaf index ->
-    sizing, shard names and parent context."""
+def _declare_shard_keys(registry, names, leaves, plan: ExportPlan,
+                        stale) -> dict:
+    """Realise a changed plan in the registry: declare each shard
+    leaf's and each piece leaf's subrange keys, in flatten order (every
+    worker flattens the same tree, so the declared keys agree across
+    workers), and the parent name as the production-order anchor all of
+    a leaf's shards share; then free the names in ``stale`` the plan no
+    longer holds (a leaf resized, the knob flipped, the mesh changed:
+    dead keys must not skew later least-loaded assignments). Returns
+    leaf index -> sizing, subrange names and parent context."""
     from ..core.types import DataType
 
     info: Dict[int, dict] = {}
@@ -549,7 +770,13 @@ def _declare_shard_keys(registry, names, plan: ExportPlan, stale) -> dict:
         info[i] = {"n": plan.n_shard, "shard_len": shard_len, "size": size,
                    "dtype": dt, "names": [c.name for c in ctxs],
                    "parent": registry.declare(names[i], dtype)}
-        declared.update(info[i]["names"])
+    for i, depth in plan.pieces:
+        ctxs = registry.declare_shards(
+            names[i], leaves[i].nbytes // depth, depth,
+            DataType.from_np(np.dtype(leaves[i].dtype)))
+        info[i] = {"n": depth, "names": [c.name for c in ctxs]}
+    for entry in info.values():
+        declared.update(entry["names"])
     for name in stale - declared:
         registry.free(name)
     return info
@@ -578,9 +805,9 @@ def make_ps_train_step(
     the completion-ordered drain, so UPDATE(k) overlaps PULL(k+1)
     (servers only sum — the update stays on the worker).
 
-    The way off the chip is one: every gradient leaf is an OUTPUT of the
-    backward, on every topology, and no program holds a host callback
-    (so the persistent compile cache serves them). A leaf on a
+    The way off the chip is one: every gradient leaf is an OUTPUT of a
+    backward program, on every topology, and no program holds a host
+    callback (so the persistent compile cache serves them). A leaf on a
     whole-leaf key (dense or host-compressed), a bucket member
     (sub-BYTEPS_FUSION_BYTES), a rowsparse or device-compressed leaf is
     one replicated array; a shard leaf of the export plan
@@ -594,6 +821,29 @@ def make_ps_train_step(
     backward (``_scatter_backward``). (On the v5e a program output
     reaches the host at 3.0-4.5 GB/s, a host callback's operand at
     0.4-1.0: PERF.md section 6, PRs 24, 25 and 27.)
+
+    How MANY programs the backward is depends on how the loss is
+    written. A program's outputs exist for the host only when the
+    program has ended, so behind one program the chip idles while every
+    byte crosses. A loss written as a chain of links (``ops/chain.py``:
+    an embedding, a run of rematerialised layers, a head) is found
+    behind whatever closure wraps it (the chain registers itself during
+    ``grad_fn``'s first trace at a batch's shapes) and, where the plan shards nothing and
+    no host codec is set, its backward runs as one program a link and a
+    layer, last first (``_cut_backward``: the same mathematics and
+    FLOPs, all dispatched at once). The train thread waits for them in
+    the order they end and claims what each hands over while the next
+    runs: a whole leaf as above, a run's stacked leaf as ``depth``
+    PIECES (layer ``j``'s slice under a subrange key of its own, pulled
+    into its slice of one leaf-sized slot; the leaf is imported and
+    applied whole when its last piece has landed). Bucket members and
+    row-sparse leaves are claimed once the last program has ended, in
+    flatten order, so buckets, digests and keys are the one-program
+    step's; so are the bytes pushed. Nothing selects this but what the
+    step observes (a chain that covers the tree, remat on its runs, a
+    plan without shards, a running scheduler, no host codec); counters
+    ``export/backward_programs``, ``export/piece_bytes`` and
+    ``export/under_backward_bytes`` say how often it engages.
 
     ``sharded_apply`` (BYTEPS_SHARDED_APPLY, default on): split the
     monolithic apply jit into per-leaf donated partial updates
@@ -668,10 +918,15 @@ def make_ps_train_step(
     # the export plan as last realised ("key": the gradient tree and
     # the plan): the backward it runs and how many of its outputs it pins
     # row-major (``_row_major_outputs``), leaf index -> sizing/names of
-    # the shard leaves (their declared subrange names are freed when the
-    # plan changes); "tag" counts this closure's PS rounds
+    # the shard or piece leaves (their declared subrange names are freed
+    # when the plan changes); "tag" counts this closure's PS rounds.
+    # By the shapes of ``params`` and ``batch`` (``_shapes``), as a jit
+    # caches its traces: "chains", the loss's chain as collected for
+    # them (None: no chain, or more than one), and "cuts", the programs
+    # of the plan's cut backward (``_CutBackward``) built from it
     plan_cache: dict = {"key": None, "backward": None, "pinned": 0,
-                        "tag": 0, "shard_info": {}}
+                        "tag": 0, "shard_info": {}, "chains": {},
+                        "cuts": {}}
     # sharded-apply build cache (keyed by params+opt_state structure;
     # sa None = transform not separable -> fused apply; ssa None =
     # not SHARD-separable -> gather gradients, full-leaf apply)
@@ -711,6 +966,21 @@ def make_ps_train_step(
     def step(params, opt_state, batch):
         state = get_state()
         client = state.ps_client
+        shapes = _shapes((params, batch))
+        if shapes not in plan_cache["chains"]:
+            # the loss's chain, if it is written as one: ``grad_fn``'s
+            # FIRST trace at these shapes, under the collector a called
+            # chain registers with (the ledger's lowering, the layout
+            # look and the run below are served from that trace: a loss
+            # with no chain pays nothing here). One chain, called once,
+            # is a loss that can be cut; anything else runs as one
+            # program. Collected anew for new shapes (an epoch's last
+            # batch), as the one program is traced anew: a loss may
+            # build its chain from what it sees of the batch.
+            with chain_mod.collecting() as found:
+                grad_fn.trace(params, batch)
+            plan_cache["chains"][shapes] = \
+                found[0] if len(found) == 1 else None
         # drain the previous sharded round's deferred arena releases
         # FIRST: the imported arrays' readiness proves the host staging
         # was consumed (their H2D completed), and releasing before this
@@ -906,6 +1176,14 @@ def make_ps_train_step(
         # array that was not C-contiguous: expected 0)
         exp_pinned_ctr = metrics.counter("export/pinned_layout_leaves")
         exp_relayout_ctr = metrics.counter("export/host_relayout_bytes")
+        # how often the cut backward engages: the programs this step's
+        # backward ran as (1 where nothing is cut), the bytes that left
+        # as pieces and the bytes whose submit ended before the
+        # backward did (over the step's bytes: the share of the export
+        # that is hidden under the chip's own work)
+        exp_programs_ctr = metrics.counter("export/backward_programs")
+        exp_piece_ctr = metrics.counter("export/piece_bytes")
+        exp_under_ctr = metrics.counter("export/under_backward_bytes")
         ag_hist = metrics.histogram("step/allgather_us")
 
         # time-to-first-push: wall from the backward's dispatch to the
@@ -914,6 +1192,10 @@ def make_ps_train_step(
         round_t0 = _time.perf_counter()
         first_push = [None]
 
+        # bytes submitted so far: what has left by ``backward_done`` is
+        # the step's ``export/under_backward_bytes``
+        sent = [0]
+
         def mark_first_push():
             if first_push[0] is None:
                 first_push[0] = _time.perf_counter() - round_t0
@@ -921,6 +1203,7 @@ def make_ps_train_step(
         def submit_sparse(name, h2d, out_dtype):
             from .. import _rowsparse_submit
             mark_first_push()
+            sent[0] += h2d.nbytes
             handle = state.handles.allocate(name)
             obuf = checkout(f"{name}:out", h2d.size * 4, np.float32)
             _rowsparse_submit(state, name,
@@ -929,16 +1212,19 @@ def make_ps_train_step(
             return (lambda: state.handles.wait_and_clear(
                 handle.id).astype(out_dtype, copy=False)), handle
 
-        def submit(name, flat, priority=None, tag=None):
+        def submit(name, flat, priority=None, tag=None, out=None):
             """Returns (finish, notifier): ``finish()`` yields the
             reduced array (non-blocking once ``notifier`` — a Handle
             or Future with add_done_callback, or None for an already
-            complete result — has fired)."""
+            complete result — has fired). ``out``: where the dense
+            scheduled pull lands, in place of a slot of the key's own
+            (a piece's slice of its leaf's slot)."""
             if chaos_nan is not None:
                 flat = _chaos_nan_poison(
                     chaos_nan, name, flat,
                     prof.step if prof is not None else 0)
             mark_first_push()
+            sent[0] += flat.nbytes
             if reg is not None:
                 flat = flat.astype(np.float32, copy=False)
                 if state.scheduler is not None:
@@ -959,7 +1245,8 @@ def make_ps_train_step(
                 # fall back to a fresh allocation every step
                 okey = (f"{name}:out~x{xb_par}"
                         if name in xb_carry_names else f"{name}:out")
-                obuf = checkout(okey, flat.nbytes, flat.dtype, tag=tag)
+                obuf = out if out is not None else checkout(
+                    okey, flat.nbytes, flat.dtype, tag=tag)
                 hd = bps.push_pull_async(flat, name, average=True,
                                          priority=priority, out=obuf)
                 return (lambda: bps.synchronize(hd),
@@ -1068,31 +1355,52 @@ def make_ps_train_step(
 
         # ---- the export plan (``_export_plan``): which leaves leave as
         # per-device shards; realised when the tree or the plan changes
+        ch = plan_cache["chains"][shapes]
+        link_leaves = _chain_leaves(ch, params, paths) \
+            if ch is not None else None
+        # the stacked leaves of the chain's runs, by depth
+        stacked = {} if link_leaves is None else {
+            i: ln.depth for k, ln in enumerate(ch.links)
+            if isinstance(ln, chain_mod.Run) for i in link_leaves[k]}
         plan = _export_plan(
             names, p_leaves, mesh=mesh, axis=axis, fusion_bytes=fusion,
             shard_min_bytes=getattr(state.config, "shard_min_bytes", 65536),
             local_shard=local_shard_export if local_shard_export is not None
             else getattr(state.config, "local_shard_export", True),
             rowsparse_params=rowsparse_params, host_codec=reg is not None,
-            scheduler_running=state.scheduler is not None)
+            scheduler_running=state.scheduler is not None,
+            stacked=stacked)
         shard_set, n_shard = plan.shard_set, plan.n_shard
+        # the leaves on a key of their own leave the chip in the wire's
+        # order, a piece too (bucket members are copied into their
+        # bucket's slot anyway, a shard is flat; a run's leaf the plan
+        # keeps whole is put together on the host: its layers' outputs
+        # come as they come)
+        own = {i for i, pl in enumerate(p_leaves)
+               if i not in shard_set and getattr(pl, "nbytes", 0) >= fusion}
+        if plan.pieces:
+            own -= stacked.keys() - dict(plan.pieces).keys()
         if plan_cache["key"] != (treedef, plan):
             stale = {n for info in plan_cache["shard_info"].values()
                      for n in info["names"]}
             plan_cache["shard_info"] = _declare_shard_keys(
-                state.registry, names, plan, stale)
-            backward = _scatter_backward(
-                loss_and_stats, mesh, axis, shard_set, len(names)) \
-                if shard_set else grad_fn
-            # the whole leaves on a key of their own leave the chip in
-            # the wire's order (bucket members are copied into their
-            # bucket's slot anyway, a shard is flat)
-            plan_cache["backward"], plan_cache["pinned"] = \
-                _row_major_outputs(backward, (params, batch), [
-                    i for i, pl in enumerate(p_leaves)
-                    if i not in shard_set
-                    and getattr(pl, "nbytes", 0) >= fusion], mesh)
+                state.registry, names, p_leaves, plan, stale)
+            # another plan's cut programs are dead
+            plan_cache["cuts"] = {}
+            if not plan.pieces:
+                backward = _scatter_backward(
+                    loss_and_stats, mesh, axis, shard_set, len(names)) \
+                    if shard_set else grad_fn
+                plan_cache["backward"], plan_cache["pinned"] = \
+                    _row_major_outputs(backward, (params, batch),
+                                       sorted(own), mesh)
             plan_cache["key"] = (treedef, plan)
+        if plan.pieces and shapes not in plan_cache["cuts"]:
+            # the cut programs run the links of the chain collected at
+            # THESE shapes
+            cut = _cut_backward(ch, mesh, axis, link_leaves)
+            _pin_cut_outputs(cut, params, batch, own, mesh, axis)
+            plan_cache["cuts"][shapes] = cut
 
         # ---- sharded-apply build (cached per tree structure) ----
         sharded_cfg = sharded_apply if sharded_apply is not None \
@@ -1148,7 +1456,7 @@ def make_ps_train_step(
         xb_carry_set: set = set()
         if xb_on:
             xb_state["par"] ^= 1
-            shard_planned = set(shard_set)
+            shard_planned = set(shard_set) | dict(plan.pieces).keys()
             rel_n = max(1, (len(names) + 1) // 2)
             for i, nm in enumerate(names):
                 if i < rel_n:
@@ -1164,22 +1472,35 @@ def make_ps_train_step(
         xb_par = xb_state["par"]
 
         # ---- dispatch the backward: the scatter backward where the plan
-        # shards leaves, else ``grad_fn``
+        # shards leaves, the chain's programs where it has pieces, all at
+        # once (``_dispatch_cut``), else ``grad_fn``
+        cut = plan_cache["cuts"][shapes] if plan.pieces else None
+        programs: list = []
         with tracing.span(tracing.STEP_DISPATCH, step=tag):
-            loss, grads = plan_cache["backward"](params, batch)
+            if cut is None:
+                loss, grads = plan_cache["backward"](params, batch)
+            else:
+                loss, programs = _dispatch_cut(cut, params, batch)
         # the train thread's two phases as spans: ``claim`` from here to
         # the export_done mark, ``drain`` from there to drain_done
         phase = tracing.span(tracing.STEP_CLAIM, step=tag).start()
-        g_leaves = jax.tree.leaves(grads)
+        g_leaves = jax.tree.leaves(grads) if cut is None else []
         # per-leaf shard import state (BYTEPS_LOCAL_SHARD_EXPORT):
         # shard k of leaf i lands on the device that owns it the moment
         # its pull completes; when the last shard of a leaf lands, the
         # shards assemble into one P(axis)-sharded array and the
         # shard update + all-gather dispatch
-        active_shard = plan_cache["shard_info"]
+        active_shard = plan_cache["shard_info"] if shard_set else {}
         shard_parts: Dict[int, list] = {}
         shard_left: Dict[int, int] = {}
         axis_devs = list(mesh.devices.flat)
+        # a piece leaf's import state: its pieces are pulled into slices
+        # of ONE leaf-sized arena slot, and the leaf lands whole when
+        # the last of them has (the apply stays a leaf at a time)
+        piece_info = plan_cache["shard_info"] if plan.pieces else {}
+        piece_slot: Dict[int, np.ndarray] = {}
+        piece_left: Dict[int, int] = {}
+
         def device_parts(leaf):
             by_dev = {s.device: s.data for s in leaf.addressable_shards}
             return [by_dev[d] for d in axis_devs]
@@ -1200,10 +1521,188 @@ def make_ps_train_step(
                     w = submit_shard(i, dev, h.reshape(-1))
                 waiters.append((("shard", i, dev), *w))
 
-        # start the D2H copies of the leaves now, all of them, in
-        # flatten order, a shard leaf's per-device arrays in
-        # mesh-device order (a pure function of the plan: every worker
-        # issues and claims them alike). What an np.asarray of an
+        def in_wire_order(h, nb):
+            """The guard: a backend that ignored the plan's pin. The
+            copy is the train thread's, one core's, and is counted."""
+            if h.flags.c_contiguous:
+                return h
+            exp_relayout_ctr.inc(nb)
+            return np.ascontiguousarray(h)
+
+        def claim_piece(i, j, leaf):
+            """Layer ``j``'s piece of leaf ``i``, an output of layer
+            ``j``'s program: claimed and submitted under its subrange
+            key, at its own place in the production order; its pull
+            lands in its slice of the leaf's slot."""
+            from ..server.client import get_or_init_ctx
+            info = piece_info[i]
+            name, nb = info["names"][j], leaf.nbytes
+            if i not in piece_slot:
+                piece_slot[i] = checkout(
+                    f"{names[i]}:out", p_leaves[i].nbytes,
+                    np.dtype(leaf.dtype))
+                piece_left[i] = info["n"]
+            exp_whole_ctr.inc(nb)
+            exp_dev0_ctr.inc(nb)
+            exp_piece_ctr.inc(nb)
+            with tracing.span(tracing.EXPORT_INGEST, tid=names[i], step=tag,
+                              leaf=i, layer=j, bytes=nb,
+                              cause=f"out:{i}/{j}"):
+                with tracing.span(tracing.EXPORT_MATERIALIZE, step=tag,
+                                  leaf=i, bytes=nb):
+                    h = np.asarray(leaf)
+                with tracing.span(tracing.EXPORT_SUBMIT, tid=name, step=tag,
+                                  leaf=i, layer=j, bytes=nb,
+                                  contiguous=h.flags.c_contiguous) as sp:
+                    flat = in_wire_order(h, nb).reshape(-1)
+                    ctx = get_or_init_ctx(state, name, flat)
+                    sp.set(key=ctx.declared_key,
+                           partitions=len(ctx.partitions))
+                    w = submit(
+                        name, flat,
+                        priority=state.scheduler.production_priority(ctx),
+                        out=piece_slot[i][j * flat.size:
+                                          (j + 1) * flat.size])
+            waiters.append((("piece", i, j), *w))
+
+        def claim_leaf(i, name, leaf):
+            """One whole leaf, in its turn: a shard leaf's per-device
+            shards, a bucket member, or a leaf on a key of its own
+            (dense or row-sparse)."""
+            nonlocal bucket_bytes
+            if i in out_shards:
+                claim_shards(i, name, out_shards[i])
+                return
+            nb = leaf.nbytes
+            exp_whole_ctr.inc(nb)
+            exp_dev0_ctr.inc(nb)
+            sparse = _route_rowsparse(name, leaf, state, rowsparse_params)
+            if not sparse and nb < fusion:
+                # bucket member: a cross-leaf artifact, submitted
+                # by whichever later leaf flushes the bucket
+                with tracing.span(tracing.EXPORT_BUCKET_MEMBER,
+                                  step=tag, leaf=i, bytes=nb):
+                    h = np.asarray(leaf)
+                if bucket and (bucket[0][2].dtype != h.dtype
+                               or bucket_bytes + nb > bucket_cap):
+                    flush_bucket()
+                bucket.append((i, name, h))
+                bucket_bytes += nb
+                return
+            # a leaf on a key of its own: ``cause`` names the
+            # program output it is
+            with tracing.span(tracing.EXPORT_INGEST, tid=name,
+                              step=tag, leaf=i, bytes=nb,
+                              cause=f"out:{i}"):
+                with tracing.span(tracing.EXPORT_MATERIALIZE,
+                                  step=tag, leaf=i, bytes=nb):
+                    # ready-or-wait for THIS leaf's transfer
+                    h = np.asarray(leaf)
+                if sparse:
+                    flush_bucket()
+                # a whole-leaf key does not close the bucket: its
+                # members, and so its digest, depend on the tree
+                # and the fusion size alone
+                with tracing.span(tracing.EXPORT_SUBMIT, tid=name,
+                                  step=tag, leaf=i, bytes=nb,
+                                  contiguous=h.flags.c_contiguous) as sp:
+                    h = in_wire_order(h, nb)
+                    if sparse:
+                        # non-f32 grads upcast for the wire, cast
+                        # back
+                        w = submit_sparse(name, h, h.dtype)
+                    else:
+                        w = submit(name, h.reshape(-1))
+                        ctx = state.registry.get(name)
+                        if ctx is not None:
+                            # what the wire's sends name as their
+                            # cause
+                            sp.set(key=ctx.declared_key,
+                                   partitions=len(ctx.partitions))
+            waiters.append((i, *w))
+
+        # what is claimed once the backward has ended, in flatten order:
+        # every leaf of a one-program backward; of a cut one the bucket
+        # members and the row-sparse leaves (a bucket's members, and so
+        # its digest and key, are the one-program step's) and a run's
+        # leaves the plan keeps whole
+        late: Dict[int, Any] = {}
+
+        # a cut backward's programs whose end has been seen, in the order
+        # they end; ``backward_ended`` once the last one's has
+        seen = [0, False]
+        # the train thread's own CPU when the claim starts, and what of
+        # it went into the waits for the backward since
+        waited_cpu = [0.0, 0.0]
+
+        def backward_wait(ready):
+            at = _time.thread_time()
+            ready.block_until_ready()
+            waited_cpu[1] += _time.thread_time() - at
+
+        def programs_ended(upto):
+            """Wait for the programs up to ``upto``, each under a span
+            of its own."""
+            for n in range(seen[0], upto + 1):
+                label, _, ready, outs = programs[n]
+                with tracing.span(
+                        tracing.STEP_BACKWARD_PROGRAM, step=tag, index=n,
+                        links=label,
+                        bytes=sum(leaf.nbytes for leaf in outs.values())):
+                    backward_wait(ready)
+            seen[0] = max(seen[0], upto + 1)
+
+        def backward_ended():
+            """The backward has ended on the device: the wait's span
+            ends, ``backward_done`` is marked, and what has been
+            submitted by now left under the backward. The mark's thread
+            CPU is the claim's start and the waits for the backward, so
+            that ``claim_thread_cpu_ms`` (from there to ``export_done``)
+            is what the train thread worked in claiming whenever it
+            claimed: behind one program the mark's own reading, on a
+            cut step the claims under the backward too."""
+            if not seen[1]:
+                seen[1] = True
+                wait.stop()
+                if prof is not None:
+                    prof.mark("backward_done")
+                    prof.thread_cpu_marks["backward_done"] = sum(waited_cpu)
+                exp_under_ctr.inc(sent[0])
+
+        def claim_program(n):
+            """What program ``n`` hands over and can leave at once: a
+            layer's pieces, a dense leaf on a key of its own. Between
+            two of them the train thread looks whether the LAST program
+            has ended meanwhile (it need not wait for it: the claims
+            before it take longer than a short last program), so that
+            ``backward_done`` is the chip's, not the claim's."""
+            _, layer, _, outs = programs[n]
+            handed = list(outs.items())
+            # a claimed output's place on the chip is free as soon as
+            # the wire has its bytes: no reference is kept here
+            outs.clear()
+            for i, leaf in handed:
+                if i in piece_info:
+                    claim_piece(i, layer, leaf)
+                elif layer is not None:
+                    late.setdefault(i, _Layers([None] * len(
+                        p_leaves[i]))).parts[layer] = leaf
+                    continue
+                elif leaf.nbytes < fusion or _route_rowsparse(
+                        names[i], leaf, state, rowsparse_params):
+                    late[i] = leaf
+                    continue
+                else:
+                    claim_leaf(i, names[i], leaf)
+                if not seen[1] and programs[-1][2].is_ready():
+                    programs_ended(len(programs) - 1)
+                    backward_ended()
+
+        # start the D2H copies of the leaves now, all of them: a
+        # one-program backward's in flatten order, a shard leaf's
+        # per-device arrays in mesh-device order, a cut backward's in
+        # the order its programs end (a pure function of the plan: every
+        # worker issues and claims them alike). What an np.asarray of an
         # output below can cost is the wait for ITS transfer and no
         # more: it returns a view of the buffer the runtime filled, in
         # the output's own dimension order, and that order is the
@@ -1211,11 +1710,17 @@ def make_ps_train_step(
         # come otherwise (``_row_major_outputs``; a shard is flat). It
         # never costs a copy: the train thread moves no leaf's bytes.
         # The TPU runtime works on the copies side by side (it de-tiles
-        # each on host threads) and the large ones finish close
-        # together, late in the claim; a bounded window of copies in
-        # flight does overlap the PUSH with the transfers but slows
-        # the transfers by as much, on one host's cores (PERF.md
-        # section 6, PR 25).
+        # each on host threads). A program's outputs exist for the host
+        # only when the program has ended, so behind ONE program the
+        # chip idles while the host's cores move every byte, and the
+        # large copies finish close together, late in the claim; a
+        # bounded window of copies in flight there does overlap the
+        # PUSH with the transfers but slows the transfers by as much,
+        # on one host's cores (PERF.md section 6, PR 25). Behind a CUT
+        # backward the runtime moves program k's outputs while program
+        # k + 1 runs, on cores the backward leaves idle, and the train
+        # thread claims and submits them meanwhile (PERF.md section 6,
+        # PR 42).
         out_shards: Dict[int, list] = {}
         for i, leaf in enumerate(g_leaves):
             if i in active_shard:
@@ -1224,79 +1729,42 @@ def make_ps_train_step(
                     part.copy_to_host_async()
             elif hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
+        for _, _, _, outs in programs:
+            for leaf in outs.values():
+                leaf.copy_to_host_async()
 
-        exp_pinned_ctr.inc(plan_cache["pinned"])
+        exp_pinned_ctr.inc(plan_cache["pinned"] if cut is None
+                           else cut.outputs_pinned)
+        exp_programs_ctr.inc(max(1, len(programs)))
 
         imported: list = [None] * len(names)
         new_params: list = [None] * len(names)
         apply_parts: list = [None] * len(names)
+        wait = tracing.span(tracing.STEP_BACKWARD_WAIT, step=tag)
         try:
-            # the claim starts by waiting for the backward PROGRAM to
-            # end on the device (the loss is an output of it), where the
-            # first np.asarray below would have waited as long: from
-            # here on a materialize is a wait for a transfer and nothing
-            # else
-            with tracing.span(tracing.STEP_BACKWARD_WAIT, step=tag):
-                loss[0].block_until_ready()
-            if prof is not None:
-                prof.mark("backward_done", thread_cpu=True)
-            for i, (name, leaf) in enumerate(zip(names, g_leaves)):
-                if i in out_shards:
-                    claim_shards(i, name, out_shards[i])
-                    continue
-                nb = leaf.nbytes
-                exp_whole_ctr.inc(nb)
-                exp_dev0_ctr.inc(nb)
-                sparse = _route_rowsparse(name, leaf, state,
-                                          rowsparse_params)
-                if not sparse and nb < fusion:
-                    # bucket member: a cross-leaf artifact, submitted
-                    # by whichever later leaf flushes the bucket
-                    with tracing.span(tracing.EXPORT_BUCKET_MEMBER,
-                                      step=tag, leaf=i, bytes=nb):
-                        h = np.asarray(leaf)
-                    if bucket and (bucket[0][2].dtype != h.dtype
-                                   or bucket_bytes + nb > bucket_cap):
-                        flush_bucket()
-                    bucket.append((i, name, h))
-                    bucket_bytes += nb
-                    continue
-                # a leaf on a key of its own: ``cause`` names the
-                # program output it is
-                with tracing.span(tracing.EXPORT_INGEST, tid=name,
-                                  step=tag, leaf=i, bytes=nb,
-                                  cause=f"out:{i}"):
-                    with tracing.span(tracing.EXPORT_MATERIALIZE,
-                                      step=tag, leaf=i, bytes=nb):
-                        # ready-or-wait for THIS leaf's transfer
-                        h = np.asarray(leaf)
-                    if sparse:
-                        flush_bucket()
-                    # a whole-leaf key does not close the bucket: its
-                    # members, and so its digest, depend on the tree
-                    # and the fusion size alone
-                    with tracing.span(tracing.EXPORT_SUBMIT, tid=name,
-                                      step=tag, leaf=i, bytes=nb,
-                                      contiguous=h.flags.c_contiguous) as sp:
-                        if not h.flags.c_contiguous:
-                            # the guard: a backend that ignored the
-                            # plan's pin. The copy is the train
-                            # thread's, one core's, and is counted
-                            exp_relayout_ctr.inc(nb)
-                            h = np.ascontiguousarray(h)
-                        if sparse:
-                            # non-f32 grads upcast for the wire, cast
-                            # back
-                            w = submit_sparse(name, h, h.dtype)
-                        else:
-                            w = submit(name, h.reshape(-1))
-                            ctx = state.registry.get(name)
-                            if ctx is not None:
-                                # what the wire's sends name as their
-                                # cause
-                                sp.set(key=ctx.declared_key,
-                                       partitions=len(ctx.partitions))
-                    waiters.append((i, *w))
+            # the claim starts by waiting for the backward to end on the
+            # device. ONE program (the loss is an output of it): the
+            # first np.asarray below would have waited as long, and
+            # from here on a materialize is a wait for a transfer and
+            # nothing else. A cut backward: program by program in the
+            # order they end, and what each hands over is claimed and
+            # submitted while the next runs; the wait's span and
+            # ``backward_done`` end where the LAST program has ended
+            # (``backward_ended``), whatever the train thread claimed
+            # meanwhile
+            wait.start()
+            waited_cpu[0] = _time.thread_time()
+            if cut is None:
+                backward_wait(loss[0])
+                late.update(enumerate(g_leaves))
+                backward_ended()
+            for n in range(len(programs)):
+                programs_ended(n)
+                if n == len(programs) - 1:
+                    backward_ended()
+                claim_program(n)
+            for i in sorted(late):
+                claim_leaf(i, names[i], late[i])
             flush_bucket()
             if prof is not None:
                 # every leaf is now off the device and submitted (each
@@ -1480,6 +1948,19 @@ def make_ps_train_step(
                 if prof is not None:
                     prof.stage_sample("ALLGATHER", dt)
 
+            def land_piece(s, j, piece):
+                # the pull wrote piece ``j`` into its slice of the
+                # leaf's slot (a reply that came in another buffer, a
+                # retry's, is copied there: correctness never depends on
+                # staging); the leaf lands whole with its last piece
+                flat = piece.reshape(-1)
+                view = piece_slot[s][j * flat.size:(j + 1) * flat.size]
+                if not np.may_share_memory(view, flat):
+                    view[:] = flat
+                piece_left[s] -= 1
+                if not piece_left[s]:
+                    land(s, piece_slot[s])
+
             def _dispatch(wi):
                 slot, finish, _ = waiters[wi]
                 # the landed waiter's result: a leaf, a device's shard
@@ -1490,6 +1971,8 @@ def make_ps_train_step(
                 if isinstance(slot, list):
                     for s, piece in zip(slot, got):
                         land(s, piece)
+                elif isinstance(slot, tuple) and slot[0] == "piece":
+                    land_piece(slot[1], slot[2], got)
                 elif isinstance(slot, tuple):
                     land_shard(slot[1], slot[2], got)
                 else:
@@ -1567,6 +2050,7 @@ def make_ps_train_step(
                 phase.set(pull_wait_ms=prof.pull_wait_s * 1e3)
             phase.stop()
         except BaseException:
+            wait.stop()
             phase.stop()
             # a failed round (submission OR drain) may leave pulls
             # mid-flight into these slots: abandon (drop from the

@@ -51,6 +51,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ..ops import chain
 from ..ops.flash_attention import flash_attention, publish_walk_sizes
 from . import llama as L
 from . import mellum
@@ -139,26 +140,35 @@ def _block(x, p, rope, cfg: SDARConfig, ep_axis):
     return mellum.moe_sublayer(x, p, cfg, ep_axis)
 
 
+def _rope(cfg: SDARConfig, half: int):
+    """Plain ``theta^(-2d / head_dim)`` at ``n(p)``: llama's table of a
+    row's positions, once for each copy."""
+    return tuple(jnp.concatenate([t, t]) for t in L.rope_cache(cfg, half))
+
+
+def _layers(cfg: SDARConfig, ep_axis) -> chain.Run:
+    """The run of ``n_layers`` blocks over ``params["blocks"]``: a scan,
+    so that a kernel's instruction carries its scope's name alone
+    (PERF.md section 3). Its statistics: the load ``[layers, n_held]``,
+    the other counts summed over the layers."""
+    return chain.Run(
+        lambda p, x, rope: _block(x, p, rope, cfg, ep_axis), "blocks",
+        cfg.n_layers, remat=cfg.remat, unroll=LAYER_UNROLL,
+        consts=lambda batch: _rope(cfg, batch["tokens"].shape[1]),
+        stats=lambda stats: {name: v if v.ndim == 2 else jnp.sum(v)
+                             for name, v in stats.items()})
+
+
 def forward_hidden(params: Dict[str, Any], tokens: jnp.ndarray,
                    cfg: SDARConfig, ep_axis: Optional[str] = None):
     """tokens [B, 2 L], each row its noised copy then its clean copy ->
-    (final normed hidden [B, 2 L, d], the step's statistics: the load
-    [layers, n_held], the other counts summed over the layers)."""
-    half = tokens.shape[1] // 2
-    # plain ``theta^(-2d / head_dim)`` at ``n(p)``: llama's table of a
-    # row's positions, once for each copy
-    rope = tuple(jnp.concatenate([t, t]) for t in L.rope_cache(cfg, half))
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    block = jax.checkpoint(_block, static_argnums=(3, 4)) \
-        if cfg.remat else _block
-    # a scan, so that a kernel's instruction carries its scope's name
-    # alone (PERF.md section 3)
-    x, stats = jax.lax.scan(
-        lambda x, p: block(x, p, rope, cfg, ep_axis), x, params["blocks"],
-        unroll=min(LAYER_UNROLL, cfg.n_layers))
-    x = L._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    return x, {name: v if v.ndim == 2 else jnp.sum(v)
-               for name, v in stats.items()}
+    (final normed hidden [B, 2 L, d], the step's statistics)."""
+    layers = _layers(cfg, ep_axis)
+    x, stats = layers.scan(
+        params["blocks"], params["embed"].astype(cfg.dtype)[tokens],
+        _rope(cfg, tokens.shape[1] // 2))
+    return (L._rmsnorm(x, params["final_norm"], cfg.norm_eps),
+            layers.stats(stats))
 
 
 def loss_fn(params: Dict[str, Any], batch: Dict[str, jnp.ndarray],
@@ -171,24 +181,43 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jnp.ndarray],
     batch: ``tokens`` [rows, L] clean ids, ``noise_mask`` [rows, L] bool
     (the tokens replaced by the mask token in the noised copy) and
     ``rates`` [rows, L / block_length] (the rate each block was noised
-    at: a masked position's loss is weighed by its inverse)."""
-    clean, noise, rates = (batch["tokens"], batch["noise_mask"],
-                           batch["rates"])
-    rows, n = clean.shape
-    if n % cfg.block_length or rates.shape != (rows, n // cfg.block_length):
+    at: a masked position's loss is weighed by its inverse).
+
+    Written as a chain (``ops/chain.py``): the embedding, the run of
+    blocks, then the final norm, the head and the loss. Any step maker
+    runs it as the one program it was; ``make_ps_train_step`` cuts its
+    backward at the links."""
+    rows, n = batch["tokens"].shape
+    if n % cfg.block_length \
+            or batch["rates"].shape != (rows, n // cfg.block_length):
         raise ValueError(
             f"rows of {n} tokens in blocks of {cfg.block_length} need "
-            f"rates [{rows}, {n // cfg.block_length}], got {rates.shape}")
-    noised = jnp.where(noise, cfg.mask_id, clean)
-    x, stats = forward_hidden(
-        params, jnp.concatenate([noised, clean], axis=1), cfg, ep_axis)
-    # the head over the noised half only; no shift: position i predicts
-    # the clean token AT i
-    logits = (x[:, :n] @ params["lm_head"].astype(cfg.dtype)
-              ).astype(jnp.float32)
-    nll = jax.scipy.special.logsumexp(logits, axis=-1) \
-        - jnp.take_along_axis(logits, clean[..., None], axis=-1)[..., 0]
-    weight = noise / jnp.repeat(rates.astype(jnp.float32),
-                                cfg.block_length, axis=1)
-    stats["diffusion/masked_tokens"] = jnp.sum(noise, dtype=jnp.int32)
-    return jnp.sum(weight * nll) / (rows * n), stats
+            f"rates [{rows}, {n // cfg.block_length}], got "
+            f"{batch['rates'].shape}")
+
+    def embed(p, _, batch):
+        clean = batch["tokens"]
+        noised = jnp.where(batch["noise_mask"], cfg.mask_id, clean)
+        tokens = jnp.concatenate([noised, clean], axis=1)
+        return p["embed"].astype(cfg.dtype)[tokens], {}
+
+    def head(p, x, batch):
+        # a link reads what it needs of the batch from ``batch``: the
+        # cut step traces it on its own
+        clean, noise = batch["tokens"], batch["noise_mask"]
+        rows, n = clean.shape
+        x = L._rmsnorm(x, p["final_norm"], cfg.norm_eps)
+        # the head over the noised half only; no shift: position i
+        # predicts the clean token AT i
+        logits = (x[:, :n] @ p["lm_head"].astype(cfg.dtype)
+                  ).astype(jnp.float32)
+        nll = jax.scipy.special.logsumexp(logits, axis=-1) \
+            - jnp.take_along_axis(logits, clean[..., None], axis=-1)[..., 0]
+        weight = noise / jnp.repeat(batch["rates"].astype(jnp.float32),
+                                    cfg.block_length, axis=1)
+        return jnp.sum(weight * nll) / (rows * n), {
+            "diffusion/masked_tokens": jnp.sum(noise, dtype=jnp.int32)}
+
+    return chain.Chain((
+        chain.Link(embed, "embed"), _layers(cfg, ep_axis),
+        chain.Link(head, ("final_norm", "lm_head"))))(params, batch)

@@ -1,0 +1,223 @@
+"""A loss written as a chain of links.
+
+A model's loss is usually one function of the whole parameter tree, and
+its backward one program: every gradient exists for the host only when
+that program has ended. Written as a CHAIN the loss says where it can be
+cut: a list of links, each a function ``(its parameters, carry, batch)
+-> (carry, additive statistics)`` and the rule that picks its parameters
+out of the tree. The first link's carry is ``None``, the last link's is
+the loss. Two kinds of link:
+
+- ``Link(fn, keys)``: the whole leaves under the top-level ``keys`` (one
+  name or several) of the parameter tree (``fn`` is handed ``{key:
+  params[key]}``);
+- ``Run(block, key, depth, ...)``: a run of ``depth`` like layers whose
+  leaves are stacked on a leading axis under ``params[key]``, declared
+  ONCE: ``block(layer j's parameters, carry, consts)`` is one layer.
+
+``Chain(links)`` is a plain ``loss_fn(params, batch) -> (loss, stats)``.
+Called as any loss is called it composes the links, and a run
+is the ``lax.scan`` over its stacked leaves (under ``jax.checkpoint``
+where ``remat``): the program a model that scans its layers has without
+this module. ``jax/train.py make_ps_train_step`` finds the chain behind
+whatever closure the loss is wrapped in (a chain that is called
+registers itself with the collector of ``collecting()``) and, where
+every run is rematerialised, runs the backward as one program a link
+and a layer, last first (``forward``, ``last``, ``pull_layer``,
+``pull_link`` below are those programs' bodies on one data shard), so
+that each program's gradients cross to the host while the next runs.
+The FLOPs are the uncut backward's: a rematerialised layer's forward is
+computed again in its backward either way.
+
+Carries are pytrees of floating arrays (they are differentiated).
+Statistics are additive counts (``jax/train.py _loss_and_stats``); their
+names are unique across links.
+
+A link is a function of its three arguments and of nothing else that
+changes from call to call: what it needs of the batch (a row count, a
+length) it reads from ``batch`` INSIDE ``fn``, not from the scope that
+built it. The cut programs are traced from the links one at a time, and
+a link that closed over the first batch's shape would carry it into a
+later trace. (The step also collects the chain anew for every new shape
+of ``params`` and ``batch``, as ``jax.jit`` traces a loss anew, so a
+loss that builds its chain inside the call, as ``models/sdar.py`` does,
+is held to the shapes it was built for.)
+
+This module needs jax alone: a model file imports it without the step
+makers.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import contextvars
+import dataclasses
+from typing import Any, Callable, Dict, List, Optional, Tuple
+
+import jax
+
+_collector: contextvars.ContextVar = contextvars.ContextVar(
+    "bps_chain_collector", default=None)
+
+
+@contextlib.contextmanager
+def collecting():
+    """The chains called inside the block, in call order (each call
+    counts: a loss that calls one chain twice has used its parameters
+    twice)."""
+    found: List["Chain"] = []
+    token = _collector.set(found)
+    try:
+        yield found
+    finally:
+        _collector.reset(token)
+
+
+@dataclasses.dataclass(frozen=True)
+class Link:
+    """``fn(params, carry, batch) -> (carry, stats)`` over the whole
+    leaves under ``keys``."""
+
+    fn: Callable
+    keys: Tuple[str, ...]
+
+    def __post_init__(self):
+        keys = self.keys
+        object.__setattr__(
+            self, "keys", (keys,) if isinstance(keys, str) else tuple(keys))
+
+    def pick(self, params) -> Dict[str, Any]:
+        return {key: params[key] for key in self.keys}
+
+    def __call__(self, p, carry, batch):
+        return self.fn(p, carry, batch)
+
+
+@dataclasses.dataclass(frozen=True)
+class Run(Link):
+    """``depth`` like layers, stacked under ``params[keys[0]]``:
+    ``fn(layer, carry, consts)`` is one of them, ``consts(batch)`` what
+    all of them read and none changes (computed once a program, outside
+    the scan: a rotary table), ``stats(stacked)`` turns the layers'
+    statistics, stacked on a leading axis, into the run's."""
+
+    depth: int = 1
+    remat: bool = True
+    unroll: int = 1
+    consts: Optional[Callable] = None
+    stats: Optional[Callable] = None
+
+    def _consts(self, batch):
+        return batch if self.consts is None else self.consts(batch)
+
+    def scan(self, stacked, carry, consts, keep: bool = False):
+        """The run over ``stacked`` (any depth) -> (carry, the layers'
+        stacked statistics); ``keep``: the layers' INPUT carries,
+        stacked, beside the statistics."""
+        def block(x, p, consts):
+            return self.fn(p, x, consts)
+
+        if self.remat:
+            block = jax.checkpoint(block)
+        depth = jax.tree.leaves(stacked)[0].shape[0]
+
+        def body(x, p):
+            y, st = block(x, p, consts)
+            return y, ((x, st) if keep else st)
+
+        return jax.lax.scan(body, carry, stacked,
+                            unroll=min(self.unroll, depth))
+
+    def __call__(self, p, carry, batch):
+        carry, stacked = self.scan(p[self.keys[0]], carry,
+                                   self._consts(batch))
+        return carry, stacked if self.stats is None else self.stats(stacked)
+
+
+@dataclasses.dataclass(frozen=True)
+class Chain:
+    links: Tuple[Link, ...]
+
+    def __post_init__(self):
+        object.__setattr__(self, "links", tuple(self.links))
+
+    def __call__(self, params, batch):
+        found = _collector.get()
+        if found is not None:
+            found.append(self)
+        carry, stats = None, {}
+        for ln in self.links:
+            carry, st = ln(ln.pick(params), carry, batch)
+            stats.update(st)
+        return carry, stats
+
+    # ---- what a step that cuts the backward needs ------------------- #
+
+    def cuts(self, params) -> bool:
+        """Whether the backward can run a link at a time at the uncut
+        FLOPs: more than one link, every top-level key of ``params``
+        under exactly one of them, every run rematerialised and as deep
+        as its leaves."""
+        keys = [key for ln in self.links for key in ln.keys]
+        if len(self.links) < 2 or not hasattr(params, "keys") \
+                or sorted(keys) != sorted(params.keys()):
+            return False
+        return all(ln.remat and all(
+            leaf.ndim >= 1 and leaf.shape[0] == ln.depth
+            for leaf in jax.tree.leaves(ln.pick(params)))
+            for ln in self.links if isinstance(ln, Run))
+
+    def forward(self, params, batch):
+        """Through every link but the last, keeping no residual:
+        (``kept``, one entry a link: its input carry, a run's layers'
+        input carries stacked; the statistics of the links run)."""
+        carry, kept, stats = None, [], {}
+        for ln in self.links[:-1]:
+            if isinstance(ln, Run):
+                plain = dataclasses.replace(ln, remat=False)
+                carry, (inputs, st) = plain.scan(
+                    params[ln.keys[0]], carry, ln._consts(batch), keep=True)
+                kept.append(inputs)
+                stats.update(st if ln.stats is None else ln.stats(st))
+            else:
+                kept.append(carry)
+                carry, st = ln(ln.pick(params), carry, batch)
+                stats.update(st)
+        kept.append(carry)
+        return kept, stats
+
+    def last(self, p, carry, batch):
+        """The last link at its kept input: (loss, its statistics, the
+        cotangent of its input, its gradients)."""
+        (loss, stats), (g_p, g_carry) = jax.value_and_grad(
+            lambda p, c: self.links[-1](p, c, batch), argnums=(0, 1),
+            has_aux=True)(p, carry)
+        return loss, stats, g_carry, g_p
+
+    def pull_link(self, k: int, p, carry, batch, ct):
+        """Link ``k`` at its kept input, pulled back by ``ct``: (the
+        cotangent of its input, its gradients)."""
+        _, vjp = jax.vjp(lambda p, c: self.links[k](p, c, batch)[0],
+                         p, carry)
+        g_p, g_carry = vjp(ct)
+        return g_carry, g_p
+
+    def pull_layer(self, k: int, p, j, inputs, batch, ct):
+        """Layer ``j`` (traced) of run ``k`` (``p``: its stacked leaves
+        as picked) at its kept input ``inputs[j]``, pulled back by
+        ``ct``: (the cotangent of its input, the layer's gradients, each
+        ``[1, ...]``, in ``p``'s structure). What is
+        differentiated is the run's scan over ONE layer, not the bare
+        block, so that a kernel inside is named as in the uncut
+        program."""
+        ln = self.links[k]
+        one = jax.tree.map(
+            lambda a: jax.lax.dynamic_slice_in_dim(a, j, 1, 0), p)
+        x = jax.tree.map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, j, 0, keepdims=False),
+            inputs)
+        consts = ln._consts(batch)
+        _, vjp = jax.vjp(
+            lambda p, c: ln.scan(p[ln.keys[0]], c, consts)[0], one, x)
+        g_one, g_x = vjp(ct)
+        return g_x, g_one

@@ -78,6 +78,7 @@ def estimate_clock_offset(
 STEP_DISPATCH = "bps.step.dispatch"
 STEP_CLAIM = "bps.step.claim"
 STEP_BACKWARD_WAIT = "bps.step.backward_wait"
+STEP_BACKWARD_PROGRAM = "bps.step.backward_program"
 STEP_DRAIN = "bps.step.drain"
 STEP_HOST_CPU = "bps.step.host_cpu"
 EXPORT_INGEST = "bps.export.ingest"

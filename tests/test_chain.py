@@ -1,0 +1,488 @@
+"""A loss written as a chain (ops/chain.py) and the PS step that cuts its
+backward at the links (jax/train.py ``_cut_backward``): the chain
+called plainly is the scan it replaces, bit for bit (``models/sdar.py``
+against the parent's scan, kept here as its reference); a cut PS step
+against a local server is the one-program step bit for bit, pushes the
+same bytes and counts its programs, its pieces and the bytes that left
+under the backward; each of the plan's rules keeps the backward one
+program; the pieces' keys are a pure function of the tree; the
+programs of the cut name their kernels' scope as the uncut one does."""
+
+import dataclasses
+import itertools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh
+
+from byteps_tpu.ops import chain
+from byteps_tpu.jax.train import (ExportPlan, _chain_leaves, _cut_backward,
+                                  _declare_shard_keys, _dispatch_cut,
+                                  _export_plan, make_ps_train_step,
+                                  make_train_step)
+from byteps_tpu.models import llama, sdar
+from byteps_tpu.ops.push_pull import psum_tree
+
+from test_export_spans import _ps_env
+
+# every leaf of the tiny models but the norms rides keys of its own
+ENV = {"BYTEPS_FUSION_BYTES": "1024", "BYTEPS_SHARD_MIN_BYTES": "1024"}
+# the servers' ports, this file's own (``_ps_env``)
+PORTS = itertools.count(25600)
+
+
+# --------------------------------------------------------------------- #
+# models/sdar.py as a chain against the scan it was
+# --------------------------------------------------------------------- #
+
+
+def _parents_loss(params, batch, cfg):
+    """``models/sdar.py loss_fn`` as it stood before it was a chain."""
+    clean, noise, rates = (batch["tokens"], batch["noise_mask"],
+                           batch["rates"])
+    rows, n = clean.shape
+    tokens = jnp.concatenate(
+        [jnp.where(noise, cfg.mask_id, clean), clean], axis=1)
+    rope = tuple(jnp.concatenate([t, t]) for t in llama.rope_cache(cfg, n))
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    block = jax.checkpoint(sdar._block, static_argnums=(3, 4)) \
+        if cfg.remat else sdar._block
+    x, stats = jax.lax.scan(
+        lambda x, p: block(x, p, rope, cfg, None), x, params["blocks"],
+        unroll=min(sdar.LAYER_UNROLL, cfg.n_layers))
+    x = llama._rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    stats = {name: v if v.ndim == 2 else jnp.sum(v)
+             for name, v in stats.items()}
+    logits = (x[:, :n] @ params["lm_head"].astype(cfg.dtype)
+              ).astype(jnp.float32)
+    nll = jax.scipy.special.logsumexp(logits, axis=-1) \
+        - jnp.take_along_axis(logits, clean[..., None], axis=-1)[..., 0]
+    weight = noise / jnp.repeat(rates.astype(jnp.float32),
+                                cfg.block_length, axis=1)
+    stats["diffusion/masked_tokens"] = jnp.sum(noise, dtype=jnp.int32)
+    return jnp.sum(weight * nll) / (rows * n), stats
+
+
+def _sdar(remat=True, n_layers=3, seed=3):
+    cfg = dataclasses.replace(sdar.SDARConfig.tiny(), remat=remat,
+                              n_layers=n_layers)
+    key = jax.random.PRNGKey(seed)
+    params = sdar.init_params(key, cfg)
+    # norms off one, so that their gradients are no accident
+    params = jax.tree.map(
+        lambda a: a + 0.1 * jax.random.normal(key, a.shape, a.dtype)
+        if a.ndim <= 2 and a.shape[-1] <= 32 else a, params)
+    rows, n = 2, 32
+    ks = jax.random.split(key, 3)
+    batch = {"tokens": jax.random.randint(ks[0], (rows, n), 0, 63),
+             "noise_mask": jax.random.bernoulli(ks[1], 0.6, (rows, n)),
+             "rates": jax.random.uniform(ks[2], (rows, n // 4),
+                                         minval=0.45, maxval=0.95)}
+    return cfg, params, batch
+
+
+def _assert_trees_equal(got, want):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "no-remat"])
+def test_the_chain_called_plainly_is_the_scan_it_replaces(remat):
+    cfg, params, batch = _sdar(remat)
+    (loss, stats), grads = jax.jit(jax.value_and_grad(
+        lambda p, b: sdar.loss_fn(p, b, cfg), has_aux=True))(params, batch)
+    (want, want_stats), want_grads = jax.jit(jax.value_and_grad(
+        lambda p, b: _parents_loss(p, b, cfg), has_aux=True))(params, batch)
+    assert float(loss) == float(want) and float(loss) > 0
+    _assert_trees_equal(stats, want_stats)
+    assert stats["moe/expert_load"].shape == (3, 4)
+    _assert_trees_equal(grads, want_grads)
+    assert all(np.any(np.asarray(g)) for g in jax.tree.leaves(grads))
+
+
+def test_a_called_chain_registers_with_the_collector_and_nowhere_else():
+    cfg, params, batch = _sdar()
+
+    def trace():  # a function of its own each time: no trace is cached
+        jax.eval_shape(lambda p, b: sdar.loss_fn(p, b, cfg), params, batch)
+
+    with chain.collecting() as found:
+        jax.eval_shape(jax.value_and_grad(
+            lambda p, b: sdar.loss_fn(p, b, cfg), has_aux=True),
+            params, batch)
+    (ch,) = found
+    assert [type(ln).__name__ for ln in ch.links] == ["Link", "Run", "Link"]
+    assert [ln.keys for ln in ch.links] == [
+        ("embed",), ("blocks",), ("final_norm", "lm_head")]
+    assert ch.cuts(params)
+    # outside a collector a call leaves no trace; the collector is this
+    # context's alone
+    trace()
+    assert len(found) == 1
+    with chain.collecting() as outer:
+        with chain.collecting() as inner:
+            trace()
+        assert len(inner) == 1 and outer == []
+
+
+def _chain_of(cfg):
+    return chain.Chain([chain.Link(None, "embed"), sdar._layers(cfg, None),
+                        chain.Link(None, ["final_norm", "lm_head"])])
+
+
+@pytest.mark.parametrize("why,change", [
+    ("a run that keeps its residuals",
+     lambda cfg, p: (dataclasses.replace(cfg, remat=False), p)),
+    ("a leaf under no link",
+     lambda cfg, p: (cfg, {**p, "extra": jnp.zeros(3)})),
+    ("a link with no leaf",
+     lambda cfg, p: (cfg, {k: v for k, v in p.items() if k != "embed"})),
+    ("a run deeper than it says",
+     lambda cfg, p: (dataclasses.replace(cfg, n_layers=2), p)),
+    ("a tree with no keys", lambda cfg, p: (cfg, list(p.values()))),
+], ids=lambda v: v.replace(" ", "-") if isinstance(v, str) else "")
+def test_what_a_chain_cannot_be_cut_over(why, change):
+    cfg, params, _ = _sdar()
+    assert _chain_of(cfg).cuts(params)
+    cfg, changed = change(cfg, params)
+    assert not _chain_of(cfg).cuts(changed), why
+    assert _chain_leaves(_chain_of(cfg), changed) is None
+    # one link is nothing to cut
+    assert not chain.Chain([chain.Link(None, tuple(params))]).cuts(params)
+
+
+def test_a_links_keys_are_one_name_or_several_and_a_chains_links_any_list():
+    one, two = chain.Link(None, "embed"), chain.Link(None, ["a", "b"])
+    assert one.keys == ("embed",) and two.keys == ("a", "b")
+    layers = chain.Run(None, "blocks", 3, remat=False)
+    assert layers.keys == ("blocks",) and layers.depth == 3
+    assert dataclasses.replace(layers, remat=True).keys == ("blocks",)
+    assert chain.Chain([one, layers, two]).links == (one, layers, two)
+    assert one.pick({"embed": 1, "other": 2}) == {"embed": 1}
+
+
+def test_the_links_leaves_are_runs_of_the_trees_flatten_order():
+    cfg, params, batch = _sdar()
+    with chain.collecting() as found:
+        jax.eval_shape(lambda p, b: sdar.loss_fn(p, b, cfg), params, batch)
+    leaves = _chain_leaves(found[0], params)
+    # blocks (12 stacked leaves), embed, final_norm, lm_head
+    assert leaves == {0: (12,), 1: tuple(range(12)), 2: (13, 14)}
+
+
+# --------------------------------------------------------------------- #
+# the cut programs against the one program, no server
+# --------------------------------------------------------------------- #
+
+
+@pytest.mark.parametrize("devices", [1, 2], ids=["1dev", "2dev"])
+def test_the_cut_programs_give_the_one_programs_loss_stats_and_gradients(
+        devices):
+    from byteps_tpu.jax.train import _loss_and_stats, _psum_backward
+
+    cfg, params, batch = _sdar()
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+    loss = lambda p, b: sdar.loss_fn(p, b, cfg)  # noqa: E731
+    with chain.collecting() as found:
+        whole = _psum_backward(_loss_and_stats(loss), mesh, "dp")
+        (want, want_stats), want_grads = whole(params, batch)
+    cut = _cut_backward(found[0], mesh, "dp",
+                        _chain_leaves(found[0], params))
+    # forward, head, three layers, embedding
+    assert cut.programs == 6
+    (got, stats), programs = _dispatch_cut(cut, params, batch)
+    assert [(links, layer) for links, layer, _, _ in programs] == [
+        ("0-1", None), ("2", None), ("1", 2), ("1", 1), ("1", 0),
+        ("0", None)]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _assert_trees_equal(stats, want_stats)
+    flat = jax.tree.leaves(want_grads)
+    seen = set()
+    for _, layer, ready, outs in programs:
+        for i, g in outs.items():
+            w = np.asarray(flat[i])
+            if layer is not None:
+                assert g.shape == (1,) + w.shape[1:]
+                w = w[layer:layer + 1]
+            np.testing.assert_array_equal(np.asarray(g), w, err_msg=str(i))
+            seen.add((i, layer))
+    assert len(seen) == 3 + 12 * 3
+
+
+# --------------------------------------------------------------------- #
+# PS steps against a local server
+# --------------------------------------------------------------------- #
+
+COUNTERS = ("export/backward_programs", "export/piece_bytes",
+            "export/under_backward_bytes", "export/whole_bytes",
+            "wire/push_bytes")
+
+
+def _run_ps(loss, params, batch, steps=3, devices=1, env=None, **kw):
+    """``steps`` PS steps of adam -> (params, opt state, losses, the
+    counters' growth, the last step's spans and reports)."""
+    from byteps_tpu.core.state import get_state
+
+    tx = optax.adam(1e-2)
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+    params = jax.tree.map(jnp.array, params)  # the step donates its own
+    with _ps_env({**ENV, **(env or {})}, port=next(PORTS)) as bps:
+        step = make_ps_train_step(loss, tx, mesh, **kw)
+        before = bps.get_metrics()["counters"]
+        opt, losses = tx.init(params), []
+        for _ in range(steps):
+            params, opt, value = step(params, opt, batch)
+            losses.append(float(value))
+        jax.block_until_ready((params, opt))
+        after = bps.get_metrics()["counters"]
+        out = {"params": params, "opt": opt, "losses": losses,
+               "grew": {c: after.get(c, 0) - before.get(c, 0)
+                        for c in COUNTERS},
+               "spans": get_state().profiler.last_spans(),
+               "reports": bps.get_step_reports()[-steps:],
+               "keys": {c.name: c.declared_key
+                        for c in get_state().registry.contexts_in_order()}}
+    return out
+
+
+@pytest.fixture(scope="module")
+def cut_and_whole():
+    """Three steps of the chained loss (cut: four programs and three
+    layers') and of the scan it replaces (no chain: one program)."""
+    cfg, params, batch = _sdar()
+    n_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+    cut = _run_ps(lambda p, b: sdar.loss_fn(p, b, cfg), params, batch)
+    whole = _run_ps(lambda p, b: _parents_loss(p, b, cfg), params, batch)
+    return cut, whole, n_bytes, params
+
+
+def test_a_cut_step_is_the_one_program_step_bit_for_bit(cut_and_whole):
+    cut, whole, _, _ = cut_and_whole
+    assert cut["losses"] == whole["losses"]
+    assert cut["losses"][-1] < cut["losses"][0]
+    _assert_trees_equal(cut["params"], whole["params"])
+    _assert_trees_equal(cut["opt"], whole["opt"])
+
+
+def test_a_cut_step_pushes_every_gradient_byte_once_and_counts_itself(
+        cut_and_whole):
+    cut, whole, n_bytes, params = cut_and_whole
+    for run in (cut, whole):
+        assert run["grew"]["wire/push_bytes"] == 3 * n_bytes
+        assert run["grew"]["export/whole_bytes"] == 3 * n_bytes
+    # forward, head, three layers, embedding; one where nothing is cut
+    assert cut["grew"]["export/backward_programs"] == 3 * 6
+    assert whole["grew"]["export/backward_programs"] == 3
+    # the eight weights of a layer leave as pieces, the four norms whole
+    blocks = params["blocks"]
+    pieces = sum(v.nbytes for k, v in blocks.items() if "norm" not in k)
+    assert cut["grew"]["export/piece_bytes"] == 3 * pieces
+    assert whole["grew"]["export/piece_bytes"] == 0
+    # what left before the last program was seen to have ended: the
+    # head's leaf at least (it is claimed before anyone looks), at most
+    # all but the last program's own leaf and what waits for the end,
+    # the norms and final_norm
+    late = params["embed"].nbytes + params["final_norm"].nbytes \
+        + sum(v.nbytes for k, v in blocks.items() if "norm" in k)
+    assert 3 * params["lm_head"].nbytes \
+        <= cut["grew"]["export/under_backward_bytes"] <= 3 * (n_bytes - late)
+    assert whole["grew"]["export/under_backward_bytes"] == 0
+    # StepReport keeps the two counts the benchmark subscripts
+    for run in (cut, whole):
+        assert run["reports"][-1]["fallback_leaves"] == 15
+        assert run["reports"][-1]["streamed_leaves"] == 0
+
+
+def test_a_cut_steps_keys_are_the_one_program_steps_and_the_pieces(
+        cut_and_whole):
+    cut, whole, _, _ = cut_and_whole
+    piece_names = {n for n in cut["keys"] if "@shard" in n}
+    assert len(piece_names) == 8 * 3
+    assert {n.split("@")[1] for n in piece_names} == {
+        "shard0of3", "shard1of3", "shard2of3"}
+    # the bucket of the five norms has the one-program step's digest, the
+    # whole leaves their names
+    fused = {n for n in cut["keys"] if n.startswith("fused/")}
+    assert fused and fused == {n for n in whole["keys"]
+                               if n.startswith("fused/")}
+    for name in ("grad/embed", "grad/lm_head"):
+        assert name in cut["keys"] and name in whole["keys"]
+
+
+def test_the_pieces_keys_are_the_same_on_two_workers():
+    """Declared from the plan alone, in flatten order, before any leaf
+    is claimed: two registries that realise the same plan agree."""
+    from byteps_tpu.config import Config
+    from byteps_tpu.core.registry import TensorRegistry
+
+    cfg, params, _ = _sdar()
+    paths = jax.tree_util.tree_flatten_with_path(params)[0]
+    names = ["grad/" + "/".join(str(k.key) for k in path)
+             for path, _ in paths]
+    leaves = [leaf for _, leaf in paths]
+    plan = _export_plan(
+        names, leaves, mesh=Mesh(np.array(jax.devices()[:1]), ("dp",)),
+        axis="dp", fusion_bytes=1024, shard_min_bytes=1024,
+        local_shard=True, rowsparse_params=None, host_codec=False,
+        scheduler_running=True, stacked={i: 3 for i in range(12)})
+    assert plan.shard_set == () and plan.n_shard == 0
+    assert [i for i, _ in plan.pieces] == [4, 5, 6, 7, 8, 9, 10, 11]
+    keys = []
+    for _ in range(2):
+        registry = TensorRegistry(Config(num_workers=2, num_servers=1))
+        registry.declare("something/else")
+        info = _declare_shard_keys(registry, names, leaves, plan, set())
+        keys.append({n: registry.get(n).declared_key
+                     for i in info for n in info[i]["names"]})
+        assert [registry.get(n).partitions[0].length
+                for n in info[4]["names"]] == [leaves[4].nbytes // 3] * 3
+    assert keys[0] == keys[1] and len(keys[0]) == 24
+    # a plan that has no piece frees them
+    registry.declare("grad/embed")
+    _declare_shard_keys(registry, names, leaves, ExportPlan(), set(keys[0]))
+    assert not any(registry.is_declared(n) for n in keys[0])
+
+
+def _twice(cfg):
+    def loss(p, b):
+        a, stats = sdar.loss_fn(p, b, cfg)
+        return (a + sdar.loss_fn(p, b, cfg)[0]) / 2, stats
+    return loss
+
+
+@pytest.mark.parametrize("rule", [
+    "remat off", "a host codec", "a plan that shards", "no chain",
+    "a chain called twice", "every leaf under the fusion size"])
+def test_each_rule_of_the_plan_keeps_the_backward_one_program(rule):
+    cfg, params, batch = _sdar(remat=rule != "remat off")
+    loss = lambda p, b: sdar.loss_fn(p, b, cfg)  # noqa: E731
+    kw, env, devices = {}, {}, 1
+    if rule == "a host codec":
+        kw = {"compression": {"compressor": "onebit", "ef": "vanilla"},
+              "device_compress": False}
+    elif rule == "a plan that shards":
+        devices = 2
+        batch = jax.tree.map(lambda a: jnp.concatenate([a, a]), batch)
+    elif rule == "no chain":
+        loss = lambda p, b: _parents_loss(p, b, cfg)  # noqa: E731
+    elif rule == "a chain called twice":
+        loss = _twice(cfg)
+    elif rule == "every leaf under the fusion size":
+        env = {"BYTEPS_FUSION_BYTES": str(1 << 20)}
+    out = _run_ps(loss, params, batch, steps=2, devices=devices, env=env,
+                  **kw)
+    assert out["grew"]["export/backward_programs"] == 2
+    assert out["grew"]["export/piece_bytes"] == 0
+    assert out["grew"]["export/under_backward_bytes"] == 0
+    assert not any("@shard" in n and "of3" in n for n in out["keys"])
+    assert out["losses"][1] < out["losses"][0]
+
+
+def test_on_a_mesh_that_shards_nothing_the_cut_step_is_the_one_programs():
+    """Two devices, ``local_shard_export`` off: the programs run over the
+    mesh, carries a device's own, gradients psum'd link by link."""
+    cfg, params, batch = _sdar()
+    batch = jax.tree.map(lambda a: jnp.concatenate([a, a[::-1]]), batch)
+    cut = _run_ps(lambda p, b: sdar.loss_fn(p, b, cfg), params, batch,
+                  devices=2, local_shard_export=False)
+    whole = _run_ps(lambda p, b: _parents_loss(p, b, cfg), params, batch,
+                    devices=2, local_shard_export=False)
+    assert cut["grew"]["export/backward_programs"] == 3 * 6
+    assert whole["grew"]["export/backward_programs"] == 3
+    assert cut["losses"] == whole["losses"]
+    _assert_trees_equal(cut["params"], whole["params"])
+    _assert_trees_equal(cut["opt"], whole["opt"])
+
+
+def _rows(batch, rows):
+    return jax.tree.map(lambda a: a[:rows], batch)
+
+
+def test_a_cut_step_with_another_row_count_is_the_one_program_steps():
+    """An epoch's last batch: the links are traced anew at its shapes
+    and read their row count from it (a head that kept the first
+    batch's would scale the loss and every gradient by the old one)."""
+    cfg, params, batch = _sdar()
+    batch = jax.tree.map(lambda a: jnp.concatenate([a, a[::-1], a]), batch)
+    batches = [batch, _rows(batch, 2), batch]
+
+    def run(loss):
+        from byteps_tpu.core.state import get_state
+
+        tx = optax.adam(1e-2)
+        mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+        with _ps_env(ENV, port=next(PORTS)) as bps:
+            step = make_ps_train_step(loss, tx, mesh)
+            p, opt = jax.tree.map(jnp.array, params), tx.init(params)
+            before = bps.get_metrics()["counters"]
+            losses = []
+            for b in batches:
+                p, opt, value = step(p, opt, b)
+                losses.append(float(value))
+            jax.block_until_ready((p, opt))
+            programs = bps.get_metrics()["counters"][
+                "export/backward_programs"] - before.get(
+                    "export/backward_programs", 0)
+            keys = [c.name for c in get_state().registry.contexts_in_order()]
+        return p, opt, losses, programs, keys
+
+    cut = run(lambda p, b: sdar.loss_fn(p, b, cfg))
+    whole = run(lambda p, b: _parents_loss(p, b, cfg))
+    # every step cut, the short one too, on the pieces' keys declared once
+    assert cut[3] == 3 * 6 and whole[3] == 3
+    assert len([n for n in cut[4] if "@shard" in n]) == 8 * 3
+    assert cut[2] == whole[2]
+    _assert_trees_equal(cut[:2], whole[:2])
+
+
+def test_a_loss_that_is_a_chain_at_some_shapes_only_is_cut_at_those():
+    """The chain is collected for each new shape of the batch, as the
+    one program is traced: a loss that calls no chain on a short batch
+    runs that batch as one program, and the next full one cut again."""
+    cfg, params, batch = _sdar()
+    batch = jax.tree.map(lambda a: jnp.concatenate([a, a[::-1]]), batch)
+
+    def loss(p, b):
+        if b["tokens"].shape[0] < 4:
+            return _parents_loss(p, b, cfg)
+        return sdar.loss_fn(p, b, cfg)
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    tx = optax.adam(1e-2)
+    with _ps_env(ENV, port=next(PORTS)) as bps:
+        step = make_ps_train_step(loss, tx, mesh)
+        p, opt = jax.tree.map(jnp.array, params), tx.init(params)
+        grew = []
+        for b in (batch, _rows(batch, 2), batch):
+            before = bps.get_metrics()["counters"].get(
+                "export/backward_programs", 0)
+            p, opt, value = step(p, opt, b)
+            grew.append(bps.get_metrics()["counters"][
+                "export/backward_programs"] - before)
+        jax.block_until_ready((p, opt))
+    assert grew == [6, 1, 6] and np.isfinite(float(value))
+
+
+def test_the_fused_step_runs_the_chain_as_the_scan():
+    cfg, params, batch = _sdar()
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    tx = optax.adam(1e-2)
+
+    def run(loss):
+        step = make_train_step(
+            loss, tx, mesh, donate=False,
+            grads_transform=lambda g: psum_tree(g, axis="dp", average=True))
+        p, o, value = step(params, tx.init(params), batch)
+        step.fold_stats()
+        return p, o, float(value)
+
+    got = run(lambda p, b: sdar.loss_fn(p, b, cfg))
+    want = run(lambda p, b: _parents_loss(p, b, cfg))
+    assert got[2] == want[2]
+    _assert_trees_equal(got[:2], want[:2])

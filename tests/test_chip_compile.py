@@ -516,3 +516,62 @@ def test_the_block_diffusion_decoders_block_compiles_for_a_v5e(
     assert "bps.attn.full" not in text and "bps.attn.window" not in text
     assert "ragged-dot.bps" in text and "ragged-dot-none" not in text
     assert re.search(r"\[16384,(?:2048|768)\]", text)
+
+
+def test_a_cut_backwards_layer_program_names_its_kernels_by_scope_alone(
+        v5e_host, monkeypatch):
+    """The program a PS step runs once a layer where the loss is a chain
+    (``ops/chain.py``; ``jax/train.py _cut_backward``), at SDAR-30B-A3B's
+    published widths over two stacked layers: it differentiates the
+    run's scan over ONE layer, so its Pallas calls carry their scope's
+    name and no transform's (``jvp_bps.attn...`` is what a bare block
+    would give, and the benchmark's readers find ``bps.attn.blockdiff``
+    and ``ragged-dot.bps`` by name); its gradient outputs are one
+    layer's ``[1, ...]`` slices."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from byteps_tpu.jax import train
+    from byteps_tpu.ops import chain
+    from byteps_tpu.models import sdar
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(sdar.SDARConfig(), vocab_size=18992,
+                              n_experts_held=16, n_layers=2)
+    rows, seq = 2, 8192
+    mesh = Mesh(np.array(v5e_host[:1]), ("dp",))
+    rep, dp = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    params = jax.tree.map(
+        lambda v: _sds(v.shape, v.dtype, rep),
+        jax.eval_shape(lambda: sdar.init_params(jax.random.PRNGKey(0), cfg)))
+    batch = {"tokens": _sds((rows, seq), jnp.int32, dp),
+             "noise_mask": _sds((rows, seq), jnp.bool_, dp),
+             "rates": _sds((rows, seq // cfg.block_length), jnp.float32, dp)}
+    with chain.collecting() as found:
+        jax.eval_shape(lambda p, b: sdar.loss_fn(p, b, cfg), params, batch)
+    (ch,) = found
+    cut = train._cut_backward(ch, mesh, "dp",
+                              train._chain_leaves(ch, params))
+    assert cut.programs == 5
+    carry = _sds((1, rows, 2 * seq, cfg.dim), cfg.dtype, dp)
+    inputs = _sds((1, cfg.n_layers, rows, 2 * seq, cfg.dim), cfg.dtype, dp)
+    blocks = ch.links[1].pick(params)
+    compiled = cut.pulls[1].lower(blocks, np.int32(1), inputs, batch,
+                                  carry).compile()
+    text = compiled.as_text()
+    names = set(re.findall(r"%([\w.\-]*(?:bps\.attn|ragged-dot)[\w.\-]*) = ",
+                           text))
+    assert names and all(
+        re.fullmatch(r"(bps\.attn\.blockdiff|ragged-dot\.bps)(\.\d+)*", n)
+        for n in names), sorted(names)
+    # the forward run again, dK/dV and dQ; the three grouped products
+    # and their transposes
+    assert len({n for n in names if n.startswith("bps.attn")}) == 3
+    assert len({n for n in names if n.startswith("ragged-dot")}) >= 9
+    g_carry, g_blocks = jax.eval_shape(
+        cut.pulls[1], blocks, np.int32(1), inputs, batch, carry)
+    assert g_carry.shape == carry.shape
+    assert jax.tree.map(lambda g: g.shape, g_blocks) == jax.tree.map(
+        lambda p: (1,) + p.shape[1:], blocks)

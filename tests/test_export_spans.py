@@ -32,11 +32,15 @@ TAP_FIELDS = ("export_tap_span_ms", "export_router_wait_max_ms")
 
 
 @contextlib.contextmanager
-def _ps_env(extra_env: dict = None):
+def _ps_env(extra_env: dict = None, port: int = None):
+    """``port``: one of the caller's own. The counter starts at the same
+    number in every process, and under xdist the files that share it
+    run side by side."""
     from byteps_tpu.core.state import GlobalState
 
-    port = _PORT[0]
-    _PORT[0] += 1
+    if port is None:
+        port = _PORT[0]
+        _PORT[0] += 1
     env = {
         "DMLC_NUM_WORKER": "1", "DMLC_NUM_SERVER": "1",
         "DMLC_PS_ROOT_URI": "127.0.0.1", "DMLC_PS_ROOT_PORT": str(port),

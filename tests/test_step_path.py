@@ -252,28 +252,122 @@ def test_bucket_members_have_a_span_of_their_own_and_no_ingest(stepped):
     assert len(by[tracing.EXPORT_MATERIALIZE]) == len(ingests)
 
 
+def _assert_identities(r, cut=False):
+    for f in ALL_FIELDS:
+        assert r[f] is not None and r[f] >= 0, (f, r)
+    # the step's time before the dispatch and between the dispatch
+    # and the wait (the D2H copies' issue)
+    rest = (r["compute_ms"] - r["dispatch_ms"] - r["backward_wait_ms"]
+            - r["export_behind_backward_ms"])
+    assert -EPS <= rest <= r["compute_ms"]
+    # leaves are claimed behind a one-program backward; a cut one's also
+    # while it runs, inside the wait
+    assert (r["export_materialize_ms"] + r["export_bucket_member_ms"]
+            <= r["export_behind_backward_ms"]
+            + (r["backward_wait_ms"] if cut else 0.0) + EPS)
+    assert (r["pull_wait_ms"] + r["drain_land_ms"]
+            + r["drain_finish_ms"] <= r["drain_ms"] + EPS)
+    assert r["wire_tail_after_claim_ms"] \
+        <= r["drain_ms"] + r["tail_ms"] + EPS
+    # the train thread's CPU in claiming, wherever it claimed: a cut
+    # step's claims under the backward count too
+    assert r["claim_thread_cpu_ms"] \
+        <= r["export_behind_backward_ms"] \
+        + (r["backward_wait_ms"] if cut else 0.0) + CPU_GRAIN_MS
+    assert 0 <= r["claim_thread_cpu_ms"] \
+        <= r["step_cpu_ms"] + CPU_GRAIN_MS
+
+
 def test_the_fields_hold_their_identities_on_every_report(stepped):
     for r in stepped["reports"]:
-        for f in ALL_FIELDS:
-            assert r[f] is not None and r[f] >= 0, (f, r)
-        # the step's time before the dispatch and between the dispatch
-        # and the wait (the D2H copies' issue)
-        rest = (r["compute_ms"] - r["dispatch_ms"] - r["backward_wait_ms"]
-                - r["export_behind_backward_ms"])
-        assert -EPS <= rest <= r["compute_ms"]
-        assert (r["export_materialize_ms"] + r["export_bucket_member_ms"]
-                <= r["export_behind_backward_ms"] + EPS)
-        assert (r["pull_wait_ms"] + r["drain_land_ms"]
-                + r["drain_finish_ms"] <= r["drain_ms"] + EPS)
-        assert r["wire_tail_after_claim_ms"] \
-            <= r["drain_ms"] + r["tail_ms"] + EPS
-        assert r["claim_thread_cpu_ms"] \
-            <= r["export_behind_backward_ms"] + CPU_GRAIN_MS
-        assert 0 <= r["claim_thread_cpu_ms"] \
-            <= r["step_cpu_ms"] + CPU_GRAIN_MS
+        _assert_identities(r)
         if stepped["devices"] > 1:
             assert r["allgather_ms"] > 0
             assert r["drain_land_ms"] >= r["allgather_ms"] - EPS
+
+
+@pytest.fixture(scope="module")
+def cut_stepped():
+    """Three PS steps of a loss written as a chain (``models/sdar.py``
+    at test sizes: the embedding, a run of three layers, the head), its
+    backward cut at the links: the last step's spans, every report."""
+    import jax
+    from jax.sharding import Mesh
+
+    from byteps_tpu.core.state import get_state
+    from byteps_tpu.jax.train import make_ps_train_step
+    from byteps_tpu.models import sdar
+    from test_chain import _sdar
+
+    cfg, params, batch = _sdar()
+    tx = optax.adam(1e-2)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    # a port of test_chain.py's own range: ``_ps_env``'s counter is
+    # another process's too under xdist
+    with _ps_env(ENV, port=25590) as bps:
+        step = make_ps_train_step(
+            lambda p, b: sdar.loss_fn(p, b, cfg), tx, mesh)
+        opt = tx.init(params)
+        for _ in range(3):
+            params, opt, loss = step(params, opt, batch)
+            jax.block_until_ready((params, opt, loss))
+        out = {"spans": get_state().profiler.last_spans(),
+               "reports": bps.get_step_reports()[-3:]}
+    return out
+
+
+def test_a_cut_step_holds_the_identities(cut_stepped):
+    for r in cut_stepped["reports"]:
+        _assert_identities(r, cut=True)
+        # the head's program ends first: the first push is under the
+        # backward
+        assert r["ttfp_ms"] \
+            <= r["compute_ms"] - r["export_behind_backward_ms"] + EPS
+
+
+def test_a_cut_steps_wait_holds_a_span_a_program_and_the_early_claims(
+        cut_stepped):
+    by = _by_stage(cut_stepped["spans"])
+    (wait,), (claim,) = by[tracing.STEP_BACKWARD_WAIT], by[tracing.STEP_CLAIM]
+    assert wait[4] == {"step": 3} and claim[2] <= wait[2] <= wait[3]
+    programs = by[tracing.STEP_BACKWARD_PROGRAM]
+    # the forward, the head, three layers, the embedding: in the order
+    # they end, each inside the wait, the last one ending it
+    assert [sp[4]["index"] for sp in programs] == list(range(6))
+    assert [sp[4]["links"] for sp in programs] \
+        == ["0-1", "2", "1", "1", "1", "0"]
+    assert all(set(sp[4]) == {"step", "index", "links", "bytes"}
+               and sp[4]["step"] == 3 for sp in programs)
+    assert programs[0][4]["bytes"] == 0 and programs[2][4]["bytes"] > 0
+    assert len({sp[4]["bytes"] for sp in programs[2:5]}) == 1
+    assert {sp[1] for sp in programs} == {threading.current_thread().name}
+    assert wait[2] <= programs[0][2]
+    assert all(a[3] <= b[2] for a, b in zip(programs, programs[1:]))
+    assert programs[-1][3] <= wait[3] <= programs[-1][3] + 0.05
+    # what a program hands over is claimed once it has ended: the head's
+    # lm_head, then a layer's eight pieces, last layer first, then the
+    # embedding; the wait ends between two claims, wherever the train
+    # thread saw that the last program had ended, never before the
+    # head's leaf was claimed
+    ingests = sorted(by[tracing.EXPORT_INGEST], key=lambda sp: sp[2])
+    assert [sp[4].get("layer") for sp in ingests] \
+        == [None] + [2] * 8 + [1] * 8 + [0] * 8 + [None]
+    assert [sp[4]["cause"] for sp in ingests[:1] + ingests[-1:]] \
+        == ["out:14", "out:12"]
+    assert {sp[4]["cause"] for sp in ingests[1:9]} \
+        == {f"out:{i}/2" for i in range(4, 12)}
+    for n, group in ((1, ingests[:1]), (2, ingests[1:9]),
+                     (3, ingests[9:17]), (4, ingests[17:25]),
+                     (5, ingests[25:])):
+        assert all(programs[n][3] <= sp[2] for sp in group)
+    assert ingests[0][3] <= wait[3] <= ingests[-1][2]
+    assert not any(sp[2] < wait[3] < sp[3] for sp in ingests)
+    # behind the backward, after the embedding: the bucket members in
+    # flatten order
+    late = ingests[-1:]
+    members = by[tracing.EXPORT_BUCKET_MEMBER]
+    assert [sp[4]["leaf"] for sp in members] == [0, 1, 2, 3, 13]
+    assert all(sp[2] >= late[0][3] for sp in members)
 
 
 def test_the_claim_and_the_drain_say_what_they_waited_for(stepped):
