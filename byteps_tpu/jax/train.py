@@ -542,7 +542,13 @@ def _dispatch_cut(cut: _CutBackward, params, batch):
     one ``(links, layer, ready, outputs)`` a program in the order the
     programs END, a pure function of the chain: the links it runs (a
     label), the layer of a run it is (or None), an output of it to wait
-    on, and its gradient outputs by flatten index."""
+    on, and its gradient outputs by flatten index.
+
+    (The runtime finds a program's temporaries when the program is
+    ENQUEUED: where two programs' do not fit beside the step's state the
+    second enqueue blocks until the first has ended, and the train
+    thread sits here instead of claiming. PERF.md section 6, PR 43, has
+    the step that did, and section 7 what was tried.)"""
     links = cut.chain.links
     last = len(links) - 1
     kept, stats = cut.forward(params, batch)
@@ -565,7 +571,7 @@ def _dispatch_cut(cut: _CutBackward, params, batch):
         else:
             ct, grads = cut.pulls[k](p, kept[k], batch, ct)
             ran(k, None, ct, grads)
-    return (loss, {**stats, **last_stats}), programs
+    return (loss, chain_mod.add_stats(dict(stats), last_stats)), programs
 
 
 class _Layers:
@@ -833,7 +839,8 @@ def make_ps_train_step(
     layer, last first (``_cut_backward``: the same mathematics and
     FLOPs, all dispatched at once). The train thread waits for them in
     the order they end and claims what each hands over while the next
-    runs: a whole leaf as above, a run's stacked leaf as ``depth``
+    runs: a whole leaf as above (a run of ONE layer's leaves are such:
+    the piece that is its leaf), a deeper run's stacked leaf as ``depth``
     PIECES (layer ``j``'s slice under a subrange key of its own, pulled
     into its slice of one leaf-sized slot; the leaf is imported and
     applied whole when its last piece has landed). Bucket members and
@@ -1379,7 +1386,10 @@ def make_ps_train_step(
         own = {i for i, pl in enumerate(p_leaves)
                if i not in shard_set and getattr(pl, "nbytes", 0) >= fusion}
         if plan.pieces:
-            own -= stacked.keys() - dict(plan.pieces).keys()
+            # (a run of ONE layer hands its leaves over whole, from its
+            # one program: they ride their own keys like a link's)
+            own -= {i for i, depth in stacked.items() if depth > 1} \
+                - dict(plan.pieces).keys()
         if plan_cache["key"] != (treedef, plan):
             stale = {n for info in plan_cache["shard_info"].values()
                      for n in info["names"]}
@@ -1671,7 +1681,9 @@ def make_ps_train_step(
 
         def claim_program(n):
             """What program ``n`` hands over and can leave at once: a
-            layer's pieces, a dense leaf on a key of its own. Between
+            layer's pieces, a dense leaf on a key of its own (a link's,
+            or the whole leaf that a run of one layer's program hands
+            over: the piece that is its leaf). Between
             two of them the train thread looks whether the LAST program
             has ended meanwhile (it need not wait for it: the claims
             before it take longer than a short last program), so that
@@ -1684,7 +1696,7 @@ def make_ps_train_step(
             for i, leaf in handed:
                 if i in piece_info:
                     claim_piece(i, layer, leaf)
-                elif layer is not None:
+                elif layer is not None and stacked[i] > 1:
                     late.setdefault(i, _Layers([None] * len(
                         p_leaves[i]))).parts[layer] = leaf
                     continue

@@ -30,8 +30,9 @@ The FLOPs are the uncut backward's: a rematerialised layer's forward is
 computed again in its backward either way.
 
 Carries are pytrees of floating arrays (they are differentiated).
-Statistics are additive counts (``jax/train.py _loss_and_stats``); their
-names are unique across links.
+Statistics are additive counts (``jax/train.py _loss_and_stats``): links
+that count under one name add up (``add_stats``: the runs of a model
+with several each count their layers' pairs).
 
 A link is a function of its three arguments and of nothing else that
 changes from call to call: what it needs of the batch (a row count, a
@@ -73,6 +74,13 @@ def collecting():
         _collector.reset(token)
 
 
+def add_stats(stats: Dict[str, Any], more: Dict[str, Any]) -> Dict[str, Any]:
+    """``more`` counted into ``stats``: a name both hold is their sum."""
+    for name, value in more.items():
+        stats[name] = stats[name] + value if name in stats else value
+    return stats
+
+
 @dataclasses.dataclass(frozen=True)
 class Link:
     """``fn(params, carry, batch) -> (carry, stats)`` over the whole
@@ -98,39 +106,51 @@ class Run(Link):
     """``depth`` like layers, stacked under ``params[keys[0]]``:
     ``fn(layer, carry, consts)`` is one of them, ``consts(batch)`` what
     all of them read and none changes (computed once a program, outside
-    the scan: a rotary table), ``stats(stacked)`` turns the layers'
-    statistics, stacked on a leading axis, into the run's."""
+    the scan: a rotary table), ``each(batch)`` what layer ``j`` ALONE
+    reads and is no parameter (a buffer a layer: a router's selection
+    bias), stacked on a leading axis of ``depth`` and scanned beside the
+    leaves: the block is then ``fn(layer, carry, consts, layer j's
+    slice)``; ``stats(stacked)`` turns the layers' statistics, stacked
+    on a leading axis, into the run's."""
 
     depth: int = 1
     remat: bool = True
     unroll: int = 1
     consts: Optional[Callable] = None
     stats: Optional[Callable] = None
+    each: Optional[Callable] = None
 
     def _consts(self, batch):
         return batch if self.consts is None else self.consts(batch)
 
-    def scan(self, stacked, carry, consts, keep: bool = False):
-        """The run over ``stacked`` (any depth) -> (carry, the layers'
-        stacked statistics); ``keep``: the layers' INPUT carries,
-        stacked, beside the statistics."""
-        def block(x, p, consts):
-            return self.fn(p, x, consts)
+    def _each(self, batch):
+        return None if self.each is None else self.each(batch)
+
+    def scan(self, stacked, carry, consts, keep: bool = False, each=None):
+        """The run over ``stacked`` (any depth; ``each``: the layers'
+        own slices, as deep) -> (carry, the layers' stacked
+        statistics); ``keep``: the layers' INPUT carries, stacked,
+        beside the statistics."""
+        def block(x, p, consts, *own):
+            return self.fn(p, x, consts, *own)
 
         if self.remat:
             block = jax.checkpoint(block)
         depth = jax.tree.leaves(stacked)[0].shape[0]
 
-        def body(x, p):
-            y, st = block(x, p, consts)
+        def body(x, layer):
+            p, *own = (layer,) if each is None else layer
+            y, st = block(x, p, consts, *own)
             return y, ((x, st) if keep else st)
 
-        return jax.lax.scan(body, carry, stacked,
+        return jax.lax.scan(body, carry,
+                            stacked if each is None else (stacked, each),
                             unroll=min(self.unroll, depth))
 
     def __call__(self, p, carry, batch):
         carry, stacked = self.scan(p[self.keys[0]], carry,
-                                   self._consts(batch))
+                                   self._consts(batch),
+                                   each=self._each(batch))
         return carry, stacked if self.stats is None else self.stats(stacked)
 
 
@@ -148,7 +168,7 @@ class Chain:
         carry, stats = None, {}
         for ln in self.links:
             carry, st = ln(ln.pick(params), carry, batch)
-            stats.update(st)
+            add_stats(stats, st)
         return carry, stats
 
     # ---- what a step that cuts the backward needs ------------------- #
@@ -176,13 +196,14 @@ class Chain:
             if isinstance(ln, Run):
                 plain = dataclasses.replace(ln, remat=False)
                 carry, (inputs, st) = plain.scan(
-                    params[ln.keys[0]], carry, ln._consts(batch), keep=True)
+                    params[ln.keys[0]], carry, ln._consts(batch), keep=True,
+                    each=ln._each(batch))
                 kept.append(inputs)
-                stats.update(st if ln.stats is None else ln.stats(st))
+                add_stats(stats, st if ln.stats is None else ln.stats(st))
             else:
                 kept.append(carry)
                 carry, st = ln(ln.pick(params), carry, batch)
-                stats.update(st)
+                add_stats(stats, st)
         kept.append(carry)
         return kept, stats
 
@@ -211,13 +232,19 @@ class Chain:
         block, so that a kernel inside is named as in the uncut
         program."""
         ln = self.links[k]
-        one = jax.tree.map(
-            lambda a: jax.lax.dynamic_slice_in_dim(a, j, 1, 0), p)
+
+        def layer(tree):
+            return jax.tree.map(
+                lambda a: jax.lax.dynamic_slice_in_dim(a, j, 1, 0), tree)
+
+        one, each = layer(p), ln._each(batch)
+        each = None if each is None else layer(each)
         x = jax.tree.map(
             lambda a: jax.lax.dynamic_index_in_dim(a, j, 0, keepdims=False),
             inputs)
         consts = ln._consts(batch)
         _, vjp = jax.vjp(
-            lambda p, c: ln.scan(p[ln.keys[0]], c, consts)[0], one, x)
+            lambda p, c: ln.scan(p[ln.keys[0]], c, consts, each=each)[0],
+            one, x)
         g_one, g_x = vjp(ct)
         return g_x, g_one

@@ -6,7 +6,10 @@ against a local server is the one-program step bit for bit, pushes the
 same bytes and counts its programs, its pieces and the bytes that left
 under the backward; each of the plan's rules keeps the backward one
 program; the pieces' keys are a pure function of the tree; the
-programs of the cut name their kernels' scope as the uncut one does."""
+programs of the cut name their kernels' scope as the uncut one does; a
+chain of SEVERAL runs of unlike blocks, runs of one layer among them and
+a buffer a layer beside the leaves (``models/kimi.py``), is cut a
+program a layer, and a run of one layer hands its leaves over whole."""
 
 import dataclasses
 import itertools
@@ -23,7 +26,7 @@ from byteps_tpu.jax.train import (ExportPlan, _chain_leaves, _cut_backward,
                                   _declare_shard_keys, _dispatch_cut,
                                   _export_plan, make_ps_train_step,
                                   make_train_step)
-from byteps_tpu.models import llama, sdar
+from byteps_tpu.models import kimi, llama, sdar
 from byteps_tpu.ops.push_pull import psum_tree
 
 from test_export_spans import _ps_env
@@ -486,3 +489,199 @@ def test_the_fused_step_runs_the_chain_as_the_scan():
     want = run(lambda p, b: _parents_loss(p, b, cfg))
     assert got[2] == want[2]
     _assert_trees_equal(got[:2], want[:2])
+
+
+# --------------------------------------------------------------------- #
+# several runs of unlike blocks, runs of one layer (models/kimi.py)
+# --------------------------------------------------------------------- #
+
+
+def _kimi(seed=5):
+    """The benchmark cell's five layers at test size, rematerialised:
+    runs of depths 1, 2, 1, 1 between the embedding and the head, a
+    selection bias a sparse layer beside the leaves."""
+    cfg = dataclasses.replace(kimi.KimiConfig.tiny(), remat=True)
+    key = jax.random.PRNGKey(seed)
+    params = kimi.init_params(key, cfg)
+    tokens = jax.random.randint(key, (4, 33), 0, cfg.vocab_size)
+    bias = jax.random.uniform(key, (4, cfg.n_experts), minval=-0.1,
+                              maxval=0.1)
+    return cfg, params, {"tokens": tokens}, bias
+
+
+def _unchained(cfg, bias):
+    """``kimi.loss_fn`` link by link, with no chain called: what no
+    step can cut."""
+    def loss(params, batch):
+        carry, stats = None, {}
+        for ln in kimi._chain(cfg, bias, None).links:
+            carry, st = ln(ln.pick(params), carry, batch)
+            chain.add_stats(stats, st)
+        return carry, stats
+    return loss
+
+
+def _assert_trees_close(got, want, rtol=2e-6, atol=0.0):
+    """To float32's last digits. XLA:CPU compiles this model's head
+    alone and inside the whole backward to programs that differ in the
+    last bit (the mean's scalar moved through a product), and every
+    cotangent behind it inherits that; SDAR's head, weighed a position,
+    compiles alike, and its steps are compared bit for bit above."""
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        w = np.asarray(w)
+        np.testing.assert_allclose(
+            np.asarray(g), w, rtol=rtol,
+            atol=max(atol, rtol * float(np.abs(w).max()) if w.size else 0),
+            err_msg=jax.tree_util.keystr(path))
+
+
+def test_links_that_count_under_one_name_add_up():
+    stats = chain.add_stats({"a": 1, "b": np.array([1, 2])},
+                            {"b": np.array([3, 4]), "c": 5})
+    assert stats["a"] == 1 and stats["c"] == 5
+    np.testing.assert_array_equal(stats["b"], [4, 6])
+
+
+def test_a_run_scans_what_each_layer_alone_reads_beside_its_leaves():
+    """``each``: layer ``j`` is handed row ``j``; the run cut at layer
+    ``j`` pulls back through that row alone."""
+    run = chain.Run(lambda p, x, _, row: (x * p["w"] + row, {}), "w3", 3,
+                    each=lambda batch: batch["rows"])
+    params = {"w3": {"w": jnp.array([2.0, 3.0, 5.0])}}
+    batch = {"rows": jnp.array([0.5, 0.25, 0.125])}
+    out, _ = run(params, jnp.float32(1.0), batch)
+    assert float(out) == ((1 * 2 + 0.5) * 3 + 0.25) * 5 + 0.125
+    ch = chain.Chain([chain.Link(None, "a"), run, chain.Link(None, "b")])
+    g_x, g_p = ch.pull_layer(1, params, 1, jnp.array([1.0, 2.5, 7.75]),
+                             batch, jnp.float32(1.0))
+    assert float(g_x) == 3.0
+    np.testing.assert_array_equal(g_p["w3"]["w"], [2.5])
+
+
+@pytest.mark.parametrize("devices", [1, 2], ids=["1dev", "2dev"])
+def test_a_chain_of_unlike_runs_is_cut_a_program_a_layer(devices):
+    from byteps_tpu.jax.train import _loss_and_stats, _psum_backward
+
+    cfg, params, batch, bias = _kimi()
+    if devices == 2:
+        batch = {"tokens": jnp.concatenate([batch["tokens"]] * 2)[:4]}
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+    loss = lambda p, b: kimi.loss_fn(p, b, cfg, bias)  # noqa: E731
+    with chain.collecting() as found:
+        whole = _psum_backward(_loss_and_stats(loss), mesh, "dp")
+        (want, want_stats), want_grads = whole(params, batch)
+    leaves = _chain_leaves(found[0], params)
+    # embed, final_norm and lm_head, then the runs, in flatten order
+    assert [len(leaves[k]) for k in range(6)] == [1, 21, 25, 14, 25, 2]
+    cut = _cut_backward(found[0], mesh, "dp", leaves)
+    # forward, head, five layers, embedding
+    assert cut.programs == 8
+    (got, stats), programs = _dispatch_cut(cut, params, batch)
+    assert [(links, layer) for links, layer, _, _ in programs] == [
+        ("0-4", None), ("5", None), ("4", 0), ("3", 0), ("2", 1), ("2", 0),
+        ("1", 0), ("0", None)]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _assert_trees_equal(stats, want_stats)
+    assert stats["moe/expert_load"].shape == (4, 4)
+    assert int(stats["kda/chunk_steps"]) > 0
+    flat = jax.tree.leaves(want_grads)
+    seen = set()
+    for _, layer, _, outs in programs:
+        for i, g in outs.items():
+            w = np.asarray(flat[i])
+            if layer is not None:
+                assert g.shape == (1,) + w.shape[1:]
+                w = w[layer:layer + 1]
+            _assert_trees_close(g, w)
+            seen.add((i, layer))
+    assert len(seen) == 3 + 21 + 2 * 25 + 14 + 25
+
+
+def test_a_cut_step_over_unlike_runs_is_the_one_program_step():
+    """Three steps, a short batch between two full ones: losses,
+    parameters and optimizer state the one-program step's to float32's
+    last digits (``_assert_trees_close`` says why not to the bit);
+    eight programs a step; the run of two layers leaves as pieces, the
+    runs of one hand their leaves over whole, on the one-program step's
+    keys, and under the backward."""
+    cfg, params, batch, bias = _kimi()
+    batches = [batch, _rows(batch, 2), batch]
+    n_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+
+    def run(loss):
+        from byteps_tpu.core.state import get_state
+
+        tx = optax.adam(1e-2)
+        mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+        with _ps_env(ENV, port=next(PORTS)) as bps:
+            step = make_ps_train_step(loss, tx, mesh)
+            p, opt = jax.tree.map(jnp.array, params), tx.init(params)
+            before = bps.get_metrics()["counters"]
+            losses = []
+            for b in batches:
+                p, opt, value = step(p, opt, b)
+                losses.append(float(value))
+            jax.block_until_ready((p, opt))
+            after = bps.get_metrics()["counters"]
+            keys = [c.name for c in get_state().registry.contexts_in_order()]
+            spans = get_state().profiler.last_spans()
+        grew = {c: after.get(c, 0) - before.get(c, 0) for c in COUNTERS}
+        return p, opt, losses, grew, keys, spans
+
+    cut = run(lambda p, b: kimi.loss_fn(p, b, cfg, bias))
+    whole = run(_unchained(cfg, bias))
+    np.testing.assert_allclose(cut[2], whole[2], rtol=1e-6)
+    assert cut[2][-1] < cut[2][0]
+    # (adam divides by a gradient's own size: where a gradient is
+    # rounding alone, a last digit of it is a visible share of one
+    # update of 1e-2; a hundredth of an update is the floor here)
+    _assert_trees_close(cut[:2], whole[:2], rtol=2e-4, atol=1e-4)
+    assert cut[3]["export/backward_programs"] == 3 * 8
+    assert whole[3]["export/backward_programs"] == 3
+    for side in (cut, whole):
+        assert side[3]["wire/push_bytes"] == 3 * n_bytes
+        assert side[3]["export/whole_bytes"] == 3 * n_bytes
+    # the pieces: the run of two layers' leaves over the fusion size
+    own = lambda a: a.nbytes >= 1024  # noqa: E731
+    pieces = sum(a.nbytes for a in jax.tree.leaves(params["run01"])
+                 if own(a))
+    assert cut[3]["export/piece_bytes"] == 3 * pieces > 0
+    assert whole[3]["export/piece_bytes"] == 0
+    piece_names = {n for n in cut[4] if "@shard" in n}
+    assert piece_names and all("run01" in n and n.endswith("of2")
+                               for n in piece_names)
+    # a run of one layer: its leaves on the one-program step's keys
+    for name in ("grad/run00/op/wq", "grad/run02/op/wkv_b",
+                 "grad/run03/ffn/w_gate", "grad/embed", "grad/lm_head"):
+        assert name in cut[4] and name in whole[4], name
+    assert {n for n in cut[4] if n.startswith("fused/")} == \
+        {n for n in whole[4] if n.startswith("fused/")}
+    # what left before the last program was seen to have ended: the
+    # head's leaf at least (a tiny backward ends under the first claims)
+    assert 3 * params["lm_head"].nbytes \
+        <= cut[3]["export/under_backward_bytes"] <= 3 * n_bytes
+    assert whole[3]["export/under_backward_bytes"] == 0
+    # a span a program; a whole leaf of a run of ONE layer is claimed
+    # when its program has ended, before the wait for the next program
+    # (it does not wait for the backward's end as a kept-whole leaf of a
+    # deeper run does)
+    programs = sorted((s for s in cut[5]
+                       if s[0] == "bps.step.backward_program"),
+                      key=lambda s: s[2])
+    assert [s[4]["links"] for s in programs] == [
+        "0-4", "5", "4", "3", "2", "2", "1", "0"]
+    index = {jax.tree_util.keystr(path): i for i, (path, _) in enumerate(
+        jax.tree_util.tree_leaves_with_path(params))}
+    ingests = {s[4]["leaf"]: s[2] for s in cut[5]
+               if s[0] == "bps.export.ingest"}
+
+    def claimed(run, group, leaf):
+        return ingests[index[f"['{run}']['{group}']['{leaf}']"]]
+
+    assert programs[2][3] <= claimed("run03", "op", "wq") <= programs[3][2]
+    assert programs[3][3] <= claimed("run02", "op", "wkv_b") \
+        <= programs[4][2]
+    # (the last program's end is looked for between the claims)
+    assert programs[6][3] <= claimed("run00", "ffn", "w1")

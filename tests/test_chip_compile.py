@@ -575,3 +575,81 @@ def test_a_cut_backwards_layer_program_names_its_kernels_by_scope_alone(
     assert g_carry.shape == carry.shape
     assert jax.tree.map(lambda g: g.shape, g_blocks) == jax.tree.map(
         lambda p: (1,) + p.shape[1:], blocks)
+
+
+def test_the_delta_rules_kernels_compile_at_the_cells_shape(v5e, monkeypatch):
+    """``ops/delta_rule.py``'s forward and backward kernels (the
+    backward's body is ``jax.vjp`` of the chunk, traced into the kernel:
+    what Mosaic is asked to lower is decided there) at Kimi-Linear's
+    widths, 2 rows of 8192 positions, 32 heads of 128, bfloat16, as the
+    TPU's path takes them (bfloat16 products, no widening)."""
+    from byteps_tpu.ops import delta_rule
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    wide = _sds((2, 8192, 32, 128), jnp.bfloat16, v5e)
+    g = _sds((2, 8192, 32, 128), jnp.float32, v5e)
+    beta = _sds((2, 8192, 32), jnp.float32, v5e)
+    compiled = jax.jit(jax.grad(
+        lambda *a: jnp.sum(delta_rule.delta_rule(*a).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4))).lower(wide, wide, wide, g, beta).compile()
+    text = compiled.as_text()
+    # the forward that keeps the chunks' states, and the backward
+    assert text.count("tpu_custom_call") >= 2
+    assert set(re.findall(r"%([\w.\-]*bps\.attn[\w.\-]*) = ", text)), text[:200]
+    grads = jax.eval_shape(jax.grad(
+        lambda *a: jnp.sum(delta_rule.delta_rule(*a).astype(jnp.float32)),
+        argnums=(0, 1, 2, 3, 4)), wide, wide, wide, g, beta)
+    assert [x.dtype for x in grads] == [jnp.bfloat16] * 3 + [jnp.float32] * 2
+
+
+def test_a_cut_backwards_program_of_a_run_of_one_names_its_kernels_by_scope(
+        v5e_host, monkeypatch):
+    """``models/kimi.py`` at Kimi-Linear's published widths: the program
+    of a run of ONE layer (KDA and a sparse FFN) differentiates the
+    run's scan over that layer, so the delta rule's two kernels are
+    ``bps.attn.kda`` and the grouped products ``ragged-dot.bps`` by name;
+    its gradient outputs are the run's whole leaves."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from byteps_tpu.jax import train
+    from byteps_tpu.models import kimi
+    from byteps_tpu.ops import chain
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        kimi.KimiConfig(), vocab_size=2048, n_experts_held=8,
+        layer_ops=("kda", "kda", "kda", "mla", "kda"))
+    rows, seq = 2, 8192
+    mesh = Mesh(np.array(v5e_host[:1]), ("dp",))
+    rep, dp = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    params = jax.tree.map(
+        lambda v: _sds(v.shape, v.dtype, rep),
+        jax.eval_shape(lambda: kimi.init_params(jax.random.PRNGKey(0), cfg)))
+    batch = {"tokens": _sds((rows, seq + 1), jnp.int32, dp)}
+    with chain.collecting() as found:
+        jax.eval_shape(lambda p, b: kimi.loss_fn(p, b, cfg), params, batch)
+    (ch,) = found
+    cut = train._cut_backward(ch, mesh, "dp",
+                              train._chain_leaves(ch, params))
+    assert cut.programs == 8
+    carry = _sds((1, rows, seq, cfg.dim), cfg.dtype, dp)
+    inputs = _sds((1, 1, rows, seq, cfg.dim), cfg.dtype, dp)
+    run = ch.links[4].pick(params)
+    compiled = cut.pulls[4].lower(run, np.int32(0), inputs, batch,
+                                  carry).compile()
+    names = set(re.findall(r"%([\w.\-]*(?:bps\.attn|ragged-dot)[\w.\-]*) = ",
+                           compiled.as_text()))
+    assert names and all(
+        re.fullmatch(r"(bps\.attn\.kda|ragged-dot\.bps)(\.\d+)*", n)
+        for n in names), sorted(names)
+    # the forward run again (the block's remat), once more with its
+    # states kept (the operator's own checkpoint), and the backward
+    assert len({n for n in names if n.startswith("bps.attn")}) == 3
+    g_carry, g_run = jax.eval_shape(
+        cut.pulls[4], run, np.int32(0), inputs, batch, carry)
+    assert g_carry.shape == carry.shape
+    assert jax.tree.map(lambda g: g.shape, g_run) == jax.tree.map(
+        lambda p: p.shape, run)
