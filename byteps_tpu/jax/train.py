@@ -20,6 +20,7 @@ Two flavors:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -432,16 +433,44 @@ def _shapes(tree):
 class _CutBackward:
     """The programs of a backward cut at a chain's links
     (``_cut_backward``), each a jit over the mesh, and which gradient
-    leaves each hands over: ``leaves[k]`` are link ``k``'s flatten
+    leaves each computes: ``leaves[k]`` are link ``k``'s flatten
     indices in the parameter tree, in the order of its program's
-    gradient outputs."""
+    gradient outputs. A leaf under several links is SHARED (a tied
+    embedding): the programs run last link first, each that reads the
+    leaf but the last to run keeps its term on the chip (``held``), an
+    input of the next that reads it (``taken``), and the last to run
+    hands the sum over."""
 
     chain: Any
-    forward: Callable
-    last: Callable
-    pulls: Dict[int, Callable]
     leaves: Dict[int, Tuple[int, ...]]
+    forward: Optional[Callable] = None
+    last: Optional[Callable] = None
+    pulls: Dict[int, Callable] = dataclasses.field(default_factory=dict)
     pinned: Dict[int, int] = dataclasses.field(default_factory=dict)
+    # XLA's cost analysis summed over a step's programs (the ledger's)
+    cost: Dict[str, float] = dataclasses.field(default_factory=dict)
+
+    @functools.cached_property
+    def shared(self) -> Dict[int, Tuple[int, ...]]:
+        """Flatten index of a shared leaf -> the links that read it,
+        ascending."""
+        readers: Dict[int, list] = {}
+        for k, found in sorted(self.leaves.items()):
+            for i in found:
+                readers.setdefault(i, []).append(k)
+        return {i: tuple(ks) for i, ks in readers.items() if len(ks) > 1}
+
+    def held(self, k: int) -> Tuple[int, ...]:
+        """The leaves whose term link ``k``'s program keeps on the chip:
+        an earlier link, whose program runs later, reads them too."""
+        return tuple(i for i in self.leaves[k]
+                     if i in self.shared and self.shared[i][0] < k)
+
+    def taken(self, k: int) -> Tuple[int, ...]:
+        """The leaves whose term so far is an input of link ``k``'s
+        program: a later link, whose program has run, read them."""
+        return tuple(i for i in self.leaves[k]
+                     if i in self.shared and self.shared[i][-1] > k)
 
     @property
     def outputs_pinned(self) -> int:
@@ -461,17 +490,16 @@ class _CutBackward:
 def _chain_leaves(ch, params, paths=None
                   ) -> Optional[Dict[int, Tuple[int, ...]]]:
     """Link index -> the flatten indices in ``params`` of the leaves the
-    link picks, in the order it picks them; None where ``ch`` cannot be
-    cut over this tree (``chain.Chain.cuts``) or a link's leaves are not
-    the tree's own under its keys. ``paths``: ``params`` flattened with
-    paths, where the caller has it."""
-    if not ch.cuts(params):
-        return None
+    link picks, in the order it picks them (a leaf under several links
+    is in each one's: ``_CutBackward.shared``); None where ``ch`` cannot
+    be cut over this tree (``chain.Chain.cover``) or a link's leaves are
+    not the tree's own under its keys, in the tree's order. ``paths``:
+    ``params`` flattened with paths, where the caller has it."""
     if paths is None:
         paths = jax.tree_util.tree_flatten_with_path(params)[0]
-    leaves = {k: tuple(i for i, (path, _) in enumerate(paths)
-                       if getattr(path[0], "key", None) in ln.keys)
-              for k, ln in enumerate(ch.links)}
+    leaves = ch.cover(params, paths)
+    if leaves is None:
+        return None
     for k, ln in enumerate(ch.links):
         picked = jax.tree.leaves(ln.pick(params))
         if len(picked) != len(leaves[k]) or any(
@@ -492,8 +520,19 @@ def _cut_backward(ch, mesh: Mesh, axis: str, leaves) -> _CutBackward:
     with the device as its leading dimension. Every program that has
     gradients returns them LAST and takes their parameters FIRST, which
     is what ``_row_major_outputs`` looks at. ``leaves``:
-    ``_chain_leaves``."""
+    ``_chain_leaves``.
+
+    A shared leaf (``_CutBackward.shared``): the program of a link that
+    ``held`` it returns its term in the leaf's place among the
+    gradients, a data shard's own (as a carry is: nothing is psum'd
+    yet); the program of a link that has ``taken`` it has one argument
+    more, the terms so far, LAST, and adds its own to each; the program
+    that runs last of those that read the leaf psums the sum and returns
+    it as the leaf's gradient, as ``_psum_backward`` does the sum the
+    one program makes. A link that reads no shared leaf has the program
+    it had."""
     rep, loc = P(), P(axis)
+    cut = _CutBackward(ch, leaves)
 
     def lift(tree):
         return jax.tree.map(lambda a: a[None], tree)
@@ -508,6 +547,26 @@ def _cut_backward(ch, mesh: Mesh, axis: str, leaves) -> _CutBackward:
         return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                                      out_specs=out_specs, check_vma=False))
 
+    def handed(k, g_p, terms=()):
+        """Link ``k``'s gradients as its program returns them: the terms
+        so far added where taken, the others psum'd; where a term is
+        held it stays a shard's own, and the gradients are a tuple, one
+        a leaf in the order of ``leaves[k]`` (``handed_specs`` gives a
+        leaf its own spec)."""
+        flat, tree = jax.tree.flatten(g_p)
+        at = {i: n for n, i in enumerate(leaves[k])}
+        for i, term in zip(cut.taken(k), terms):
+            flat[at[i]] = drop(term) + flat[at[i]]
+        keep = {at[i] for i in cut.held(k)}
+        whole = iter(mean([g for n, g in enumerate(flat) if n not in keep]))
+        flat = [lift(g) if n in keep else next(whole)
+                for n, g in enumerate(flat)]
+        return tuple(flat) if keep else tree.unflatten(flat)
+
+    def handed_specs(k):
+        return tuple(loc if i in cut.held(k) else rep
+                     for i in leaves[k]) if cut.held(k) else rep
+
     def forward(params, batch):
         kept, stats = ch.forward(params, batch)
         return lift(kept), jax.tree.map(
@@ -515,7 +574,8 @@ def _cut_backward(ch, mesh: Mesh, axis: str, leaves) -> _CutBackward:
 
     def last(p, carry, batch):
         loss, stats, g_carry, g_p = ch.last(p, drop(carry), batch)
-        return (_reduce_loss((loss, stats), axis), lift(g_carry)), mean(g_p)
+        return (_reduce_loss((loss, stats), axis), lift(g_carry)), \
+            handed(len(ch.links) - 1, g_p)
 
     def pull(k):
         if isinstance(ch.links[k], chain_mod.Run):
@@ -525,15 +585,18 @@ def _cut_backward(ch, mesh: Mesh, axis: str, leaves) -> _CutBackward:
                 return lift(g_x), mean(g_p)
             return program(layer, (rep, rep, loc, loc, loc), (loc, rep))
 
-        def whole(p, carry, batch, ct):
+        def whole(p, carry, batch, ct, *terms):
             g_carry, g_p = ch.pull_link(k, p, drop(carry), batch, drop(ct))
-            return lift(g_carry), mean(g_p)
-        return program(whole, (rep, loc, loc, loc), (loc, rep))
+            return lift(g_carry), handed(k, g_p, *terms)
+        return program(
+            whole, (rep, loc, loc, loc) + ((loc,) if cut.taken(k) else ()),
+            (loc, handed_specs(k)))
 
-    return _CutBackward(
-        ch, program(forward, (rep, loc), (loc, rep)),
-        program(last, (rep, loc, loc), ((rep, loc), rep)),
-        {k: pull(k) for k in range(len(ch.links) - 1)}, leaves)
+    cut.forward = program(forward, (rep, loc), (loc, rep))
+    cut.last = program(last, (rep, loc, loc),
+                       ((rep, loc), handed_specs(len(ch.links) - 1)))
+    cut.pulls = {k: pull(k) for k in range(len(ch.links) - 1)}
+    return cut
 
 
 def _dispatch_cut(cut: _CutBackward, params, batch):
@@ -542,7 +605,10 @@ def _dispatch_cut(cut: _CutBackward, params, batch):
     one ``(links, layer, ready, outputs)`` a program in the order the
     programs END, a pure function of the chain: the links it runs (a
     label), the layer of a run it is (or None), an output of it to wait
-    on, and its gradient outputs by flatten index.
+    on, and the gradient outputs it HANDS OVER by flatten index (a
+    shared leaf's term that a program keeps on the chip goes to the next
+    program that reads the leaf and is in no one's; the sum is in the
+    last such program's).
 
     (The runtime finds a program's temporaries when the program is
     ENQUEUED: where two programs' do not fit beside the step's state the
@@ -553,11 +619,16 @@ def _dispatch_cut(cut: _CutBackward, params, batch):
     last = len(links) - 1
     kept, stats = cut.forward(params, batch)
     programs = [(f"0-{last - 1}", None, jax.tree.leaves(kept)[-1], {})]
+    # a shared leaf's term so far, from the program that held it to the
+    # next that reads the leaf
+    terms: Dict[int, Any] = {}
 
     def ran(k, layer, ct, grads):
-        grads = jax.tree.leaves(grads)
-        programs.append((str(k), layer, (grads or jax.tree.leaves(ct))[0],
-                         dict(zip(cut.leaves[k], grads))))
+        grads = dict(zip(cut.leaves[k], jax.tree.leaves(grads)))
+        ready = (list(grads.values()) or jax.tree.leaves(ct))[0]
+        for i in cut.held(k):
+            terms[i] = grads.pop(i)
+        programs.append((str(k), layer, ready, grads))
 
     ((loss, last_stats), ct), grads = cut.last(
         links[last].pick(params), kept[last], batch)
@@ -569,7 +640,9 @@ def _dispatch_cut(cut: _CutBackward, params, batch):
                 ct, grads = cut.pulls[k](p, np.int32(j), kept[k], batch, ct)
                 ran(k, j, ct, grads)
         else:
-            ct, grads = cut.pulls[k](p, kept[k], batch, ct)
+            taken = tuple(terms.pop(i) for i in cut.taken(k))
+            ct, grads = cut.pulls[k](p, kept[k], batch, ct,
+                                     *((taken,) if taken else ()))
             ran(k, None, ct, grads)
     return (loss, chain_mod.add_stats(dict(stats), last_stats)), programs
 
@@ -654,9 +727,16 @@ def _pin_cut_outputs(cut: _CutBackward, params, batch, own, mesh: Mesh,
     """``_row_major_outputs`` for each program of ``cut`` that has
     gradients, last link first, as the step will run them: the outputs
     in ``own`` (flatten indices of ``params``; a piece is its leaf's
-    layer) are pinned major-to-minor. Nothing runs: a program's carries
-    and cotangent are described by the shapes the program before it
-    returns, each a ``P(axis)`` array."""
+    layer) are pinned major-to-minor, a shared leaf's on the program
+    that hands its sum over (a term kept on the chip keeps the layout
+    the compiler gives it). Nothing runs: a program's carries, cotangent
+    and terms are described by the shapes the program before it returns,
+    each a ``P(axis)`` array. The programs' costs by XLA's analysis are
+    added up into ``cut.cost`` on the way (the step's ledger reads
+    them: the one program a cut step does not run is not lowered for
+    its cost)."""
+    from ..core.ledger import extract_cost
+
     local = NamedSharding(mesh, P(axis))
     links = cut.chain.links
 
@@ -664,13 +744,31 @@ def _pin_cut_outputs(cut: _CutBackward, params, batch, own, mesh: Mesh,
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=local), tree)
 
+    # a shared leaf's term so far, described (``_dispatch_cut``'s)
+    terms: Dict[int, Any] = {}
+
+    def count(fn, args, times=1):
+        """A program's cost, ``times`` a step, into ``cut.cost`` (the
+        lowering is the one the look made, or the run's: it is kept)."""
+        for name, value in (extract_cost(fn.lower(*args)) or {}).items():
+            cut.cost[name] = cut.cost.get(name, 0.0) + times * value
+
     def pin(k, program, *args):
         p = links[k].pick(params)
+        taken = tuple(terms.pop(i) for i in cut.taken(k))
+        args = args + ((taken,) if taken else ())
+        held = cut.held(k)
         fn, cut.pinned[k] = _row_major_outputs(
             program, (p, *args),
-            [n for n, i in enumerate(cut.leaves[k]) if i in own], mesh)
-        return fn, fn.trace(p, *args).out_info[0]
+            [n for n, i in enumerate(cut.leaves[k])
+             if i in own and i not in held], mesh)
+        count(fn, (p, *args), getattr(links[k], "depth", 1))
+        carried, grads = fn.trace(p, *args).out_info
+        grads = dict(zip(cut.leaves[k], jax.tree.leaves(grads)))
+        terms.update((i, described(grads[i])) for i in held)
+        return fn, carried
 
+    count(cut.forward, (params, batch))
     kept = described(cut.forward.trace(params, batch).out_info[0])
     cut.last, (_, ct) = pin(len(links) - 1, cut.last, kept[-1], batch)
     for k in reversed(range(len(links) - 1)):
@@ -846,11 +944,17 @@ def make_ps_train_step(
     applied whole when its last piece has landed). Bucket members and
     row-sparse leaves are claimed once the last program has ended, in
     flatten order, so buckets, digests and keys are the one-program
-    step's; so are the bytes pushed. Nothing selects this but what the
-    step observes (a chain that covers the tree, remat on its runs, a
-    plan without shards, a running scheduler, no host codec); counters
-    ``export/backward_programs``, ``export/piece_bytes`` and
-    ``export/under_backward_bytes`` say how often it engages.
+    step's; so are the bytes pushed. A leaf that lies under SEVERAL
+    links (an embedding that is also the head; read off the links' keys)
+    is summed on the device: each program that reads it but the last to
+    run keeps its term on the chip for the next, and the last hands the
+    sum over, once, on the leaf's one-program key. Nothing selects this
+    but what the step observes (a chain whose links cover the tree's
+    leaves, remat on its runs, a plan without shards, a running
+    scheduler, no host codec); counters ``export/backward_programs``,
+    ``export/piece_bytes``, ``export/under_backward_bytes``,
+    ``export/shared_leaves`` and ``export/shared_carry_bytes`` say how
+    often it engages.
 
     ``sharded_apply`` (BYTEPS_SHARDED_APPLY, default on): split the
     monolithic apply jit into per-leaf donated partial updates
@@ -977,15 +1081,22 @@ def make_ps_train_step(
         if shapes not in plan_cache["chains"]:
             # the loss's chain, if it is written as one: ``grad_fn``'s
             # FIRST trace at these shapes, under the collector a called
-            # chain registers with (the ledger's lowering, the layout
-            # look and the run below are served from that trace: a loss
-            # with no chain pays nothing here). One chain, called once,
-            # is a loss that can be cut; anything else runs as one
-            # program. Collected anew for new shapes (an epoch's last
-            # batch), as the one program is traced anew: a loss may
-            # build its chain from what it sees of the batch.
-            with chain_mod.collecting() as found:
+            # chain registers with. A loss with no chain pays nothing
+            # here (the ledger's lowering, the layout look and the run
+            # below are served from that trace). At a chain's first
+            # call the trace is abandoned (a cut step never runs
+            # ``grad_fn``: its whole trace and lowering would be set-up
+            # spent on a program that is not run) and the chain's calls
+            # are counted over the loss's forward alone. One chain,
+            # called once, is a loss that can be cut; anything else
+            # runs as one program. Collected anew for new shapes (an
+            # epoch's last batch), as the one program is traced anew: a
+            # loss may build its chain from what it sees of the batch.
+            with chain_mod.collecting(first=True) as found:
                 grad_fn.trace(params, batch)
+            if found:
+                with chain_mod.collecting() as found:
+                    jax.eval_shape(loss_and_stats, params, batch)
             plan_cache["chains"][shapes] = \
                 found[0] if len(found) == 1 else None
         # drain the previous sharded round's deferred arena releases
@@ -1051,39 +1162,48 @@ def make_ps_train_step(
         # way), so end_step prices every step in MFU / roofline /
         # wire-efficiency terms. A backend without a cost model
         # registers the wire sizes alone (MFU stays None, never 0).
+        # ``cut``: the programs of a cut backward, whose costs
+        # ``_pin_cut_outputs`` added up; else ``grad_fn`` is lowered.
         ledger = getattr(state, "ledger", None)
-        if ledger is not None and ledger.enabled:
-            cost_key = (treedef, tuple(
+
+        def register_cost(cut=None):
+            if ledger is None or not ledger.enabled:
+                return
+            cost_key = (treedef, cut is not None, tuple(
                 (tuple(np.shape(pl)), str(getattr(pl, "dtype", "")))
                 for pl in p_leaves))
             # keyed on the LEDGER INSTANCE too: suspend/resume replaces
             # state.ledger, and a plan-key-only cache would leave the
             # fresh ledger with no cost model (post-resume MFU None)
-            if (plan_cache.get("cost_key") != cost_key
-                    or plan_cache.get("cost_ledger") is not ledger):
-                plan_cache["cost_key"] = cost_key
-                plan_cache["cost_ledger"] = ledger
-                from ..core import ledger as ledger_mod
-                flops = acc_bytes = None
-                for part in (ledger_mod.jit_cost(grad_fn, params, batch),
-                             ledger_mod.jit_cost(apply_fn, params,
-                                                 opt_state, params)):
-                    if part:
-                        if part.get("flops"):
-                            flops = (flops or 0.0) + part["flops"]
-                        if part.get("bytes_accessed"):
-                            acc_bytes = (acc_bytes or 0.0) \
-                                + part["bytes_accessed"]
-                ledger.register_step_cost(
-                    flops=flops, bytes_accessed=acc_bytes,
-                    ideal_wire_bytes=2 * sum(
-                        int(getattr(pl, "nbytes", 0))
-                        for pl in p_leaves),
-                    source="xla" if flops else "none")
+            if (plan_cache.get("cost_key") == cost_key
+                    and plan_cache.get("cost_ledger") is ledger):
+                return
+            plan_cache["cost_key"] = cost_key
+            plan_cache["cost_ledger"] = ledger
+            from ..core import ledger as ledger_mod
+            flops = acc_bytes = None
+            for part in (cut.cost if cut is not None
+                         else ledger_mod.jit_cost(grad_fn, params, batch),
+                         ledger_mod.jit_cost(apply_fn, params,
+                                             opt_state, params)):
+                if part:
+                    if part.get("flops"):
+                        flops = (flops or 0.0) + part["flops"]
+                    if part.get("bytes_accessed"):
+                        acc_bytes = (acc_bytes or 0.0) \
+                            + part["bytes_accessed"]
+            ledger.register_step_cost(
+                flops=flops, bytes_accessed=acc_bytes,
+                ideal_wire_bytes=2 * sum(
+                    int(getattr(pl, "nbytes", 0))
+                    for pl in p_leaves),
+                source="xla" if flops else "none")
+
         use_device = (compression is not None
                       and device_compress is not False
                       and state.scheduler is not None)
         if use_device:
+            register_cost()
             with tracing.span(tracing.STEP_DISPATCH, step=tag):
                 loss, grads = grad_fn(params, batch)
             grads = _device_compressed_round(
@@ -1191,6 +1311,11 @@ def make_ps_train_step(
         exp_programs_ctr = metrics.counter("export/backward_programs")
         exp_piece_ctr = metrics.counter("export/piece_bytes")
         exp_under_ctr = metrics.counter("export/under_backward_bytes")
+        # the leaves whose gradient was summed over several programs on
+        # the chip (a leaf under several links of the chain: a tied
+        # embedding) and the bytes of their terms held between programs
+        exp_shared_ctr = metrics.counter("export/shared_leaves")
+        exp_carry_ctr = metrics.counter("export/shared_carry_bytes")
         ag_hist = metrics.histogram("step/allgather_us")
 
         # time-to-first-push: wall from the backward's dispatch to the
@@ -1411,6 +1536,7 @@ def make_ps_train_step(
             cut = _cut_backward(ch, mesh, axis, link_leaves)
             _pin_cut_outputs(cut, params, batch, own, mesh, axis)
             plan_cache["cuts"][shapes] = cut
+        register_cost(plan_cache["cuts"][shapes] if plan.pieces else None)
 
         # ---- sharded-apply build (cached per tree structure) ----
         sharded_cfg = sharded_apply if sharded_apply is not None \
@@ -1712,9 +1838,10 @@ def make_ps_train_step(
 
         # start the D2H copies of the leaves now, all of them: a
         # one-program backward's in flatten order, a shard leaf's
-        # per-device arrays in mesh-device order, a cut backward's in
-        # the order its programs end (a pure function of the plan: every
-        # worker issues and claims them alike). What an np.asarray of an
+        # per-device arrays in mesh-device order (a cut backward's
+        # start in the claim loop, in the order its programs end; a
+        # pure function of the plan either way: every worker issues and
+        # claims them alike). What an np.asarray of an
         # output below can cost is the wait for ITS transfer and no
         # more: it returns a view of the buffer the runtime filled, in
         # the output's own dimension order, and that order is the
@@ -1732,7 +1859,16 @@ def make_ps_train_step(
         # backward the runtime moves program k's outputs while program
         # k + 1 runs, on cores the backward leaves idle, and the train
         # thread claims and submits them meanwhile (PERF.md section 6,
-        # PR 42).
+        # PR 42). A cut backward's copies are NOT all issued here: a
+        # program's outputs start to cross when the program before it
+        # has been seen to end (``copy_outputs`` in the claim loop
+        # below), so at most the outputs being claimed and the next
+        # program's cross side by side. Where the chip sets the pace
+        # every program's copy is issued while the program still runs,
+        # as before; where the host does (LFM2: 4.7 GB of gradients a
+        # chip-second), all the later programs' outputs issued at once
+        # cross together and land together, late, and the wire has
+        # nothing to push meanwhile (PERF.md section 6, PR 44).
         out_shards: Dict[int, list] = {}
         for i, leaf in enumerate(g_leaves):
             if i in active_shard:
@@ -1741,13 +1877,21 @@ def make_ps_train_step(
                     part.copy_to_host_async()
             elif hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
-        for _, _, _, outs in programs:
-            for leaf in outs.values():
-                leaf.copy_to_host_async()
+
+        def copy_outputs(n):
+            """Start the D2H copies of what program ``n`` of a cut
+            backward hands over (the forward, program 0, hands nothing
+            over)."""
+            if n < len(programs):
+                for leaf in programs[n][3].values():
+                    leaf.copy_to_host_async()
 
         exp_pinned_ctr.inc(plan_cache["pinned"] if cut is None
                            else cut.outputs_pinned)
         exp_programs_ctr.inc(max(1, len(programs)))
+        if cut is not None:
+            exp_shared_ctr.inc(len(cut.shared))
+            exp_carry_ctr.inc(sum(p_leaves[i].nbytes for i in cut.shared))
 
         imported: list = [None] * len(names)
         new_params: list = [None] * len(names)
@@ -1774,6 +1918,7 @@ def make_ps_train_step(
                 programs_ended(n)
                 if n == len(programs) - 1:
                     backward_ended()
+                copy_outputs(n + 1)
                 claim_program(n)
             for i in sorted(late):
                 claim_leaf(i, names[i], late[i])

@@ -372,14 +372,6 @@ def _block(x, p, bias, cfg: KimiConfig, op: str, ffn: str, ep_axis):
                      "moe/bias_moved_pairs": st["bias_moved"]}
 
 
-def _expert_bias(cfg: KimiConfig, expert_bias, first: int, n: int):
-    """Rows ``first .. first + n - 1`` of the bias ``[sparse layers,
-    n_experts]``, without a gradient; none is zeros."""
-    if expert_bias is None:
-        return jnp.zeros((n, cfg.n_experts), jnp.float32)
-    return jax.lax.stop_gradient(expert_bias[first:first + n])
-
-
 def _runs(cfg: KimiConfig, expert_bias, ep_axis) -> List[chain.Run]:
     """A ``chain.Run`` a stretch of like blocks. A sparse run reads its
     layers' rows of the bias and counts them into its rows of
@@ -400,21 +392,13 @@ def _runs(cfg: KimiConfig, expert_bias, ep_axis) -> List[chain.Run]:
         def block(p, x, _, *row, op=op, ffn=ffn):
             return _block(x, p, *(row or (None,)), cfg, op, ffn, ep_axis)
 
-        def stats(stacked, first=first):
-            out = {name: jnp.sum(v, axis=0) for name, v in stacked.items()
-                   if name != "moe/expert_load"}
-            if "moe/expert_load" in stacked:
-                load = stacked["moe/expert_load"]
-                out["moe/expert_load"] = jnp.zeros(
-                    (cfg.n_sparse_layers, load.shape[1]), load.dtype
-                ).at[first:first + load.shape[0]].set(load)
-            return out
-
         runs.append(chain.Run(
             block, run_key(i), n, remat=cfg.remat, consts=lambda batch: (),
-            stats=stats, each=None if ffn == DENSE else
-            (lambda batch, first=first, n=n: _expert_bias(
-                cfg, expert_bias, first, n))))
+            stats=lambda stacked, first=first: moe.run_stats(
+                stacked, first, cfg.n_sparse_layers),
+            each=None if ffn == DENSE else
+            (lambda batch, first=first, n=n: moe.bias_rows(
+                expert_bias, cfg.n_experts, first, n))))
     return runs
 
 

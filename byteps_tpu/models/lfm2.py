@@ -44,7 +44,11 @@ no gradient and no decay and never travels. (The published buffer is
 moved by a balancing rule between steps; no such rule runs here.)
 
 ``loss_fn`` returns ``(loss, stats)``: the ``moe/*`` statistics leave the
-chip beside the loss (``jax/train.py _loss_and_stats``).
+chip beside the loss (``jax/train.py _loss_and_stats``). It is written
+as a chain (``ops/chain.py``): the lookup, a ``chain.Run`` a run under
+``("runs", i)``, the final norm and the head, ``embed`` under the first
+link and the last; ``make_ps_train_step`` cuts its backward at the
+links.
 """
 
 from __future__ import annotations
@@ -56,6 +60,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import chain
 from ..ops.flash_attention import flash_attention, publish_walk_sizes
 from . import llama as L
 from . import moe
@@ -246,8 +251,60 @@ def _block(x, p, bias, rope, cfg: LFM2Config, kind, ep_axis):
 
 
 # --------------------------------------------------------------------- #
-# forward
+# forward and loss: a chain of links (``ops/chain.py``)
 # --------------------------------------------------------------------- #
+
+def _runs(cfg: LFM2Config, expert_bias, ep_axis) -> List[chain.Run]:
+    """A ``chain.Run`` a run of ``cfg.runs()``, its stacked leaves under
+    ``params["runs"][i]``. A run of one layer is a scan of one step too:
+    one walk, and a kernel's instruction is named alike in every run. A
+    sparse run reads its layers' rows of the bias and counts them into
+    its rows of ``moe/expert_load`` ``[sparse layers, n_held]``, zero
+    elsewhere: the runs' tables add up to the step's. A scalar a layer
+    is summed over a run's layers. (A link closes over nothing that is
+    traced: the bias's rows are cut, and its gradient stopped, where the
+    link runs.)"""
+    runs, first = [], 0
+    for i, (kind, n) in enumerate(cfg.runs()):
+        sparse = kind[1] == SPARSE
+
+        def block(p, x, rope, *row, kind=kind):
+            return _block(x, p, *(row or (None,)), rope, cfg, kind, ep_axis)
+
+        # plain ``theta^(-2d / head_dim)``: llama's table, read from
+        # this configuration's ``head_dim`` and ``rope_theta``, made
+        # once a program
+        runs.append(chain.Run(
+            block, ("runs", i), n, remat=cfg.remat,
+            consts=lambda batch: L.rope_cache(
+                cfg, L.split_batch(batch)[0].shape[1]),
+            stats=lambda stacked, first=first: moe.run_stats(
+                stacked, first, cfg.n_sparse_layers),
+            each=(lambda batch, first=first, n=n: moe.bias_rows(
+                expert_bias, cfg.n_experts, first, n)) if sparse else None))
+        first += n if sparse else 0
+    return runs
+
+
+def _chain(cfg: LFM2Config, expert_bias, ep_axis) -> chain.Chain:
+    """The loss as links: the lookup, a run a stretch of like layers,
+    the final norm and the head. ``embed`` lies under the first link AND
+    the last (the head is the embedding itself): a step that cuts the
+    backward sums its two terms on the chip."""
+    def embed(p, _, batch):
+        return p["embed"].astype(cfg.dtype)[L.split_batch(batch)[0]], {}
+
+    def head(p, x, batch):
+        # a link reads what it needs of the batch from ``batch``: the
+        # cut step traces it on its own
+        x = L._rmsnorm(x, p["final_norm"], cfg.norm_eps)
+        logits = jnp.einsum("bsd,vd->bsv", x, p["embed"].astype(cfg.dtype))
+        return L.next_token_xent(logits, L.split_batch(batch)[1]), {}
+
+    return chain.Chain((
+        chain.Link(embed, "embed"), *_runs(cfg, expert_bias, ep_axis),
+        chain.Link(head, ("final_norm", "embed"))))
+
 
 def forward_hidden(params: Dict[str, Any], tokens: jnp.ndarray,
                    cfg: LFM2Config, expert_bias: Optional[jnp.ndarray] = None,
@@ -256,40 +313,12 @@ def forward_hidden(params: Dict[str, Any], tokens: jnp.ndarray,
     statistics: the load [sparse layers, n_held], the other counts
     summed over the sparse layers). ``expert_bias`` [sparse layers,
     n_experts]; none is zeros."""
-    if expert_bias is None:
-        expert_bias = jnp.zeros((cfg.n_sparse_layers, cfg.n_experts),
-                                jnp.float32)
-    # plain ``theta^(-2d / head_dim)``: llama's table, read from this
-    # configuration's ``head_dim`` and ``rope_theta``
-    rope = L.rope_cache(cfg, tokens.shape[1])
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    block = jax.checkpoint(_block, static_argnums=(4, 5, 6)) \
-        if cfg.remat else _block
-    stats, sparse_seen = [], 0
-    for (kind, n), p in zip(cfg.runs(), params["runs"]):
-        bias = None
-        if kind[1] == SPARSE:
-            bias = jax.lax.stop_gradient(
-                expert_bias[sparse_seen:sparse_seen + n])
-            sparse_seen += n
-
-        def body(x, layer, kind=kind):
-            return block(x, layer["p"], layer.get("bias"), rope, cfg, kind,
-                         ep_axis)
-
-        layers = {"p": p} if bias is None else {"p": p, "bias": bias}
-        # a run of one layer is a scan of one step too: one walk, and a
-        # kernel's instruction is named alike in every run
-        x, st = jax.lax.scan(body, x, layers)
-        if st:
-            stats.append(st)
-    x = L._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    # a run's statistics are [layers of the run, ...]: a vector a layer
-    # (the load) keeps its layers, a scalar a layer is summed over them
-    stats = jax.tree.map(lambda *a: jnp.concatenate(a), *stats) \
-        if stats else {}
-    return x, {name: v if v.ndim == 2 else jnp.sum(v)
-               for name, v in stats.items()}
+    batch = {"inputs": tokens, "targets": tokens}
+    x, stats = None, {}
+    for ln in _chain(cfg, expert_bias, ep_axis).links[:-1]:
+        x, st = ln(ln.pick(params), x, batch)
+        chain.add_stats(stats, st)
+    return L._rmsnorm(x, params["final_norm"], cfg.norm_eps), stats
 
 
 def loss_fn(params: Dict[str, Any], batch: Dict[str, jnp.ndarray],
@@ -305,8 +334,9 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jnp.ndarray],
     The head is the embedding itself: one leaf, whose gradient is the
     sum of its two uses'.
     batch: ``{"tokens"}`` (shifted here) or pre-shifted ``{"inputs",
-    "targets"}``."""
-    inputs, targets = L.split_batch(batch)
-    x, stats = forward_hidden(params, inputs, cfg, expert_bias, ep_axis)
-    logits = jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(cfg.dtype))
-    return L.next_token_xent(logits, targets), stats
+    "targets"}``.
+
+    Written as a chain (``ops/chain.py``): any step maker runs it as one
+    program; ``make_ps_train_step`` cuts its backward at the links: the
+    head's program, one a layer, the embedding's."""
+    return _chain(cfg, expert_bias, ep_axis)(params, batch)

@@ -201,6 +201,32 @@ def bias_moved_pairs(probs: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return jnp.sum(~kept, dtype=jnp.int32)
 
 
+def bias_rows(expert_bias, n_experts: int, first: int, n: int):
+    """Rows ``first .. first + n - 1`` of a selection bias ``[sparse
+    layers, n_experts]``, without a gradient; none is zeros: what a run
+    of ``n`` sparse layers scans beside its leaves (``ops/chain.py
+    Run(each=)``)."""
+    if expert_bias is None:
+        return jnp.zeros((n, n_experts), jnp.float32)
+    return jax.lax.stop_gradient(expert_bias[first:first + n])
+
+
+def run_stats(stacked: Dict[str, Any], first: int, n_sparse: int):
+    """The statistics of a run's layers, stacked on a leading axis, as
+    the run's share of a step's: ``moe/expert_load`` in rows ``first
+    ..`` of the ``[n_sparse, n_held]`` table, zero elsewhere (the runs'
+    tables add up to the step's); every other count summed over the
+    layers."""
+    out = {name: jnp.sum(v, axis=0) for name, v in stacked.items()
+           if name != "moe/expert_load"}
+    if "moe/expert_load" in stacked:
+        load = stacked["moe/expert_load"]
+        out["moe/expert_load"] = jnp.zeros(
+            (n_sparse, load.shape[1]), load.dtype
+        ).at[first:first + load.shape[0]].set(load)
+    return out
+
+
 def switch_aux_loss(probs: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """Switch aux loss: E * sum_e f_e * p_e (f = token fraction routed
     to e on the primary choice, p = mean router prob)."""

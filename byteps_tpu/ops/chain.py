@@ -8,12 +8,34 @@ cut: a list of links, each a function ``(its parameters, carry, batch)
 out of the tree. The first link's carry is ``None``, the last link's is
 the loss. Two kinds of link:
 
-- ``Link(fn, keys)``: the whole leaves under the top-level ``keys`` (one
-  name or several) of the parameter tree (``fn`` is handed ``{key:
-  params[key]}``);
+- ``Link(fn, keys)``: the whole leaves under ``keys`` (one key or
+  several) of the parameter tree;
 - ``Run(block, key, depth, ...)``: a run of ``depth`` like layers whose
-  leaves are stacked on a leading axis under ``params[key]``, declared
-  ONCE: ``block(layer j's parameters, carry, consts)`` is one layer.
+  leaves are stacked on a leading axis under ONE key, declared ONCE:
+  ``block(layer j's parameters, carry, consts)`` is one layer.
+
+A KEY says where in the tree a link's leaves lie: a top-level name
+(``"embed"``) or a path, a name and the positions below it (``("runs",
+2)``: ``params["runs"][2]``, a run that lives inside a list). Several
+keys are a list of such (``("final_norm", "embed")``, ``[("runs", 0),
+"final_norm"]``); a tuple that holds a position is ONE path, and a path
+of names alone is written as a list of one (``[("extra", "inner")]``).
+A link is handed exactly its subtrees, in the shape of the tree above
+them (``Link.pick``: ``{"runs": {2: ...}}``, a list's positions as a
+dict's keys), so ``fn`` reads ``p["runs"][2]`` as it would read the
+whole tree.
+
+A LEAF MAY LIE UNDER SEVERAL LINKS: an embedding matrix that is also the
+head is read by the first link and by the last, and its gradient is the
+sum of the two uses'. Which leaves are shared is read off the links'
+keys, nothing else says so. A run's stacked leaf cannot be shared (its
+layers' programs hand it over a layer at a time). What a shared leaf
+costs a step that cuts the backward: the term of the first program that
+reads it (the last link's; the parameter's shape, in the gradient's
+type) stays on the chip, an input of the next program that reads the
+leaf, until the LAST program that reads it has added its own and hands
+the sum over, once; that leaf's bytes leave behind that program and no
+earlier.
 
 ``Chain(links)`` is a plain ``loss_fn(params, batch) -> (loss, stats)``.
 Called as any loss is called it composes the links, and a run
@@ -53,23 +75,36 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
 import jax
+
+# where a link's leaves lie: a top-level name, or a name and the steps
+# (names, positions) below it
+Key = Union[str, Tuple[Union[str, int], ...]]
 
 _collector: contextvars.ContextVar = contextvars.ContextVar(
     "bps_chain_collector", default=None)
 
 
+class _Called(Exception):
+    """Ends a ``collecting(first=True)`` block at the first chain's
+    call."""
+
+
 @contextlib.contextmanager
-def collecting():
+def collecting(first: bool = False):
     """The chains called inside the block, in call order (each call
     counts: a loss that calls one chain twice has used its parameters
-    twice)."""
+    twice). ``first``: the block ENDS at the first chain's call (what it
+    was doing is abandoned there): whether a loss calls a chain at all,
+    at the price of its trace up to that call."""
     found: List["Chain"] = []
-    token = _collector.set(found)
+    token = _collector.set((found, first))
     try:
         yield found
+    except _Called:
+        pass
     finally:
         _collector.reset(token)
 
@@ -81,21 +116,57 @@ def add_stats(stats: Dict[str, Any], more: Dict[str, Any]) -> Dict[str, Any]:
     return stats
 
 
+def _path(key: Key) -> tuple:
+    return (key,) if isinstance(key, str) else key
+
+
+def _steps(path) -> tuple:
+    """A leaf's path (``tree_flatten_with_path``) as the steps a key is
+    written in: a dict's names, a list's positions."""
+    return tuple(getattr(entry, "key", getattr(entry, "idx", None))
+                 for entry in path)
+
+
 @dataclasses.dataclass(frozen=True)
 class Link:
     """``fn(params, carry, batch) -> (carry, stats)`` over the whole
-    leaves under ``keys``."""
+    leaves under ``keys`` (the module's text says what a key is)."""
 
     fn: Callable
-    keys: Tuple[str, ...]
+    keys: Tuple[Key, ...]
 
     def __post_init__(self):
         keys = self.keys
-        object.__setattr__(
-            self, "keys", (keys,) if isinstance(keys, str) else tuple(keys))
+        if isinstance(keys, str):
+            keys = (keys,)
+        elif any(isinstance(step, int) for step in keys):
+            keys = (tuple(keys),)  # ONE path: a name, positions below it
+        else:
+            keys = tuple(key if isinstance(key, str) else tuple(key)
+                         for key in keys)
+        paths = [_path(key) for key in keys]
+        if any(a[:len(b)] == b for n, a in enumerate(paths)
+               for m, b in enumerate(paths) if n != m):
+            raise ValueError(f"a link's keys lie apart in the tree: {keys}")
+        object.__setattr__(self, "keys", keys)
 
-    def pick(self, params) -> Dict[str, Any]:
-        return {key: params[key] for key in self.keys}
+    def pick(self, params) -> Dict[Any, Any]:
+        """The subtrees under ``keys``, in the shape of the tree above
+        them: ``{"embed": ...}``, ``{"runs": {2: ...}}``. Raises (a
+        lookup's error) where a key names no subtree."""
+        out: Dict[Any, Any] = {}
+        for key in self.keys:
+            *above, name = _path(key)
+            node, sub = out, params
+            for step in above:
+                node, sub = node.setdefault(step, {}), sub[step]
+            node[name] = sub[name]
+        return out
+
+    def covers(self, steps: tuple) -> bool:
+        """Whether the leaf at ``steps`` (``_steps``) lies under a key."""
+        return any(steps[:len(path)] == path
+                   for path in map(_path, self.keys))
 
     def __call__(self, p, carry, batch):
         return self.fn(p, carry, batch)
@@ -103,7 +174,7 @@ class Link:
 
 @dataclasses.dataclass(frozen=True)
 class Run(Link):
-    """``depth`` like layers, stacked under ``params[keys[0]]``:
+    """``depth`` like layers, stacked under the ONE key:
     ``fn(layer, carry, consts)`` is one of them, ``consts(batch)`` what
     all of them read and none changes (computed once a program, outside
     the scan: a rotary table), ``each(batch)`` what layer ``j`` ALONE
@@ -125,6 +196,13 @@ class Run(Link):
 
     def _each(self, batch):
         return None if self.each is None else self.each(batch)
+
+    def stacked(self, p):
+        """The run's stacked leaves in ``p`` (the whole tree, or what
+        ``pick`` made of it)."""
+        for step in _path(self.keys[0]):
+            p = p[step]
+        return p
 
     def scan(self, stacked, carry, consts, keep: bool = False, each=None):
         """The run over ``stacked`` (any depth; ``each``: the layers'
@@ -148,7 +226,7 @@ class Run(Link):
                             unroll=min(self.unroll, depth))
 
     def __call__(self, p, carry, batch):
-        carry, stacked = self.scan(p[self.keys[0]], carry,
+        carry, stacked = self.scan(self.stacked(p), carry,
                                    self._consts(batch),
                                    each=self._each(batch))
         return carry, stacked if self.stats is None else self.stats(stacked)
@@ -162,9 +240,11 @@ class Chain:
         object.__setattr__(self, "links", tuple(self.links))
 
     def __call__(self, params, batch):
-        found = _collector.get()
+        found, first = _collector.get() or (None, False)
         if found is not None:
             found.append(self)
+            if first:
+                raise _Called
         carry, stats = None, {}
         for ln in self.links:
             carry, st = ln(ln.pick(params), carry, batch)
@@ -173,19 +253,48 @@ class Chain:
 
     # ---- what a step that cuts the backward needs ------------------- #
 
+    def cover(self, params, paths=None
+              ) -> Optional[Dict[int, Tuple[int, ...]]]:
+        """Link index -> the flatten indices in ``params`` of the leaves
+        under the link's keys, ascending; None where the backward cannot
+        run a link at a time at the uncut FLOPs. It can where there is
+        more than one link, every key names a subtree, every LEAF of
+        ``params`` lies under one link or under several whole links (not
+        under a run and another link: a run's stacked leaf is handed
+        over a layer at a time), and every run is rematerialised and as
+        deep as its leaves. ``paths``: ``params`` flattened with paths,
+        where the caller has it."""
+        if len(self.links) < 2:
+            return None
+        try:
+            for ln in self.links:
+                ln.pick(params)
+        except (KeyError, IndexError, TypeError):
+            return None  # a key that names no subtree
+        if paths is None:
+            paths = jax.tree_util.tree_flatten_with_path(params)[0]
+        steps = [_steps(path) for path, _ in paths]
+        under = {k: tuple(i for i, at in enumerate(steps) if ln.covers(at))
+                 for k, ln in enumerate(self.links)}
+        readers: Dict[int, List[int]] = {}
+        for k, found in under.items():
+            for i in found:
+                readers.setdefault(i, []).append(k)
+        if len(readers) != len(paths) or any(
+                len(ks) > 1 and any(isinstance(self.links[k], Run)
+                                    for k in ks)
+                for ks in readers.values()):
+            return None
+        if not all(ln.remat and all(
+                paths[i][1].ndim >= 1 and paths[i][1].shape[0] == ln.depth
+                for i in under[k])
+                for k, ln in enumerate(self.links) if isinstance(ln, Run)):
+            return None
+        return under
+
     def cuts(self, params) -> bool:
-        """Whether the backward can run a link at a time at the uncut
-        FLOPs: more than one link, every top-level key of ``params``
-        under exactly one of them, every run rematerialised and as deep
-        as its leaves."""
-        keys = [key for ln in self.links for key in ln.keys]
-        if len(self.links) < 2 or not hasattr(params, "keys") \
-                or sorted(keys) != sorted(params.keys()):
-            return False
-        return all(ln.remat and all(
-            leaf.ndim >= 1 and leaf.shape[0] == ln.depth
-            for leaf in jax.tree.leaves(ln.pick(params)))
-            for ln in self.links if isinstance(ln, Run))
+        """Whether the backward can run a link at a time (``cover``)."""
+        return self.cover(params) is not None
 
     def forward(self, params, batch):
         """Through every link but the last, keeping no residual:
@@ -196,7 +305,7 @@ class Chain:
             if isinstance(ln, Run):
                 plain = dataclasses.replace(ln, remat=False)
                 carry, (inputs, st) = plain.scan(
-                    params[ln.keys[0]], carry, ln._consts(batch), keep=True,
+                    ln.stacked(params), carry, ln._consts(batch), keep=True,
                     each=ln._each(batch))
                 kept.append(inputs)
                 add_stats(stats, st if ln.stats is None else ln.stats(st))
@@ -244,7 +353,7 @@ class Chain:
             inputs)
         consts = ln._consts(batch)
         _, vjp = jax.vjp(
-            lambda p, c: ln.scan(p[ln.keys[0]], c, consts, each=each)[0],
+            lambda p, c: ln.scan(ln.stacked(p), c, consts, each=each)[0],
             one, x)
         g_one, g_x = vjp(ct)
         return g_x, g_one

@@ -9,7 +9,11 @@ program; the pieces' keys are a pure function of the tree; the
 programs of the cut name their kernels' scope as the uncut one does; a
 chain of SEVERAL runs of unlike blocks, runs of one layer among them and
 a buffer a layer beside the leaves (``models/kimi.py``), is cut a
-program a layer, and a run of one layer hands its leaves over whole."""
+program a layer, and a run of one layer hands its leaves over whole; a
+chain whose runs live inside a LIST and whose embedding is also its head
+(a toy shaped like ``models/lfm2.py`` and ``models/joyai.py``) is cut
+too: a link's key may be a path, and the leaf under two links leaves
+once, its two terms summed on the device."""
 
 import dataclasses
 import itertools
@@ -134,6 +138,32 @@ def test_a_called_chain_registers_with_the_collector_and_nowhere_else():
         assert len(inner) == 1 and outer == []
 
 
+def test_a_collector_may_end_its_block_at_the_first_chain_called():
+    """``collecting(first=True)``: what the block was doing is abandoned
+    at the first chain's call (the step asks whether a loss calls a
+    chain at all without tracing its whole backward); a block that calls
+    none runs to its end."""
+    cfg, params, batch = _sdar()
+    after = []
+
+    def trace():
+        jax.eval_shape(jax.value_and_grad(
+            lambda p, b: sdar.loss_fn(p, b, cfg), has_aux=True),
+            params, batch)
+        after.append("ran on")
+
+    with chain.collecting(first=True) as found:
+        trace()
+    assert len(found) == 1 and after == []
+    with chain.collecting(first=True) as found:
+        jax.eval_shape(lambda p, b: _parents_loss(p, b, cfg), params, batch)
+        after.append("no chain")
+    assert found == [] and after == ["no chain"]
+    # and no collector is left behind
+    trace()
+    assert after == ["no chain", "ran on"]
+
+
 def _chain_of(cfg):
     return chain.Chain([chain.Link(None, "embed"), sdar._layers(cfg, None),
                         chain.Link(None, ["final_norm", "lm_head"])])
@@ -149,13 +179,34 @@ def _chain_of(cfg):
     ("a run deeper than it says",
      lambda cfg, p: (dataclasses.replace(cfg, n_layers=2), p)),
     ("a tree with no keys", lambda cfg, p: (cfg, list(p.values()))),
+    # (a leaf under several links is fine only where each is a WHOLE
+    # link: ``_toy`` below is cut)
+    ("a run's stacked leaf under two links",
+     lambda cfg, p: (cfg, p, chain.Chain([
+         chain.Link(None, "embed"), sdar._layers(cfg, None),
+         chain.Link(None, ["final_norm", "lm_head", "blocks"])]))),
+    ("a leaf of a run's under a link beside it",
+     lambda cfg, p: (cfg, p, chain.Chain([
+         chain.Link(None, ["embed", ("blocks", "wq")]),
+         sdar._layers(cfg, None),
+         chain.Link(None, ["final_norm", "lm_head"])]))),
+    ("a path that names no subtree",
+     lambda cfg, p: (cfg, p, chain.Chain([
+         chain.Link(None, "embed"),
+         dataclasses.replace(sdar._layers(cfg, None), keys=("blocks", 3)),
+         chain.Link(None, ["final_norm", "lm_head"])]))),
+    ("a path that ends inside a leaf",
+     lambda cfg, p: (cfg, p, chain.Chain([
+         chain.Link(None, ("embed", 0)), sdar._layers(cfg, None),
+         chain.Link(None, ["final_norm", "lm_head"])]))),
 ], ids=lambda v: v.replace(" ", "-") if isinstance(v, str) else "")
 def test_what_a_chain_cannot_be_cut_over(why, change):
     cfg, params, _ = _sdar()
     assert _chain_of(cfg).cuts(params)
-    cfg, changed = change(cfg, params)
-    assert not _chain_of(cfg).cuts(changed), why
-    assert _chain_leaves(_chain_of(cfg), changed) is None
+    cfg, changed, *other = change(cfg, params)
+    ch = other[0] if other else _chain_of(cfg)
+    assert not ch.cuts(changed), why
+    assert _chain_leaves(ch, changed) is None
     # one link is nothing to cut
     assert not chain.Chain([chain.Link(None, tuple(params))]).cuts(params)
 
@@ -168,6 +219,40 @@ def test_a_links_keys_are_one_name_or_several_and_a_chains_links_any_list():
     assert dataclasses.replace(layers, remat=True).keys == ("blocks",)
     assert chain.Chain([one, layers, two]).links == (one, layers, two)
     assert one.pick({"embed": 1, "other": 2}) == {"embed": 1}
+
+
+def test_a_key_may_be_a_path_and_a_link_is_handed_its_subtrees():
+    """A name and the positions below it; a tuple that holds a position
+    is ONE path, a path of names alone a list of one."""
+    run = chain.Run(None, ("runs", 2), 3)
+    assert run.keys == (("runs", 2),) and run.depth == 3
+    assert dataclasses.replace(run, remat=False).keys == (("runs", 2),)
+    two = chain.Link(None, [("runs", 0), "final_norm"])
+    assert two.keys == (("runs", 0), "final_norm")
+    assert chain.Link(None, [("extra", "inner")]).keys == (
+        ("extra", "inner"),)
+    tree = {"embed": 1, "runs": [{"w": 2}, {"w": 3}, {"w": 4}],
+            "final_norm": 5, "extra": {"inner": 6, "other": 7}}
+    # in the shape of the tree above them: ``fn`` reads p["runs"][2]
+    assert run.pick(tree) == {"runs": {2: {"w": 4}}}
+    assert run.stacked(tree) is tree["runs"][2]
+    assert run.stacked(run.pick(tree)) is tree["runs"][2]
+    assert two.pick(tree) == {"runs": {0: {"w": 2}}, "final_norm": 5}
+    assert chain.Link(None, [("extra", "inner"), ("runs", 1), ("runs", 0)]
+                      ).pick(tree) == {"extra": {"inner": 6},
+                                       "runs": {1: {"w": 3}, 0: {"w": 2}}}
+    # and flattens in the tree's order
+    assert jax.tree.leaves(chain.Link(
+        None, [("runs", 1), ("extra", "inner"), ("runs", 0), "embed"]
+    ).pick(tree)) == [1, 6, 2, 3]
+    for missing in (("runs", 3), "head", [("extra", "none")]):
+        with pytest.raises((KeyError, IndexError)):
+            chain.Link(None, missing).pick(tree)
+    # a link's keys lie apart: none is above another
+    with pytest.raises(ValueError):
+        chain.Link(None, ["runs", ("runs", 1)])
+    with pytest.raises(ValueError):
+        chain.Link(None, ["embed", "embed"])
 
 
 def test_the_links_leaves_are_runs_of_the_trees_flatten_order():
@@ -224,7 +309,8 @@ def test_the_cut_programs_give_the_one_programs_loss_stats_and_gradients(
 
 COUNTERS = ("export/backward_programs", "export/piece_bytes",
             "export/under_backward_bytes", "export/whole_bytes",
-            "wire/push_bytes")
+            "wire/push_bytes", "export/shared_leaves",
+            "export/shared_carry_bytes")
 
 
 def _run_ps(loss, params, batch, steps=3, devices=1, env=None, **kw):
@@ -245,6 +331,7 @@ def _run_ps(loss, params, batch, steps=3, devices=1, env=None, **kw):
         jax.block_until_ready((params, opt))
         after = bps.get_metrics()["counters"]
         out = {"params": params, "opt": opt, "losses": losses,
+               "ledger": bps.get_ledger(),
                "grew": {c: after.get(c, 0) - before.get(c, 0)
                         for c in COUNTERS},
                "spans": get_state().profiler.last_spans(),
@@ -685,3 +772,209 @@ def test_a_cut_step_over_unlike_runs_is_the_one_program_step():
         <= programs[4][2]
     # (the last program's end is looked for between the claims)
     assert programs[6][3] <= claimed("run00", "ffn", "w1")
+
+
+# --------------------------------------------------------------------- #
+# runs inside a list, an embedding that is also the head (the shape of
+# models/lfm2.py's and models/joyai.py's trees)
+# --------------------------------------------------------------------- #
+
+
+def _toy(seed=9, remat=True):
+    """``{"embed", "runs": [run of 1, run of 3], "final_norm", "extra":
+    {...}}``: the lookup reads ``embed`` and so does the head, whose link
+    holds ``final_norm`` and the ``extra`` module beside it. Every leaf
+    but the norms and a bias is over ``ENV``'s fusion size."""
+    V, d = 48, 16
+    ks = jax.random.split(jax.random.PRNGKey(seed), 8)
+
+    def run(key, n):
+        k1, k2 = jax.random.split(key)
+        return {"w": jax.random.normal(k1, (n, d, d)) * 0.3,
+                "norm": 1 + 0.1 * jax.random.normal(k2, (n, d))}
+
+    params = {"embed": jax.random.normal(ks[0], (V, d)) * 0.5,
+              "runs": [run(ks[1], 1), run(ks[2], 3)],
+              "final_norm": 1 + 0.1 * jax.random.normal(ks[3], (d,)),
+              "extra": {"proj": jax.random.normal(ks[4], (d, d)) * 0.3,
+                        "bias": 0.1 * jax.random.normal(ks[5], (V,))}}
+    batch = {"tokens": jax.random.randint(ks[6], (4, 9), 0, V)}
+
+    def norm(x, w):
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) * w
+
+    def embed(p, _, batch):
+        return p["embed"][batch["tokens"][:, :-1]], {}
+
+    def block(p, x, scale):
+        y = x + scale * jnp.tanh(norm(x, p["norm"]) @ p["w"])
+        return y, {"toy/layers": jnp.int32(1),
+                   "toy/positive": jnp.sum(y > 0, dtype=jnp.int32)}
+
+    def head(p, x, batch):
+        # the extra module, then the tied head
+        x = jnp.tanh(norm(x, p["final_norm"]) @ p["extra"]["proj"])
+        logits = x @ p["embed"].T + p["extra"]["bias"]
+        targets = batch["tokens"][:, 1:]
+        nll = jax.scipy.special.logsumexp(logits, axis=-1) \
+            - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        return jnp.sum(nll) / targets.size, {}
+
+    def total(stacked):
+        return {name: jnp.sum(v) for name, v in stacked.items()}
+
+    ch = chain.Chain([
+        chain.Link(embed, "embed"),
+        *(chain.Run(block, ("runs", i), n, remat=remat, stats=total,
+                    consts=lambda batch: jnp.float32(0.5))
+          for i, n in enumerate((1, 3))),
+        chain.Link(head, ["final_norm", "extra", "embed"])])
+    return ch, params, batch
+
+
+def _plainly(ch):
+    """The chain's links composed with no chain called: the one-program
+    backward, kept as the reference."""
+    def loss(params, batch):
+        carry, stats = None, {}
+        for ln in ch.links:
+            carry, st = ln(ln.pick(params), carry, batch)
+            chain.add_stats(stats, st)
+        return carry, stats
+    return loss
+
+
+def test_a_chain_over_a_list_of_runs_and_a_tied_leaf_can_be_cut():
+    ch, params, batch = _toy()
+    assert ch.cuts(params)
+    # flatten order: embed, extra/bias, extra/proj, final_norm, then
+    # each run's norm and w; embed under the first link and the last
+    leaves = _chain_leaves(ch, params)
+    assert leaves == {0: (0,), 1: (4, 5), 2: (6, 7), 3: (0, 1, 2, 3)}
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    cut = _cut_backward(ch, mesh, "dp", leaves)
+    assert cut.shared == {0: (0, 3)}
+    # the head's program keeps its term, the lookup's takes it
+    assert [cut.held(k) for k in range(4)] == [(), (), (), (0,)]
+    assert [cut.taken(k) for k in range(4)] == [(0,), (), (), ()]
+    # forward, head, 3 + 1 layers, embedding
+    assert cut.programs == 7
+    # called as any loss it registers, and is what its links compose to
+    with chain.collecting() as found:
+        loss, stats = jax.jit(ch)(params, batch)
+    assert found == [ch] and int(stats["toy/layers"]) == 4
+    want, want_stats = jax.jit(_plainly(ch))(params, batch)
+    assert float(loss) == float(want)
+    _assert_trees_equal(stats, want_stats)
+    # remat off on a run, as ever, keeps the backward one program
+    assert not _toy(remat=False)[0].cuts(params)
+
+
+@pytest.mark.parametrize("devices", [1, 2], ids=["1dev", "2dev"])
+def test_the_cut_programs_sum_a_shared_leafs_terms_on_the_device(devices):
+    """Loss, statistics and every gradient the one-program backward's;
+    the head's program hands over no output for ``embed``, the
+    lookup's hands over the sum."""
+    from byteps_tpu.jax.train import (_loss_and_stats, _pin_cut_outputs,
+                                      _psum_backward)
+
+    ch, params, batch = _toy()
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+    whole = _psum_backward(_loss_and_stats(_plainly(ch)), mesh, "dp")
+    (want, want_stats), want_grads = whole(params, batch)
+    cut = _cut_backward(ch, mesh, "dp", _chain_leaves(ch, params))
+    # as the step builds them: the layouts looked at, the terms described
+    _pin_cut_outputs(cut, params, batch, {0, 2, 5, 7}, mesh, "dp")
+    (got, stats), programs = _dispatch_cut(cut, params, batch)
+    assert [(links, layer, sorted(outs)) for links, layer, _, outs
+            in programs] == [
+        ("0-2", None, []), ("3", None, [1, 2, 3]), ("2", 2, [6, 7]),
+        ("2", 1, [6, 7]), ("2", 0, [6, 7]), ("1", 0, [4, 5]),
+        ("0", None, [0])]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _assert_trees_equal(stats, want_stats)
+    flat = jax.tree.leaves(want_grads)
+    for _, layer, _, outs in programs:
+        for i, g in outs.items():
+            w = np.asarray(flat[i])
+            if layer is not None and i in (6, 7):
+                w = w[layer:layer + 1]
+            _assert_trees_equal(np.asarray(g).reshape(w.shape), w)
+    # both uses are in the sum: neither term alone is the gradient
+    lookup = jax.grad(lambda e: _plainly(ch)(
+        {**params, "embed": e}, batch)[0])
+    head_only = jax.grad(lambda e: ch.links[-1](
+        {**ch.links[-1].pick(params), "embed": e},
+        jax.lax.stop_gradient(ch.forward(params, batch)[0][-1]), batch)[0])
+    assert float(jnp.abs(lookup(params["embed"])
+                         - head_only(params["embed"])).max()) > 1e-4
+    assert float(jnp.abs(head_only(params["embed"])).max()) > 1e-4
+
+
+@pytest.fixture(scope="module")
+def toy_cut_and_whole():
+    ch, params, batch = _toy()
+    n_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+    return (_run_ps(ch, params, batch), _run_ps(_plainly(ch), params, batch),
+            n_bytes, params)
+
+
+def test_a_cut_step_with_a_shared_leaf_is_the_one_program_step(
+        toy_cut_and_whole):
+    cut, whole, _, _ = toy_cut_and_whole
+    assert cut["losses"] == whole["losses"]
+    assert cut["losses"][-1] < cut["losses"][0]
+    _assert_trees_equal(cut["params"], whole["params"])
+    _assert_trees_equal(cut["opt"], whole["opt"])
+
+
+def test_a_shared_leaf_is_pushed_once_on_its_one_program_key(
+        toy_cut_and_whole):
+    cut, whole, n_bytes, params = toy_cut_and_whole
+    for run in (cut, whole):
+        assert run["grew"]["wire/push_bytes"] == 3 * n_bytes
+        assert run["grew"]["export/whole_bytes"] == 3 * n_bytes
+    assert cut["grew"]["export/backward_programs"] == 3 * 7
+    assert whole["grew"]["export/backward_programs"] == 3
+    # the ledger prices a cut step by the programs it runs (the one
+    # program it does not run is not lowered for its cost)
+    for run in (cut, whole):
+        assert run["ledger"]["source"] == "xla"
+        assert run["ledger"]["model_flops"] > 0
+    assert cut["ledger"]["model_flops"] > whole["ledger"]["model_flops"]
+    # one leaf a step summed over two programs, its term held between
+    assert cut["grew"]["export/shared_leaves"] == 3
+    assert cut["grew"]["export/shared_carry_bytes"] \
+        == 3 * params["embed"].nbytes
+    assert whole["grew"]["export/shared_leaves"] == 0
+    assert whole["grew"]["export/shared_carry_bytes"] == 0
+    # the run of three's weight leaves as pieces, the run of one's whole
+    assert cut["grew"]["export/piece_bytes"] \
+        == 3 * params["runs"][1]["w"].nbytes
+    assert {n for n in cut["keys"] if "@shard" in n} == {
+        f"grad/runs/1/w@shard{j}of3" for j in range(3)}
+    # every other key is the one-program step's, the tied leaf's and
+    # the bucket's among them
+    assert {n for n in cut["keys"] if "@shard" not in n} \
+        == set(whole["keys"]) - {"grad/runs/1/w"}
+    for name in ("grad/embed", "grad/runs/0/w", "grad/extra/proj"):
+        assert name in cut["keys"] and name in whole["keys"], name
+    assert {n for n in cut["keys"] if n.startswith("fused/")} == \
+        {n for n in whole["keys"] if n.startswith("fused/")}
+    # ONE ingest of the tied leaf a step, after the LAST program (the
+    # lookup's, which hands the sum over) has ended
+    ingests = [s for s in cut["spans"] if s[0] == "bps.export.ingest"
+               and s[4]["leaf"] == 0]
+    programs = sorted((s for s in cut["spans"]
+                       if s[0] == "bps.step.backward_program"),
+                      key=lambda s: s[2])
+    assert [s[4]["links"] for s in programs] == [
+        "0-2", "3", "2", "2", "2", "1", "0"]
+    assert len(ingests) == 1 and ingests[0][4]["cause"] == "out:0"
+    assert ingests[0][4]["bytes"] == params["embed"].nbytes
+    assert ingests[0][2] >= programs[-1][3]
+    # the head's program's span counts what it hands over: not the term
+    handed = params["final_norm"].nbytes + params["extra"]["proj"].nbytes \
+        + params["extra"]["bias"].nbytes
+    assert programs[1][4]["bytes"] == handed
+    assert programs[-1][4]["bytes"] == params["embed"].nbytes

@@ -5,7 +5,10 @@ three optimizer steps, fused and through the PS step with a loopback
 server; the four expert-parallel shares of one sparse layer add up to
 the uncut layer; the short convolution is causal to the bit, leaks
 nothing across batch rows and equals a per-position loop; the tied
-leaf's gradient is the sum of its two uses'."""
+leaf's gradient is the sum of its two uses'; the loss written as a chain
+(``ops/chain.py``) is the composition it replaced, kept here as its
+reference, and the PS step cuts its backward a program a layer, the tied
+leaf's two terms summed on the device."""
 
 import contextlib
 import json
@@ -194,6 +197,179 @@ def test_the_tied_leafs_gradient_is_the_sum_of_its_two_uses():
     np.testing.assert_allclose(np.asarray(tied),
                                np.asarray(g_lookup + g_head),
                                rtol=1e-5, atol=1e-9)
+
+
+# ------------------------------------------------------------------ #
+# the loss as a chain against the composition it replaced
+# ------------------------------------------------------------------ #
+
+def _parents_hidden(params, tokens, cfg, expert_bias=None):
+    """``lfm2.forward_hidden`` as it stood before the loss was a chain:
+    the lookup and the runs' scans."""
+    if expert_bias is None:
+        expert_bias = jnp.zeros((cfg.n_sparse_layers, cfg.n_experts),
+                                jnp.float32)
+    rope = lfm2.L.rope_cache(cfg, tokens.shape[1])
+    x = params["embed"].astype(cfg.dtype)[tokens]
+    block = jax.checkpoint(lfm2._block, static_argnums=(4, 5, 6)) \
+        if cfg.remat else lfm2._block
+    stats, sparse_seen = [], 0
+    for (kind, n), p in zip(cfg.runs(), params["runs"]):
+        bias = None
+        if kind[1] == lfm2.SPARSE:
+            bias = jax.lax.stop_gradient(
+                expert_bias[sparse_seen:sparse_seen + n])
+            sparse_seen += n
+
+        def body(x, layer, kind=kind):
+            return block(x, layer["p"], layer.get("bias"), rope, cfg, kind,
+                         None)
+
+        layers = {"p": p} if bias is None else {"p": p, "bias": bias}
+        x, st = jax.lax.scan(body, x, layers)
+        if st:
+            stats.append(st)
+    x = lfm2.L._rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    stats = jax.tree.map(lambda *a: jnp.concatenate(a), *stats) \
+        if stats else {}
+    return x, {name: v if v.ndim == 2 else jnp.sum(v)
+               for name, v in stats.items()}
+
+
+def _parents_loss(params, batch, cfg, expert_bias=None):
+    """``lfm2.loss_fn`` as it stood: that walk, then the tied head."""
+    inputs, targets = lfm2.L.split_batch(batch)
+    x, stats = _parents_hidden(params, inputs, cfg, expert_bias)
+    logits = jnp.einsum("bsd,vd->bsv", x, params["embed"].astype(cfg.dtype))
+    return lfm2.L.next_token_xent(logits, targets), stats
+
+
+def _assert_trees_equal(got, want):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "no-remat"])
+@pytest.mark.parametrize("biased", [True, False], ids=["bias", "no-bias"])
+def test_the_loss_as_a_chain_is_the_composition_it_replaced(biased, remat):
+    """Loss, every statistic (the ``[sparse layers, n_held]`` load by
+    row) and every gradient, to the bit; ``forward_hidden`` too."""
+    cfg = _config(remat=remat)
+    params, batch = _state(cfg)
+    pc = family.program_config(cfg)
+    bias = reference.expert_bias(cfg) if biased else None
+    (loss, stats), grads = jax.jit(jax.value_and_grad(
+        lambda p, b: lfm2.loss_fn(p, b, pc, bias), has_aux=True))(
+            params, batch)
+    (want, want_stats), want_grads = jax.jit(jax.value_and_grad(
+        lambda p, b: _parents_loss(p, b, pc, bias), has_aux=True))(
+            params, batch)
+    assert float(loss) == float(want) and float(loss) > 0
+    _assert_trees_equal(stats, want_stats)
+    load = np.asarray(stats["moe/expert_load"])
+    assert load.shape == (4, 2) and all(load.sum(axis=1) > 0)
+    _assert_trees_equal(grads, want_grads)
+    assert all(np.any(np.asarray(g)) for g in jax.tree.leaves(grads))
+    hidden = jax.jit(lambda p, t: lfm2.forward_hidden(p, t, pc, bias))(
+        params, batch["inputs"])
+    _assert_trees_equal(hidden, jax.jit(
+        lambda p, t: _parents_hidden(p, t, pc, bias))(
+            params, batch["inputs"]))
+    assert hidden[0].shape == (2, cfg["seq_len"], cfg["hidden_size"])
+
+
+def test_the_chain_names_the_runs_inside_the_list_and_the_tied_leaf_twice():
+    from byteps_tpu.jax.train import _chain_leaves
+    from byteps_tpu.ops import chain
+
+    cfg = _config(remat=True)
+    params, batch = _state(cfg)
+    with chain.collecting() as found:
+        jax.eval_shape(family.program_loss(cfg), params, batch)
+    (ch,) = found
+    assert [ln.keys for ln in ch.links] == [
+        ("embed",), (("runs", 0),), (("runs", 1),), (("runs", 2),),
+        ("final_norm", "embed")]
+    assert [getattr(ln, "depth", None) for ln in ch.links] == [
+        None, 1, 1, 3, None]
+    assert ch.cuts(params)
+    leaves = _chain_leaves(ch, params)
+    # embed (flatten index 0) under the lookup and under the head
+    assert leaves[0] == (0,) and leaves[4] == (0, 1)
+    assert sorted(i for found in leaves.values() for i in found) \
+        == [0] + list(range(31))
+    # remat off (the rehearsal's own setting): one program, as ever
+    off = _config()
+    with chain.collecting() as found:
+        jax.eval_shape(family.program_loss(off), params, batch)
+    assert not found[0].cuts(params)
+
+
+def test_the_ps_step_cuts_the_backward_and_is_the_one_program_step(
+        monkeypatch):
+    """The file's own test-scale configuration (runs of 1, 1 and 3
+    layers, as the cell's), remat on: 2 + layers + 1 programs a step,
+    the tied leaf's terms summed on the device and pushed once, the run
+    of three as pieces; losses, parameters and optimizer state the
+    one-program step's (the composition above, which registers no
+    chain)."""
+    import optax
+
+    cfg = _config(remat=True)
+    params, batch = _state(cfg)
+    pc = family.program_config(cfg)
+    bias = reference.expert_bias(cfg)
+    n_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+    # every weight of the tiny model on a key of its own
+    monkeypatch.setenv("BYTEPS_FUSION_BYTES", "1024")
+    monkeypatch.setenv("BYTEPS_SHARD_MIN_BYTES", "1024")
+    names = ("export/backward_programs", "export/shared_leaves",
+             "export/shared_carry_bytes", "export/piece_bytes",
+             "export/whole_bytes", "wire/push_bytes")
+
+    def run(loss):
+        from byteps_tpu.core.state import get_state
+
+        tx = optax.adam(1e-2)
+        with _ps_env() as bps:
+            step = make_ps_train_step(loss, tx, _one_device_mesh())
+            p, opt = jax.tree.map(jnp.array, params), tx.init(params)
+            before = bps.get_metrics()["counters"]
+            losses = []
+            for _ in range(3):
+                p, opt, value = step(p, opt, batch)
+                losses.append(float(value))
+            jax.block_until_ready((p, opt))
+            after = bps.get_metrics()["counters"]
+            keys = {c.name for c in get_state().registry.contexts_in_order()}
+        return p, opt, losses, {n: after.get(n, 0) - before.get(n, 0)
+                                for n in names}, keys
+
+    cut = run(lambda p, b: lfm2.loss_fn(p, b, pc, bias))
+    whole = run(lambda p, b: _parents_loss(p, b, pc, bias))
+    # forward, head, five layers, embedding
+    assert cut[3]["export/backward_programs"] == 3 * (2 + 5 + 1)
+    assert whole[3]["export/backward_programs"] == 3
+    assert cut[3]["export/shared_leaves"] == 3
+    assert cut[3]["export/shared_carry_bytes"] == 3 * params["embed"].nbytes
+    assert whole[3]["export/shared_leaves"] == 0
+    for side in (cut, whole):
+        assert side[3]["wire/push_bytes"] == 3 * n_bytes
+        assert side[3]["export/whole_bytes"] == 3 * n_bytes
+    pieces = sum(a.nbytes for a in jax.tree.leaves(params["runs"][2])
+                 if a.nbytes >= 1024)
+    assert cut[3]["export/piece_bytes"] == 3 * pieces > 0
+    assert all(n.startswith("grad/runs/2/") and n.endswith("of3")
+               for n in cut[4] if "@shard" in n)
+    for name in ("grad/embed", "grad/runs/0/ffn/w1", "grad/runs/1/op/wq"):
+        assert name in cut[4] and name in whole[4], name
+    # to the bit: XLA:CPU compiles these links alone as it does inside
+    # the one program
+    assert cut[2] == whole[2] and cut[2][-1] < cut[2][0]
+    _assert_trees_equal(cut[:2], whole[:2])
 
 
 # ------------------------------------------------------------------ #
