@@ -49,7 +49,14 @@ selection is not written: ``n_group`` 1 has nothing to limit, more is
 refused.
 
 ``loss_fn`` returns ``(loss, stats)``: ``lfm2.py``'s ``moe/*`` statistics
-(the module's block as one more layer of the load) and ``mtp/*``.
+(the module's block as one more layer of the load) and ``mtp/*``. It is
+written as a chain (``ops/chain.py``): the lookup under ``embed``, a
+``chain.Run`` a run under ``("runs", i)``, and ONE last link that holds
+both heads and the prediction module (``final_norm``, ``head``, ``mtp``
+and ``embed`` again: the module looks ``t_{i+1}`` up in the same
+matrix). ``embed`` so lies under the first link and the last;
+``make_ps_train_step`` cuts the backward at the links and sums the
+leaf's two terms on the chip.
 """
 
 from __future__ import annotations
@@ -61,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import chain
 from ..ops.flash_attention import latent_attention, publish_walk_sizes
 from . import llama as L
 from . import moe
@@ -265,67 +273,6 @@ def _block(x, p, bias, rope, cfg: JoyAIConfig, ffn, ep_axis):
                      "moe/bias_moved_pairs": st["bias_moved"]}
 
 
-def _run(x, p, bias, rope, cfg: JoyAIConfig, ffn, ep_axis):
-    """A run of layers of one kind, scanned (a run of one layer too: a
-    kernel's instruction is named alike in every run). p: the run's
-    stacked leaves; ``bias`` [layers of the run, n_experts] or None.
-    Returns (x, the run's statistics, [layers of the run, ...] each)."""
-    block = jax.checkpoint(_block, static_argnums=(4, 5, 6)) \
-        if cfg.remat else _block
-
-    def body(x, layer):
-        return block(x, layer["p"], layer.get("bias"), rope, cfg, ffn,
-                     ep_axis)
-
-    layers = {"p": p} if bias is None else {"p": p, "bias": bias}
-    return jax.lax.scan(body, x, layers)
-
-
-def _summed(stats: List[Dict[str, jnp.ndarray]]) -> Dict[str, jnp.ndarray]:
-    """Runs' statistics as a step's: a vector a layer (the load) keeps
-    its layers, a scalar a layer is summed over them."""
-    stats = [st for st in stats if st]
-    if not stats:
-        return {}
-    joined = jax.tree.map(lambda *a: jnp.concatenate(a), *stats)
-    return {name: v if v.ndim == 2 else jnp.sum(v)
-            for name, v in joined.items()}
-
-
-def _expert_bias(cfg: JoyAIConfig, expert_bias):
-    """[sparse layers + the module's block, n_experts], without a
-    gradient; none is zeros."""
-    rows = cfg.n_sparse_layers + cfg.n_mtp
-    if expert_bias is None:
-        return jnp.zeros((rows, cfg.n_experts), jnp.float32)
-    if expert_bias.shape != (rows, cfg.n_experts):
-        raise ValueError(
-            f"expert_bias {expert_bias.shape}: a row a sparse layer and one "
-            f"for the prediction module's block, ({rows}, {cfg.n_experts})")
-    return jax.lax.stop_gradient(expert_bias)
-
-
-# --------------------------------------------------------------------- #
-# forward and loss
-# --------------------------------------------------------------------- #
-
-def forward_hidden(params: Dict[str, Any], tokens: jnp.ndarray,
-                   cfg: JoyAIConfig, expert_bias: Optional[jnp.ndarray] = None,
-                   ep_axis: Optional[str] = None):
-    """tokens [B, S] -> (the last block's output ``h_L`` [B, S, d],
-    BEFORE the final norm: the head and the prediction module each norm
-    it themselves; the layers' statistics, a list a run)."""
-    bias = _expert_bias(cfg, expert_bias)
-    rope = rope_cache(cfg, tokens.shape[1])
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    stats = []
-    for (ffn, n), p in zip(cfg.runs(), params["runs"]):
-        rows = bias[:cfg.n_sparse_layers] if ffn == SPARSE else None
-        x, st = _run(x, p, rows, rope, cfg, ffn, ep_axis)
-        stats.append(st)
-    return x, stats
-
-
 def head_nll(x, norm, head, targets, cfg: JoyAIConfig, last: int = 0):
     """The sum of the cross-entropies of ``targets`` [B, S] under
     ``RMSNorm(x) W_head``, the last ``last`` positions of every row
@@ -347,20 +294,124 @@ def head_nll(x, norm, head, targets, cfg: JoyAIConfig, last: int = 0):
     return jnp.sum(jax.lax.map(row, (x, targets)))
 
 
+# --------------------------------------------------------------------- #
+# forward and loss: a chain of links (``ops/chain.py``)
+# --------------------------------------------------------------------- #
+
+def _layers(cfg: JoyAIConfig, ffn, ep_axis, key, depth, **more) -> chain.Run:
+    """``depth`` like layers of FFN kind ``ffn``, their leaves stacked
+    under ``key``, scanned (a run of one layer too: a kernel's
+    instruction is named alike in every run); the rotary table is made
+    from the batch once a program."""
+    def block(p, x, rope, *row):
+        return _block(x, p, *(row or (None,)), rope, cfg, ffn, ep_axis)
+
+    return chain.Run(
+        block, key, depth, remat=cfg.remat,
+        consts=lambda batch: rope_cache(
+            cfg, L.split_batch(batch)[0].shape[1]), **more)
+
+
+def _runs(cfg: JoyAIConfig, expert_bias, ep_axis) -> List[chain.Run]:
+    """A ``chain.Run`` a run of ``cfg.runs()``, its stacked leaves under
+    ``params["runs"][i]``. A sparse run reads its layers' rows of the
+    bias and counts them into its rows of ``moe/expert_load`` ``[sparse
+    layers + the module's block, n_held]``, zero elsewhere: the runs'
+    tables and the module's add up to the step's. A scalar a layer is
+    summed over a run's layers. (A link closes over nothing that is
+    traced: the bias's rows are cut, and its gradient stopped, where
+    the link runs.)"""
+    rows = cfg.n_sparse_layers + cfg.n_mtp
+    runs, first = [], 0
+    for i, (ffn, n) in enumerate(cfg.runs()):
+        sparse = ffn == SPARSE
+        runs.append(_layers(
+            cfg, ffn, ep_axis, ("runs", i), n,
+            stats=lambda stacked, first=first: moe.run_stats(
+                stacked, first, rows),
+            each=(lambda batch, first=first, n=n: moe.bias_rows(
+                expert_bias, cfg.n_experts, first, n)) if sparse else None))
+        first += n if sparse else 0
+    return runs
+
+
 def mtp_hidden(params: Dict[str, Any], h: jnp.ndarray, targets: jnp.ndarray,
                cfg: JoyAIConfig, bias: jnp.ndarray, ep_axis: Optional[str]):
     """The prediction module's block output: position ``i`` joins ``h_L``
     at ``i`` with the embedding of ``t_{i+1}`` (``targets`` at ``i``: the
     SAME embedding leaf) and passes one sparse block, positions as in
-    the main model. Returns (x [B, S, d], the block's statistics)."""
+    the main model. ``bias``: the block's row ``[n_experts]``. Returns
+    (x [B, S, d], the block's statistics, ``[1, ...]`` each)."""
     p, dt, eps = params["mtp"], cfg.dtype, cfg.norm_eps
     with jax.named_scope("bps.mtp"):
         nxt = params["embed"].astype(dt)[targets]
         x = jnp.concatenate([L._rmsnorm(h, p["norm_h"], eps),
                              L._rmsnorm(nxt, p["norm_e"], eps)], axis=-1) \
             @ p["proj"].astype(dt)
-    return _run(x, p["block"], bias[None], rope_cache(cfg, h.shape[1]), cfg,
-                SPARSE, ep_axis)
+    return _layers(cfg, SPARSE, ep_axis, ("mtp", "block"), 1).scan(
+        p["block"], x, rope_cache(cfg, h.shape[1]), each=bias[None])
+
+
+def _chain(cfg: JoyAIConfig, expert_bias, ep_axis) -> chain.Chain:
+    """The loss as links: the lookup, a run a stretch of like layers,
+    and a last link that holds the final norm, the main head's
+    cross-entropy AND the prediction module (its block the last row of
+    the bias and of the load, its norm, the second pass over the same
+    ``head``, the lookup of ``t_{i+1}`` in the same ``embed``).
+    ``embed`` lies under the first link and the last: a step that cuts
+    the backward sums its two terms on the chip. Without a module the
+    last link is the head alone."""
+    rows = cfg.n_sparse_layers + cfg.n_mtp
+    if expert_bias is not None and expert_bias.shape != (rows, cfg.n_experts):
+        raise ValueError(
+            f"expert_bias {expert_bias.shape}: a row a sparse layer and one "
+            f"for the prediction module's block, ({rows}, {cfg.n_experts})")
+
+    def embed(p, _, batch):
+        return p["embed"].astype(cfg.dtype)[L.split_batch(batch)[0]], {}
+
+    def heads(p, h, batch):
+        # a link reads what it needs of the batch from ``batch``: the
+        # cut step traces it on its own
+        inputs, targets = L.split_batch(batch)
+        n, S = inputs.shape
+        loss = head_nll(h, p["final_norm"], p["head"], targets,
+                        cfg) / (n * S)
+        if not cfg.n_mtp:
+            return loss, {}
+        bias = moe.bias_rows(expert_bias, cfg.n_experts,
+                             cfg.n_sparse_layers, 1)[0]
+        x, st = mtp_hidden(p, h, targets, cfg, bias, ep_axis)
+        with jax.named_scope("bps.mtp"):
+            # the target of position i is the main target of i + 1; the
+            # roll's wrapped last entry is masked
+            nll = head_nll(x, p["mtp"]["final_norm"], p["head"],
+                           jnp.roll(targets, -1, axis=1), cfg, last=1)
+        stats = moe.run_stats(st, cfg.n_sparse_layers, rows)
+        stats["mtp/predicted_tokens"] = jnp.asarray(n * (S - 1), jnp.int32)
+        stats["mtp/nll_sum"] = nll
+        return loss + cfg.mtp_weight * nll / (n * (S - 1)), stats
+
+    keys = ("final_norm", "head") + (("mtp", "embed") if cfg.n_mtp else ())
+    return chain.Chain((
+        chain.Link(embed, "embed"), *_runs(cfg, expert_bias, ep_axis),
+        chain.Link(heads, keys)))
+
+
+def forward_hidden(params: Dict[str, Any], tokens: jnp.ndarray,
+                   cfg: JoyAIConfig, expert_bias: Optional[jnp.ndarray] = None,
+                   ep_axis: Optional[str] = None):
+    """tokens [B, S] -> (the last block's output ``h_L`` [B, S, d],
+    BEFORE the final norm: the head and the prediction module each norm
+    it themselves; the layers' statistics as a step's: the load in the
+    leading rows of ``[sparse layers + the module's block, n_held]``,
+    the other counts summed over the layers)."""
+    batch = {"inputs": tokens, "targets": tokens}
+    x, stats = None, {}
+    for ln in _chain(cfg, expert_bias, ep_axis).links[:-1]:
+        x, st = ln(ln.pick(params), x, batch)
+        chain.add_stats(stats, st)
+    return x, stats
 
 
 def loss_fn(params: Dict[str, Any], batch: Dict[str, jnp.ndarray],
@@ -376,22 +427,12 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jnp.ndarray],
     batch: ``{"tokens"}`` (shifted here) or pre-shifted ``{"inputs",
     "targets"}``; a row of ``S`` inputs gives ``S`` main positions and
     ``S - 1`` of the module (position ``i`` predicts ``t_{i+2}``, the
-    target after its own: the last has none)."""
-    inputs, targets = L.split_batch(batch)
-    rows, S = inputs.shape
-    h, stats = forward_hidden(params, inputs, cfg, expert_bias, ep_axis)
-    loss = head_nll(h, params["final_norm"], params["head"], targets,
-                    cfg) / (rows * S)
-    if not cfg.n_mtp:
-        return loss, _summed(stats)
-    bias = _expert_bias(cfg, expert_bias)[-1]
-    x, st = mtp_hidden(params, h, targets, cfg, bias, ep_axis)
-    with jax.named_scope("bps.mtp"):
-        # the target of position i is the main target of i + 1; the
-        # roll's wrapped last entry is masked
-        nll = head_nll(x, params["mtp"]["final_norm"], params["head"],
-                       jnp.roll(targets, -1, axis=1), cfg, last=1)
-    stats = _summed(stats + [st])
-    stats["mtp/predicted_tokens"] = jnp.asarray(rows * (S - 1), jnp.int32)
-    stats["mtp/nll_sum"] = nll
-    return loss + cfg.mtp_weight * nll / (rows * (S - 1)), stats
+    target after its own: the last has none).
+
+    Written as a chain (``ops/chain.py``, ``_chain``): any step maker
+    runs it as one program; ``make_ps_train_step`` cuts its backward at
+    the links: the program of the last link (both head passes and the
+    module: ``head``, ``mtp`` and the norms leave behind it, ``embed``'s
+    term stays on the chip), one a layer, and the lookup's, which adds
+    its own term to ``embed``'s and hands the sum over once."""
+    return _chain(cfg, expert_bias, ep_axis)(params, batch)
