@@ -242,7 +242,7 @@ class Config:
     # --- metrics / observability (rebuild addition; core/metrics.py:
     # the unified registry + per-step pipeline profiler every perf PR
     # reports against). metrics_on=0 turns every instrument op into a
-    # flag check (the bench metrics_ab A/B); metrics_port > 0 serves a
+    # flag check; metrics_port > 0 serves a
     # stdlib Prometheus text endpoint on 127.0.0.1; stall_diag logs a
     # one-line per-step bound-stage diagnosis from the StepReport ring
     # (window = step_report_window). ---
@@ -256,7 +256,7 @@ class Config:
     # hook (counter deltas / gauges / StepReport + ledger fields +
     # per-stripe wire and per-leaf staleness series); ts_points bounds
     # every series ring. bps.get_timeseries() / `byteps_tpu.tools.top`
-    # read it; a JSONL artifact rides SIGTERM/shutdown + bench runs. ---
+    # read it; a JSONL artifact rides SIGTERM/shutdown. ---
     timeseries: bool = True               # BYTEPS_TIMESERIES
     ts_points: int = 512                  # BYTEPS_TS_POINTS
 

@@ -24,8 +24,8 @@ efficient a step is*. Three coupled pieces (docs/observability.md
   JSONL record per step (buffered; file I/O deferred to
   ``BYTEPS_PERF_FLUSH_STEPS`` boundaries so the hot path is a dict +
   one dumps), flushed on interval, at ``shutdown()`` and on SIGTERM
-  alongside the flight record — every bench phase and real run leaves
-  a replayable efficiency history ``ci/perf_gate.py`` can gate on.
+  alongside the flight record — a run leaves a replayable efficiency
+  history.
 
 - **Efficiency-drop flight events** — when ``mfu`` or ``overlap_frac``
   falls more than ``BYTEPS_EFF_DROP_FRAC`` below its trailing-window
@@ -57,8 +57,8 @@ __all__ = [
 
 # bf16 peak FLOP/s and HBM GB/s per device kind, matched as lowercase
 # substrings of ``device.device_kind`` LONGEST FIRST (so "v5 lite" wins
-# over "v5"). Sources: published TPU specs (docs/performance.md "Chip
-# peak table"). A device that is in no row is an ERROR, not a default:
+# over "v5"). Sources: published TPU specs. A device that is in no
+# row is an ERROR, not a default:
 # a utilization against an assumed peak is not a measurement.
 PEAK_TABLE: Tuple[Tuple[str, float, float], ...] = (
     ("v6 lite", 918e12, 1640.0),
@@ -75,8 +75,7 @@ PEAK_TABLE: Tuple[Tuple[str, float, float], ...] = (
 # loopback steps need a stable denominator to exercise the pricing
 # path. Source ``cpu-nominal``: a ratio against it tracks regressions
 # on one host and is NOT a model-FLOP/s utilization — nothing that
-# reports a device metric (bench.py's device phases, chip_smoke.py)
-# accepts this source.
+# reports a device metric (chip_smoke.py) accepts this source.
 _CPU_FLOPS_PER_CORE = 5e10
 _CPU_BW_GBPS = 20.0
 
@@ -477,9 +476,9 @@ class EfficiencyLedger:
                   "queue_depth_peak", "credit_stalls",
                   # training-health fields (core/health.py): archived
                   # so a perf record also tells you whether the run
-                  # was numerically sane; ci/perf_gate.py skips
-                  # grad_norm/update_ratio_p95 (no better-direction)
-                  # and reads nonfinite_leaves lower-is-better
+                  # was numerically sane (grad_norm/update_ratio_p95
+                  # have no better-direction; nonfinite_leaves is
+                  # lower-is-better)
                   "grad_norm", "update_ratio_p95", "nonfinite_leaves",
                   "fidelity_drift"):
             v = getattr(report, k, None)

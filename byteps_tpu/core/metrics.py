@@ -13,8 +13,7 @@ Three layers:
   (direct or lazily collected from a callback) and fixed-log2-bucket
   ``Histogram``s. Thread-safe; the hot path is one lock + integer
   mutation on preallocated storage (no per-sample allocation). Disabled
-  (``BYTEPS_METRICS=0``) every instrument op is a flag check + return —
-  the A/B ``bench.py --phase metrics_ab`` measures exactly this delta.
+  (``BYTEPS_METRICS=0``) every instrument op is a flag check + return.
 - ``StepProfiler`` — per-train-step ``StepReport`` assembly: the PS
   train step opens a report, the scheduler's stage pool threads feed
   per-task stage samples into it, and ``end_step`` closes it into a

@@ -516,7 +516,7 @@ class PipelineScheduler:
             self._comp_pre = metrics.counter("compress/bytes_pre")
             self._comp_post = metrics.counter("compress/bytes_post")
             # lossless tier's own byte accounting (codec plane evidence:
-            # codec/lossless_ratio = post/pre; bench codec_adapt_ab)
+            # codec/lossless_ratio = post/pre)
             self._lossless_pre = metrics.counter(
                 "codec/lossless_bytes_pre")
             self._lossless_post = metrics.counter(

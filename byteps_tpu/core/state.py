@@ -103,8 +103,8 @@ class _Telemetry:
         train thread), how many of them as per-device shards, and the
         round's time-to-first-push (first submit entering the
         scheduler, measured from the backward's dispatch). Cumulative
-        counters + the last round's TTFP let tests and the bench assert
-        the plan ran as often as it says."""
+        counters + the last round's TTFP let tests and chip_smoke.py
+        assert the plan ran as often as it says."""
         with self._lock:
             self._export_leaves = \
                 getattr(self, "_export_leaves", 0) + int(leaves)
@@ -564,7 +564,7 @@ class GlobalState:
         """Per-step server-attribution probe (StepProfiler): cumulative
         per-stage ns summed over the fleet, or None when no server is
         reachable. In-process mirror first — a ctypes read, cheap
-        enough for every step boundary (the metrics_ab ≤2% bar) — the
+        enough for every step boundary — the
         wire op only when the fleet is genuinely out-of-process.
 
         The wire path runs ON THE TRAIN THREAD (step boundaries), so

@@ -32,9 +32,8 @@ Read surfaces: ``bps.get_timeseries()`` (full rings), the
 ``timeseries`` section of ``bps.get_metrics()`` (bounded tails — what
 ``python -m byteps_tpu.tools.top`` renders over the local or HTTP
 snapshot path), and a JSONL dump artifact that rides the SIGTERM term-
-hook chain (pinned FIRST: timeseries → perf archive → flight dump),
-``bps.shutdown()`` and each ``bench.py`` phase
-(docs/observability.md "Time-series plane").
+hook chain (pinned FIRST: timeseries → perf archive → flight dump)
+and ``bps.shutdown()`` (docs/observability.md "Time-series plane").
 """
 
 from __future__ import annotations

@@ -4,10 +4,8 @@
 elementwise expression per leaf — the best case a fused (XLA- or
 Pallas-lowered) optimizer pass can reach, vs optax.adam's chain of
 per-transform tree passes. Numerics validated bit-close to optax
-(max |Δparam| ≈ 1e-7 after 5 steps on the tiny llama config; the CPU
-validation lives alongside the A/B in examples/mfu_experiments.py).
-Shared by bench.py's ``fused_adam`` train variant and the MFU harness
-so the validated math exists exactly once.
+(max |Δparam| ≈ 1e-7 after 5 steps on the tiny llama config:
+tests/test_train.py).
 
 ``make_sharded_apply`` splits an optax transformation into per-leaf
 jitted partial updates for the PS train step's tail overlap
@@ -22,7 +20,7 @@ caller falls back to the fused apply.
 
 Reference context: the reference leaves optimizer fusion to the
 framework (torch fused adam etc.); here it is an A/B lever for the
-"optimizer pass" suspect in docs/performance.md's ceiling analysis.
+optimizer pass.
 """
 
 from __future__ import annotations
@@ -40,7 +38,7 @@ def fused_adam_step(loss_fn, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8,
     ``loss_fn(params, batch) -> scalar``; ``step(params, opt_state,
     batch) -> (params, opt_state, loss)`` with every per-leaf update in
     a single fused expression. ``mu_dtype=bfloat16`` halves the first
-    moment's HBM traffic (matching the bench's optax baseline); nu
+    moment's HBM traffic (optax.adam's ``mu_dtype``); nu
     stays f32 (variance needs the range).
     """
 

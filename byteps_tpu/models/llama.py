@@ -40,15 +40,14 @@ class LlamaConfig:
     remat: bool = True               # jax.checkpoint each block
     # jax.checkpoint_policies name, e.g. "dots_with_no_batch_dims_saveable"
     # (save projection outputs, recompute elementwise + attention einsums);
-    # None = full recompute. On the 125M bench both time the same; the
-    # policy trades activation memory back for recompute at larger scale.
+    # None = full recompute. The policy trades activation memory back
+    # for recompute at larger scale.
     remat_policy: Optional[str] = None
     # > 0: loss_fn computes the cross entropy per vocab chunk under a
     # nothing-saveable checkpoint, so the [B, S, V] logits are never
     # resident at once — trades an extra lm_head matmul in bwd for the
-    # logits' HBM round-trips (the MFU experiment harness's chunked-xent
-    # candidate, examples/mfu_experiments.py; bench.py A/Bs it). Vocab
-    # must divide evenly or the dense path is used.
+    # logits' HBM round-trips. Vocab must divide evenly or the dense
+    # path is used.
     xent_chunks: int = 0
 
     @property
@@ -203,8 +202,7 @@ def next_token_xent(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
     Uses the logsumexp form rather than log_softmax: log_softmax would
     materialize a full [B, S, V] fp32 normalized array only to gather one
     element per token, a pure HBM-bandwidth tax; logsumexp reduces to
-    [B, S] and the fp32 cast fuses into the reduction (~3% step time on
-    the 125M bench)."""
+    [B, S] and the fp32 cast fuses into the reduction."""
     lf = logits.astype(jnp.float32)
     lse = jax.scipy.special.logsumexp(lf, axis=-1)
     picked = jnp.take_along_axis(lf, targets[..., None], axis=-1)[..., 0]
@@ -219,9 +217,8 @@ def chunked_next_token_xent(hidden: jnp.ndarray, lm_head: jnp.ndarray,
     checkpoint, then combine the per-chunk partials (logsumexp over
     chunks; the picked logit lives in exactly one chunk, -inf in the
     rest, so a max recovers it). Trades one extra lm_head matmul in the
-    backward for the logits' HBM round-trips — the MFU-experiment
-    winner shape at V=32k (examples/mfu_experiments.py). Identical math
-    to next_token_xent (a test asserts closeness)."""
+    backward for the logits' HBM round-trips. Identical math to
+    next_token_xent (a test asserts closeness)."""
     import functools
 
     V = lm_head.shape[1]

@@ -2,7 +2,7 @@
 
 The dense attention in models/llama.py materializes [B, H, S, S] scores;
 XLA fuses the softmax well enough that at S=1024 on v5e it beats a
-hand-written kernel (measured, docs/performance.md "rejected" table).
+hand-written kernel.
 The quadratic HBM term wins at longer S, so long-context runs get:
 
 - ``blockwise_attention`` — jnp ``lax.scan`` over KV blocks with the

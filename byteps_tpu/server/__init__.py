@@ -359,8 +359,8 @@ def engine_stats() -> List[List[int]]:
 
 def stage_section() -> Dict[str, float]:
     """The ``server`` section of ``bps.get_metrics()``: per-stage walls
-    in milliseconds plus counts, the fold-byte total (the fold_ab
-    bench's HARD proof counter), zero-copy tier engagement, the active
+    in milliseconds plus counts, the fold-byte total (exactly the
+    payload bytes folded), zero-copy tier engagement, the active
     SIMD tier, and how many servers are live in this process. Keys are
     fixed whether or not a server is local, so the documented schema
     resolves on every deployment."""

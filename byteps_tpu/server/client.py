@@ -395,7 +395,7 @@ class PSClient:
         ``stripe_bytes`` count fused PUSHPULL traffic the client split
         across the BYTEPS_WIRE_STRIPES data connections (segments and
         payload bytes; framing overhead is 72B per segment — the
-        byte-conservation identity the stripe_ab bench asserts is
+        byte-conservation identity tests/test_wire_stripe.py asserts is
         ``sum(stripe_conn_bytes()) == stripe_bytes + 72*stripe_segs``)."""
         if self._closed:
             raise RuntimeError("transport_stats on a closed PSClient")

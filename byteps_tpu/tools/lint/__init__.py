@@ -1,9 +1,10 @@
 """byteps-lint: project-native static analysis (docs/static-analysis.md).
 
 Run with ``python -m byteps_tpu.tools.lint``; programmatic entry is
-``run_lint(root) -> List[Finding]``. Five rules, each encoding an
+``run_lint(root) -> List[Finding]``. Six rules, each encoding an
 invariant a past PR enforced only by memory: ``wire-layout``,
-``guarded-by``, ``device-thread``, ``env-sync``, ``metrics-schema``.
+``guarded-by``, ``device-thread``, ``env-sync``, ``metrics-schema``,
+``doc-paths``.
 Per-line suppression: ``# bps-lint: disable=<rule>``.
 """
 

@@ -5,7 +5,8 @@ must run in CI boxes and pre-commit hooks without the training stack.
 Each rule is one class with a ``name``, a one-line ``doc`` and a
 ``check(project)`` returning structured findings; ``run_lint`` filters
 per-line suppressions (``# bps-lint: disable=<rule>`` on the flagged
-line or the line directly above; ``//`` comments work in C++ sources).
+line or the line directly above; ``//`` comments work in C++ sources,
+``<!-- ... -->`` in Markdown).
 
 The rules encode invariants that previously lived only in reviewers'
 heads — see docs/static-analysis.md for the catalog and the historical
@@ -24,7 +25,8 @@ from typing import Dict, List, Optional, Sequence
 # names, env vars and metric names as DATA), caches, VCS internals.
 _SKIP_DIRS = {"__pycache__", ".git", ".pytest_cache", "lint"}
 
-_SUPPRESS_RE = re.compile(r"(?:#|//)\s*bps-lint:\s*disable=([\w,\-\s]+)")
+_SUPPRESS_RE = re.compile(
+    r"(?:#|//|<!--)\s*bps-lint:\s*disable=([\w,\-\s]+)")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -92,16 +94,17 @@ class Project:
         p = os.path.join(self.docs_root, name)
         return p if os.path.exists(p) else None
 
+    def documents(self) -> List[str]:
+        """``README.md`` and the Markdown files under ``docs/``."""
+        readme = os.path.join(self.root, "README.md")
+        return ([readme] if os.path.isfile(readme) else []) \
+            + self._walk(self.docs_root, ".md")
+
     def env_scan_files(self) -> List[str]:
         """Sources scanned for BYTEPS_*/DMLC_* env reads: the package
-        (.py and .cc) plus the repo-level bench/examples entry points
-        that read documented knobs."""
-        out = self.py_files() + self.cc_files()
-        bench = os.path.join(self.root, "bench.py")
-        if os.path.exists(bench):
-            out.append(bench)
-        out += self._walk(os.path.join(self.root, "examples"), ".py")
-        return out
+        (.py and .cc) plus the examples, which read documented knobs."""
+        return (self.py_files() + self.cc_files()
+                + self._walk(os.path.join(self.root, "examples"), ".py"))
 
     # -- content caches ------------------------------------------------ #
 
@@ -142,7 +145,8 @@ class Project:
             if 1 <= ln <= len(lines):
                 m = _SUPPRESS_RE.search(lines[ln - 1])
                 if m:
-                    rules = {r.strip() for r in m.group(1).split(",")}
+                    # " -": the tail of a Markdown comment's "-->"
+                    rules = {r.strip(" -") for r in m.group(1).split(",")}
                     if rule in rules or "all" in rules:
                         return True
         return False
@@ -164,13 +168,14 @@ def all_rules() -> List[Rule]:
     """The registered rule set, import-cycle-free (rules import base,
     never each other)."""
     from .device_thread import DeviceThreadRule
+    from .doc_paths import DocPathsRule
     from .env_sync import EnvSyncRule
     from .locks import GuardedByRule
     from .metrics_schema import MetricsSchemaRule
     from .wire_layout import WireLayoutRule
 
     return [WireLayoutRule(), GuardedByRule(), DeviceThreadRule(),
-            EnvSyncRule(), MetricsSchemaRule()]
+            EnvSyncRule(), MetricsSchemaRule(), DocPathsRule()]
 
 
 def run_lint(root: str,

@@ -15,7 +15,7 @@ Three snapshot sources, one renderer:
   ``BYTEPS_METRICS_PORT`` serves (the remote / out-of-process view);
   defaults to that env var's port when set.
 - ``--file path`` — a dumped snapshot JSON, or a ``timeseries-*.jsonl``
-  SIGTERM/shutdown/bench artifact (post-mortem mode: the console
+  SIGTERM/shutdown artifact (post-mortem mode: the console
   renders a dead run's tail).
 - ``--local`` — ``bps.get_metrics()`` in this process (debugging a
   live training process from a REPL / the same interpreter).

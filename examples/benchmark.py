@@ -14,9 +14,6 @@ server. ``--no-comm`` restores the old compute-only step for A/B-ing the
 communication overhead.
 
     python examples/benchmark.py --model llama --num-iters 5
-
-Scaling efficiency across real worker processes: see
-examples/benchmark_scaling.py (reference: README.md:34-40).
 """
 
 from __future__ import annotations
@@ -31,8 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-# runnable as `python examples/<name>.py` from anywhere (same idiom as
-# benchmark_scaling.py)
+# runnable as `python examples/<name>.py` from anywhere
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)  # noqa: E402 — before the byteps_tpu import
 

@@ -25,8 +25,7 @@ from jax.sharding import PartitionSpec as P
 import os
 import sys
 
-# runnable as `python examples/<name>.py` from anywhere (same idiom as
-# benchmark_scaling.py)
+# runnable as `python examples/<name>.py` from anywhere
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 

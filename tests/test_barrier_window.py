@@ -418,7 +418,7 @@ def test_staleness0_bitwise_identical(fusion, kw):
 
 def test_staleness1_carry_engages_and_converges():
     """At staleness 1 the carry actually engages (carried-leaf counter
-    nonzero — the engaged-proof the A/B bench pins), training stays
+    nonzero), training stays
     finite and converges, and ``flush`` folds the outstanding tail so
     the final trees are complete."""
     from byteps_tpu.core.state import get_state

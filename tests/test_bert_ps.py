@@ -114,3 +114,30 @@ def test_benchmark_bert_smoke():
     assert r.returncode == 0, r.stdout[-2000:] + r.stderr[-2000:]
     assert "img/sec" in r.stdout or "examples/sec" in r.stdout or \
         "Total img/sec" in r.stdout, r.stdout[-800:]
+
+
+def test_multichip_envelope_bounded():
+    """Dryrun envelope guard: the dryrun's worst case — every phase running to its full
+    per-phase timeout — must fit HALF the driver window, so phase growth
+    without budget fails here, in tier-1, instead of silently pushing a
+    future driver round past its kill deadline. Also pins the phase
+    list to the functions that actually exist (a renamed/removed phase
+    fn breaks the product silently otherwise)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "__graft_entry__",
+        os.path.join(os.path.dirname(__file__), "..", "__graft_entry__.py"))
+    g = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(g)
+    phases = g._DRYRUN_PHASES
+    assert len(phases) >= 7  # the envelope covers the real suite
+    worst_case = len(phases) * g.DRYRUN_PHASE_TIMEOUT_S
+    assert worst_case <= g.DRYRUN_DRIVER_WINDOW_S / 2, (
+        f"{len(phases)} dryrun phases x {g.DRYRUN_PHASE_TIMEOUT_S:.0f}s "
+        f"= {worst_case:.0f}s worst case exceeds half the "
+        f"{g.DRYRUN_DRIVER_WINDOW_S:.0f}s driver window — trim a phase "
+        f"or grow the budget DELIBERATELY")
+    # the re-exec child's hard timeout mirrors the same half-window
+    for name, fn in phases:
+        assert callable(fn), name

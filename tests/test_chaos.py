@@ -350,8 +350,7 @@ print("DROP_OK retries=", retries)
 @pytest.mark.chaos
 def test_dropped_replies_retry_bitwise_identical():
     """Forced reply drops + epoch-stamped retries produce bitwise-exact
-    aggregates (the acceptance idempotence proof, test-side twin of
-    ``bench.py --phase churn_ab``)."""
+    aggregates (the acceptance idempotence proof)."""
     env = {**os.environ,
            "BPS_REPO": REPO,
            "BYTEPS_CLIENT_TIMEOUT_S": "2",

@@ -349,8 +349,7 @@ def _ports(n):
 
 class _Fleet:
     """Scoped loopback fleet: N in-process servers + an initialized bps
-    worker, with env save/restore (the test-side twin of bench.py's
-    _loopback_ps, plus runtime growth)."""
+    worker, with env save/restore, plus runtime growth."""
 
     def __init__(self, num_servers, extra_env=None):
         self.ports = _ports(num_servers)

@@ -1,15 +1,11 @@
-"""Step efficiency ledger (core/ledger.py) + perf regression gate
-(ci/perf_gate.py): cost-analysis extraction with the no-backend
-fallback, overlap-fraction math on synthetic span timelines, the
-device-kind peak table with env override, archive JSONL round-trip +
-SIGTERM flush, gate statistics (injected 20% regression on tight
-synthetic histories trips; run-to-run noise replayed from the parsed
-samples of older driver rounds does not), and the loopback PS end-to-end: non-null
+"""Step efficiency ledger (core/ledger.py): cost-analysis extraction
+with the no-backend fallback, overlap-fraction math on synthetic span
+timelines, the device-kind peak table with env override, archive JSONL
+round-trip + SIGTERM flush, and the loopback PS end-to-end: non-null
 ``mfu``/``overlap_frac``/``wire_efficiency`` in ``get_step_reports()``
 with the efficiency verdict in ``classify_step``."""
 
 import contextlib
-import importlib.util
 import json
 import os
 import signal
@@ -34,14 +30,6 @@ from byteps_tpu.server import run_server
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
 _PORT = [24700]
-
-
-def _load_perf_gate():
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(REPO, "ci", "perf_gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # --------------------------------------------------------------------- #
@@ -330,112 +318,6 @@ time.sleep(30)
     with open(path) as f:
         recs = [json.loads(ln) for ln in f.read().strip().splitlines()]
     assert [r["step"] for r in recs] == list(range(1, 8))
-
-
-# --------------------------------------------------------------------- #
-# perf regression gate (ci/perf_gate.py)
-# --------------------------------------------------------------------- #
-
-
-def test_gate_trips_injected_regression():
-    pg = _load_perf_gate()
-    baseline = {"keys": {"pushpull_dense_gbps": {
-        "samples": [10.0, 10.1, 9.9, 10.05, 9.95]}}}
-    # 20% down on a tight history: far past max(10% floor, 3 sigma)
-    rep = pg.compare({"pushpull_dense_gbps": 8.0}, baseline)
-    assert not rep["ok"]
-    assert rep["regressions"][0]["key"] == "pushpull_dense_gbps"
-    # within the noise band: passes
-    assert pg.compare({"pushpull_dense_gbps": 9.85}, baseline)["ok"]
-    # a big IMPROVEMENT is never a regression (directionality)
-    rep = pg.compare({"pushpull_dense_gbps": 20.0}, baseline)
-    assert rep["ok"]
-    assert rep["rows"][0]["verdict"] == "improvement"
-
-
-def test_gate_directionality_lower_is_better():
-    pg = _load_perf_gate()
-    baseline = {"keys": {"arena_on_step_ms": {
-        "samples": [5.0, 5.05, 4.95]}}}
-    rep = pg.compare({"arena_on_step_ms": 6.2}, baseline)  # 24% slower
-    assert not rep["ok"]
-    assert pg.compare({"arena_on_step_ms": 4.0}, baseline)["ok"]
-    # unknown-direction keys are skipped, never guessed
-    rep = pg.compare({"mystery_quantity": 1.0},
-                     {"keys": {"mystery_quantity": {"samples": [2.0]}}})
-    assert rep["ok"] and rep["rows"][0]["verdict"] == "skipped"
-    # explicit per-key override beats the suffix table
-    rep = pg.compare(
-        {"weird_gbps": 1.0},
-        {"keys": {"weird_gbps": {"samples": [2.0],
-                                 "direction": "lower"}}})
-    assert rep["ok"] and rep["rows"][0]["verdict"] == "improvement"
-
-
-def test_gate_noise_replay_from_real_bench_tails(tmp_path):
-    """Run-to-run noise replayed from the parsed samples of three older
-    driver rounds (tests/data/bench_round_samples.json; the records
-    themselves were removed in PR 21) must not trip the committed
-    baseline: one round's dense 2.155 vs the next's 2.923 is a 26%
-    historical swing, and the MAD band absorbs replaying either. A
-    round that parsed null reads as missing, never as a loss."""
-    pg = _load_perf_gate()
-    baseline = pg.load_baseline(
-        os.path.join(REPO, "ci", "perf_baseline.json"))
-    with open(os.path.join(REPO, "tests", "data",
-                           "bench_round_samples.json")) as f:
-        rounds = json.load(f)["rounds"]
-    reports = []
-    for i, artifact in enumerate(rounds):
-        # through load_candidate, in the driver-artifact shape
-        path = tmp_path / f"round{i}.json"
-        path.write_text(json.dumps(artifact))
-        rep = pg.compare(pg.load_candidate(str(path)), baseline)
-        assert rep["ok"], (i, rep["regressions"])
-        reports.append(rep)
-    assert reports[0]["checked"] > 0 and reports[1]["checked"] > 0
-    # the null parse: every key missing, zero checked, still ok
-    assert rounds[-1]["parsed"] is None
-    assert reports[-1]["checked"] == 0
-    assert all(r["verdict"] == "missing" for r in reports[-1]["rows"])
-
-
-def test_gate_archive_candidate(tmp_path):
-    """A BYTEPS_PERF_ARCHIVE JSONL is a first-class gate candidate:
-    numeric keys collapse to their median over the records."""
-    pg = _load_perf_gate()
-    path = tmp_path / "perf-123.jsonl"
-    with open(path, "w") as f:
-        for i in range(9):
-            f.write(json.dumps({"step": i + 1, "wall_ms": 10.0 + i,
-                                "mfu": 0.30 + 0.01 * i}) + "\n")
-    cand = pg.load_candidate(str(path))
-    assert cand["wall_ms"] == 14.0 and cand["mfu"] == \
-        pytest.approx(0.34)
-    baseline = {"keys": {"mfu": {"samples": [0.33, 0.35, 0.34]}}}
-    assert pg.compare(cand, baseline)["ok"]
-    baseline = {"keys": {"mfu": {"samples": [0.50, 0.51, 0.49]}}}
-    assert not pg.compare(cand, baseline)["ok"]
-
-
-def test_gate_cli_exit_codes(tmp_path):
-    gate = os.path.join(REPO, "ci", "perf_gate.py")
-    base = tmp_path / "base.json"
-    base.write_text(json.dumps(
-        {"keys": {"x_gbps": {"samples": [10.0, 10.1, 9.9]}}}))
-    good = tmp_path / "good.json"
-    good.write_text(json.dumps({"x_gbps": 10.0}))
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps({"x_gbps": 7.0}))
-    assert subprocess.run(
-        [sys.executable, gate, "--baseline", str(base),
-         "--candidate", str(good)]).returncode == 0
-    assert subprocess.run(
-        [sys.executable, gate, "--baseline", str(base),
-         "--candidate", str(bad)]).returncode == 1
-    assert subprocess.run(
-        [sys.executable, gate, "--baseline", str(base)],
-        stderr=subprocess.DEVNULL).returncode == 2
 
 
 # --------------------------------------------------------------------- #

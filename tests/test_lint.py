@@ -6,7 +6,7 @@ Two layers:
   deliberately skewed wire-header constant and a mis-documented
   BYTEPS_* default), stays quiet on the known-good twin, and honors
   per-line suppression;
-- the real repo: ``run_lint(REPO)`` must be CLEAN with all five rules
+- the real repo: ``run_lint(REPO)`` must be CLEAN with all six rules
   active — the PR gate ci/checks.sh runs — and the full-repo pass must
   stay under 30 s so it can live inside tier-1.
 
@@ -979,6 +979,96 @@ def test_metrics_schema_tracer_calls_ignored(tmp_path):
 
 
 # --------------------------------------------------------------------- #
+# doc-paths
+# --------------------------------------------------------------------- #
+
+_DOC_TREE = {
+    "byteps_tpu/jax/train.py": "x = 1\ny = 2\nz = 3\n",
+    "byteps_tpu/native/ps.cc": "// wire\n",
+    "benchmark/run.py": "pass\n",
+    ".gitignore": "_scratch/\n*.pyc\n",
+}
+
+_DOC_GOOD = """
+    Run `python3 benchmark/run.py --workload <cell>` and read
+    `jax/train.py:2`, `byteps_tpu/jax/train.py` and `ps.cc`; a user's
+    `python train.py` is theirs. The reference's
+    `byteps/common/global.cc:42`, a placeholder `<dir>/0/comm.json`, a
+    glob `*.py` and the git-ignored `_scratch/probe.py` are out of scope.
+"""
+
+
+def test_doc_paths_clean_fixture(tmp_path):
+    root = _write_tree(tmp_path, {**_DOC_TREE, "README.md": _DOC_GOOD,
+                                  "docs/guide.md": _DOC_GOOD})
+    assert run_lint(root, ["doc-paths"]) == []
+
+
+def test_doc_paths_stale_path(tmp_path):
+    # THE drift class: the script went, the documents kept citing it:
+    # by its bare name, inside a command, by a path from the root and by
+    # one from the package
+    root = _write_tree(tmp_path, {
+        **_DOC_TREE,
+        "README.md": "See `oldscript.py`.\n",
+        "docs/guide.md": "Run `python oldscript.py --phase wire_ab`,\n"
+                         "then `benchmark/gone.py` and `jax/gone.py`.\n"})
+    findings = run_lint(root, ["doc-paths"])
+    assert [(f.path, f.line) for f in findings] == [
+        ("README.md", 1), (os.path.join("docs", "guide.md"), 1),
+        (os.path.join("docs", "guide.md"), 2),
+        (os.path.join("docs", "guide.md"), 2)]
+    assert all("oldscript.py" in f.message for f in findings[:2])
+    assert "benchmark/gone.py" in findings[2].message
+    assert "jax/gone.py" in findings[3].message
+    assert _rules_hit(findings) == {"doc-paths"}
+
+
+def test_doc_paths_path_with_a_line_number(tmp_path):
+    root = _write_tree(tmp_path, {
+        **_DOC_TREE,
+        "docs/guide.md": "`jax/train.py:3` and `ps.cc:584` hold,\n"
+                         "`jax/gone.py:40` and `gone.cc:7` do not.\n"})
+    findings = run_lint(root, ["doc-paths"])
+    assert [f.line for f in findings] == [2, 2]
+    assert "`jax/gone.py`" in findings[0].message
+    assert "`gone.cc`" in findings[1].message
+
+
+def test_doc_paths_suppression(tmp_path):
+    root = _write_tree(tmp_path, {
+        **_DOC_TREE,
+        "docs/guide.md": "<!-- bps-lint: disable=doc-paths -->\n"
+                         "PR 47 deleted `oldscript.py`.\n"
+                         "\n"
+                         "It is still `oldscript.py` here.\n"})
+    findings = run_lint(root, ["doc-paths"])
+    assert [f.line for f in findings] == [4]
+
+
+_DOCUMENTS = ["README.md"] + sorted(
+    os.path.join("docs", f) for f in os.listdir(os.path.join(REPO, "docs"))
+    if f.endswith(".md"))
+
+
+@pytest.fixture(scope="module")
+def stale_citations():
+    """document -> its findings, from ONE pass over the real tree."""
+    by_document = {}
+    for f in run_lint(REPO, ["doc-paths"]):
+        by_document.setdefault(f.path, []).append(f.format())
+    return by_document
+
+
+@pytest.mark.parametrize("document", _DOCUMENTS)
+def test_every_file_a_document_cites_exists(document, stale_citations):
+    """One case a document of the real tree: what keeps a deleted script
+    from living on in the documents (every one of them that cited the
+    second benchmark's script failed here until PR 47 deleted it)."""
+    assert stale_citations.get(document, []) == []
+
+
+# --------------------------------------------------------------------- #
 # CLI contract
 # --------------------------------------------------------------------- #
 
@@ -995,7 +1085,7 @@ def test_cli_clean_exit_zero(tmp_path):
     })
     proc = _run_cli("--root", root)
     assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "byteps-lint: clean (5 rule(s) run)" in proc.stdout
+    assert "byteps-lint: clean (6 rule(s) run)" in proc.stdout
 
 
 def test_cli_findings_exit_one_and_format(tmp_path):
@@ -1022,7 +1112,7 @@ def test_cli_list_names_all_rules():
     proc = _run_cli("--list")
     assert proc.returncode == 0
     for rule in ("wire-layout", "guarded-by", "device-thread",
-                 "env-sync", "metrics-schema"):
+                 "env-sync", "metrics-schema", "doc-paths"):
         assert rule in proc.stdout
 
 

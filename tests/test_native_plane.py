@@ -206,6 +206,10 @@ def _two_worker_aggregates(monkeypatch, simd: str) -> dict:
             np.asarray(res[0][key]).view(np.uint8),
             np.asarray(res[1][key]).view(np.uint8))
         out[key] = np.asarray(res[0][key]).tobytes()
+    # the arm ran the kernels it names: a "scalar" arm on a vector tier
+    # would make the parity below vacuous
+    tier = cs[0].server_stats(0)["simd_tier"]
+    assert (tier == 0) == (simd == "scalar"), (simd, tier)
     for c in cs:
         c.close()
     for t in threads:
@@ -313,7 +317,7 @@ def test_oob_arena_wrap_and_reclaim(monkeypatch):
 
 def test_stage_stats_live_and_accounted():
     """The per-stage counters move with traffic and fold_bytes accounts
-    exactly the payload bytes folded (the fold_ab proof counter).
+    exactly the payload bytes folded.
     Delta-based throughout: in the full suite, earlier test files leave
     daemon server threads parked in bps_server_run forever, so the
     aggregate registry is never empty — but those stragglers have no

@@ -1,5 +1,5 @@
 """Pallas kernel parity tests (interpret mode on the CPU mesh; the compiled
-path is exercised on real TPU by bench/verify runs)."""
+path is exercised on real TPU by chip_smoke.py)."""
 
 import jax.numpy as jnp
 import numpy as np

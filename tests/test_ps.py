@@ -103,8 +103,8 @@ def test_throttled_servers_scale_bandwidth(monkeypatch):
     bounds: the 2srv wall must be under 0.75x the 1srv wall (ideal
     0.5x), and the 1srv wall must be within its cap's predicted range.
 
-    Each configuration times BEST-OF-2 rounds (bench.py's _best_of
-    rationale): on a loaded shared host, scheduler jitter hitting the
+    Each configuration times BEST-OF-2 rounds: on a loaded shared
+    host, scheduler jitter hitting the
     two wall() calls asymmetrically can push a single draw past the
     0.75x bound — the per-rep spread here has measured >50%; the best
     round is the capability number the rule speaks about."""

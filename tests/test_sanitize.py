@@ -531,7 +531,7 @@ def test_tsan_waiter_pool_smoke(tmp_path):
     the PR-6 Waiter-pool minimal repro. A regression in the per-conn
     Waiter pool or the pthread-initialized Mu/Cv wrappers reports
     "double lock of a destroyed mutex" within seconds of this loop,
-    so the class is caught by the 870 s tier-1 gate instead of only by
+    so the class is caught by the tier-1 gate instead of only by
     the slow sanitize burst. The TSAN build is content-hash-cached
     (~6 s cold on the 2-core box); the stress itself is ~4 threads x
     60 blocking rounds."""

@@ -412,6 +412,8 @@ def _route_run(shard, steps=4):
             "keys": sorted(c.name for c in
                            get_state().registry.contexts_in_order()),
             "shard_bytes": ctr.get("export/shard_bytes", 0),
+            "whole_bytes": ctr.get("export/whole_bytes", 0),
+            "push_bytes": ctr.get("wire/push_bytes", 0),
             "device_bytes": {k: v for k, v in ctr.items()
                              if k.startswith("export/device_bytes/")},
             "arena": bps.get_arena_stats(),
@@ -438,6 +440,15 @@ def test_shards_on_a_mesh_are_bitwise_the_whole_leaves():
         f"export/device_bytes/{d}" for d in range(8)]
     assert len({plan["device_bytes"][f"export/device_bytes/{d}"]
                 for d in range(1, 8)}) == 1
+    # a device's share is exactly an eighth of the sharded leaves'
+    # bytes, which are the bytes the whole-leaf step exported beyond
+    # what both steps export whole; the wire carries the same bytes
+    assert plan["device_bytes"]["export/device_bytes/7"] * 8 \
+        == plan["shard_bytes"]
+    assert plan["shard_bytes"] == off["whole_bytes"] - plan["whole_bytes"]
+    assert off["device_bytes"] == {
+        "export/device_bytes/0": off["whole_bytes"]}
+    assert plan["push_bytes"] == off["push_bytes"]
     n_leaves = len(plan["leaves"])
     assert plan["arena"]["export_shard_leaves"] == 4 * 3
     assert plan["arena"]["export_leaves"] == 4 * n_leaves

@@ -363,7 +363,7 @@ def test_e2e_timeseries_off_disarms_surface():
 
 
 def test_e2e_stripe_and_staleness_series_engaged():
-    """The ts_ab engaged-proof as a test: striped data conns (IPC off,
+    """The engaged-proof: striped data conns (IPC off,
     2 lanes, >=2MB leaves) + bounded staleness under the slow-server
     knob must land nonzero per-lane stripe series AND staleness-lag
     series, and STRIPE_PULL must answer over the wire."""

@@ -6,18 +6,25 @@ Checks: loss decreases through distributed_optimizer; plain-psum and ZeRO
 steps agree; tiny llama trains.
 """
 
+import gc
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax.sharding import Mesh
 
 from byteps_tpu.core.state import get_state
 from byteps_tpu.jax import distributed_optimizer
 from byteps_tpu.jax.train import (
-    make_train_step, make_zero_train_step, init_zero_state,
+    make_ps_train_step, make_train_step, make_zero_train_step,
+    init_zero_state,
 )
 from byteps_tpu.models import mlp, llama
+
+from test_chain import ENV, _plainly, _toy
+from test_export_spans import _ps_env
 
 
 def synthetic_classification(n=256, dim=784, classes=10, seed=0):
@@ -95,8 +102,7 @@ def test_tiny_llama_trains(bps):
 
 
 def test_fused_adam_matches_optax(bps):
-    """byteps_tpu.jax.optim.fused_adam_step (bench.py's fused_adam train
-    variant and the MFU harness share it) must track optax.adam: same
+    """byteps_tpu.jax.optim.fused_adam_step must track optax.adam: same
     loss trajectory and params within float tolerance after 5 steps."""
     from byteps_tpu.jax.optim import fused_adam_step
 
@@ -158,3 +164,38 @@ def test_llama_causality(bps):
     np.testing.assert_allclose(np.asarray(l1[0, :6]), np.asarray(l2[0, :6]),
                                atol=1e-5)
     assert not np.allclose(np.asarray(l1[0, 6:]), np.asarray(l2[0, 6:]))
+
+
+@pytest.mark.parametrize("cut", [False, True], ids=["one-program", "cut"])
+def test_a_steps_device_arrays_die_with_the_step(cut):
+    """With the collector off, no more device arrays are alive at the
+    end of step 6 than at the end of step 3: a step's gradients, pieces
+    and carried terms are locals of ``step`` and die with the call. A
+    step whose per-step state sits in objects that refer to themselves
+    keeps them until the collector runs, which on a chip is a step's
+    gradients held beside the next step's (PR 46's first version:
+    ``RESOURCE_EXHAUSTED`` on two cells, ROADMAP.md queue 3 item 4)."""
+    ch, params, batch = _toy()
+    loss = ch if cut else _plainly(ch)
+    tx = optax.adam(1e-2)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    params = jax.tree.map(jnp.array, params)  # the step donates its own
+    # a port of this file's own: test_chain.py's servers run beside it
+    with _ps_env(ENV, port=26000 + cut) as bps:
+        step = make_ps_train_step(loss, tx, mesh)
+        opt, alive = tx.init(params), []
+        before = bps.get_metrics()["counters"]
+        gc.collect()
+        gc.disable()
+        try:
+            for _ in range(6):
+                params, opt, value = step(params, opt, batch)
+                jax.block_until_ready((params, opt, value))
+                alive.append(len(jax.live_arrays()))
+        finally:
+            gc.enable()
+        after = bps.get_metrics()["counters"]
+    programs = after["export/backward_programs"] \
+        - before.get("export/backward_programs", 0)
+    assert programs == 6 * (7 if cut else 1)
+    assert alive[5] <= alive[2], alive

@@ -331,8 +331,7 @@ def test_single_stripe_death_fails_over():
 
 def test_wire_ring_off_parity():
     """BYTEPS_WIRE_RING=0 (per-message blocking send/recv, the legacy
-    wire) is bitwise identical to the batched default — the A/B lever
-    bench --phase stripe_ab leans on."""
+    wire) is bitwise identical to the batched default."""
     ringless = _battery({**_STRIPED_ENV, "BYTEPS_WIRE_RING": "0"})
     striped = _battery(_STRIPED_ENV)
     for leg in _LEGS:
