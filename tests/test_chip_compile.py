@@ -653,3 +653,65 @@ def test_a_cut_backwards_program_of_a_run_of_one_names_its_kernels_by_scope(
     assert g_carry.shape == carry.shape
     assert jax.tree.map(lambda g: g.shape, g_run) == jax.tree.map(
         lambda p: p.shape, run)
+
+
+@pytest.mark.parametrize("link, layer, kernel", [
+    (4, 1, "bps.attn.window"), (3, 0, "bps.attn.full")],
+    ids=["window-piece-of-two", "full-run-of-one"])
+def test_the_window_and_full_kernels_compile_behind_a_cut_backward(
+        v5e_host, monkeypatch, link, layer, kernel):
+    """``models/afmoe.py`` at Trinity-Mini's published widths, 4 rows of
+    8192: the program of one layer of the run of TWO sparse + sliding
+    layers (a piece: its gradient outputs are that layer's slices) and
+    of the run of ONE sparse + full layer differentiate the run's scan,
+    so the attention kernels are ``bps.attn.window`` / ``bps.attn.full``
+    and the grouped products ``ragged-dot.bps`` by name: the forward run
+    again under the block's remat, once more under the row's own
+    checkpoint (the attention sublayer walks the rows there), dK/dV and
+    dQ."""
+    import dataclasses
+
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from byteps_tpu.jax import train
+    from byteps_tpu.models import afmoe
+    from byteps_tpu.ops import chain
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(
+        afmoe.AfmoeConfig(), vocab_size=2048, n_experts_held=8,
+        n_dense_layers=1, layer_types=afmoe.published_layers()[1:6])
+    rows, seq = 4, 8192
+    mesh = Mesh(np.array(v5e_host[:1]), ("dp",))
+    rep, dp = NamedSharding(mesh, P()), NamedSharding(mesh, P("dp"))
+    params = jax.tree.map(
+        lambda v: _sds(v.shape, v.dtype, rep),
+        jax.eval_shape(lambda: afmoe.init_params(jax.random.PRNGKey(0), cfg)))
+    batch = {"tokens": _sds((rows, seq + 1), jnp.int32, dp)}
+    with chain.collecting() as found:
+        jax.eval_shape(lambda p, b: afmoe.loss_fn(p, b, cfg), params, batch)
+    (ch,) = found
+    assert [getattr(ln, "depth", None) for ln in ch.links] == \
+        [None, 1, 1, 1, 2, None]
+    cut = train._cut_backward(ch, mesh, "dp",
+                              train._chain_leaves(ch, params))
+    assert cut.programs == 8
+    depth = ch.links[link].depth
+    carry = _sds((1, rows, seq, cfg.dim), cfg.dtype, dp)
+    inputs = _sds((1, depth, rows, seq, cfg.dim), cfg.dtype, dp)
+    run = ch.links[link].pick(params)
+    compiled = cut.pulls[link].lower(run, np.int32(layer), inputs, batch,
+                                     carry).compile()
+    names = set(re.findall(r"%([\w.\-]*(?:bps\.attn|ragged-dot)[\w.\-]*) = ",
+                           compiled.as_text()))
+    assert names and all(
+        re.fullmatch(rf"({re.escape(kernel)}|ragged-dot\.bps)(\.\d+)*", n)
+        for n in names), sorted(names)
+    assert len({n for n in names if n.startswith("bps.attn")}) == 4
+    assert len({n for n in names if n.startswith("ragged-dot")}) >= 9
+    g_carry, g_run = jax.eval_shape(
+        cut.pulls[link], run, np.int32(layer), inputs, batch, carry)
+    assert g_carry.shape == carry.shape
+    assert jax.tree.map(lambda g: g.shape, g_run) == jax.tree.map(
+        lambda p: (1,) + p.shape[1:], run)
