@@ -445,7 +445,11 @@ class _CutBackward:
     leaves: Dict[int, Tuple[int, ...]]
     forward: Optional[Callable] = None
     last: Optional[Callable] = None
-    pulls: Dict[int, Callable] = dataclasses.field(default_factory=dict)
+    # link -> its program; a run's: one a distinct kind of its layers
+    # (``chain.Run.layer_kinds``), by kind
+    pulls: Dict[int, Any] = dataclasses.field(default_factory=dict)
+    # link -> the gradient outputs its programs hand over under a pinned
+    # layout in a step (a layer's program runs once a layer)
     pinned: Dict[int, int] = dataclasses.field(default_factory=dict)
     # XLA's cost analysis summed over a step's programs (the ledger's)
     cost: Dict[str, float] = dataclasses.field(default_factory=dict)
@@ -475,9 +479,8 @@ class _CutBackward:
     @property
     def outputs_pinned(self) -> int:
         """Gradient outputs a step's programs hand over under a pinned
-        layout: a layer's program runs once a layer."""
-        return sum(n * getattr(self.chain.links[k], "depth", 1)
-                   for k, n in self.pinned.items())
+        layout."""
+        return sum(self.pinned.values())
 
     @property
     def programs(self) -> int:
@@ -513,8 +516,11 @@ def _cut_backward(ch, mesh: Mesh, axis: str, leaves) -> _CutBackward:
     program a link (``ops/chain.py``): ``forward(params, batch) ->
     (kept, stats)``, ``last(p, kept[-1], batch) -> (((loss, stats),
     cotangent), gradients)`` and, for each link ``k`` before the last,
-    ``pulls[k](p, [layer,] kept[k], batch, cotangent) -> (cotangent,
-    gradients)``: ``_psum_backward``'s mathematics link by link, every
+    ``pulls[k](p, kept[k], batch, cotangent) -> (cotangent,
+    gradients)``, a run's ``pulls[k][kind](p, layer, kept[k], ...)``,
+    one program a distinct kind of its layers (one in all where they
+    have no kinds), shared by the layers of that kind through the traced
+    index: ``_psum_backward``'s mathematics link by link, every
     gradient psum'd over ``axis`` where it is computed. A carry is a
     data shard's own value: between programs it is a ``P(axis)`` array
     with the device as its leading dimension. Every program that has
@@ -579,11 +585,14 @@ def _cut_backward(ch, mesh: Mesh, axis: str, leaves) -> _CutBackward:
 
     def pull(k):
         if isinstance(ch.links[k], chain_mod.Run):
-            def layer(p, j, inputs, batch, ct):
-                g_x, g_p = ch.pull_layer(k, p, j, drop(inputs), batch,
-                                         drop(ct))
-                return lift(g_x), mean(g_p)
-            return program(layer, (rep, rep, loc, loc, loc), (loc, rep))
+            def layer_of(kind):
+                def layer(p, j, inputs, batch, ct):
+                    g_x, g_p = ch.pull_layer(k, p, j, drop(inputs), batch,
+                                             drop(ct), kind)
+                    return lift(g_x), mean(g_p)
+                return program(layer, (rep, rep, loc, loc, loc), (loc, rep))
+            return {kind: layer_of(kind)
+                    for kind in dict.fromkeys(ch.links[k].layer_kinds)}
 
         def whole(p, carry, batch, ct, *terms):
             g_carry, g_p = ch.pull_link(k, p, drop(carry), batch, drop(ct))
@@ -636,8 +645,9 @@ def _dispatch_cut(cut: _CutBackward, params, batch):
     for k in reversed(range(last)):
         p = links[k].pick(params)
         if isinstance(links[k], chain_mod.Run):
-            for j in reversed(range(links[k].depth)):
-                ct, grads = cut.pulls[k](p, np.int32(j), kept[k], batch, ct)
+            for j, kind in reversed(list(enumerate(links[k].layer_kinds))):
+                ct, grads = cut.pulls[k][kind](p, np.int32(j), kept[k],
+                                               batch, ct)
                 ran(k, j, ct, grads)
         else:
             taken = tuple(terms.pop(i) for i in cut.taken(k))
@@ -753,16 +763,18 @@ def _pin_cut_outputs(cut: _CutBackward, params, batch, own, mesh: Mesh,
         for name, value in (extract_cost(fn.lower(*args)) or {}).items():
             cut.cost[name] = cut.cost.get(name, 0.0) + times * value
 
-    def pin(k, program, *args):
+    def pin(k, program, *args, times=1):
+        """``program`` of link ``k``, which a step runs ``times``."""
         p = links[k].pick(params)
         taken = tuple(terms.pop(i) for i in cut.taken(k))
         args = args + ((taken,) if taken else ())
         held = cut.held(k)
-        fn, cut.pinned[k] = _row_major_outputs(
+        fn, pinned = _row_major_outputs(
             program, (p, *args),
             [n for n, i in enumerate(cut.leaves[k])
              if i in own and i not in held], mesh)
-        count(fn, (p, *args), getattr(links[k], "depth", 1))
+        cut.pinned[k] = cut.pinned.get(k, 0) + times * pinned
+        count(fn, (p, *args), times)
         carried, grads = fn.trace(p, *args).out_info
         grads = dict(zip(cut.leaves[k], jax.tree.leaves(grads)))
         terms.update((i, described(grads[i])) for i in held)
@@ -772,9 +784,17 @@ def _pin_cut_outputs(cut: _CutBackward, params, batch, own, mesh: Mesh,
     kept = described(cut.forward.trace(params, batch).out_info[0])
     cut.last, (_, ct) = pin(len(links) - 1, cut.last, kept[-1], batch)
     for k in reversed(range(len(links) - 1)):
-        layer = (np.int32(0),) if isinstance(links[k], chain_mod.Run) else ()
-        cut.pulls[k], ct = pin(k, cut.pulls[k], *layer, kept[k], batch,
-                               described(ct))
+        ct = described(ct)
+        if isinstance(links[k], chain_mod.Run):
+            # a kind's program once, in the order the step first runs them
+            kinds = links[k].layer_kinds
+            for kind in dict.fromkeys(reversed(kinds)):
+                cut.pulls[k][kind], carried = pin(
+                    k, cut.pulls[k][kind], np.int32(0), kept[k], batch, ct,
+                    times=kinds.count(kind))
+            ct = carried
+        else:
+            cut.pulls[k], ct = pin(k, cut.pulls[k], kept[k], batch, ct)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops import chain
 from ..ops.flash_attention import flash_attention, publish_walk_sizes
 from . import llama as L
 from . import moe
@@ -218,15 +219,25 @@ def moe_sublayer(x, p, cfg, ep_axis):
                      "moe/kernel_tile_rows": st["kernel_tile_rows"]}
 
 
-def _period(kinds: Tuple[str, ...]) -> int:
-    """The shortest period of the layer pattern that divides the depth:
-    the layer scan's body holds one block per kind in a period and is
-    compiled once, however many periods the model is deep."""
-    n = len(kinds)
-    for p in range(1, n + 1):
-        if n % p == 0 and all(kinds[i] == kinds[i % p] for i in range(n)):
-            return p
-    return n
+# the layer pattern's shortest period, where a run can use it
+_period = chain.period
+
+
+def _layers(cfg: MellumConfig, ep_axis) -> chain.Run:
+    """The run of ``n_layers`` blocks over ``params["blocks"]``, layer
+    ``j`` of kind ``layer_types[j]`` (its mask, its rotary table): a
+    scan over the periods of the pattern, one block a layer of a period
+    in its body, compiled once however many periods the model is deep.
+    Its statistics: the load ``[layers, n_held]``, the other counts
+    summed over the layers."""
+    return chain.Run(
+        lambda p, x, ropes, kind: _block(x, p, ropes, cfg, kind, ep_axis),
+        "blocks", cfg.n_layers, remat=cfg.remat,
+        kinds=tuple(cfg.layer_types[:cfg.n_layers]),
+        consts=lambda batch: rope_tables(
+            cfg, L.split_batch(batch)[0].shape[1]),
+        stats=lambda stats: {name: v if v.ndim == 2 else jnp.sum(v)
+                             for name, v in stats.items()})
 
 
 def forward_hidden(params: Dict[str, Any], tokens: jnp.ndarray,
@@ -234,31 +245,12 @@ def forward_hidden(params: Dict[str, Any], tokens: jnp.ndarray,
     """tokens [B, S] -> (final normed hidden [B, S, d], the step's
     statistics: the load [layers, n_held], the other counts summed over
     the layers)."""
-    B, S = tokens.shape
-    kinds = tuple(cfg.layer_types[:cfg.n_layers])
-    period = _period(kinds)
-    ropes = rope_tables(cfg, S)
-    x = params["embed"].astype(cfg.dtype)[tokens]
-    block = jax.checkpoint(_block, static_argnums=(3, 4, 5)) \
-        if cfg.remat else _block
-
-    def body(x, layers):
-        stats = []
-        for j in range(period):
-            p = jax.tree.map(lambda a: a[j], layers)
-            x, st = block(x, p, ropes, cfg, kinds[j], ep_axis)
-            stats.append(st)
-        return x, jax.tree.map(lambda *a: jnp.stack(a), *stats)
-
-    stacked = jax.tree.map(
-        lambda a: a.reshape(cfg.n_layers // period, period, *a.shape[1:]),
-        params["blocks"])
-    x, stats = jax.lax.scan(body, x, stacked)
-    x = L._rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    # the scan stacks [periods, period, ...]: a vector a layer (the
-    # load) keeps its layers, a scalar a layer is summed over them
-    return x, {name: v.reshape(cfg.n_layers, -1) if v.ndim == 3
-               else jnp.sum(v) for name, v in stats.items()}
+    layers = _layers(cfg, ep_axis)
+    x, stats = layers.scan(
+        params["blocks"], params["embed"].astype(cfg.dtype)[tokens],
+        rope_tables(cfg, tokens.shape[1]))
+    return (L._rmsnorm(x, params["final_norm"], cfg.norm_eps),
+            layers.stats(stats))
 
 
 def loss_fn(params: Dict[str, Any], batch: Dict[str, jnp.ndarray],
@@ -270,8 +262,21 @@ def loss_fn(params: Dict[str, Any], batch: Dict[str, jnp.ndarray],
     ``moe/full_slices``; all are counts, so they add up across data
     shards as the step makers need).
     batch: ``{"tokens"}`` (shifted here) or pre-shifted ``{"inputs",
-    "targets"}``."""
-    inputs, targets = L.split_batch(batch)
-    x, stats = forward_hidden(params, inputs, cfg, ep_axis)
-    logits = x @ params["lm_head"].astype(cfg.dtype)
-    return L.next_token_xent(logits, targets), stats
+    "targets"}``.
+
+    Written as a chain (``ops/chain.py``): the embedding, the run of
+    blocks, then the final norm, the head and the loss. Any step maker
+    runs it as the one program it was; ``make_ps_train_step`` cuts its
+    backward at the links, the sliding layers through one program and
+    the full ones through another."""
+    def embed(p, _, batch):
+        return p["embed"].astype(cfg.dtype)[L.split_batch(batch)[0]], {}
+
+    def head(p, x, batch):
+        x = L._rmsnorm(x, p["final_norm"], cfg.norm_eps)
+        logits = x @ p["lm_head"].astype(cfg.dtype)
+        return L.next_token_xent(logits, L.split_batch(batch)[1]), {}
+
+    return chain.Chain((
+        chain.Link(embed, "embed"), _layers(cfg, ep_axis),
+        chain.Link(head, ("final_norm", "lm_head"))))(params, batch)

@@ -12,7 +12,11 @@ the loss. Two kinds of link:
   several) of the parameter tree;
 - ``Run(block, key, depth, ...)``: a run of ``depth`` like layers whose
   leaves are stacked on a leading axis under ONE key, declared ONCE:
-  ``block(layer j's parameters, carry, consts)`` is one layer.
+  ``block(layer j's parameters, carry, consts)`` is one layer. Layers
+  that are alike in their leaves and unlike in something STATIC (a mask,
+  a rotary table: window, window, window, full) are one run too: it
+  declares ``kinds``, a label a layer, and the block takes its layer's
+  label last.
 
 A KEY says where in the tree a link's leaves lie: a top-level name
 (``"embed"``) or a path, a name and the positions below it (``("runs",
@@ -75,9 +79,11 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
-from typing import Any, Callable, Dict, List, Optional, Tuple, Union
+from typing import (Any, Callable, Dict, Hashable, List, Optional, Tuple,
+                    Union)
 
 import jax
+import jax.numpy as jnp
 
 # where a link's leaves lie: a top-level name, or a name and the steps
 # (names, positions) below it
@@ -118,6 +124,18 @@ def add_stats(stats: Dict[str, Any], more: Dict[str, Any]) -> Dict[str, Any]:
 
 def _path(key: Key) -> tuple:
     return (key,) if isinstance(key, str) else key
+
+
+def period(kinds) -> int:
+    """The shortest period of the pattern ``kinds`` that divides its
+    length: a scan over a run of unlike layers holds one block for each
+    layer of a period and is compiled once, however many periods the
+    run is deep."""
+    n = len(kinds)
+    for p in range(1, n + 1):
+        if n % p == 0 and all(kinds[i] == kinds[i % p] for i in range(n)):
+            return p
+    return n
 
 
 def _steps(path) -> tuple:
@@ -182,7 +200,15 @@ class Run(Link):
     bias), stacked on a leading axis of ``depth`` and scanned beside the
     leaves: the block is then ``fn(layer, carry, consts, layer j's
     slice)``; ``stats(stacked)`` turns the layers' statistics, stacked
-    on a leading axis, into the run's."""
+    on a leading axis, into the run's.
+
+    ``kinds``: ``depth`` hashable labels, layer ``j``'s STATIC kind
+    (what Python decides while the block is traced: a mask, which rotary
+    table); the block is then ``fn(layer, carry, consts[, the layer's
+    slice], kind)``. Such a run is a scan over the ``period`` of its
+    kinds, one block a layer of the period in its body (a scan over the
+    layers where all are of one kind); a cut backward runs one
+    executable a distinct kind, the layer's index traced."""
 
     depth: int = 1
     remat: bool = True
@@ -190,6 +216,17 @@ class Run(Link):
     consts: Optional[Callable] = None
     stats: Optional[Callable] = None
     each: Optional[Callable] = None
+    kinds: Optional[Tuple[Hashable, ...]] = None
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.kinds is not None:
+            object.__setattr__(self, "kinds", tuple(self.kinds))
+
+    @property
+    def layer_kinds(self) -> tuple:
+        """A kind a layer: ``kinds``, or None ``depth`` times."""
+        return (None,) * self.depth if self.kinds is None else self.kinds
 
     def _consts(self, batch):
         return batch if self.consts is None else self.consts(batch)
@@ -204,26 +241,60 @@ class Run(Link):
             p = p[step]
         return p
 
-    def scan(self, stacked, carry, consts, keep: bool = False, each=None):
+    def scan(self, stacked, carry, consts, keep: bool = False, each=None,
+             kinds=None):
         """The run over ``stacked`` (any depth; ``each``: the layers'
-        own slices, as deep) -> (carry, the layers' stacked
+        own slices, as deep; ``kinds``: those layers' kinds, the run's
+        own where not given) -> (carry, the layers' stacked
         statistics); ``keep``: the layers' INPUT carries, stacked,
         beside the statistics."""
-        def block(x, p, consts, *own):
-            return self.fn(p, x, consts, *own)
-
-        if self.remat:
-            block = jax.checkpoint(block)
+        kinds = self.kinds if kinds is None else tuple(kinds)
         depth = jax.tree.leaves(stacked)[0].shape[0]
+        if kinds is not None and len(kinds) != depth:
+            raise ValueError(
+                f"a run of {depth} layers has {len(kinds)} kinds: {kinds}")
 
-        def body(x, layer):
+        def block_of(*kind):
+            def block(x, p, consts, *own):
+                return self.fn(p, x, consts, *own, *kind)
+            return jax.checkpoint(block) if self.remat else block
+
+        def step(block, x, layer):
             p, *own = (layer,) if each is None else layer
             y, st = block(x, p, consts, *own)
             return y, ((x, st) if keep else st)
 
-        return jax.lax.scan(body, carry,
-                            stacked if each is None else (stacked, each),
-                            unroll=min(self.unroll, depth))
+        xs = stacked if each is None else (stacked, each)
+        span = 1 if kinds is None else period(kinds)
+        if span == 1:
+            block = block_of(*(() if kinds is None else kinds[:1]))
+
+            def body(x, layer):
+                return step(block, x, layer)
+
+            return jax.lax.scan(body, carry, xs,
+                                unroll=min(self.unroll, depth))
+
+        # layers of unlike kinds: a scan over the periods, a block of
+        # its own kind for each layer of one
+        of = {kind: block_of(kind) for kind in dict.fromkeys(kinds[:span])}
+        blocks = [of[kind] for kind in kinds[:span]]
+
+        def body(x, layers):
+            outs = []
+            for j, block in enumerate(blocks):
+                x, out = step(block, x,
+                              jax.tree.map(lambda a: a[j], layers))
+                outs.append(out)
+            return x, jax.tree.map(lambda *a: jnp.stack(a), *outs)
+
+        carry, outs = jax.lax.scan(
+            body, carry,
+            jax.tree.map(lambda a: a.reshape(depth // span, span,
+                                             *a.shape[1:]), xs),
+            unroll=min(self.unroll, depth // span))
+        return carry, jax.tree.map(
+            lambda a: a.reshape(depth, *a.shape[2:]), outs)
 
     def __call__(self, p, carry, batch):
         carry, stacked = self.scan(self.stacked(p), carry,
@@ -261,9 +332,10 @@ class Chain:
         more than one link, every key names a subtree, every LEAF of
         ``params`` lies under one link or under several whole links (not
         under a run and another link: a run's stacked leaf is handed
-        over a layer at a time), and every run is rematerialised and as
-        deep as its leaves. ``paths``: ``params`` flattened with paths,
-        where the caller has it."""
+        over a layer at a time), and every run is rematerialised, as
+        deep as its leaves and, where its layers have kinds, as deep as
+        those. ``paths``: ``params`` flattened with paths, where the
+        caller has it."""
         if len(self.links) < 2:
             return None
         try:
@@ -288,6 +360,7 @@ class Chain:
         if not all(ln.remat and all(
                 paths[i][1].ndim >= 1 and paths[i][1].shape[0] == ln.depth
                 for i in under[k])
+                and (ln.kinds is None or len(ln.kinds) == ln.depth)
                 for k, ln in enumerate(self.links) if isinstance(ln, Run)):
             return None
         return under
@@ -332,15 +405,17 @@ class Chain:
         g_p, g_carry = vjp(ct)
         return g_carry, g_p
 
-    def pull_layer(self, k: int, p, j, inputs, batch, ct):
+    def pull_layer(self, k: int, p, j, inputs, batch, ct, kind=None):
         """Layer ``j`` (traced) of run ``k`` (``p``: its stacked leaves
         as picked) at its kept input ``inputs[j]``, pulled back by
         ``ct``: (the cotangent of its input, the layer's gradients, each
-        ``[1, ...]``, in ``p``'s structure). What is
-        differentiated is the run's scan over ONE layer, not the bare
-        block, so that a kernel inside is named as in the uncut
-        program."""
+        ``[1, ...]``, in ``p``'s structure). ``kind`` (static): the
+        layer's, where the run's layers have ``kinds``; ``j`` is then
+        any layer of that kind. What is differentiated is the run's scan
+        over ONE layer, not the bare block, so that a kernel inside is
+        named as in the uncut program."""
         ln = self.links[k]
+        kinds = None if ln.kinds is None else (kind,)
 
         def layer(tree):
             return jax.tree.map(
@@ -353,7 +428,8 @@ class Chain:
             inputs)
         consts = ln._consts(batch)
         _, vjp = jax.vjp(
-            lambda p, c: ln.scan(ln.stacked(p), c, consts, each=each)[0],
+            lambda p, c: ln.scan(ln.stacked(p), c, consts, each=each,
+                                 kinds=kinds)[0],
             one, x)
         g_one, g_x = vjp(ct)
         return g_x, g_one

@@ -13,27 +13,44 @@ program a layer, and a run of one layer hands its leaves over whole; a
 chain whose runs live inside a LIST and whose embedding is also its head
 (a toy shaped like ``models/lfm2.py`` and ``models/joyai.py``) is cut
 too: a link's key may be a path, and the leaf under two links leaves
-once, its two terms summed on the device."""
+once, its two terms summed on the device; a run whose layers are of
+unlike static KINDS under one stacked key (a toy, then
+``models/mellum.py``'s window, window, window, full against the
+composition it replaced) is a period scan called plainly and is cut
+into one executable a kind; and a run WITHOUT kinds is the run it was:
+the cut programs of the five chained configurations that declare none
+trace to the parent commit's jaxprs. All of it in this one file: xdist
+hands out the files with the most tests first, and a late file of few
+slow tests is what the suite's limit cannot afford."""
 
+import collections
 import dataclasses
+import hashlib
+import importlib
 import itertools
+import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from byteps_tpu.ops import chain
 from byteps_tpu.jax.train import (ExportPlan, _chain_leaves, _cut_backward,
                                   _declare_shard_keys, _dispatch_cut,
                                   _export_plan, make_ps_train_step,
                                   make_train_step)
-from byteps_tpu.models import kimi, llama, sdar
+from byteps_tpu.models import kimi, llama, mellum, sdar
 from byteps_tpu.ops.push_pull import psum_tree
 
+from benchmark.layers._cell import _overlay
 from test_export_spans import _ps_env
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # every leaf of the tiny models but the norms rides keys of its own
 ENV = {"BYTEPS_FUSION_BYTES": "1024", "BYTEPS_SHARD_MIN_BYTES": "1024"}
@@ -199,6 +216,11 @@ def _chain_of(cfg):
      lambda cfg, p: (cfg, p, chain.Chain([
          chain.Link(None, ("embed", 0)), sdar._layers(cfg, None),
          chain.Link(None, ["final_norm", "lm_head"])]))),
+    ("a run with fewer kinds than layers",
+     lambda cfg, p: (cfg, p, chain.Chain([
+         chain.Link(None, "embed"),
+         dataclasses.replace(sdar._layers(cfg, None), kinds=("a", "b")),
+         chain.Link(None, ["final_norm", "lm_head"])]))),
 ], ids=lambda v: v.replace(" ", "-") if isinstance(v, str) else "")
 def test_what_a_chain_cannot_be_cut_over(why, change):
     cfg, params, _ = _sdar()
@@ -313,15 +335,17 @@ COUNTERS = ("export/backward_programs", "export/piece_bytes",
             "export/shared_carry_bytes")
 
 
-def _run_ps(loss, params, batch, steps=3, devices=1, env=None, **kw):
+def _run_ps(loss, params, batch, steps=3, devices=1, env=None, port=None,
+            **kw):
     """``steps`` PS steps of adam -> (params, opt state, losses, the
-    counters' growth, the last step's spans and reports)."""
+    counters' growth, the last step's spans and reports). ``port``: the
+    server's, one of the caller's own where another file calls."""
     from byteps_tpu.core.state import get_state
 
     tx = optax.adam(1e-2)
     mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
     params = jax.tree.map(jnp.array, params)  # the step donates its own
-    with _ps_env({**ENV, **(env or {})}, port=next(PORTS)) as bps:
+    with _ps_env({**ENV, **(env or {})}, port=port or next(PORTS)) as bps:
         step = make_ps_train_step(loss, tx, mesh, **kw)
         before = bps.get_metrics()["counters"]
         opt, losses = tx.init(params), []
@@ -978,3 +1002,493 @@ def test_a_shared_leaf_is_pushed_once_on_its_one_program_key(
         + params["extra"]["bias"].nbytes
     assert programs[1][4]["bytes"] == handed
     assert programs[-1][4]["bytes"] == params["embed"].nbytes
+
+
+# --------------------------------------------------------------------- #
+# a run whose layers are of unlike static kinds under one stacked key
+# --------------------------------------------------------------------- #
+
+KINDS = [("a", "b"), ("a", "a", "a", "b"), ("a", "a", "b", "a", "a", "b")]
+
+
+def _kinded(kinds, remat=True, d=8, V=11, seed=9):
+    """A chain whose one run holds layers of two kinds (the kind picks
+    the block's nonlinearity and which of the run's two constants it
+    reads, as a mask and a rotary table are picked), a row a layer
+    beside the leaves; and the period scan such a model had without
+    ``kinds`` (``models/mellum.py forward_hidden`` before PR 50): the
+    reference."""
+    n = len(kinds)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    params = {"embed": jax.random.normal(ks[0], (V, d)) * 0.5,
+              "blocks": {"w": jax.random.normal(ks[1], (n, d, d)) * 0.3,
+                         "norm": 1 + 0.1 * jax.random.normal(ks[2], (n, d))},
+              "head": jax.random.normal(ks[3], (d, V)) * 0.5}
+    batch = {"tokens": jax.random.randint(ks[4], (4, 9), 0, V)}
+    rows = 0.1 * np.asarray(jax.random.normal(ks[5], (n, d)))
+
+    def embed(p, _, batch):
+        return p["embed"][batch["tokens"][:, :-1]], {}
+
+    def block(p, x, scales, row, kind):
+        h = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-5) \
+            * p["norm"]
+        act = jnp.tanh if kind == "a" else jnp.sin
+        y = x + scales[kind] * act(h @ p["w"] + row)
+        return y, {"toy/positive": jnp.sum(y > 0, dtype=jnp.int32),
+                   "toy/mean": jnp.mean(y, axis=(0, 1))}
+
+    def head(p, x, batch):
+        logits = x @ p["head"]
+        targets = batch["tokens"][:, 1:]
+        nll = jax.scipy.special.logsumexp(logits, axis=-1) \
+            - jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        return jnp.sum(nll) / targets.size, {}
+
+    def scales(batch):
+        return {"a": jnp.float32(0.5), "b": jnp.float32(0.25)}
+
+    def total(stacked):
+        return {name: v if v.ndim == 2 else jnp.sum(v)
+                for name, v in stacked.items()}
+
+    ch = chain.Chain([
+        chain.Link(embed, "embed"),
+        chain.Run(block, "blocks", n, remat=remat, kinds=kinds,
+                  consts=scales, each=lambda batch: jnp.asarray(rows),
+                  stats=total),
+        chain.Link(head, "head")])
+
+    def period_scan(params, batch):
+        span = chain.period(kinds)
+        x, _ = embed(params, None, batch)
+        consts = scales(batch)
+        one = jax.checkpoint(block, static_argnums=(4,)) if remat else block
+
+        def body(x, layers):
+            stats = []
+            for j in range(span):
+                p, row = jax.tree.map(lambda a: a[j], layers)
+                x, st = one(p, x, consts, row, kinds[j])
+                stats.append(st)
+            return x, jax.tree.map(lambda *a: jnp.stack(a), *stats)
+
+        x, stats = jax.lax.scan(body, x, jax.tree.map(
+            lambda a: a.reshape(n // span, span, *a.shape[1:]),
+            (params["blocks"], jnp.asarray(rows))))
+        loss, _ = head(params, x, batch)
+        return loss, {name: v.reshape(n, -1) if v.ndim == 3 else jnp.sum(v)
+                      for name, v in stats.items()}
+
+    return ch, params, batch, period_scan
+
+
+W, F = mellum.SLIDING, mellum.FULL
+
+
+@pytest.mark.parametrize("kinds, want", [
+    ("ab", 2), ("aaab", 4), ("aabaab", 3), ("aaaaaa", 1), ("abb", 3),
+    ("a", 1), ("abab", 2), ("abababab", 2), ("aab", 3), ("abcabc", 3),
+    ("abcab", 5), ("aaabaaab", 4), ("aaabaaba", 8), ("abba", 4),
+    ((W, W, W, F) * 7, 4), ((W, F, W, F), 2), ((F,) * 6, 1),
+    ((W, F, F), 3), ((1, 2, 1, 2), 2), ((("w", 1024), ("f", None)) * 2, 2)],
+    ids=lambda v: None if isinstance(v, int) else "".join(map(str, v))[:24])
+def test_a_pattern_of_kinds_has_a_shortest_period(kinds, want):
+    """The shortest period that DIVIDES the length (a pattern that
+    repeats but for its tail does not repeat), of any hashable labels."""
+    assert chain.period(tuple(kinds)) == want
+    assert len(kinds) % want == 0
+    assert mellum._period is chain.period
+
+
+def _bare(kinds, depth=None, remat=True):
+    """A chain of ``embed``, a run of ``len(kinds)`` layers (or
+    ``depth``) and ``head`` over a tree of shapes: what ``cover`` and
+    ``_cut_backward`` look at, nothing traced."""
+    n = len(kinds) if depth is None else depth
+    tree = {"embed": jax.ShapeDtypeStruct((5, 4), jnp.float32),
+            "blocks": {"w": jax.ShapeDtypeStruct((n, 4, 4), jnp.float32)},
+            "head": jax.ShapeDtypeStruct((4, 5), jnp.float32)}
+    return chain.Chain([
+        chain.Link(None, "embed"),
+        chain.Run(None, "blocks", n, remat=remat, kinds=kinds),
+        chain.Link(None, "head")]), tree
+
+
+@pytest.mark.parametrize("kinds, depth, cuts", [
+    (None, 4, True), ("aaab", None, True), ("abab", None, True),
+    ("aaaa", None, True), ("a", None, True), (["x", "y"], None, True),
+    ("aaab", 3, False), ("aaab", 5, False), ("ab", 4, False),
+    ((), 2, False)], ids=str)
+def test_cover_asks_of_a_run_with_kinds_that_they_are_as_deep(
+        kinds, depth, cuts):
+    ch, tree = _bare(kinds, depth)
+    assert ch.cuts(tree) == cuts
+    assert (_chain_leaves(ch, tree) is not None) == cuts
+    # remat off keeps any run whole, kinds or none
+    assert not _bare(kinds, depth, remat=False)[0].cuts(tree)
+    run = ch.links[1]
+    assert run.kinds is None or isinstance(run.kinds, tuple)
+    assert len(run.layer_kinds) == (run.depth if kinds is None
+                                    else len(kinds))
+
+
+@pytest.mark.parametrize("kinds, executables", [
+    (None, [None]), ("aaab", ["a", "b"]), ("baaa", ["b", "a"]),
+    ("abab", ["a", "b"]), ("aaaa", ["a"]), ("abc", ["a", "b", "c"]),
+    ("abcabc", ["a", "b", "c"]), ((W, W, W, F), [W, F]),
+    ((W, W, W, F) * 2, [W, F]), ((F, W), [F, W])], ids=str)
+def test_a_cut_backward_builds_one_layer_program_a_distinct_kind(
+        kinds, executables):
+    """However deep the run: the table is keyed by kind in the order
+    the kinds first appear; the step's count of programs is the
+    depth's alone (forward, head, a program a layer, lookup)."""
+    depth = 4 if kinds is None else None
+    ch, tree = _bare(kinds, depth)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    cut = _cut_backward(ch, mesh, "dp", _chain_leaves(ch, tree))
+    assert list(cut.pulls[1]) == executables
+    assert callable(cut.pulls[0]) and set(cut.pulls) == {0, 1}
+    assert cut.programs == ch.links[1].depth + 3
+    assert cut.shared == {} and cut.outputs_pinned == 0
+
+
+@pytest.mark.parametrize("kinds, remat", [
+    *((kinds, True) for kinds in KINDS), (KINDS[1], False)],
+    ids=lambda v: "".join(v) if isinstance(v, tuple) else f"remat-{v}")
+def test_a_run_with_kinds_called_plainly_is_the_period_scan(kinds, remat):
+    ch, params, batch, period_scan = _kinded(kinds, remat)
+    (loss, stats), grads = jax.jit(jax.value_and_grad(
+        ch, has_aux=True))(params, batch)
+    (want, want_stats), want_grads = jax.jit(jax.value_and_grad(
+        period_scan, has_aux=True))(params, batch)
+    assert float(loss) == float(want) and float(loss) > 0
+    _assert_trees_equal(stats, want_stats)
+    assert stats["toy/mean"].shape == (len(kinds), 8)
+    _assert_trees_equal(grads, want_grads)
+    assert all(np.any(np.asarray(g)) for g in jax.tree.leaves(grads))
+    # the kept carries are the layers' inputs, one a layer
+    kept, _ = ch.forward(params, batch)
+    assert kept[1].shape == (len(kinds), 4, 8, 8)
+    # remat off, as ever, keeps the backward one program
+    assert ch.cuts(params) == remat
+    with pytest.raises(ValueError, match="kinds"):
+        ch.links[1].scan(params["blocks"], kept[1][0], None, kinds=("a",))
+
+
+@pytest.mark.parametrize("kinds, devices", [
+    (KINDS[1], 1), (KINDS[1], 2), (KINDS[2], 1)],
+    ids=lambda v: "".join(v) if isinstance(v, tuple) else f"{v}dev")
+def test_the_cut_programs_over_unlike_kinds_give_the_one_programs(
+        kinds, devices):
+    """Forward, head, a program a layer, embedding: ``depth + 3``
+    programs a step, the layers of a kind
+    through ONE executable (the layer's index is traced, its kind is
+    not); loss, statistics and every gradient the one program's."""
+    from byteps_tpu.jax.train import (_loss_and_stats, _pin_cut_outputs,
+                                      _psum_backward)
+
+    ch, params, batch, _ = _kinded(kinds)
+    n = len(kinds)
+    mesh = Mesh(np.array(jax.devices()[:devices]), ("dp",))
+    whole = _psum_backward(_loss_and_stats(_plainly(ch)), mesh, "dp")
+    (want, want_stats), want_grads = whole(params, batch)
+    cut = _cut_backward(ch, mesh, "dp", _chain_leaves(ch, params))
+    assert cut.programs == n + 3
+    # one executable a distinct kind, whatever the depth
+    assert list(cut.pulls[1]) == ["a", "b"]
+    # as the step builds them: each kind's layouts looked at, its cost
+    # counted once a layer of the kind; ``w`` rides keys of its own
+    _pin_cut_outputs(cut, params, batch, {1}, mesh, "dp")
+    assert cut.cost["flops"] > 0 and cut.outputs_pinned == 0
+    (got, stats), programs = _dispatch_cut(cut, params, batch)
+    assert [(links, layer) for links, layer, _, _ in programs] == [
+        ("0-1", None), ("2", None), *(("1", j) for j in reversed(range(n))),
+        ("0", None)]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    _assert_trees_equal(stats, want_stats)
+    flat = jax.tree.leaves(want_grads)
+    seen = set()
+    for _, layer, _, outs in programs:
+        for i, g in outs.items():
+            w = np.asarray(flat[i])
+            if layer is not None:
+                assert g.shape == (1,) + w.shape[1:]
+                w = w[layer:layer + 1]
+            np.testing.assert_array_equal(np.asarray(g), w, err_msg=str(i))
+            seen.add((i, layer))
+    assert len(seen) == 2 + 2 * n
+    # a layer's program run at the other kind is another function
+    p = ch.links[1].pick(params)
+    kept, _ = ch.forward(params, batch)
+    ct = jnp.ones_like(kept[1][0])
+    last = n - 1
+    right, wrong = (ch.pull_layer(1, p, last, kept[1], batch, ct, kind)[0]
+                    for kind in (kinds[last], "a"))
+    assert float(jnp.abs(right - wrong).max()) > 1e-3
+
+
+# --------------------------------------------------------------------- #
+# models/mellum.py as a chain: window, window, window, full under one
+# stacked key, against the composition it replaced (kept here)
+# --------------------------------------------------------------------- #
+
+
+def _mellums_parent(params, batch, cfg, ep_axis=None):
+    """``mellum.loss_fn`` as it stood before it was a chain: the period
+    scan written out in ``forward_hidden``, the head after it."""
+    inputs, targets = llama.split_batch(batch)
+    kinds = tuple(cfg.layer_types[:cfg.n_layers])
+    period = mellum._period(kinds)
+    ropes = mellum.rope_tables(cfg, inputs.shape[1])
+    x = params["embed"].astype(cfg.dtype)[inputs]
+    block = jax.checkpoint(mellum._block, static_argnums=(3, 4, 5)) \
+        if cfg.remat else mellum._block
+
+    def body(x, layers):
+        stats = []
+        for j in range(period):
+            p = jax.tree.map(lambda a: a[j], layers)
+            x, st = block(x, p, ropes, cfg, kinds[j], ep_axis)
+            stats.append(st)
+        return x, jax.tree.map(lambda *a: jnp.stack(a), *stats)
+
+    stacked = jax.tree.map(
+        lambda a: a.reshape(cfg.n_layers // period, period, *a.shape[1:]),
+        params["blocks"])
+    x, stats = jax.lax.scan(body, x, stacked)
+    x = llama._rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    logits = x @ params["lm_head"].astype(cfg.dtype)
+    return llama.next_token_xent(logits, targets), {
+        name: v.reshape(cfg.n_layers, -1) if v.ndim == 3 else jnp.sum(v)
+        for name, v in stats.items()}
+
+
+def _mellum(remat=True, held=4, kinds=None, seed=11):
+    kinds = kinds or (mellum.SLIDING,) * 3 + (mellum.FULL,)
+    cfg = dataclasses.replace(
+        mellum.MellumConfig.tiny(), remat=remat, n_experts_held=held,
+        n_layers=len(kinds), layer_types=kinds)
+    key = jax.random.PRNGKey(seed)
+    params = mellum.init_params(key, cfg)
+    # norms off one, so that their gradients are no accident
+    params = jax.tree.map(
+        lambda a: a + 0.1 * jax.random.normal(key, a.shape, a.dtype)
+        if a.shape[-1] == cfg.dim and a.ndim <= 2 else a, params)
+    batch = {"tokens": jax.random.randint(key, (2, 33), 0, cfg.vocab_size)}
+    return cfg, params, batch
+
+
+def test_mellums_loss_as_a_chain_is_the_composition_it_replaced():
+    """Window, window, window, full, remat on, four of eight experts
+    held; two held: the PS steps below."""
+    held = 4
+    cfg, params, batch = _mellum(held=held)
+    (loss, stats), grads = jax.jit(jax.value_and_grad(
+        lambda p, b: mellum.loss_fn(p, b, cfg), has_aux=True))(params, batch)
+    (want, want_stats), want_grads = jax.jit(jax.value_and_grad(
+        lambda p, b: _mellums_parent(p, b, cfg), has_aux=True))(params, batch)
+    assert float(loss) == float(want) and float(loss) > 0
+    _assert_trees_equal(stats, want_stats)
+    assert stats["moe/expert_load"].shape == (cfg.n_layers, held)
+    _assert_trees_equal(grads, want_grads)
+    assert all(np.any(np.asarray(g)) for g in jax.tree.leaves(grads))
+    hidden, _ = mellum.forward_hidden(params, batch["tokens"][:, :-1], cfg)
+    assert hidden.shape == (2, 32, cfg.dim)
+
+
+def _canonical(text):
+    """A lowered program as the multiset of its operations, the values'
+    and the private functions' numbering left out: two programs that
+    hold the same operations in another order read alike."""
+    lines = collections.Counter()
+    for line in text.splitlines():
+        line = re.sub(r"%[\w#:]+", "%", line)
+        lines[re.sub(r"@([A-Za-z_]+?)_\d+\b", r"@\1", line).strip()] += 1
+    return lines
+
+
+def test_the_fused_steps_program_is_the_compositions_but_for_five_sums():
+    """Lowered for a rematerialised period of four, the chain called
+    plainly holds the operations the composition it replaced holds, one
+    for one (the targets' slice stands later in the text: the head
+    reads the batch itself), except that the five counts a layer are
+    summed over ``[layers]`` where they were summed over ``[periods,
+    period]``: a reshape of four integers each, nothing of the model."""
+    cfg, params, batch = _mellum()
+
+    def lowered(loss):
+        return _canonical(jax.jit(jax.value_and_grad(
+            lambda p, b: loss(p, b, cfg), has_aux=True)).lower(
+                params, batch).as_text())
+
+    got, want = lowered(mellum.loss_fn), lowered(_mellums_parent)
+    assert sum(want.values()) > 3000
+    gone, new = want - got, got - want
+    assert sum(gone.values()) == 5 and sum(new.values()) == 10
+    assert all("stablehlo.reduce" in line and "tensor<1x4xi32>" in line
+               for line in gone)
+    assert all(("stablehlo.reduce" in line or "stablehlo.reshape" in line)
+               and "tensor<4xi32>" in line for line in new)
+
+
+@pytest.fixture(scope="module")
+def mellum_cut_and_whole():
+    """Two steps of the chained loss (cut) and of the composition it
+    replaced (no chain: one program), rematerialised, two of eight
+    experts held."""
+    cfg, params, batch = _mellum(held=2)
+    n_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+    cut = _run_ps(lambda p, b: mellum.loss_fn(p, b, cfg), params, batch,
+                  steps=2)
+    whole = _run_ps(lambda p, b: _mellums_parent(p, b, cfg), params, batch,
+                    steps=2)
+    return cut, whole, n_bytes, params
+
+
+def test_mellums_cut_step_is_its_one_program_step_bit_for_bit(
+        mellum_cut_and_whole):
+    cut, whole, _, _ = mellum_cut_and_whole
+    assert cut["losses"] == whole["losses"]
+    assert cut["losses"][-1] < cut["losses"][0]
+    _assert_trees_equal(cut["params"], whole["params"])
+    _assert_trees_equal(cut["opt"], whole["opt"])
+
+
+def test_mellums_cut_step_pushes_every_byte_once_under_its_keys(
+        mellum_cut_and_whole):
+    cut, whole, n_bytes, params = mellum_cut_and_whole
+    for run in (cut, whole):
+        assert run["grew"]["wire/push_bytes"] == 2 * n_bytes
+        assert run["grew"]["export/whole_bytes"] == 2 * n_bytes
+    # forward, head, four layers, embedding; one where nothing is cut
+    assert cut["grew"]["export/backward_programs"] == 2 * 7
+    assert whole["grew"]["export/backward_programs"] == 2
+    programs = sorted((s for s in cut["spans"]
+                       if s[0] == "bps.step.backward_program"),
+                      key=lambda s: s[2])
+    assert [s[4]["links"] for s in programs] == [
+        "0-1", "2", "1", "1", "1", "1", "0"]
+    # the eight weights of a layer leave as pieces, the two norms whole
+    blocks = params["blocks"]
+    pieces = {k: v for k, v in blocks.items() if "norm" not in k}
+    assert cut["grew"]["export/piece_bytes"] \
+        == 2 * sum(v.nbytes for v in pieces.values())
+    assert cut["grew"]["export/under_backward_bytes"] \
+        >= 2 * params["lm_head"].nbytes
+    assert whole["grew"]["export/piece_bytes"] == 0
+    # the keys: a piece a layer of each stacked weight, whatever the
+    # layer's kind; every other key the one-program step's
+    assert {n for n in cut["keys"] if "@shard" in n} == {
+        f"grad/blocks/{k}@shard{j}of4" for k in pieces for j in range(4)}
+    assert {n for n in cut["keys"] if "@shard" not in n} \
+        == set(whole["keys"]) - {f"grad/blocks/{k}" for k in pieces}
+    assert {n for n in cut["keys"] if n.startswith("fused/")} == \
+        {n for n in whole["keys"] if n.startswith("fused/")} != set()
+
+
+# --------------------------------------------------------------------- #
+# a run without kinds is the run it was: every program of the cut
+# backward that runs a run's code, for each chained configuration that
+# declares none (the
+# benchmark's rehearsal sizes, remat on) traces to the jaxpr it traced
+# to at the parent commit ``dc8d088``. ``PARENTS`` holds that commit's
+# digests, taken there by this ``cut_program_texts`` (the parent's
+# ``cut.pulls[k]`` was the one program, not a table of one)
+# --------------------------------------------------------------------- #
+
+# sha256 (16 digits) of each program's jaxpr, addresses left out
+PARENTS = {
+    "sdar-30b-a3b": {
+        "forward": "15970e952ced98eb", "link1": "19dee5ce0fb56e26",
+    },
+    "lfm2-8b-a1b": {
+        "forward": "d02fb6b69f0ca25a", "link3": "5bde70ef05674c03",
+        "link2": "9246f0427c7d6d72", "link1": "51ec8e4f94790c57",
+    },
+    "joyai-llm-flash": {
+        "forward": "911ad3086b7036ef", "link2": "5d4d30fc03a183aa",
+        "link1": "75368e18364b7027",
+    },
+    "kimi-linear-48b-a3b": {
+        "forward": "ec1e75c7c20ec62c", "link4": "de0a876e71151ddf",
+        "link3": "2268a13a4bab3ea8", "link2": "09a0a0c6fef77329",
+        "link1": "f12b69637be4b0ce",
+    },
+    "trinity-mini": {
+        "forward": "3da576728337a740", "link4": "2fcc52687a97d6ac",
+        "link3": "9b2f18c088962fce", "link2": "68d1a2503b73073e",
+        "link1": "773dfabfccc0c4da",
+    },
+}
+
+
+def cut_program_texts(loss, params, batch):
+    """Label -> jaxpr text of the programs ``_cut_backward`` builds for
+    the chain ``loss`` calls that run a RUN's code (``Run.scan``,
+    ``Chain.forward``, ``Chain.pull_layer``): the forward and each
+    run's layer programs, traced on the shapes the step hands them, on
+    one device. (The last link's program and a whole link's go through
+    none of it, and tracing a head costs as much as the rest.)"""
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    # the loss's trace is abandoned where it calls its chain
+    with chain.collecting(first=True) as found:
+        jax.eval_shape(loss, params, batch)
+    (ch,) = found
+    cut = _cut_backward(ch, mesh, "dp", _chain_leaves(ch, params))
+    local = NamedSharding(mesh, P("dp"))
+
+    def text(traced):
+        return re.sub(r"0x[0-9a-f]+", "", str(traced.jaxpr))
+
+    traced = cut.forward.trace(params, batch)
+    out = {"forward": text(traced)}
+    for k, ln in enumerate(ch.links):
+        if not isinstance(ln, chain.Run):
+            continue
+        # the layers' kept inputs [device, depth, ...]; a layer's
+        # cotangent has its carry's shape
+        kept = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=local), traced.out_info[0][k])
+        ct = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape[:1] + a.shape[2:], a.dtype, sharding=local), kept)
+        for kind, fn in cut.pulls[k].items():
+            label = f"link{k}" if kind is None else f"link{k}.{kind}"
+            out[label] = text(fn.trace(ln.pick(params), np.int32(0), kept,
+                                       batch, ct))
+    return out
+
+
+def _rehearsal(name):
+    """A configuration at its rehearsal sizes, rematerialised: the
+    program's loss, its weights' shapes and two rows'."""
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           name + ".json")) as f:
+        cfg = json.load(f)
+    cfg = _overlay(cfg, cfg["rehearse"])
+    cfg.update(remat=True)
+    family = importlib.import_module(f"benchmark.families.{cfg['family']}")
+    key = jax.random.PRNGKey(7)
+    # shapes alone: nothing runs
+    return (family.program_loss(cfg),
+            jax.eval_shape(lambda: family.reference.init_params(key, cfg)),
+            jax.eval_shape(lambda: family.reference.make_batch(
+                key, 0, 2, cfg)))
+
+
+@pytest.mark.parametrize("name", sorted(PARENTS))
+def test_a_run_without_kinds_is_cut_into_the_parents_programs(name):
+    texts = cut_program_texts(*_rehearsal(name))
+    got = {label: hashlib.sha256(t.encode()).hexdigest()[:16]
+           for label, t in texts.items()}
+    assert got == PARENTS[name]
+
+
+def test_mellums_run_is_cut_into_a_program_a_kind():
+    """The same walk over the one configuration that declares kinds:
+    forward, head, ONE program for the three window layers, one for the
+    full layer, the lookup; the two layer programs differ."""
+    texts = cut_program_texts(*_rehearsal("mellum2-12b"))
+    assert list(texts) == [
+        "forward", "link1.sliding_attention", "link1.full_attention"]
+    assert texts["link1.sliding_attention"] != texts["link1.full_attention"]

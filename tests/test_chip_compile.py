@@ -558,7 +558,7 @@ def test_a_cut_backwards_layer_program_names_its_kernels_by_scope_alone(
     carry = _sds((1, rows, 2 * seq, cfg.dim), cfg.dtype, dp)
     inputs = _sds((1, cfg.n_layers, rows, 2 * seq, cfg.dim), cfg.dtype, dp)
     blocks = ch.links[1].pick(params)
-    compiled = cut.pulls[1].lower(blocks, np.int32(1), inputs, batch,
+    compiled = cut.pulls[1][None].lower(blocks, np.int32(1), inputs, batch,
                                   carry).compile()
     text = compiled.as_text()
     names = set(re.findall(r"%([\w.\-]*(?:bps\.attn|ragged-dot)[\w.\-]*) = ",
@@ -571,7 +571,7 @@ def test_a_cut_backwards_layer_program_names_its_kernels_by_scope_alone(
     assert len({n for n in names if n.startswith("bps.attn")}) == 3
     assert len({n for n in names if n.startswith("ragged-dot")}) >= 9
     g_carry, g_blocks = jax.eval_shape(
-        cut.pulls[1], blocks, np.int32(1), inputs, batch, carry)
+        cut.pulls[1][None], blocks, np.int32(1), inputs, batch, carry)
     assert g_carry.shape == carry.shape
     assert jax.tree.map(lambda g: g.shape, g_blocks) == jax.tree.map(
         lambda p: (1,) + p.shape[1:], blocks)
@@ -638,7 +638,7 @@ def test_a_cut_backwards_program_of_a_run_of_one_names_its_kernels_by_scope(
     carry = _sds((1, rows, seq, cfg.dim), cfg.dtype, dp)
     inputs = _sds((1, 1, rows, seq, cfg.dim), cfg.dtype, dp)
     run = ch.links[4].pick(params)
-    compiled = cut.pulls[4].lower(run, np.int32(0), inputs, batch,
+    compiled = cut.pulls[4][None].lower(run, np.int32(0), inputs, batch,
                                   carry).compile()
     names = set(re.findall(r"%([\w.\-]*(?:bps\.attn|ragged-dot)[\w.\-]*) = ",
                            compiled.as_text()))
@@ -649,7 +649,7 @@ def test_a_cut_backwards_program_of_a_run_of_one_names_its_kernels_by_scope(
     # states kept (the operator's own checkpoint), and the backward
     assert len({n for n in names if n.startswith("bps.attn")}) == 3
     g_carry, g_run = jax.eval_shape(
-        cut.pulls[4], run, np.int32(0), inputs, batch, carry)
+        cut.pulls[4][None], run, np.int32(0), inputs, batch, carry)
     assert g_carry.shape == carry.shape
     assert jax.tree.map(lambda g: g.shape, g_run) == jax.tree.map(
         lambda p: p.shape, run)
@@ -701,7 +701,7 @@ def test_the_window_and_full_kernels_compile_behind_a_cut_backward(
     carry = _sds((1, rows, seq, cfg.dim), cfg.dtype, dp)
     inputs = _sds((1, depth, rows, seq, cfg.dim), cfg.dtype, dp)
     run = ch.links[link].pick(params)
-    compiled = cut.pulls[link].lower(run, np.int32(layer), inputs, batch,
+    compiled = cut.pulls[link][None].lower(run, np.int32(layer), inputs, batch,
                                      carry).compile()
     names = set(re.findall(r"%([\w.\-]*(?:bps\.attn|ragged-dot)[\w.\-]*) = ",
                            compiled.as_text()))
@@ -711,7 +711,7 @@ def test_the_window_and_full_kernels_compile_behind_a_cut_backward(
     assert len({n for n in names if n.startswith("bps.attn")}) == 4
     assert len({n for n in names if n.startswith("ragged-dot")}) >= 9
     g_carry, g_run = jax.eval_shape(
-        cut.pulls[link], run, np.int32(layer), inputs, batch, carry)
+        cut.pulls[link][None], run, np.int32(layer), inputs, batch, carry)
     assert g_carry.shape == carry.shape
     assert jax.tree.map(lambda g: g.shape, g_run) == jax.tree.map(
         lambda p: (1,) + p.shape[1:], run)

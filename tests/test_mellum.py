@@ -3,7 +3,8 @@
 small sizes with seeded random weights: loss and per-leaf gradients,
 fused and through the PS step with a loopback server; the rotary tables
 against the written-out formula; ``(loss, stats)`` through both step
-makers, a scalar loss still accepted."""
+makers, a scalar loss still accepted. (The loss as a chain and its cut
+backward: ``tests/test_chain.py``.)"""
 
 import contextlib
 import json
@@ -247,9 +248,15 @@ def test_ps_step_matches_the_reference_and_folds_the_statistics(
         lr = 0.5
         step = make_ps_train_step(loss_fn, optax.sgd(lr), _one_device_mesh())
         before = _counters(bps)
+        programs = bps.get_metrics()["counters"].get(
+            "export/backward_programs", 0)
         with jax.default_matmul_precision("highest"):
             new, _, loss = step(params, optax.sgd(lr).init(params), batch)
         after = _counters(bps)
+        # the loss is a chain, but its run keeps its residuals (remat
+        # off at these sizes): the backward stays one program
+        assert bps.get_metrics()["counters"]["export/backward_programs"] \
+            - programs == 1
     np.testing.assert_allclose(float(loss), float(want), rtol=1e-5)
     for (path, p0), p1, g in zip(
             jax.tree_util.tree_leaves_with_path(start),
@@ -338,3 +345,4 @@ def test_a_scalar_loss_is_still_accepted(maker):
             out = step(params, tx.init(params), batch)
     assert len(out) == 3
     np.testing.assert_allclose(float(out[2]), want, rtol=1e-6)
+
